@@ -36,7 +36,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from raft_tpu.analysis.core import Finding, Workspace
 
-CLI_SCOPE = ("raft_tpu/cli", "scripts", "raft_tpu/convert.py")
+CLI_SCOPE = ("raft_tpu/cli", "scripts", "raft_tpu/convert.py",
+             "chip_smoke.py")
 DOC_SCOPE = ("README.md", "docs")
 CONFIG_CLASSES = {
     "RAFTConfig": "raft_tpu/config.py",

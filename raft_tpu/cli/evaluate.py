@@ -170,6 +170,9 @@ def main(argv=None):
 
     from raft_tpu import evaluate
     from raft_tpu.config import RAFTConfig
+    from raft_tpu.utils.profiling import enable_persistent_compile_cache
+
+    enable_persistent_compile_cache()
 
     compute_dtype = "bfloat16" if args.precision == "bf16" else "float32"
     mk = RAFTConfig.small_model if args.small else RAFTConfig.full

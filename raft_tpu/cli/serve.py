@@ -500,6 +500,9 @@ def main(argv=None):
 
     from raft_tpu.config import RAFTConfig
     from raft_tpu.serve import InferenceEngine, ServeConfig
+    from raft_tpu.utils.profiling import enable_persistent_compile_cache
+
+    enable_persistent_compile_cache()
 
     mk = RAFTConfig.small_model if args.small else RAFTConfig.full
     model_cfg = mk(compute_dtype="bfloat16" if args.precision == "bf16"

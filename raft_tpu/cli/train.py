@@ -269,6 +269,16 @@ def resolve_batch(batch_size, batch_per_chip, num_devices, lr):
     return rounded, lr
 
 
+def default_corr_impl() -> str:
+    """What ``--corr_impl auto`` trains with on this backend: the fused
+    Pallas pyramid lookup on TPU, the XLA einsum lookup elsewhere (no
+    interpret-mode Pallas in a training loop)."""
+    import jax
+
+    return "allpairs_pallas" if jax.default_backend() == "tpu" \
+        else "allpairs"
+
+
 def run(argv=None):
     """Parse flags, build the stage, and train; returns the final
     :class:`TrainState` (the curriculum driver consumes it — the
@@ -297,16 +307,17 @@ def run(argv=None):
         # (ShardedLoader host_id below).
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
             # Multi-process CPU "pods" (CI, local rehearsal of the pod
-            # flow) need an explicit collectives backend on jaxlib >=
-            # 0.4.34 — without it jitted collectives die with
-            # "Multiprocess computations aren't implemented on the CPU
-            # backend".  Gloo ships in the wheel.
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:  # older jax: flag absent, CPU built in
-                pass
+            # flow) need an explicit collectives backend — without it
+            # jitted collectives die with "Multiprocess computations
+            # aren't implemented on the CPU backend".  Gloo ships in the
+            # jaxlib wheel.
+            jax.config.update("jax_cpu_collectives_implementation",
+                              "gloo")
         jax.distributed.initialize()
+
+    from raft_tpu.utils.profiling import enable_persistent_compile_cache
+
+    enable_persistent_compile_cache()
 
     from raft_tpu import evaluate
     from raft_tpu.config import RAFTConfig, TrainConfig
@@ -320,8 +331,9 @@ def run(argv=None):
     compute_dtype = "bfloat16" if args.precision == "bf16" else "float32"
     corr_impl = args.corr_impl
     if corr_impl == "auto":
-        corr_impl = ("allpairs_pallas" if jax.default_backend() == "tpu"
-                     else "allpairs")
+        corr_impl = default_corr_impl()
+    print(f"corr_impl: {args.corr_impl} -> {corr_impl} "
+          f"(backend {jax.default_backend()})", flush=True)
     from raft_tpu.config import QUANTIZED_CORR_DTYPES
 
     if (args.corr_dtype in QUANTIZED_CORR_DTYPES

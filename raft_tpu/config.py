@@ -92,10 +92,11 @@ class RAFTConfig:
     # 'allpairs' materializes the pyramid (reference CorrBlock, corr.py:12-60)
     # and samples it with XLA einsums; 'allpairs_pallas' materializes the
     # same pyramid but samples it with a fused Pallas VPU kernel (both
-    # interpolation stages in VMEM) — faster for training crops (17.5 vs
-    # 16.2 pairs/s/chip at 368x496 batch 12 on v5e) while 'allpairs' wins
-    # at wide eval shapes (Sintel W/8=128 fills the MXU lane tile: 12.0
-    # vs 10.4 frames/s); 'chunked' is the memory-efficient blockwise path
+    # interpolation stages in VMEM) — it won at training crops in the
+    # round-1..4 sessions while 'allpairs' won at wide eval shapes
+    # (Sintel W/8=128 fills the MXU lane tile); neither margin is
+    # measured on today's code.  'chunked' is the memory-efficient
+    # blockwise path
     # (reference AlternateCorrBlock + alt_cuda_corr, corr.py:63-91);
     # 'pallas' is the fused TPU kernel version of 'chunked'.
     corr_impl: str = "allpairs"

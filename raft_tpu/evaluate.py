@@ -98,8 +98,8 @@ def make_eval_fn(model_cfg: RAFTConfig, iters: int):
 
     def capture_cost(variables, image1, image2):
         """Compile-time cost of the no-init forward at this shape
-        (obs/cost.py) — one extra ``lower().compile()``, cheap under
-        the persistent compile cache; host metadata only.  The cost
+        (obs/cost.py) — one extra ``lower().compile()`` (a full
+        compile of the forward); host metadata only.  The cost
         CLI and bench.py's eval arm call this directly."""
         from raft_tpu.obs import cost as cost_mod
 

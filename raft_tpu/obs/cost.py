@@ -246,8 +246,9 @@ def program_cost(compiled_or_fn, *args, program: str,
 
     Pass either an already-``.compile()``d executable (the serving
     engine's ledger path — zero extra work) or a jitted function plus
-    example args (one extra ``lower().compile()``, cheap under the
-    persistent compile cache — the ``hbm_usage`` precedent).
+    example args (one extra ``lower().compile()`` — a full compile:
+    a re-lowered program does not reliably hit the persistent cache,
+    so prefer handing over the executable you already have).
 
     ``analytic``: optional hand-derived ``(flops, bytes)`` used when
     XLA reports nothing (TPU custom-call bodies).  When XLA *does*

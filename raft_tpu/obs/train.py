@@ -30,11 +30,12 @@ One-time events: ``run_config`` (what scripts/telemetry_summary.py
 needs to fold the log into bench.py JSON), ``compile`` (the first
 executed step's dispatch time, which is dominated by trace+compile; the
 :class:`~raft_tpu.utils.profiling.CompileCounter` is wired into the
-registry), ``hbm_usage`` (XLA memory analysis of the compiled step;
-costs one extra ``lower().compile()`` at startup, disable with
-``RAFT_TELEMETRY_HBM=0``), and ``cost_report`` (the compiled step's
-FLOPs/bytes/roofline accounting from obs/cost.py, sharing that same
-extra compile; disable with ``RAFT_TELEMETRY_COST=0`` — per-step MFU
+registry), ``hbm_usage`` (XLA memory analysis of the compiled step —
+the loop AOT-compiles the step once and runs that executable, so this
+costs no second compile; disable with ``RAFT_TELEMETRY_HBM=0``), and
+``cost_report`` (the compiled step's FLOPs/bytes/roofline accounting
+from obs/cost.py, from the same executable; disable with
+``RAFT_TELEMETRY_COST=0`` — per-step MFU
 then refreshes through the ``raft_cost_mfu`` gauge from each step's
 wall time, still host floats only).  ``close()`` emits a ``metrics_summary``
 with the full registry snapshot so a run's aggregates survive in the
@@ -86,8 +87,8 @@ class TrainTelemetry:
         if hbm is None:
             hbm = os.environ.get("RAFT_TELEMETRY_HBM", "1") == "1"
         self.hbm_enabled = self.enabled and hbm
-        # Cost-model capture (obs/cost.py) shares the hbm_usage
-        # pattern AND its one extra lower().compile() in the loop —
+        # Cost-model capture (obs/cost.py) reads the same AOT-compiled
+        # step executable as hbm_usage (train/loop.py) —
         # disable with RAFT_TELEMETRY_COST=0.
         self.cost_enabled = self.enabled and (
             os.environ.get("RAFT_TELEMETRY_COST", "1") == "1")

@@ -39,7 +39,7 @@ one fused kernel instance holds EVERY level's ``f2`` and one query block's
 rows in VMEM.  The correlation volume never exists in HBM.
 
 Compile-time lesson (round 2, RESOLVED): the original kernels took
->10-40 minutes of Mosaic+remote compile at every shape.  The cause was
+>10-40 minutes of Mosaic compile at every shape.  The cause was
 1-D vector layouts — deriving ``cx/cy`` as ``(BQ,)`` vectors gives
 Mosaic "implicit dimension" layouts whose reductions it either rejects
 ("unsupported output implicit dimension") or compiles pathologically
@@ -90,7 +90,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from raft_tpu.ops.pallas_util import tpu_pallas_call
+from raft_tpu.ops.pallas_util import (BATCH, WHOLE, per_data_shard,
+                                      tpu_pallas_call)
 
 
 # Image rows per inner mat-mul tile; statically unrolled inside, fori_loop
@@ -297,7 +298,7 @@ _BWD_BLOCK_Q = 512       # query block of the blocked kernels (bigger than
                          # ~24 MB more VMEM working set (drows + b_j
                          # doubling), still under the 100 MB limit.
                          # Override per-run with RAFT_ODM_BWD_BLOCK_Q to
-                         # sweep on hardware (scripts/tpu_backlog_r05).
+                         # sweep on hardware (never swept on a chip).
 
 
 def _fused_bwd_est(nonempty, block_q, k):
@@ -779,7 +780,6 @@ def _pyr_levels_bwd(coords_p, g, shapes, radius, block_q, interpret):
             for lvl, (s, dt) in enumerate(shapes)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def pallas_pyramid_lookup(pyramid, coords, radius: int = 4,
                           block_q: int = 128, interpret=None,
                           out_dtype=jnp.float32):
@@ -808,6 +808,16 @@ def pallas_pyramid_lookup(pyramid, coords, radius: int = 4,
     Returns:
       ``(B, H1, W1, L * (2r+1)^2)`` ``out_dtype`` lookup features.
     """
+    def lookup(p, c):
+        return _pyramid_lookup(p, c, radius, block_q, interpret,
+                               out_dtype)
+
+    return per_data_shard(lookup, (BATCH, BATCH))(pyramid, coords)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _pyramid_lookup(pyramid, coords, radius, block_q, interpret,
+                    out_dtype):
     out, _ = _pyr_fwd(pyramid, coords, radius, block_q, interpret,
                       out_dtype)
     return out
@@ -865,7 +875,7 @@ def _pyr_bwd(radius, block_q, interpret, out_dtype, residuals, g):
     return dpyr, jnp.zeros_like(coords)
 
 
-pallas_pyramid_lookup.defvjp(_pyr_fwd, _pyr_bwd)
+_pyramid_lookup.defvjp(_pyr_fwd, _pyr_bwd)
 
 
 def pallas_pyramid_lookup_quantized(pyramid, coords, radius: int = 4,
@@ -895,6 +905,15 @@ def pallas_pyramid_lookup_quantized(pyramid, coords, radius: int = 4,
 
     Returns ``(B, H1, W1, L * (2r+1)^2)`` ``out_dtype`` features.
     """
+    def lookup(p, c):
+        return _pyramid_lookup_quantized(p, c, radius, block_q,
+                                         interpret, out_dtype)
+
+    return per_data_shard(lookup, (BATCH, BATCH))(pyramid, coords)
+
+
+def _pyramid_lookup_quantized(pyramid, coords, radius, block_q, interpret,
+                              out_dtype):
     if interpret is None:
         interpret = _auto_interpret()
     values = [lv.values for lv in pyramid]
@@ -924,7 +943,6 @@ def pallas_pyramid_lookup_quantized(pyramid, coords, radius: int = 4,
     return out.astype(out_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def pallas_corr_lookup(fmap1, fmap2_pyramid, coords, radius: int = 4,
                        block_q: int = 128, interpret=None):
     """Fused on-demand pyramid correlation lookup.
@@ -942,6 +960,15 @@ def pallas_corr_lookup(fmap1, fmap2_pyramid, coords, radius: int = 4,
     Returns:
       ``(B, H1, W1, L * (2r+1)^2)`` fp32 lookup features.
     """
+    def lookup(f1, f2p, c):
+        return _corr_lookup(f1, f2p, c, radius, block_q, interpret)
+
+    return per_data_shard(lookup, (BATCH, BATCH, BATCH))(
+        fmap1, fmap2_pyramid, coords)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _corr_lookup(fmap1, fmap2_pyramid, coords, radius, block_q, interpret):
     out, _ = _corr_fwd(fmap1, fmap2_pyramid, coords, radius, block_q,
                        interpret)
     return out
@@ -1102,8 +1129,8 @@ def _corr_bwd(radius, block_q, interpret, residuals, g):
         # dtypes only — changing RAFT_ODM_BWD_BLOCK_Q later in the same
         # process silently returns the old program (an in-process sweep
         # would record identical timings for different nominal values).
-        # Sweep with a fresh process per value (scripts/tpu_backlog_r05.sh
-        # does), or plumb it through RAFTConfig like lookup_block_q.
+        # Sweep with a fresh process per value, or plumb it through
+        # RAFTConfig like lookup_block_q.
         bq2 = int(os.environ.get("RAFT_ODM_BWD_BLOCK_Q", _BWD_BLOCK_Q))
         f1p2, cp2, _ = _pad_queries(f1, c, bq2)
         Npad2 = f1p2.shape[1]
@@ -1135,7 +1162,7 @@ def _corr_bwd(radius, block_q, interpret, residuals, g):
     return df1, tuple(df2s), jnp.zeros_like(coords)
 
 
-pallas_corr_lookup.defvjp(_corr_fwd, _corr_bwd)
+_corr_lookup.defvjp(_corr_fwd, _corr_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1242,7 +1269,6 @@ def _is_quantized_pyramid(pyramid) -> bool:
     return hasattr(pyramid[0], "values")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def pallas_pyramid_lookup_encode(pyramid, coords, weight, bias,
                                  radius: int = 4, block_q: int = 128,
                                  interpret=None, out_dtype=jnp.float32):
@@ -1279,6 +1305,17 @@ def pallas_pyramid_lookup_encode(pyramid, coords, weight, bias,
     structural zeros for quantized storage (the stop-gradient boundary
     — fnet gets zero grad through a quantized volume, unchanged).
     """
+    def encode(p, c, w, b):
+        return _pyramid_lookup_encode(p, c, w, b, radius, block_q,
+                                      interpret, out_dtype)
+
+    return per_data_shard(encode, (BATCH, BATCH, WHOLE, WHOLE))(
+        pyramid, coords, weight, bias)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _pyramid_lookup_encode(pyramid, coords, weight, bias, radius, block_q,
+                           interpret, out_dtype):
     out, _ = _pyr_enc_fwd(pyramid, coords, weight, bias, radius, block_q,
                           interpret, out_dtype)
     return out
@@ -1340,7 +1377,7 @@ def _pyr_enc_bwd(radius, block_q, interpret, out_dtype, residuals, g):
         # contraction the forward ran); codes/scales get structural
         # zeros — int codes have no tangent space (float0), and the
         # scale sits behind the same stop-gradient as the codes.
-        corr = pallas_pyramid_lookup_quantized(
+        corr = _pyramid_lookup_quantized(
             pyramid, coords, radius, block_q, interpret, jnp.float32)
 
         def _zero_ct(x):
@@ -1358,8 +1395,8 @@ def _pyr_enc_bwd(radius, block_q, interpret, out_dtype, residuals, g):
         # unfused gradient semantics (real per-level dcorr, zero
         # dcoords).
         def lookup(p, c):
-            return pallas_pyramid_lookup(p, c, radius, block_q,
-                                         interpret, jnp.float32)
+            return _pyramid_lookup(p, c, radius, block_q, interpret,
+                                   jnp.float32)
 
         corr, pullback = jax.vjp(lookup, pyramid, coords)
         g_corr = jnp.einsum("bhwf,kf->bhwk", gm,
@@ -1370,4 +1407,4 @@ def _pyr_enc_bwd(radius, block_q, interpret, out_dtype, residuals, g):
     return dpyr, dcoords, dw.astype(weight.dtype), db.astype(bias.dtype)
 
 
-pallas_pyramid_lookup_encode.defvjp(_pyr_enc_fwd, _pyr_enc_bwd)
+_pyramid_lookup_encode.defvjp(_pyr_enc_fwd, _pyr_enc_bwd)

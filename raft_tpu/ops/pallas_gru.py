@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from raft_tpu.ops.pallas_util import auto_interpret, tpu_pallas_call
+from raft_tpu.ops.pallas_util import (BATCH, auto_interpret,
+                                      per_data_shard, tpu_pallas_call)
 
 _LANES = 128
 _BLOCK_ROWS = 256
@@ -169,9 +170,13 @@ def gru_gate_rh(r_raw, h, interpret=None):
     """
     if interpret is None:
         interpret = auto_interpret()
-    (r2, h2), shape, n = _to_rows([r_raw, h])
-    out = _rh_core(r2, h2, interpret)
-    return out.reshape(-1)[:n].reshape(shape)
+
+    def gate(r_raw, h):
+        (r2, h2), shape, n = _to_rows([r_raw, h])
+        out = _rh_core(r2, h2, interpret)
+        return out.reshape(-1)[:n].reshape(shape)
+
+    return per_data_shard(gate, (BATCH, BATCH))(r_raw, h)
 
 
 def gru_gate_blend(z_raw, q_raw, h, interpret=None):
@@ -184,6 +189,10 @@ def gru_gate_blend(z_raw, q_raw, h, interpret=None):
     """
     if interpret is None:
         interpret = auto_interpret()
-    (z2, q2, h2), shape, n = _to_rows([z_raw, q_raw, h])
-    out = _blend_core(z2, q2, h2, interpret)
-    return out.reshape(-1)[:n].reshape(shape)
+
+    def blend(z_raw, q_raw, h):
+        (z2, q2, h2), shape, n = _to_rows([z_raw, q_raw, h])
+        out = _blend_core(z2, q2, h2, interpret)
+        return out.reshape(-1)[:n].reshape(shape)
+
+    return per_data_shard(blend, (BATCH, BATCH, BATCH))(z_raw, q_raw, h)

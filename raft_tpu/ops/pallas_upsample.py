@@ -60,8 +60,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from raft_tpu.ops.pallas_util import auto_interpret, tpu_pallas_call
+from raft_tpu.ops.pallas_util import (BATCH, auto_interpret,
+                                      per_data_shard, tpu_pallas_call)
+from raft_tpu.parallel.mesh import DATA_AXIS
 
 
 def _softmax_parts(m):
@@ -253,14 +256,30 @@ def pallas_upsample_loss_sums(flow: jax.Array, mask: jax.Array,
     """
     if interpret is None:
         interpret = _auto_interpret()
-    gB, H, W, _ = flow.shape
-    assert gB % gt128.shape[0] == 0, (gB, gt128.shape)
-    f8 = jnp.pad(8.0 * flow.astype(jnp.float32),
-                 ((0, 0), (1, 1), (1, 1), (0, 0)))
-    fb = jnp.concatenate([
-        jnp.broadcast_to(f8[..., 0:1], f8.shape[:3] + (64,)),
-        jnp.broadcast_to(f8[..., 1:2], f8.shape[:3] + (64,)),
-    ], axis=-1)
-    sums = _upsample_loss_core(fb, mask, gt128.astype(jnp.float32),
-                               vm64.astype(jnp.float32), interpret)
-    return sums[:, 0, :5]
+    gB = flow.shape[0]
+    B = gt128.shape[0]
+    assert gB % B == 0, (gB, gt128.shape)
+
+    def sums_of(flow, mask, gt128, vm64):
+        # (g, b, ...) operands: under a data-parallel mesh ``b`` is this
+        # shard's slice of the batch, and folding g back in batch-major
+        # keeps the kernel's ``i % b`` pairing with gt/valid intact.
+        g, b = flow.shape[:2]
+        flow = flow.reshape((g * b,) + flow.shape[2:])
+        mask = mask.reshape((g * b,) + mask.shape[2:])
+        f8 = jnp.pad(8.0 * flow.astype(jnp.float32),
+                     ((0, 0), (1, 1), (1, 1), (0, 0)))
+        fb = jnp.concatenate([
+            jnp.broadcast_to(f8[..., 0:1], f8.shape[:3] + (64,)),
+            jnp.broadcast_to(f8[..., 1:2], f8.shape[:3] + (64,)),
+        ], axis=-1)
+        sums = _upsample_loss_core(fb, mask, gt128.astype(jnp.float32),
+                                   vm64.astype(jnp.float32), interpret)
+        return sums[:, 0, :5].reshape(g, b, 5)
+
+    by_iter = P(None, DATA_AXIS)
+    sums = per_data_shard(
+        sums_of, (by_iter, by_iter, BATCH, BATCH), by_iter)(
+        flow.reshape((gB // B, B) + flow.shape[1:]),
+        mask.reshape((gB // B, B) + mask.shape[1:]), gt128, vm64)
+    return sums.reshape(gB, 5)

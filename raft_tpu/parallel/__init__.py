@@ -4,6 +4,8 @@ from raft_tpu.parallel.mesh import (  # noqa: F401
     abstract_replicated,
     make_mesh,
     batch_sharding,
+    data_parallel_kernels,
+    kernel_mesh,
     make_batch_sharder,
     mesh_shape,
     replicated_sharding,

@@ -16,6 +16,8 @@ DCN-vs-ICI placement is XLA's job, not ours.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -38,6 +40,31 @@ def make_mesh(num_data: Optional[int] = None, num_spatial: int = 1,
     assert n <= len(devices), (num_data, num_spatial, len(devices))
     grid = np.asarray(devices[:n]).reshape(num_data, num_spatial)
     return Mesh(grid, (DATA_AXIS, SPATIAL_AXIS))
+
+
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "raft_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def data_parallel_kernels(mesh: Optional[Mesh]):
+    """While active, the Pallas entry points (``ops/pallas_util.py``
+    ``per_data_shard``) run per ``data`` shard of ``mesh``.  Entered by
+    ``make_train_step`` around the trace of a step whose batch is
+    sharded over more than one device; a mesh whose ``data`` axis has
+    one device is the single-device program and changes nothing."""
+    if mesh is not None and mesh.shape[DATA_AXIS] == 1:
+        mesh = None
+    token = _KERNEL_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def kernel_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing :func:`data_parallel_kernels`, if any."""
+    return _KERNEL_MESH.get()
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

@@ -161,12 +161,22 @@ def read_manifest(path: str) -> dict:
 
 
 def import_executables(path: str, *, fingerprint: str,
+                       execution_devices=None,
                        keys: Optional[Tuple[tuple, ...]] = None
                        ) -> Dict[tuple, object]:
     """Load ``{(bucket, lanes, program): Compiled}`` from an artifact
     directory, gated on ``fingerprint`` + backend + jax version.
-    ``keys`` restricts the import (default: everything in the
-    manifest).  Raises :class:`AOTImportError` on any mismatch or
+    ``execution_devices``: the device(s) the importing engine runs on
+    (the ones its variables live on; default: the first local device,
+    where a bare ``jax.device_put`` lands).  Always passed down: left to
+    ITS default, ``deserialize_and_load`` spreads a one-device
+    executable over every local device and the first call dies with
+    "expected args to have N shards" on any host with more than one.
+    The device assignment is baked into a blob: it loads on the device
+    id it was compiled for and on no other (``KeyError`` from the
+    unpickler, surfaced as :class:`AOTImportError`).  ``keys`` restricts
+    the import (default: everything in the manifest).  Raises
+    :class:`AOTImportError` on any mismatch or
     corruption — partial results are never returned (an artifact
     either warm-starts the whole ladder or is refused)."""
     import jax
@@ -192,6 +202,8 @@ def import_executables(path: str, *, fingerprint: str,
         raise AOTImportError("AOT tree templates are not the per-blob "
                              "v2 layout (stale artifact?)")
 
+    if execution_devices is None:
+        execution_devices = jax.local_devices()[:1]
     wanted = None if keys is None else {
         (tuple(b), int(bs), str(prog)) for (b, bs, prog) in keys}
     out: Dict[tuple, object] = {}
@@ -217,7 +229,9 @@ def import_executables(path: str, *, fingerprint: str,
         in_tree = jax.tree_util.tree_structure(templates[0])
         out_tree = jax.tree_util.tree_structure(templates[1])
         try:
-            out[key] = se.deserialize_and_load(ser, in_tree, out_tree)
+            out[key] = se.deserialize_and_load(
+                ser, in_tree, out_tree,
+                execution_devices=list(execution_devices))
         except Exception as e:
             raise AOTImportError(
                 f"AOT blob {entry['file']} failed to deserialize: "

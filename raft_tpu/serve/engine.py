@@ -743,7 +743,9 @@ class InferenceEngine:
 
         try:
             exes = aot_mod.import_executables(
-                directory, fingerprint=self._aot_fingerprint)
+                directory, fingerprint=self._aot_fingerprint,
+                execution_devices=jax.tree_util.tree_leaves(
+                    self._variables)[0].devices())
         except aot_mod.AOTImportError as e:
             # A warm-start MISS, not a serve failure: log it and fall
             # back to lazy JIT compiles.

@@ -290,6 +290,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         watchdog.start()
     t0, steps_t0 = time.time(), step
     first_dispatched = False
+    run_step, compiled = step_fn, None
     try:
         while True:
             # queue_wait_s: time blocked on the input pipeline — the
@@ -347,13 +348,32 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
             if watchdog is not None and not first_dispatched:
                 # The first dispatch trace+compiles synchronously —
                 # minutes, and legitimate; don't let it look like a
-                # stall (resumed below, after the hbm snapshot's own
-                # lower+compile).
+                # stall (resumed below).
                 watchdog.pause()
+            if not first_dispatched and (telem.hbm_enabled
+                                         or telem.cost_enabled):
+                # XLA memory + cost analysis want the compiled step, and
+                # a jit call does not hand its executable out.  So
+                # compile it ahead of time ONCE and run that executable
+                # from here on: what is analysed is what runs, at no
+                # second compile.  (Lowering the step a second time
+                # after the jit call — what this block used to do —
+                # does not even hit the persistent cache: a re-lowering
+                # numbers its private functions differently, so the
+                # cache key moves and a minutes-long step compiles
+                # twice.)  A non-lowerable step_fn (stubbed in tests)
+                # keeps the plain call and the unavailable record.
+                try:
+                    compiled = step_fn.lower(state, sharded,
+                                             key).compile()
+                except Exception:
+                    compiled = None
+                else:
+                    run_step = compiled
             t_d0 = time.perf_counter()
             try:
                 with annotate_step(step):
-                    state, metrics = step_fn(state, sharded, key)
+                    state, metrics = run_step(state, sharded, key)
             except BaseException as e:
                 if st is not None:
                     trace.record_span(st, "step_dispatch", t_d0,
@@ -381,21 +401,19 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                     key=("train_step", tuple(cfg.image_size),
                          cfg.batch_size))
                 if telem.hbm_enabled or telem.cost_enabled:
-                    # XLA memory + cost analysis of the compiled step:
-                    # ONE extra lower+compile at startup shared by both
-                    # (cheap under the persistent compile cache;
-                    # RAFT_TELEMETRY_HBM=0 / RAFT_TELEMETRY_COST=0 skip
-                    # each half).  Purely host-side, runs once.  A
-                    # non-lowerable step_fn (stubbed in tests) degrades
-                    # to the unavailable record, never a loop failure.
-                    try:
-                        compiled = step_fn.lower(state, sharded,
-                                                 key).compile()
-                    except Exception:
-                        compiled = None
+                    # From the executable compiled above (host-side
+                    # metadata, runs once; RAFT_TELEMETRY_HBM=0 /
+                    # RAFT_TELEMETRY_COST=0 skip each half).
                     if telem.hbm_enabled:
+                        # tpu_custom_calls: how many Mosaic kernels the
+                        # compiled step really holds — 0 means a Pallas
+                        # impl was swapped for XLA (off-TPU fallback) or
+                        # ran through the interpreter.
                         telem.record_hbm(
-                            hbm_usage(compiled) if compiled is not None
+                            dict(hbm_usage(compiled),
+                                 tpu_custom_calls=compiled.as_text()
+                                 .count("tpu_custom_call"))
+                            if compiled is not None
                             else {"peak_hbm": "unavailable"})
                     if telem.cost_enabled and compiled is not None:
                         telem.record_cost(step_cost(

@@ -22,7 +22,8 @@ from jax.sharding import Mesh
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.models.raft import RAFT
 from raft_tpu.obs.health import tree_all_finite, tree_select
-from raft_tpu.parallel.mesh import (batch_sharding, replicated_sharding,
+from raft_tpu.parallel.mesh import (batch_sharding, data_parallel_kernels,
+                                    replicated_sharding,
                                     spatial_batch_sharding)
 from raft_tpu.train.loss import sequence_loss
 from raft_tpu.train.state import TrainState
@@ -97,6 +98,15 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
     GSPMD inserts the halo exchanges and gathers).
     ``freeze_bn`` is static per-stage (reference train.py:147-148).
 
+    Pallas kernels under a mesh: GSPMD cannot partition a Mosaic kernel,
+    so while the step traces, every Pallas entry point runs per ``data``
+    shard under ``shard_map`` (``ops/pallas_util.py per_data_shard``) —
+    the kernels only; BatchNorm statistics and the gradient all-reduce
+    stay over the global batch.  ``shard_spatial`` with a Pallas path is
+    refused: the kernels take whole images, and splitting image rows
+    across devices would need halo logic inside them that does not
+    exist — pick ``corr_impl='allpairs'`` or ``'chunked'`` there.
+
     ``cfg.accum_steps > 1`` enables gradient-accumulation microbatching:
     the batch is reshaped to ``(accum, B/accum, ...)`` and a ``lax.scan``
     runs forward+backward per microbatch, accumulating gradients in fp32;
@@ -142,6 +152,23 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
             max(cfg.batch_size // max(n_dev, 1), 1))
         if info.applied:
             model = RAFT(tuned_cfg)
+
+    if shard_spatial:
+        mc = model.config
+        pallas = [name for name, on in (
+            (f"corr_impl={mc.resolved_corr_impl!r}",
+             mc.resolved_corr_impl in ("allpairs_pallas", "pallas")),
+            ("upsample_loss_kernel='pallas'",
+             mc.resolved_upsample_loss_kernel == "pallas"),
+            ("fused_gru=True", mc.resolved_fused_gru)) if on]
+        if pallas:
+            raise ValueError(
+                f"shard_spatial=True cannot run the Pallas path "
+                f"({', '.join(pallas)}): a Mosaic kernel cannot be "
+                "partitioned over image rows and this repo does not "
+                "replicate it silently.  Use corr_impl='allpairs' or "
+                "'chunked' (XLA, partitioned by GSPMD) with spatial "
+                "sharding, or shard over the data axis only")
 
     loss_fn = make_loss_fn(model, cfg)
     accum = max(int(getattr(cfg, "accum_steps", 1)), 1)
@@ -218,11 +245,15 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
     if mesh is None:
         return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
 
+    def mesh_step_fn(state, batch, rng):
+        with data_parallel_kernels(mesh):
+            return step_fn(state, batch, rng)
+
     repl = replicated_sharding(mesh)
     data = spatial_batch_sharding(mesh) if shard_spatial \
         else batch_sharding(mesh)
     return jax.jit(
-        step_fn,
+        mesh_step_fn,
         in_shardings=(repl, data, repl),
         out_shardings=(repl, repl),
         donate_argnums=(0,) if donate else (),

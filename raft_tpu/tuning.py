@@ -1,9 +1,11 @@
 """Persisted per-hardware tuning registry for the performance knob surface.
 
-The CPU bench went 18.8 -> 74.8 image-pairs/sec/chip (BENCH_r01 -> r03)
-purely by hand-tuning the knobs ``BENCH_r03.json`` records (``corr_impl``,
-``corr_dtype``, ``scan_unroll``, ``remat``, ``fuse_upsample_in_scan``,
-``upsample_loss_kernel``, bucket/batch sizes).  Those winners are
+Before PR 1 the chairs-crop train bench moved roughly fourfold purely by
+hand-tuning a handful of knobs (``corr_impl``, ``corr_dtype``,
+``scan_unroll``, ``remat``, ``fuse_upsample_in_scan``,
+``upsample_loss_kernel``, bucket/batch sizes; the records of those runs
+were deleted in PR 22 and nothing is measured on today's code).  Those
+winners are
 HARDWARE facts, not code facts — a v5e picks differently from a v4 or a
 CPU dev box — so this module turns them into a durable per-hardware
 capability: ``scripts/autotune.py`` sweeps the cross-product on the
@@ -15,7 +17,7 @@ where ``kind`` is the workload ('train' | 'eval' | 'serve'),
 ``device_kind`` is ``jax.devices()[0].device_kind`` (e.g. 'TPU v5e',
 'cpu'), ``bucket_hw`` the /8-aligned input shape and ``batch`` the
 per-chip batch.  Every entry carries provenance (tool, time, host,
-measured throughput, sweep id) so a BENCH_r0x series can always say
+measured throughput, sweep id) so a bench record can always say
 whether its knobs came from autotune or a human.
 
 Consumers — ``make_train_step`` (raft_tpu/train/step.py),

@@ -1,8 +1,8 @@
 """Autotune the performance knob surface and persist winners per hardware.
 
-The r01->r03 bench trajectory (18.8 -> 74.8 image-pairs/sec/chip on the
-CPU backend) came from HAND-tuning the knobs ``BENCH_r03.json`` records;
-this script makes that automatic and durable: it sweeps a seeded,
+The early rounds' fourfold gain on the chairs-crop train bench came from
+HAND-tuning a handful of knobs (the records are gone — PR 22 — and
+nothing is measured on today's code); this script makes that automatic and durable: it sweeps a seeded,
 time-boxed cross-product of the ``RAFTConfig`` knob surface with
 bench.py-style timing (synthetic batches, warmup + steady-state steps,
 ``perf_counter``) and writes the winner into the per-hardware tuning
@@ -102,9 +102,9 @@ def parse_args(argv=None):
                         "cache-hit re-invocation, and a tiny train "
                         "step consuming the written entry (tier-1)")
     p.add_argument("--seed-known", action="store_true",
-                   help="no sweep: write the repo's MEASURED hand-tuned "
-                        "winners (BENCH_r03.json, 74.8 pairs/s/chip on "
-                        "the CI CPU backend) into the registry for this "
+                   help="no sweep: write the repo's hand-tuned winners "
+                        "of rounds 1-4 (not measured on today's "
+                        "code) into the registry for this "
                         "device, provenance-labeled as seeded — the "
                         "known-good starting table a real sweep later "
                         "re-measures (a seeded entry has no sweep_id, "
@@ -112,8 +112,8 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-# The r03 hand-tuned chairs-crop winners (BENCH_r03.json `config` block;
-# 18.8 -> 74.8 image-pairs/sec/chip over r01).  `--seed-known` installs
+# The hand-tuned chairs-crop winners of rounds 1-4 (their record was
+# deleted in PR 22).  `--seed-known` installs
 # them as the registry's starting point on hardware nobody has swept
 # yet; corr_impl 'allpairs_pallas' self-falls-back to 'allpairs' off-TPU
 # (RAFTConfig.resolved_corr_impl), so one entry serves both backends.
@@ -132,8 +132,8 @@ _KNOWN_WINNERS = {
 
 
 def seed_known(out=None):
-    """Write the measured hand-tuned winners as seeded registry entries
-    (provenance mode='seed-known', source=BENCH_r03.json)."""
+    """Write the hand-tuned winners as seeded registry entries
+    (provenance mode='seed-known')."""
     from raft_tpu import tuning
 
     keys = []
@@ -142,9 +142,8 @@ def seed_known(out=None):
             kind, hw, batch, knobs,
             provenance={"tool": "scripts/autotune.py",
                         "mode": "seed-known",
-                        "source": "BENCH_r03.json hand-tuned winners",
-                        "best_value": 74.824,
-                        "unit": "image-pairs/sec/chip"},
+                        "source": "hand-tuned winners of rounds 1-4; "
+                                  "not measured on today's code"},
             path=out))
     return keys
 
@@ -153,7 +152,7 @@ def _grid(kind: str, tiny: bool, allow_quantized: bool):
     """The knob cross-product for one workload on this backend.
 
     Kept deliberately curated (not every RAFTConfig field): each axis
-    here has MOVED a bench number in some round (BENCH_r0*.json), which
+    here has MOVED a bench number in some round, which
     is what makes the cross-product worth its compile time."""
     import jax
 

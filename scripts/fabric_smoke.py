@@ -122,7 +122,7 @@ def main(argv=None) -> int:
     # queue_capacity() facade rather than the shared ServeConfig.
     remote_serve_cfg = ServeConfig(
         iters=2, batching="slot", slots=2, max_wait_ms=5, max_queue=8,
-        stall_timeout_s=30.0)
+        stall_timeout_s=30.0, chaos_slow_s=0.05)
     server_engine = InferenceEngine(variables, model_cfg,
                                     remote_serve_cfg)
     server_engine.start()
@@ -134,7 +134,7 @@ def main(argv=None) -> int:
     # ---- the fleet: 1 local + 1 remote, autoscaling 1..2 locals ------
     serve_cfg = ServeConfig(
         iters=2, batching="slot", slots=2, max_wait_ms=5, max_queue=32,
-        stall_timeout_s=30.0,
+        stall_timeout_s=30.0, chaos_slow_s=0.05,
         # ONE incident must span the whole drill: generous correlation
         # window, and a quiet-close threshold longer than the drill.
         incidents=True, incident_window_s=60.0, incident_quiet_s=120.0)
@@ -229,6 +229,13 @@ def main(argv=None) -> int:
             "rejoin_generation": r1.generation}
 
         # -- 3. queue pressure -> exactly one scale-up ----------------
+        # Every device batch is made a (short) straggler while the
+        # pressure is on, so the backlog builds on ANY host: left to
+        # itself the tiny model drains its queue as fast as one thread
+        # can submit on a many-core machine, and the drill then measures
+        # the host, not the autoscaler.
+        chaos.install(chaos.FaultPlan.parse("replica_slow@p=1.0",
+                                            seed=args.seed))
         futures = []
         deadline = time.time() + 20
         while autoscale()["ups"] < 1:
@@ -241,6 +248,7 @@ def main(argv=None) -> int:
                     time.sleep(0.01)  # shed, not dropped: retry later
             if sum(not f.done() for f in futures) > backlog_cap:
                 time.sleep(0.005)
+        chaos.uninstall()
         results = [f.result(timeout=120) for f in futures]
         assert all(r.shape == shape + (2,) for r in results)
         scales = events("fleet_scale")

@@ -27,7 +27,8 @@ verdict from compile-time metadata alone (``raft_tpu/obs/cost.py``;
 ``docs/PERFORMANCE.md`` has the triage table) — a memory-bound
 verdict changes what to look for in the capture, and the measured
 FLOP rates here are what validate the cost model's analytic kernel
-formulas on hardware (``scripts/tpu_backlog_r07.sh``).
+formulas on hardware (not yet done: no trace of today's code on the
+chip exists — PERF.md "Open questions").
 """
 
 from __future__ import annotations

@@ -152,8 +152,8 @@ def main(argv=None) -> int:
 
     model_cfg = RAFTConfig.small_model()  # fp32: CPU-friendly
     # --tiny: the tier-1 CPU sizing; the default exercises a bigger
-    # bucket and a real iteration budget (on-device validation,
-    # scripts/tpu_backlog_r08.sh).
+    # bucket and a real iteration budget (for a run on the chip; none
+    # has been made — chip_smoke.py is the on-chip entry point).
     shape = (36, 52) if args.tiny else (64, 96)
     serve_iters = 2 if args.tiny else 8
     model_img = jax.numpy.zeros((1, 40, 56, 3))

@@ -85,7 +85,7 @@ def _make_step(H, W, num_spatial, iters, corr_impl="chunked"):
                      devices=jax.devices()[:num_spatial])
     # scan_unroll=1: at beyond-HBM shapes each iteration is O(100ms+) of
     # device work, so unroll buys nothing and the 12x graph is brutal to
-    # compile (it crashed the TPU remote compile helper at 1440x2560).
+    # compile.
     model_cfg = RAFTConfig.full(compute_dtype="bfloat16",
                                 corr_impl=corr_impl, remat=True,
                                 remat_policy="save_corr", scan_unroll=1)

@@ -145,6 +145,11 @@ def main(argv=None) -> int:
         chaos.uninstall()
         assert flow.shape == shape + (2,)
         assert dt < 2.5, f"hedge did not cover the {dt:.1f}s straggler"
+        # The router settles the caller's future FIRST and bumps its
+        # win/latency counters right after, on the replica's callback
+        # thread — infer() can return before the counter moves.
+        _wait_for(lambda: router.router_stats()["hedge_wins_total"] == 1,
+                  5, "hedge win never counted")
         rstats = router.router_stats()
         assert rstats["hedges_total"] == 1, rstats
         assert rstats["hedge_wins_total"] == 1, rstats

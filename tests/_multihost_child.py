@@ -25,14 +25,10 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    # jaxlib >= 0.4.34 routes multi-process CPU collectives through a
-    # pluggable backend and jitted collectives fail without one
-    # ("Multiprocess computations aren't implemented on the CPU
-    # backend"); gloo ships in the wheel.
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:  # older jax: flag absent, CPU collectives built in
-    pass
+# Multi-process CPU collectives go through a pluggable backend and jitted
+# collectives fail without one ("Multiprocess computations aren't
+# implemented on the CPU backend"); gloo ships in the jaxlib wheel.
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=nproc, process_id=pid)
 

@@ -36,12 +36,9 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    # jaxlib >= 0.4.34 needs an explicit CPU collectives backend for
-    # multi-process runs (see tests/_multihost_child.py).
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
+# Multi-process CPU runs need an explicit collectives backend (see
+# tests/_multihost_child.py).
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=nproc, process_id=pid)
 

@@ -1,0 +1,206 @@
+"""Ask the TPU's own compiler, from a machine with no TPU.
+
+libtpu is installed next to the CPU-only jaxlib the tests run on, and it
+compiles for a chip that is *described*, not attached
+(``jax.experimental.topologies``).  Interpret mode — what every other
+Pallas test here runs — lowers a kernel to ordinary HLO and so cannot see
+what Mosaic refuses: a misaligned slice, too much VMEM, a kernel GSPMD is
+asked to partition.  These cases compile the MAIN-PATH kernels at real
+widths for a described v5e and keep that guard in tier-1 at no chip time.
+
+Rules of this file (the driver runs the suite under ``xdist -n 6``; only
+ONE process may load libtpu, and it keeps it until it exits):
+
+- the topology is described inside the module-scoped, non-autouse ``topo``
+  fixture, which skips from inside if it cannot — never at import, never in
+  a ``skipif``/``parametrize`` argument, never in ``conftest.py``;
+- shardings, meshes and shapes are built in fixtures or tests;
+- every compile happens in the test's own process (no children);
+- this is the only file of its kind: a second one could land on another
+  worker, whose fixture would then skip every test in silence.
+
+Nothing here runs on a device: a compile that passes is not a chip run.
+"""
+
+import functools
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def dp_mesh(topo):
+    """The ``(data=4, spatial=1)`` mesh ``make_mesh`` would build on a
+    four-chip host."""
+    from raft_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(num_data=4, num_spatial=1, devices=topo.devices)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the tests silent and the
+    cache clean."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _with_sharding(tree, sharding):
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _lookup_operands(batch, h8, w8, sharding, channels=256):
+    """Abstract ``(fmap1, fmap2, pyramid, coords)`` at 1/8-res ``h8 x w8``
+    — the bf16-stored query-minor pyramid RAFT-full builds under bf16
+    compute, by the model's own builder."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.corr import build_corr_pyramid_flat
+
+    fmap = jax.ShapeDtypeStruct((batch, h8, w8, channels), jnp.float32)
+    pyramid = jax.eval_shape(
+        functools.partial(build_corr_pyramid_flat, num_levels=4,
+                          pad_q=128, out_dtype=jnp.bfloat16), fmap, fmap)
+    coords = jax.ShapeDtypeStruct((batch, h8, w8, 2), jnp.float32)
+    return _with_sharding((fmap, fmap, pyramid, coords), sharding)
+
+
+# What the train step runs at the chairs crop (368x496 -> 46x62), what a
+# Pallas lookup would meet at the Sintel eval shape (440x1024 -> 55x128),
+# and the on-demand kernel `evaluate --alternate_corr` picks on TPU.
+@pytest.mark.parametrize("case", [
+    "pyramid_lookup_fwd_46x62", "pyramid_lookup_bwd_46x62",
+    "pyramid_lookup_fwd_55x128", "ondemand_corr_fwd_55x128"])
+def test_main_path_kernel_compiles_for_v5e(case, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.corr import pool_fmap_pyramid
+    from raft_tpu.ops.pallas_corr import (pallas_corr_lookup,
+                                          pallas_pyramid_lookup)
+
+    h8, w8 = (46, 62) if "46x62" in case else (55, 128)
+    fmap1, fmap2, pyramid, coords = _lookup_operands(2, h8, w8, one_chip)
+
+    def pyramid_lookup(pyr, c):
+        # interpret=False explicitly: jax.default_backend() is "cpu"
+        # here and would pick the interpreter.
+        return pallas_pyramid_lookup(pyr, c, 4, 128, False, jnp.bfloat16)
+
+    if case.startswith("ondemand"):
+        def fn(f1, f2, c):
+            return pallas_corr_lookup(
+                f1, tuple(pool_fmap_pyramid(f2, 4)), c, 4, 128, False)
+
+        args = (fmap1, fmap2, coords)
+    elif "bwd" in case:
+        def fn(pyr, c):
+            return jax.grad(lambda p: jnp.sum(
+                pyramid_lookup(p, c).astype(jnp.float32)))(pyr)
+
+        args = (pyramid, coords)
+    else:
+        fn, args = pyramid_lookup, (pyramid, coords)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lookup_partitions_over_data_mesh(dp_mesh):
+    """The regression test for the default training configuration on more
+    than one chip: with the batch sharded over ``data`` the lookup must
+    lower per shard (``per_data_shard``), fwd and bwd — and the bare
+    kernel must still be what GSPMD refuses, or the wrap has lost its
+    reason."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raft_tpu.ops.pallas_corr import (_pyramid_lookup,
+                                          pallas_pyramid_lookup)
+    from raft_tpu.parallel.mesh import DATA_AXIS, data_parallel_kernels
+
+    _, _, pyramid, coords = _lookup_operands(
+        8, 46, 62, NamedSharding(dp_mesh, P(DATA_AXIS)))
+
+    def loss(lookup, pyr, c):
+        return jnp.sum(lookup(pyr, c, 4, 128, False,
+                              jnp.bfloat16).astype(jnp.float32))
+
+    def step(pyr, c):
+        with data_parallel_kernels(dp_mesh):
+            return jax.value_and_grad(
+                functools.partial(loss, pallas_pyramid_lookup))(pyr, c)
+
+    compiled = jax.jit(step).lower(pyramid, coords).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text      # the loss, summed over the shards
+    for level in compiled.output_shardings[1]:
+        assert level.spec == P(DATA_AXIS), level  # dcorr stays sharded
+
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        jax.jit(functools.partial(loss, _pyramid_lookup)).lower(
+            pyramid, coords)
+
+
+def test_raft_full_eval_forward_compiles_for_v5e(one_chip):
+    """The program validate and serve run at the Sintel shape (436x1024
+    padded to 440x1024, 32 iterations, bf16) compiles for one v5e and
+    fits its 16 GB."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.evaluate import make_inference_model
+
+    model = make_inference_model(RAFTConfig.full(compute_dtype="bfloat16"))
+    image = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
+                                 sharding=one_chip)
+    small = jnp.zeros((1, 64, 96, 3), jnp.float32)
+    rng = jax.random.PRNGKey(0)
+    variables = _with_sharding(jax.eval_shape(
+        lambda: model.init({"params": rng, "dropout": rng}, small, small,
+                           iters=1)), one_chip)
+
+    def fwd(v, a, b):
+        return model.apply(v, a, b, iters=32, test_mode=True, train=False)
+
+    compiled = jax.jit(fwd).lower(variables, image, image).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert max(need, ma.peak_memory_in_bytes) < 16 * 2 ** 30
+    _, flow_up = compiled.out_info
+    assert flow_up.shape == (1, 440, 1024, 2)

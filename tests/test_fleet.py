@@ -147,6 +147,44 @@ def test_aot_import_gates_and_corruption(variables, aot_dir, tmp_path):
         aot_mod.import_executables(str(torn), fingerprint=fp)
 
 
+def test_aot_roundtrip_runs_with_8_devices_present(tmp_path):
+    """Regression for the loader spreading a ONE-device executable over
+    every local device: with 8 virtual devices present, an exported and
+    re-imported executable must run on the one device it was compiled
+    for, whichever of the eight that is ("Expected args to
+    execute_sharded_on_local_devices to have 8 shards" before PR 22).
+    The device assignment is baked into the artifact: it loads on the
+    device it was built for and no other."""
+    import jax
+    import jax.numpy as jnp
+
+    assert jax.local_device_count() == 8  # tests/conftest.py
+    key = ((8, 8), 1, "enc")
+    x = np.arange(12.0, dtype=np.float32).reshape(3, 4)
+    for device in (jax.local_devices()[0], jax.local_devices()[5]):
+        args = jax.device_put(({"w": jnp.float32(2.0)}, x), device)
+        compiled = jax.jit(lambda v, a: v["w"] * a + 1.0).lower(
+            *args).compile()
+        path = str(tmp_path / f"dev{device.id}")
+        aot_mod.export_executables({key: compiled}, path,
+                                   fingerprint="fp")
+        exe = aot_mod.import_executables(
+            path, fingerprint="fp", execution_devices=[device])[key]
+        out = exe(*args)
+        assert out.devices() == {device}
+        np.testing.assert_array_equal(out, 2.0 * x + 1.0)
+    # Default: where a bare device_put lands (the engine's placement).
+    exe = aot_mod.import_executables(str(tmp_path / "dev0"),
+                                     fingerprint="fp")[key]
+    np.testing.assert_array_equal(
+        exe({"w": jnp.float32(3.0)}, x), 3.0 * x + 1.0)
+    other = jax.local_devices()[5:6]
+    with pytest.raises(aot_mod.AOTImportError, match="deserialize"):
+        aot_mod.import_executables(str(tmp_path / "dev0"),
+                                   fingerprint="fp",
+                                   execution_devices=other)
+
+
 def test_engine_aot_preload_zero_compiles(variables, aot_dir):
     """An engine built with ``aot_dir`` serves its first request with
     CompileCounter == 0 — the fleet's warm-start contract."""

@@ -1,15 +1,16 @@
 """Unit tests for the profiling utilities: HBM-limit artifact loader,
-allocation-probe error classification, per-user persistent compile
-cache, and the serve engine's compile-count ledger (no device work)."""
+allocation-probe error classification, the persistent compile cache's
+placement rule, and the serve engine's compile-count ledger (no device work)."""
 
 import json
 import os
 import os.path as osp
 import stat
 
+import pytest
+
 from raft_tpu.utils.profiling import (
     CompileCounter,
-    default_compile_cache_dir,
     enable_persistent_compile_cache,
     load_hbm_limit,
     probe_error_is_oom,
@@ -56,57 +57,76 @@ def test_probe_error_classification():
     assert not probe_error_is_oom(
         RuntimeError("DEADLINE_EXCEEDED: socket closed"))
     assert not probe_error_is_oom(
-        ConnectionError("relay tunnel reset by peer"))
+        ConnectionError("connection reset by peer"))
     assert not probe_error_is_oom(RuntimeError("INTERNAL: mesh barrier"))
 
 
-def test_default_cache_dir_is_per_user(monkeypatch):
-    monkeypatch.delenv("RAFT_JAX_CACHE_DIR", raising=False)
-    d = default_compile_cache_dir()
-    base = osp.basename(d)
-    assert base.startswith("raft_jaxcache-") and base != "raft_jaxcache"
-    uid = getattr(os, "getuid", lambda: None)()
-    if uid is not None:  # posix: uid embedded -> no cross-user collision
-        assert str(uid) in base
-    monkeypatch.setenv("RAFT_JAX_CACHE_DIR", "/somewhere/else")
-    assert default_compile_cache_dir() == "/somewhere/else"
+REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
 
 
-def test_enable_persistent_cache_creates_0700(tmp_path, monkeypatch):
+@pytest.fixture
+def cache_config():
+    """Snapshot/restore the two jax.config values the cache function
+    may touch, so a test can never leave a cache enabled for the rest
+    of the CPU suite."""
     import jax
 
-    target = tmp_path / "jaxcache"
-    monkeypatch.setenv("RAFT_JAX_CACHE_DIR", str(target))
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        # force=True: the suite runs on the CPU backend, where the
-        # un-forced call refuses to enable the cache (deserialized
-        # XLA:CPU executables abort the process on this jaxlib).
-        assert enable_persistent_compile_cache(force=True) == str(target)
-        assert stat.S_IMODE(os.stat(target).st_mode) == 0o700
-        assert jax.config.jax_compilation_cache_dir == str(target)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_min)
+    yield old_dir
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      old_min)
 
 
-def test_enable_persistent_cache_refuses_cpu_backend(tmp_path,
-                                                     monkeypatch):
+def test_cache_env_set_code_sets_no_directory(tmp_path, monkeypatch,
+                                              cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: JAX honours it by itself, so the
+    function names that directory and neither creates nor configures
+    one of its own."""
     import jax
 
-    if jax.default_backend() != "cpu":
-        return
-    target = tmp_path / "jaxcache"
-    monkeypatch.setenv("RAFT_JAX_CACHE_DIR", str(target))
-    old_dir = jax.config.jax_compilation_cache_dir
-    try:
+    target = tmp_path / "placed-from-outside"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(target))
+    assert enable_persistent_compile_cache(force=True) == str(target)
+    assert jax.config.jax_compilation_cache_dir == cache_config
+    assert not target.exists()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_cache_env_unset_fixed_path_in_checkout(monkeypatch,
+                                                cache_config):
+    """No env var: one fixed, git-ignored path inside the checkout,
+    mode 0700 — nothing of a tempdir, uid, user, pid or time in it, and
+    the retired private override variable is ignored."""
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("RAFT_JAX_CACHE_DIR", "/somewhere/else")
+    want = osp.join(REPO, ".jax_cache")
+    # force=True: the suite runs on the CPU backend, where the
+    # un-forced call refuses (next test).  No compile happens before
+    # the fixture restores the config, so nothing is written.
+    assert enable_persistent_compile_cache(force=True) == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert stat.S_IMODE(os.stat(want).st_mode) == 0o700
+    with open(osp.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_cache_refused_on_cpu_backend(tmp_path, monkeypatch,
+                                      cache_config):
+    import jax
+
+    assert jax.default_backend() == "cpu"  # tests/conftest.py
+    for env in (None, str(tmp_path / "jaxcache")):
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
         assert enable_persistent_compile_cache() == ""
-        assert not target.exists()
-        assert jax.config.jax_compilation_cache_dir == old_dir
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
+        assert jax.config.jax_compilation_cache_dir == cache_config
+    assert not (tmp_path / "jaxcache").exists()
 
 
 def test_step_profiler_anchors_window_on_resume(monkeypatch):

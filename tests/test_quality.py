@@ -513,18 +513,22 @@ def test_serve_config_quality_validation():
 # ---------------------------------------------------------------------------
 
 
-def test_quality_smoke_drill_tiny(capsys, aot_dir):
+def test_quality_smoke_drill_tiny(capsys):
     """The drill the PR promises: sampled scoring over healthy
     traffic, scrambled weights refused at the proxy canary, and the
     drift detector + fleet supervisor catching the same weights when
-    hot-swapped past the gate.  Reuses the module AOT export (same
-    fingerprint: same config/PRNGKey(0)/iters) so the drill's fleet
-    imports instead of recompiling."""
+    hot-swapped past the gate.  The drill keeps its own AOT directory
+    (its replica compiles and exports, the warming engine of the weight
+    update imports): loading the MODULE fixture's blobs yet again in
+    this process — the two engine tests above already hold them — trips
+    jaxlib 0.9.0's XLA:CPU AOT loader ("NOT_FOUND ... Function
+    iota_concatenate_fusion not found"), the loader whose own warning
+    says its executables may not run.  A CPU-only hazard."""
     from raft_tpu.obs import reset_default_sink
 
     mod = _load_script("quality_smoke")
     try:
-        rc = mod.main(["--tiny", "--aot-dir", aot_dir])
+        rc = mod.main(["--tiny"])
     finally:
         # The drill binds the process-global telemetry sink to its
         # temp dir; restore the default for the rest of the session.

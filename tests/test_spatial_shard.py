@@ -7,10 +7,15 @@ against the purely data-parallel result.
 The matrix covers every correlation implementation actual training can
 select — ``allpairs`` (XLA einsums), ``allpairs_pallas`` (the TPU
 training default, fused Pallas pyramid lookup) and ``pallas`` (the
-on-demand beyond-HBM path) — with the FULL model, matching the
-reference's guarantee that DataParallel wraps the whole model including
-the CUDA kernel (reference train.py:138, core/corr.py:86).  The Pallas
-kernels run in interpret mode on the CPU mesh.
+on-demand beyond-HBM path) — with the FULL model.  Spatial sharding is an
+XLA-path feature: GSPMD cannot partition a Mosaic kernel (interpret mode
+on this CPU mesh lowers it to ordinary HLO and would hide that), so
+``make_train_step`` REFUSES ``shard_spatial=True`` with a Pallas path
+(PR 22) and these tests pin the refusal.  Under pure data parallelism the
+Pallas kernels run per batch shard (``ops/pallas_util.per_data_shard``),
+matching the reference's guarantee that DataParallel wraps the whole
+model including the CUDA kernel (reference train.py:138,
+core/corr.py:86).
 """
 
 import jax
@@ -60,6 +65,12 @@ def test_spatial_sharded_step_matches_dp(corr_impl):
     _, m_dp = step_dp(state, shard_batch(batch, mesh_dp), key)
 
     mesh_sp = make_mesh(num_data=4, num_spatial=2)
+    if corr_impl != "allpairs":
+        with pytest.raises(ValueError, match="shard_spatial=True cannot"):
+            make_train_step(model, tx, cfg, mesh_sp, donate=False,
+                            shard_spatial=True)
+        assert np.isfinite(float(m_dp["loss"]))  # DP ran the kernels
+        return
     step_sp = make_train_step(model, tx, cfg, mesh_sp, donate=False,
                               shard_spatial=True)
     _, m_sp = step_sp(state, shard_batch(batch, mesh_sp, spatial=True),
@@ -74,10 +85,12 @@ def test_spatial_sharded_step_matches_dp(corr_impl):
 @pytest.mark.parametrize("corr_impl", ["allpairs_pallas", "pallas"])
 def test_flagship_bf16_spatial_step_wide_aspect(corr_impl):
     """The SHIPPED bf16 training config (what cli/train.py resolves on
-    TPU) on a realistic wide aspect ratio (96x256 ~ KITTI's 1:3.3),
-    spatially sharded — one SPMD step must run and produce a finite
-    loss.  This pins the flagship Pallas configs' partitioning behavior
-    so a regression can't ship silently (VERDICT r2, missing #2)."""
+    TPU) on a realistic wide aspect ratio (96x256 ~ KITTI's 1:3.3):
+    one data-parallel SPMD step must run the kernels per shard and
+    produce a finite loss, and the spatially sharded form must be
+    refused.  This pins the flagship Pallas configs' partitioning
+    behavior so a regression can't ship silently (VERDICT r2, missing
+    #2)."""
     if jax.device_count() < 8:
         pytest.skip("needs 8 virtual devices")
     h, w = 96, 256
@@ -92,10 +105,13 @@ def test_flagship_bf16_spatial_step_wide_aspect(corr_impl):
                         cfg.clip)
     state = init_state(model, tx, jax.random.PRNGKey(0), (h, w))
     batch = _batch(np.random.default_rng(0), h=h, w=w)
-    mesh = make_mesh(num_data=4, num_spatial=2)
-    step = make_train_step(model, tx, cfg, mesh, donate=False,
-                           shard_spatial=True)
-    _, m = step(state, shard_batch(batch, mesh, spatial=True),
-                jax.random.PRNGKey(1))
+    with pytest.raises(ValueError, match="shard_spatial=True cannot"):
+        make_train_step(model, tx, cfg,
+                        make_mesh(num_data=4, num_spatial=2),
+                        donate=False, shard_spatial=True)
+    mesh = make_mesh(num_data=4, num_spatial=1,
+                     devices=jax.devices()[:4])
+    step = make_train_step(model, tx, cfg, mesh, donate=False)
+    _, m = step(state, shard_batch(batch, mesh), jax.random.PRNGKey(1))
     assert np.isfinite(float(m["loss"])), float(m["loss"])
     assert np.isfinite(float(m["epe"])), float(m["epe"])
