@@ -1,0 +1,8 @@
+"""1 - (union of the intervals in which an operation ran) / traced window."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
