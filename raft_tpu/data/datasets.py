@@ -30,6 +30,7 @@ import dataclasses
 import os
 import os.path as osp
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from glob import glob
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -39,6 +40,8 @@ import numpy as np
 from raft_tpu import chaos
 from raft_tpu.data import frame_utils
 from raft_tpu.data.augment import FlowAugmentor, SparseFlowAugmentor
+from raft_tpu.obs import stages
+from raft_tpu.obs.registry import default_registry
 
 
 class SampleReadError(ValueError):
@@ -464,6 +467,7 @@ class ShardedLoader:
             raise InjectedWorkerCrash(
                 "chaos-injected loader-worker crash (not a decode "
                 "error: must fail the run, not quarantine)")
+        t0 = time.perf_counter()
         index = int(index)
         idx, last_err = index, None
         for resample in range(self.sample_resamples + 1):
@@ -475,9 +479,12 @@ class ShardedLoader:
                 rng = np.random.default_rng(
                     np.random.SeedSequence([self.seed, epoch, idx]))
                 try:
-                    return self.dataset.load(idx, rng)
+                    sample = self.dataset.load(idx, rng)
                 except (ValueError, OSError) as e:
                     last_err = e
+                else:
+                    self._count_sample(time.perf_counter() - t0)
+                    return sample
             self._quarantine(epoch, index, idx, resample, last_err)
             r = np.random.default_rng(np.random.SeedSequence(
                 [self.seed, epoch, index, self._RESAMPLE_SALT, resample]))
@@ -487,16 +494,34 @@ class ShardedLoader:
             f"draw(s) all failed to load — giving up (last error: "
             f"{type(last_err).__name__}: {last_err})") from last_err
 
+    def _metrics(self):
+        """Where this loader's counters go: ``registry``, else the
+        process-wide default."""
+        return self.registry if self.registry is not None \
+            else default_registry()
+
+    def _count_sample(self, seconds: float) -> None:
+        """One sample decoded and augmented in ``seconds`` of a worker
+        thread: the cumulative pair behind "how busy are the loader
+        workers" (``loader_busy_share.train``; the stage clock's
+        producer records quote the process-wide totals,
+        docs/OBSERVABILITY.md)."""
+        stages.bump("data_sample_seconds", seconds)
+        stages.bump("data_samples", 1)
+        reg = self._metrics()
+        reg.counter("raft_data_sample_seconds_total",
+                    "loader-worker seconds spent loading samples "
+                    "(decode + augment)").inc(seconds)
+        reg.counter("raft_data_samples_total",
+                    "samples the loader workers delivered").inc()
+
     def _quarantine(self, epoch: int, index: int, idx: int,
                     resample: int, err: Exception) -> None:
         from raft_tpu.obs.events import default_sink
-        from raft_tpu.obs.registry import default_registry
 
         with self._quarantine_lock:
             self.quarantined_total += 1
-        reg = self.registry if self.registry is not None \
-            else default_registry()
-        reg.counter(
+        self._metrics().counter(
             "raft_data_quarantined_total",
             "samples skipped after repeated read failures "
             "(replaced by a deterministic resample)").inc()

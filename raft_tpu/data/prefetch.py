@@ -30,22 +30,25 @@ pipeline (pulled-but-undelivered) beyond the one the consumer is
 stepping on — the device buffers of a deep queue would otherwise
 accumulate in HBM.
 
-Telemetry: the producer times both stages per batch and the consumer
-reads them (``last_prep_s`` / ``last_h2d_s``) right after ``next()``,
-so the train loop can split the old ``data_wait_s`` into consumer-side
-queue wait (the true input-bound signal under overlap) and the
-producer-side ``h2d_s`` span (docs/OBSERVABILITY.md).
+Telemetry: every batch is one ``input`` unit of the stage clock
+(``obs/stages.py``) with stages ``slot_wait`` (no buffer budget: the
+loader has slack), ``source`` (the wait on the loader: loader-bound),
+``prep`` and ``h2d``, closed on the thread that produced it.  The
+consumer reads the delivered batch's record (``last_unit``) right after
+``next()``, so the train loop can split the old ``data_wait_s`` into
+consumer-side queue wait and the producer-side ``h2d`` / ``prep`` spans
+(docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, Iterable, Iterator, Optional
 
 from raft_tpu import chaos
 from raft_tpu.chaos import InjectedProducerCrash
+from raft_tpu.obs import stages
 
 # Producer -> consumer message kinds.
 _ITEM, _END, _ERROR = "item", "end", "error"
@@ -84,6 +87,9 @@ class DevicePipeline:
     injection); called in stream order by exactly one thread.
     ``depth``: buffered batches beyond the one handed to the consumer;
     0 = synchronous serial path (no thread).
+    ``registry``: the ``MetricRegistry`` that takes the batches' stage
+    seconds (``raft_stage_seconds_total{loop="input"}``); None = only
+    the stage clock's ring.
 
     Iteration: ``next(pipeline)`` returns the next device-resident
     batch; ``StopIteration`` when the source ends.  A producer-side
@@ -98,7 +104,7 @@ class DevicePipeline:
                  prep_fn: Optional[Callable] = None,
                  depth: int = 2, keep_host: bool = False,
                  interrupt: Optional[Callable[[], bool]] = None,
-                 interrupt_poll_s: float = 0.1):
+                 interrupt_poll_s: float = 0.1, registry=None):
         if depth < 0:
             raise ValueError(f"device-prefetch depth must be >= 0, "
                              f"got {depth}")
@@ -124,14 +130,14 @@ class DevicePipeline:
         # exactly like the pre-pipeline serial loop.
         self._interrupt = interrupt
         self._interrupt_poll_s = max(float(interrupt_poll_s), 1e-3)
-        # Per-batch producer spans, valid right after next() returns.
-        self.last_prep_s = 0.0
-        self.last_h2d_s = 0.0
-        # (t_pull, t_prepped, t_put) perf_counter stamps for the batch
-        # just delivered — lets the train loop's step trace place the
-        # producer-side prep/h2d spans on the shared monotonic timeline
-        # (obs/trace.record_span) instead of only knowing durations.
-        self.last_stamps: Optional[tuple] = None
+        # The stage-clock record of the batch just delivered (seconds
+        # and perf_counter spans of slot_wait / source / prep / h2d),
+        # valid right after next() returns — lets the train loop's step
+        # trace place the producer-side prep/h2d spans on the shared
+        # monotonic timeline (obs/trace.record_span).  ``registry``
+        # takes the ``raft_stage_seconds_total{loop="input"}`` seconds.
+        self.last_unit: Optional[dict] = None
+        self._registry = registry
         self.last_host_batch = None
         # Cumulative, for the input microbench / pipeline stats.
         self.prep_total_s = 0.0
@@ -150,45 +156,56 @@ class DevicePipeline:
             self._thread.start()
 
     # -- producer (depth > 0) -------------------------------------------
+    def _make(self):
+        """Pull, prep and place one batch inside the open ``input``
+        unit -> (device batch, host batch kept, the unit's record).
+        The loader workers' sample totals ride on the record as they
+        stood when it closed, so a reader takes a window's share of
+        them as last - first.  StopIteration propagates."""
+        with stages.stage("input", "source"):
+            batch = next(self._src)
+        with stages.stage("input", "prep"):
+            if self._prep is not None:
+                batch = self._prep(batch)
+        host = batch if self.keep_host else None
+        with stages.stage("input", "h2d"):
+            batch = self._put(batch)
+        return batch, host, stages.end(
+            "input", registry=self._registry,
+            sample_seconds_total=stages.total("data_sample_seconds"),
+            samples_total=stages.total("data_samples"))
+
     def _produce(self) -> None:
         produced = 0  # pull ordinal, matches the serial path's count
         try:
             while True:
                 # Slot first: never pull (or decode, or device_put) a
                 # batch there is no buffer budget for.
-                while not self._slots.acquire(timeout=0.05):
-                    if self._stop.is_set():
-                        return
+                stages.begin("input")
+                with stages.stage("input", "slot_wait"):
+                    while not self._slots.acquire(timeout=0.05):
+                        if self._stop.is_set():
+                            return
                 if self._stop.is_set():
                     return
                 _chaos_producer_point(produced)
                 produced += 1
                 try:
-                    batch = next(self._src)
+                    self._q.put((_ITEM,) + self._make())
                 except StopIteration:
-                    self._q.put((_END, None, None, 0.0, 0.0, None))
+                    self._q.put((_END, None, None, None))
                     return
-                t0 = time.perf_counter()
-                if self._prep is not None:
-                    batch = self._prep(batch)
-                t1 = time.perf_counter()
-                host = batch if self.keep_host else None
-                batch = self._put(batch)
-                t2 = time.perf_counter()
-                self._q.put((_ITEM, batch, host, t1 - t0, t2 - t1,
-                             (t0, t1, t2)))
         except BaseException as e:  # re-raised in the consumer
-            self._q.put((_ERROR, e, None, 0.0, 0.0, None))
+            self._q.put((_ERROR, e, None, None))
 
     # -- consumer --------------------------------------------------------
     def __iter__(self) -> "DevicePipeline":
         return self
 
-    def _account(self, prep_s: float, h2d_s: float) -> None:
-        self.last_prep_s = prep_s
-        self.last_h2d_s = h2d_s
-        self.prep_total_s += prep_s
-        self.h2d_total_s += h2d_s
+    def _account(self, unit: dict) -> None:
+        self.last_unit = unit
+        self.prep_total_s += unit["stages"]["prep"]
+        self.h2d_total_s += unit["stages"]["h2d"]
         self.batches_out += 1
 
     def __next__(self):
@@ -198,19 +215,12 @@ class DevicePipeline:
             # The exact old serial path: prep + put inline, on this
             # thread, one batch at a time.
             _chaos_producer_point(self.batches_out)
-            batch = next(self._src)  # StopIteration propagates
-            t0 = time.perf_counter()
-            if self._prep is not None:
-                batch = self._prep(batch)
-            t1 = time.perf_counter()
-            self.last_host_batch = batch if self.keep_host else None
-            batch = self._put(batch)
-            t2 = time.perf_counter()
-            self.last_stamps = (t0, t1, t2)
-            self._account(t1 - t0, t2 - t1)
+            stages.begin("input")
+            batch, self.last_host_batch, unit = self._make()
+            self._account(unit)
             return batch
         if self._interrupt is None:
-            kind, payload, host, prep_s, h2d_s, stamps = self._q.get()
+            kind, payload, host, unit = self._q.get()
         else:
             # Timed wait + flag re-check: a preemption request cannot
             # interrupt queue.get, so poll.  The poll costs nothing on
@@ -219,8 +229,8 @@ class DevicePipeline:
             # a SIGTERM during an input stall to interrupt_poll_s.
             while True:
                 try:
-                    (kind, payload, host, prep_s, h2d_s,
-                     stamps) = self._q.get(timeout=self._interrupt_poll_s)
+                    kind, payload, host, unit = self._q.get(
+                        timeout=self._interrupt_poll_s)
                     break
                 except queue.Empty:
                     if self._interrupt():
@@ -235,8 +245,7 @@ class DevicePipeline:
             raise payload
         self._slots.release()
         self.last_host_batch = host
-        self.last_stamps = stamps
-        self._account(prep_s, h2d_s)
+        self._account(unit)
         return payload
 
     def buffered(self) -> int:

@@ -331,9 +331,20 @@ def _record(trace_id, span_id, parent_id, name, t_start_wall,
     return rec
 
 
+class _Recorded:
+    """What :func:`record_span` hands back: just enough of a span to be
+    the ``parent`` of further ``record_span`` calls."""
+
+    __slots__ = ("_state", "span_id")
+
+    def __init__(self, state, span_id):
+        self._state = state
+        self.span_id = span_id
+
+
 def record_span(parent, name: str, t_start_mono: float,
                 t_end_mono: float, status: str = "ok",
-                **attrs) -> None:
+                **attrs) -> Optional[_Recorded]:
     """Record an already-measured interval as a child of ``parent``.
 
     This is the cross-thread escape hatch for work timed where no
@@ -342,18 +353,23 @@ def record_span(parent, name: str, t_start_mono: float,
     attaches them to its step trace here; the serve device worker
     attaches per-request queue/pad/device windows the same way.  The
     wall-clock start is derived from the monotonic offset so Perfetto
-    export stays consistent with live spans."""
+    export stays consistent with live spans.  Returns a handle that a
+    further ``record_span`` takes as ``parent`` (the engine's
+    ``device`` span owns ``h2d`` / ``launch`` / ``drain``); None when
+    ``parent`` is no live span."""
     if parent is None or not parent:
-        return
+        return None
     st = parent._state
     wall = time.time() - (time.perf_counter() - t_start_mono)
-    rec = _record(st.trace_id, _new_id(), parent.span_id, name, wall,
+    span_id = _new_id()
+    rec = _record(st.trace_id, span_id, parent.span_id, name, wall,
                   t_start_mono, t_end_mono, status, attrs)
     with st.lock:
         st.records.append(rec)
         if status == "error":
             st.keep = True
         st._flush_locked()
+    return _Recorded(st, span_id)
 
 
 # ---------------------------------------------------------------------------

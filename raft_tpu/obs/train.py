@@ -28,11 +28,12 @@ Per step it records/emits:
 
 One-time events: ``run_config`` (what scripts/telemetry_summary.py
 needs to fold the log into bench.py JSON), ``compile`` (the first
-executed step's dispatch time, which is dominated by trace+compile; the
-:class:`~raft_tpu.utils.profiling.CompileCounter` is wired into the
-registry), ``hbm_usage`` (XLA memory analysis of the compiled step —
-the loop AOT-compiles the step once and runs that executable, so this
-costs no second compile; disable with ``RAFT_TELEMETRY_HBM=0``), and
+executed step's dispatch time, which is dominated by trace+compile; what
+XLA itself built or loaded, with seconds, is in the stage clock's
+``compile`` ring and ``raft_compile_seconds_total``), ``hbm_usage``
+(XLA memory analysis of the compiled step — the loop AOT-compiles the
+step once and runs that executable, so this costs no second compile;
+disable with ``RAFT_TELEMETRY_HBM=0``), and
 ``cost_report`` (the compiled step's FLOPs/bytes/roofline accounting
 from obs/cost.py, from the same executable; disable with
 ``RAFT_TELEMETRY_COST=0`` — per-step MFU
@@ -49,9 +50,9 @@ import os
 from typing import List, Optional, Sequence, Tuple
 
 from raft_tpu.obs import cost as cost_mod
+from raft_tpu.obs import stages
 from raft_tpu.obs.events import EventSink
 from raft_tpu.obs.registry import MetricRegistry
-from raft_tpu.utils.profiling import CompileCounter
 
 
 def _env_float(name: str, default: float = 0.0) -> float:
@@ -94,8 +95,9 @@ class TrainTelemetry:
             os.environ.get("RAFT_TELEMETRY_COST", "1") == "1")
         self._cost_book = cost_mod.CostBook(registry=self.registry,
                                             sink=self.sink)
-        self.compile_counter = CompileCounter(
-            registry=self.registry, metric="raft_train_compiles_total")
+        # raft_compile_seconds_total{kind}: what the process-wide compile
+        # listener booked, pulled when the registry is snapshot.
+        self.registry.add_collect_hook(stages.compile_seconds_hook())
         self._step_hist = self.registry.histogram(
             "raft_train_step_seconds", "wall time per training step")
         self._wait_hist = self.registry.histogram(
@@ -209,11 +211,19 @@ class TrainTelemetry:
                        num_steps=int(num_steps),
                        **self.tuning_stamp)
 
-    def record_step(self, step: int, step_time_s: float,
-                    queue_wait_s: float, h2d_s: float = 0.0,
-                    prep_s: float = 0.0) -> None:
+    def record_step(self, rec: dict, feed: Optional[dict] = None) -> None:
+        """One closed ``train`` unit of the stage clock (``rec``,
+        obs/stages.py) and the ``input`` unit of the batch it consumed
+        (``feed``, stamped on the producer thread): the histograms, the
+        pairs/s gauge and the ``train_step`` event all read these two
+        records and nothing else."""
         if not self.enabled:
             return
+        step = rec["step"]
+        step_time_s = rec["t_end"] - rec["t_start"]
+        queue_wait_s = rec["stages"].get("input_wait", 0.0)
+        fed = feed["stages"] if feed else {}
+        h2d_s, prep_s = fed.get("h2d", 0.0), fed.get("prep", 0.0)
         pps = (self.batch_size / step_time_s / self.num_devices
                if step_time_s > 0 else 0.0)
         self._step_hist.observe(step_time_s)
@@ -230,14 +240,14 @@ class TrainTelemetry:
                 and "mfu" in cost_attrs):
             self._slo.record("train_mfu",
                              cost_attrs["mfu"] >= self._mfu_floor)
-        rec = dict(step=step,
+        row = dict(step=step,
                    step_time_s=round(step_time_s, 6),
                    queue_wait_s=round(queue_wait_s, 6),
                    h2d_s=round(h2d_s, 6),
                    prep_s=round(prep_s, 6),
                    pairs_per_sec_per_chip=round(pps, 3))
-        self._recent.append(rec)
-        self.sink.emit("train_step", **rec)
+        self._recent.append(row)
+        self.sink.emit("train_step", **row)
 
     def record_health(self, step: int, *,
                       param_norm: Optional[float] = None,
@@ -290,7 +300,6 @@ class TrainTelemetry:
         dominates its wall time, so that is the recorded figure."""
         if not self.enabled:
             return
-        self.compile_counter.record(key)
         self.sink.emit("compile", step=step, key=str(key),
                        seconds=round(seconds, 6))
 

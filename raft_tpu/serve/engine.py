@@ -97,7 +97,7 @@ from raft_tpu.chaos import (InjectedDeviceError, InjectedReplicaKill,
 from raft_tpu.config import RAFTConfig
 from raft_tpu.obs import EventSink, MetricRegistry
 from raft_tpu.obs import cost as cost_mod
-from raft_tpu.obs import trace
+from raft_tpu.obs import stages, trace
 from raft_tpu.ops.pad import InputPadder, bucket_hw
 from raft_tpu.serve.stats import Counters, LatencyRecorder
 from raft_tpu.utils.profiling import CompileCounter
@@ -688,6 +688,7 @@ class InferenceEngine:
         self._pending_gauge = self.registry.gauge(
             "raft_serve_pending_requests", "requests in flight")
         self.registry.add_collect_hook(self._collect_pending)
+        self.registry.add_collect_hook(stages.compile_seconds_hook())
 
         self._pending = 0
         self._pending_lock = threading.Lock()
@@ -1296,6 +1297,12 @@ class InferenceEngine:
         }
         out["num_buckets"] = len(
             {k[0] for k in self.compile_counter.counts()})
+        # Stage clock (obs/stages.py): where the device worker's batch
+        # cycles went, cumulative seconds by stage — the very counter
+        # /metrics renders as raft_stage_seconds_total{loop="serve"}.
+        out["stage_seconds"] = {
+            dict(key)["stage"]: round(v, 6) for key, v in
+            self.registry.counter("raft_stage_seconds_total").items()}
         # Tuning-registry provenance (raft_tpu/tuning.py): which knobs
         # this replica autotuned, so a fleet operator can tell a tuned
         # replica from one running hand-rolled defaults.
@@ -1602,10 +1609,16 @@ class InferenceEngine:
         request-mode thunk over :meth:`_retry_call`)."""
 
         def thunk():
-            _, flow_up = exe(self._variables, a1, a2)
+            # The upload gets an edge of its own (it used to hide in the
+            # first jitted call's argument handling); nothing is awaited.
+            with stages.stage("serve", "h2d"):
+                d1, d2 = jax.device_put(a1), jax.device_put(a2)
+            with stages.stage("serve", "launch"):
+                _, flow_up = exe(self._variables, d1, d2)
             # np.asarray blocks on the transfer — async dispatch
             # errors surface here, inside the retry scope.
-            return np.asarray(flow_up)
+            with stages.stage("serve", "drain"):
+                return np.asarray(flow_up)
 
         return self._retry_call(bucket, seq, thunk)
 
@@ -1644,6 +1657,7 @@ class InferenceEngine:
                         or not is_transient_error(e):
                     raise
                 attempt += 1
+                self._last_retries = attempt
                 base = min(self.cfg.retry_backoff_s * 2 ** (attempt - 1),
                            self.cfg.retry_backoff_max_s)
                 backoff = base * (1.0 + self.cfg.retry_jitter
@@ -1722,55 +1736,54 @@ class InferenceEngine:
                 n=n)
 
     def _run_batch(self, bucket: tuple, reqs: List[_Request]) -> None:
+        """One request-mode batch on the device worker, timed by the
+        stage clock (obs/stages.py): ``wait`` (the worker's previous
+        batch ended -> this one entered), ``pad``, then ``h2d`` /
+        ``launch`` / ``drain`` inside the retry thunk
+        (:meth:`_call_device`), ``reply``.  The batch's record is the
+        one set of stamps: the per-request ``queue`` / ``pad`` /
+        ``device`` trace spans, the ``serve_batch`` event and the ring
+        all read it."""
         n = len(reqs)
         bs = next((s for s in self._batch_sizes if s >= n), n)
-        t_start = time.perf_counter()
+        t_in = time.perf_counter()
+        unit = stages.begin("serve", t_start=self._last_batch_done or t_in)
+        unit.add("wait", unit.t_start, t_in)
+        self._last_retries = 0
         self._batch_seq += 1
+        seq = self._batch_seq
+        bk = f"{bucket[0]}x{bucket[1]}"
         # Requests carrying a trace context get per-request queue/pad/
         # device child spans; a batch with no traced request pays only
         # this list comprehension.
         traced = [r for r in reqs if r.trace is not None]
+        error = None
         try:
-            self._chaos_replica_faults(self._batch_seq)
+            self._chaos_replica_faults(seq)
             exe = self._get_executable(bucket, bs)
-            t_pad0 = time.perf_counter()
-            im1 = [r.padder.pad_np(r.image1) for r in reqs]
-            im2 = [r.padder.pad_np(r.image2) for r in reqs]
-            if bs > n:  # ballast lanes keep the compiled batch shape
-                im1 += [im1[-1]] * (bs - n)
-                im2 += [im2[-1]] * (bs - n)
-            a1, a2 = np.stack(im1), np.stack(im2)
-            t_pad1 = time.perf_counter()
-            flow_up = self._call_device(exe, a1, a2, bucket,
-                                        self._batch_seq)
-            t_done = time.perf_counter()
-            for j, r in enumerate(reqs):
-                r.future.set_result(
-                    np.asarray(r.padder.unpad(flow_up[j:j + 1])[0]))
-                self._latency.record(t_done - r.t_submit)
-                if self._slo is not None:
-                    self._slo_request(True, t_done - r.t_submit)
-            self._counters.add_batch(real=n, padded=bs - n, failed=False)
-            self._sink.emit("serve_batch",
-                            bucket=f"{bucket[0]}x{bucket[1]}", real=n,
-                            ballast=bs - n,
-                            seconds=round(t_done - t_start, 6))
-            if traced:
-                retries = self._last_retries
-                bk = f"{bucket[0]}x{bucket[1]}"
-                cost_attrs = self._pipeline_cost_attrs(
-                    bucket, bs, self.cfg.iters, t_done - t_pad1)
-                for r in traced:
-                    trace.record_span(r.trace, "queue", r.t_submit,
-                                      t_start, batch=self._batch_seq)
-                    trace.record_span(r.trace, "pad", t_pad0, t_pad1,
-                                      real=n, ballast=bs - n)
-                    trace.record_span(r.trace, "device", t_pad1, t_done,
-                                      bucket=bk, batch=self._batch_seq,
-                                      retries=retries, **cost_attrs)
-                    if retries:  # tail-keep: a retried batch is news
-                        r.trace.mark_keep()
+            with stages.stage("serve", "pad"):
+                im1 = [r.padder.pad_np(r.image1) for r in reqs]
+                im2 = [r.padder.pad_np(r.image2) for r in reqs]
+                if bs > n:  # ballast lanes keep the compiled batch shape
+                    im1 += [im1[-1]] * (bs - n)
+                    im2 += [im2[-1]] * (bs - n)
+                a1, a2 = np.stack(im1), np.stack(im2)
+            flow_up = self._call_device(exe, a1, a2, bucket, seq)
+            with stages.stage("serve", "reply"):
+                t_done = unit.spans["drain"][1]
+                for j, r in enumerate(reqs):
+                    r.future.set_result(
+                        np.asarray(r.padder.unpad(flow_up[j:j + 1])[0]))
+                    self._latency.record(t_done - r.t_submit)
+                    if self._slo is not None:
+                        self._slo_request(True, t_done - r.t_submit)
+                self._counters.add_batch(real=n, padded=bs - n,
+                                         failed=False)
+                self._sink.emit("serve_batch", bucket=bk, real=n,
+                                ballast=bs - n,
+                                seconds=round(t_done - t_in, 6))
         except Exception as e:
+            error = type(e).__name__
             for r in reqs:
                 if not r.future.done():
                     r.future.set_exception(e)
@@ -1780,22 +1793,50 @@ class InferenceEngine:
             self._counters.add_batch(real=n, padded=bs - n, failed=True)
             if self._slo is not None:
                 self._slo_request(False, n=n)
-            self._sink.emit("serve_batch_error",
-                            bucket=f"{bucket[0]}x{bucket[1]}", real=n,
-                            error=f"{type(e).__name__}: {e}")
-            if traced:
-                t_err = time.perf_counter()
-                for r in traced:
-                    trace.record_span(r.trace, "queue", r.t_submit,
-                                      t_start, batch=self._batch_seq)
-                    trace.record_span(r.trace, "device", t_start, t_err,
-                                      status="error",
-                                      error=f"{type(e).__name__}",
-                                      batch=self._batch_seq)
+            self._sink.emit("serve_batch_error", bucket=bk, real=n,
+                            error=f"{error}: {e}")
         finally:
+            rec = stages.end(
+                "serve", registry=self.registry, batch=seq, bucket=bk,
+                real=n, ballast=bs - n, retries=self._last_retries,
+                queue_s=[t_in - r.t_submit for r in reqs], error=error)
             with self._pending_lock:
                 self._pending -= len(reqs)
-                self._last_batch_done = time.perf_counter()
+                self._last_batch_done = rec["t_end"]
+        if traced:
+            self._trace_batch(traced, rec, bucket)
+
+    def _trace_batch(self, traced: List[_Request], rec: dict,
+                     bucket: tuple) -> None:
+        """Per-request trace spans of one batch, from its stage record:
+        ``queue`` (submit -> batch entered), ``pad``, and ``device``
+        (upload through copy-back) as the parent of ``h2d`` /
+        ``launch`` / ``drain``."""
+        spans, seq = rec["spans"], rec["batch"]
+        t_in = spans["wait"][1]
+        for r in traced:
+            trace.record_span(r.trace, "queue", r.t_submit, t_in,
+                              batch=seq)
+        if rec["error"] is not None:
+            for r in traced:
+                trace.record_span(r.trace, "device", t_in, rec["t_end"],
+                                  status="error", error=rec["error"],
+                                  batch=seq)
+            return
+        t_dev0, t_dev1 = spans["h2d"][0], spans["drain"][1]
+        cost_attrs = self._pipeline_cost_attrs(
+            bucket, rec["real"] + rec["ballast"], self.cfg.iters,
+            t_dev1 - t_dev0)
+        for r in traced:
+            trace.record_span(r.trace, "pad", *spans["pad"],
+                              real=rec["real"], ballast=rec["ballast"])
+            dev = trace.record_span(
+                r.trace, "device", t_dev0, t_dev1, bucket=rec["bucket"],
+                batch=seq, retries=rec["retries"], **cost_attrs)
+            for name in ("h2d", "launch", "drain"):
+                trace.record_span(dev, name, *spans[name])
+            if rec["retries"]:  # tail-keep: a retried batch is news
+                r.trace.mark_keep()
 
     # ------------------------------------------------------------------
     # internals — device-worker thread, slot mode

@@ -34,7 +34,7 @@ from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
 from raft_tpu.models.raft import RAFT
-from raft_tpu.obs import trace
+from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
 from raft_tpu.obs.watchdog import StallWatchdog, stack_dump_path
@@ -274,7 +274,8 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         # Single-host preemption can interrupt an input-stalled consumer
         # (the pipeline polls the flag while its buffer is empty);
         # multi-host exits only through the agreed-step sync below.
-        interrupt=_PREEMPT.is_set if jax.process_count() == 1 else None)
+        interrupt=_PREEMPT.is_set if jax.process_count() == 1 else None,
+        registry=telem.registry)
     # Stall watchdog: per-iteration heartbeats; no heartbeat within
     # cfg.watchdog_timeout -> all-thread stack dump + `stall` event
     # (+ optional hard exit).  Paused around save/validate, whose
@@ -301,13 +302,18 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
             # fetch+prep+H2D cost — the old data_wait_s.
             if watchdog is not None:
                 watchdog.beat(step)
-            t_iter = time.perf_counter()
+            # The stage clock (obs/stages.py) times every step, telemetry
+            # or not: input_wait / dispatch / host.  The step's record is
+            # the one set of stamps the trace spans, the train_step event
+            # and the histograms read.
+            unit = stages.begin("train")
             # One trace root per sampled step; None when tracing is off
             # (the rate=0 hot path costs only this identity check).
             st = (tracer.start_trace("train_step", step=step)
                   if tracer is not None else None)
             try:
-                sharded = next(pipeline)
+                with stages.stage("train", "input_wait"):
+                    sharded = next(pipeline)
             except StopIteration:
                 break
             except PipelineInterrupted:
@@ -316,16 +322,6 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 # arrived).  State is the last completed step —
                 # consistent, same as the boundary exit below.
                 raise SystemExit(143)
-            queue_wait_s = time.perf_counter() - t_iter
-            if st is not None:
-                trace.record_span(st, "queue_wait", t_iter,
-                                  t_iter + queue_wait_s)
-                if pipeline.last_stamps is not None:
-                    # Producer-side spans, stamped on the producer
-                    # thread and attached here (cross-thread handoff).
-                    p0, p1, p2 = pipeline.last_stamps
-                    trace.record_span(st, "prep", p0, p1)
-                    trace.record_span(st, "h2d", p1, p2)
             if step >= cfg.num_steps:
                 break
             if health is not None:
@@ -370,28 +366,27 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                     compiled = None
                 else:
                     run_step = compiled
-            t_d0 = time.perf_counter()
             try:
-                with annotate_step(step):
+                with stages.stage("train", "dispatch"), annotate_step(step):
                     state, metrics = run_step(state, sharded, key)
             except BaseException as e:
                 if st is not None:
-                    trace.record_span(st, "step_dispatch", t_d0,
-                                      time.perf_counter(),
+                    trace.record_span(st, "step_dispatch",
+                                      *unit.spans["dispatch"],
                                       status="error",
                                       error=type(e).__name__)
                     st.end(status="error", error=type(e).__name__)
                 raise
-            if st is not None:
-                trace.record_span(st, "step_dispatch", t_d0,
-                                  time.perf_counter())
-            profiler.maybe_stop(step, sync_on=metrics.get("loss"))
-            step += 1
-            logger.push(step - 1, metrics)
+            with stages.stage("train", "host"):
+                profiler.maybe_stop(step, sync_on=metrics.get("loss"))
+                step += 1
+                logger.push(step - 1, metrics)
+            rec = stages.end("train", registry=telem.registry,
+                             step=step - 1)
             # step_time_s covers queue wait + dispatch.  Dispatch is
             # async, so once the pipeline fills this converges to the
             # device step time without ever forcing a transfer.
-            step_time_s = time.perf_counter() - t_iter
+            step_time_s = rec["t_end"] - rec["t_start"]
             if not first_dispatched:
                 first_dispatched = True
                 # The first dispatch of this signature traces+compiles
@@ -421,10 +416,17 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                             telem.num_devices))
                 if watchdog is not None:
                     watchdog.resume()  # compile window over
-            telem.record_step(step - 1, step_time_s, queue_wait_s,
-                              h2d_s=pipeline.last_h2d_s,
-                              prep_s=pipeline.last_prep_s)
+            feed = pipeline.last_unit
+            telem.record_step(rec, feed)
             if st is not None:
+                # The step's spans, from its record; the producer's
+                # (stamped on its thread) from the delivered batch's.
+                trace.record_span(st, "queue_wait",
+                                  *rec["spans"]["input_wait"])
+                for name in ("prep", "h2d"):
+                    trace.record_span(st, name, *feed["spans"][name])
+                trace.record_span(st, "step_dispatch",
+                                  *rec["spans"]["dispatch"])
                 # Flush point: sampled/kept traces emit now; the rest
                 # park in the dropped ring for a late verdict (the
                 # health monitor re-keeps non-finite steps at flush).

@@ -66,6 +66,64 @@ class CompileCounter:
             self._counts.clear()
 
 
+#: The ``jax.monitoring`` duration events the compile listener reads, as
+#: the installed jax 0.9.0 emits them (docs/OBSERVABILITY.md).
+#: ``backend_compile_duration`` wraps ``compiler.compile_or_get_cached``
+#: (``pxla.py``), so it fires for a backend compile AND for a
+#: persistent-cache hit, after ``cache_retrieval_time_sec``, which only a
+#: hit emits (``compiler.py``) on the same thread.  The other two time a
+#: jitted function's trace to a jaxpr and its lowering to MLIR — what a
+#: retrace costs before any compile; a function traced inside another's
+#: trace reports too, so these overlap and are never summed, and the
+#: thousands under ``_MIN_BUILD_STEP_S`` a process makes (every small
+#: jitted helper, once a call site) would only push the programs out of
+#: the ring.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BUILD_STEPS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
+_MIN_BUILD_STEP_S = 0.05
+
+_listen_lock = threading.Lock()
+_listening = False
+_cache_hit = threading.local()
+
+
+def _on_compile_event(event: str, seconds: float, **kwargs) -> None:
+    if event == CACHE_RETRIEVAL_EVENT:
+        _cache_hit.pending = True
+        return
+    kind = _BUILD_STEPS.get(event)
+    if kind is not None and seconds < _MIN_BUILD_STEP_S:
+        return
+    if event == BACKEND_COMPILE_EVENT:
+        kind = ("cache_load" if getattr(_cache_hit, "pending", False)
+                else "compile")
+        _cache_hit.pending = False
+    if kind is not None:
+        from raft_tpu.obs import stages
+
+        stages.note("compile", kind, float(seconds),
+                    name=str(kwargs.get("fun_name", "")))
+
+
+def listen_for_compiles() -> None:
+    """Register, once a process, the listener that turns every program
+    XLA builds or loads from the persistent cache into a ``compile``
+    record of the stage clock (``obs.stages.recent("compile")``:
+    ``{t_end, seconds, kind: compile|cache_load, name}``), and every
+    trace and lowering on the way there into one of ``kind``
+    ``trace|lower``.  Unlike :class:`CompileCounter`, which counts what
+    callers report, it sees what ``jit`` does on its own: a retrace is
+    a second ``trace`` record with the function's name."""
+    global _listening
+    with _listen_lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
 @dataclasses.dataclass
 class StepProfiler:
     """Capture a ``jax.profiler`` trace for steps [start, stop).
@@ -306,11 +364,14 @@ def enable_persistent_compile_cache(force: bool = False) -> str:
     executable is not trustworthy (earlier jaxlibs aborted the process
     on its first execution), and a cache enabled under the CPU tests
     would be read back by every later test run.  TPU deserialization is
-    the supported path."""
+    the supported path.
+
+    Every caller is about to compile, so this is also where the compile
+    listener is registered (:func:`listen_for_compiles`), on every
+    backend."""
     import os
 
-    import jax
-
+    listen_for_compiles()
     if jax.default_backend() == "cpu" and not force:
         return ""
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -448,8 +448,12 @@ def _synthesize(directory):
     b = root.child("attempt", replica="r1", hedge=True)
     record_span(b, "queue", t0 + 0.040, t0 + 0.042)
     record_span(b, "pad", t0 + 0.042, t0 + 0.043, real=1, ballast=1)
-    record_span(b, "device", t0 + 0.043, t0 + 0.055, retries=0,
-                flops=2.0e9, bytes=1.0e9, mfu=0.31)
+    dev = record_span(b, "device", t0 + 0.043, t0 + 0.055, retries=0,
+                      flops=2.0e9, bytes=1.0e9, mfu=0.31)
+    # the engine's device span owns its stages (obs/stages.py)
+    record_span(dev, "h2d", t0 + 0.043, t0 + 0.045)
+    record_span(dev, "launch", t0 + 0.045, t0 + 0.048)
+    record_span(dev, "drain", t0 + 0.048, t0 + 0.055)
     time.sleep(0.020)               # past b's device end
     b.end(status="ok", won=True)
     root.mark_keep()                # the hedge fired: tail-keep

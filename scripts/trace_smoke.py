@@ -188,6 +188,10 @@ def main(argv=None) -> int:
             kids = {c["name"]
                     for c in tree["children"].get(a["span_id"], [])}
             assert {"queue", "pad", "device"} <= kids, (a, kids)
+            dev = next(c for c in tree["children"][a["span_id"]]
+                       if c["name"] == "device")
+            assert {c["name"] for c in tree["children"].get(
+                dev["span_id"], [])} == {"h2d", "launch", "drain"}, dev
         winner = next(a for a in attempts if a.get("won"))
         loser = next(a for a in attempts if not a.get("won"))
         assert winner["hedge"] is True
@@ -199,7 +203,9 @@ def main(argv=None) -> int:
         # -- 2. critical path bottoms out in the winner's device ------
         path = report.critical_path(tree)
         names = [rec["name"] for rec, _ in path]
-        assert names[0] == "route" and names[-1] == "device", names
+        # device's last child (the wait for the chip + copy back) ends it
+        assert names[0] == "route" \
+            and names[-2:] == ["device", "drain"], names
         assert winner["span_id"] in [rec["span_id"] for rec, _ in path], \
             f"critical path skipped the hedge winner: {names}"
         assert loser["span_id"] not in [rec["span_id"] for rec, _ in

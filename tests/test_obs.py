@@ -250,8 +250,11 @@ def test_train_telemetry_stream(tmp_path, monkeypatch):
     assert t.enabled
     t.start(start_step=0, num_steps=100)
     t.record_compile(0, 12.5, key=("train_step", (368, 496), 16))
-    t.record_step(0, step_time_s=0.5, queue_wait_s=0.01, h2d_s=0.002,
-                  prep_s=0.001)
+    # a closed `train` unit of the stage clock + the `input` unit of the
+    # batch it consumed (obs/stages.py): all record_step reads
+    t.record_step({"step": 0, "t_start": 10.0, "t_end": 10.5,
+                   "stages": {"input_wait": 0.01, "dispatch": 0.4}},
+                  {"stages": {"h2d": 0.002, "prep": 0.001}})
     t.record_hbm({"peak_hbm_gb": 3.5})
     t.close()
     (f,) = tmp_path.glob("*.jsonl")
@@ -268,8 +271,8 @@ def test_train_telemetry_stream(tmp_path, monkeypatch):
     assert by_event["hbm_usage"]["peak_hbm_gb"] == 3.5
     summary = by_event["metrics_summary"]["metrics"]
     assert summary["raft_train_step_seconds"]["values"][""]["count"] == 1
-    assert summary["raft_train_compiles_total"]["values"] \
-        [f"key={('train_step', (368, 496), 16)}"] == 1
+    # the compile listener's seconds, pulled by the registry's hook
+    assert summary["raft_compile_seconds_total"]["type"] == "counter"
 
 
 def test_train_telemetry_disabled_writes_nothing(tmp_path, monkeypatch):
@@ -278,7 +281,8 @@ def test_train_telemetry_disabled_writes_nothing(tmp_path, monkeypatch):
                        image_size=(32, 32))
     assert not t.enabled and not t.hbm_enabled
     t.start(0, 10)
-    t.record_step(0, 0.1, 0.0)
+    t.record_step({"step": 0, "t_start": 0.0, "t_end": 0.1,
+                   "stages": {}})
     t.close()
     assert list(tmp_path.iterdir()) == []
 
@@ -392,6 +396,12 @@ def test_loop_data_wait_and_no_per_step_sync(tmp_path, monkeypatch,
     expected = 4 * len(_STUB_METRIC_KEYS)  # num_steps * metric keys
     assert transfers_on == transfers_off == expected
     assert flushes_on == flushes_off == 2
+    # ... and both counts are with the always-on stage clock
+    # (obs/stages.py) timing every step, telemetry or not.
+    from raft_tpu.obs import stages
+
+    assert [r["step"] for r in stages.recent("train")][-8:] \
+        == [0, 1, 2, 3] * 2
 
     (f,) = tdir.glob("telemetry-p*.jsonl")
     recs = [json.loads(line) for line in f.read_text().splitlines()]

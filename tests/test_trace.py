@@ -22,7 +22,7 @@ import pytest
 
 from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig
-from raft_tpu.obs import trace
+from raft_tpu.obs import stages, trace
 from raft_tpu.serve import (FleetConfig, FlowRouter, InferenceEngine,
                             ReplicaFleet, RouterConfig, ServeConfig)
 
@@ -311,10 +311,16 @@ def test_zero_overhead_when_disabled(variables, aot_dir):
     eng = _mk_engine(variables, aot_dir).start()
     try:
         rng = np.random.default_rng(3)
+        before = len(stages.recent("serve"))
         fut = eng.submit(*_images(rng))
         assert fut.result(timeout=60).shape == SHAPE + (2,)
         assert not sink.spans()
         assert trace.current() is None
+        # the stage clock is not the span machinery: it timed the batch
+        # all the same, with no sink, context or sample rate
+        _wait_for(lambda: len(stages.recent("serve")) == before + 1, 10,
+                  "the batch's stage record")
+        assert stages.recent("serve")[-1]["real"] == 1
     finally:
         eng.stop()
 
@@ -428,6 +434,8 @@ def test_trace_smoke_tiny(capsys):
     assert rc == 0, rec
     assert rec["metric"] == "trace_smoke" and rec["value"] == 1.0
     cfg = rec["config"]
-    assert cfg["one_tree"]["spans"] == 9  # route + 2x(attempt+q/p/d)
-    assert cfg["critical_path"][-1].startswith("device:")
+    # route + 2x(attempt + queue/pad/device + device's h2d/launch/drain)
+    assert cfg["one_tree"]["spans"] == 15
+    assert cfg["critical_path"][-2].startswith("device:")
+    assert cfg["critical_path"][-1].startswith("drain:")
     assert cfg["exports"]["traces_total"] == 3

@@ -168,7 +168,8 @@ def test_run_config_carries_tuning_stamp(tmp_path):
                                          "tuning_key": "train|cpu|x|b4",
                                          "tuning_registry_hash": "abc"})
     telem.start(start_step=0, num_steps=10)
-    telem.record_step(step=1, step_time_s=0.5, queue_wait_s=0.0)
+    telem.record_step({"step": 1, "t_start": 0.0, "t_end": 0.5,
+                       "stages": {"input_wait": 0.0}})
     telem.sink.close()
     ts = _load_script("telemetry_summary")
     (run_cfg, steps, health, faults, spans, costs, quality,
