@@ -6,7 +6,7 @@ RAFT-full's published width (hidden 128 / context 128 / 4 levels /
 radius 4, bf16 compute) on data made from ``--seed``:
 
 1. **train**    ``raft_tpu.cli.train`` — chairs stage, 368x496 crops, 12
-   iterations, ``--corr_impl auto`` (the Pallas lookup on TPU), a few steps,
+   iterations, ``--corr_impl auto`` (the Mosaic lookup on TPU), a few steps,
    a checkpoint;
 2. **validate** ``raft_tpu.cli.evaluate`` on that checkpoint over the
    generated chairs validation split, then one test-mode forward at the
@@ -185,9 +185,16 @@ def phase_train(work, data_root, split, size, seed, on_tpu):
 
     t0 = time.perf_counter()
     tdir = os.path.join(work, "telemetry-train")
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.models.raft import corr_impl_at
+
     picked = train_cli.default_corr_impl()
     if on_tpu:
-        assert picked == "allpairs_pallas", picked
+        # the code's own choice at this crop, nothing asked for by name
+        at_crop = corr_impl_at(
+            RAFTConfig.full(corr_impl=picked, compute_dtype="bfloat16"),
+            size["crop"][0] // 8, size["crop"][1] // 8)
+        assert at_crop == "allpairs_pallas", (picked, at_crop)
     state = train_cli.run([
         "--name", "smoke", "--stage", "chairs",
         "--image_size", *map(str, size["crop"]),
@@ -405,9 +412,17 @@ def phase_serve(ckpt, size, seed, batching):
     assert stats["completed"] == len(pairs), stats
     assert stats["errors"] == 0 and stats["failed_lanes"] == 0, stats
     assert stats["batching"] == batching, stats
+    # every program pair samples its pyramid with the lookup the code picks
+    # for this platform: the Mosaic kernel on the chip, XLA elsewhere
+    import jax
+
+    lookup = "mosaic" if jax.default_backend() == "tpu" else "xla"
+    assert stats["lookup"] and set(stats["lookup"].values()) == {lookup}, \
+        stats["lookup"]
     report(f"serve[{batching}]", t0, result["startup_seconds"],
            ["every answer finite, float32, shaped like its request",
-            "/v1/stats: completed == requests, 0 errors, 0 failed lanes"],
+            "/v1/stats: completed == requests, 0 errors, 0 failed lanes",
+            f"/v1/stats: every program's lookup is {lookup}"],
            compile_seconds_is="checkpoint load + warm-up, until "
                               "/v1/healthz answered",
            requests=len(pairs), latency_s=result["latency_s"],

@@ -124,10 +124,11 @@ def parse_args(argv=None):
     p.add_argument("--corr_impl", default="auto",
                    choices=["auto", "allpairs", "allpairs_pallas",
                             "chunked", "pallas"],
-                   help="'auto' = allpairs_pallas on TPU (fastest "
-                        "measured at every curriculum crop; the XLA "
-                        "allpairs path OOMs at the things stage), "
-                        "allpairs elsewhere (no interpret-mode Pallas)")
+                   help="'auto' = the materialized pyramid; which "
+                        "lookup samples it (the Mosaic kernel on a TPU "
+                        "where its block fits VMEM, XLA elsewhere) is "
+                        "chosen from the platform and the crop when "
+                        "the step traces (models/raft.py corr_impl_at)")
     p.add_argument("--data_root", default="datasets")
     p.add_argument("--chairs_split", default="chairs_split.txt")
     p.add_argument("--ckpt_dir", default="checkpoints")
@@ -270,13 +271,14 @@ def resolve_batch(batch_size, batch_per_chip, num_devices, lr):
 
 
 def default_corr_impl() -> str:
-    """What ``--corr_impl auto`` trains with on this backend: the fused
-    Pallas pyramid lookup on TPU, the XLA einsum lookup elsewhere (no
-    interpret-mode Pallas in a training loop)."""
-    import jax
+    """What ``--corr_impl auto`` hands the model: the materialized
+    pyramid, as ``RAFTConfig`` defaults to it.  Which lookup samples it
+    is not decided here: ``models.raft.corr_impl_at`` picks it from the
+    platform and the crop when the step traces, for training as for
+    serving."""
+    from raft_tpu.config import RAFTConfig
 
-    return "allpairs_pallas" if jax.default_backend() == "tpu" \
-        else "allpairs"
+    return RAFTConfig.corr_impl
 
 
 def run(argv=None):
@@ -332,8 +334,6 @@ def run(argv=None):
     corr_impl = args.corr_impl
     if corr_impl == "auto":
         corr_impl = default_corr_impl()
-    print(f"corr_impl: {args.corr_impl} -> {corr_impl} "
-          f"(backend {jax.default_backend()})", flush=True)
     from raft_tpu.config import QUANTIZED_CORR_DTYPES
 
     if (args.corr_dtype in QUANTIZED_CORR_DTYPES
@@ -357,6 +357,14 @@ def run(argv=None):
                        ("corr_levels", args.corr_levels),
                        ("corr_radius", args.corr_radius))
                       if v is not None})
+    from raft_tpu.models.raft import corr_impl_at
+    from raft_tpu.parallel.mesh import data_parallel_kernels
+
+    h8, w8 = args.image_size[0] // 8, args.image_size[1] // 8
+    with data_parallel_kernels(None, rows_split=args.shard_spatial > 1):
+        print(f"corr_impl: {args.corr_impl} -> {corr_impl}, which at "
+              f"{h8}x{w8} on {jax.default_backend()} runs "
+              f"{corr_impl_at(model_cfg, h8, w8)!r}", flush=True)
     num_hosts = jax.process_count()
     num_devices = jax.device_count()
     batch_size, lr = resolve_batch(args.batch_size, args.batch_per_chip,

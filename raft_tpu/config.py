@@ -89,15 +89,17 @@ class RAFTConfig:
     corr_levels: int = 4
     corr_radius: int = 4
     dropout: float = 0.0
-    # 'allpairs' materializes the pyramid (reference CorrBlock, corr.py:12-60)
-    # and samples it with XLA einsums; 'allpairs_pallas' materializes the
-    # same pyramid but samples it with a fused Pallas VPU kernel (both
-    # interpolation stages in VMEM) — it won at training crops in the
-    # round-1..4 sessions while 'allpairs' won at wide eval shapes
-    # (Sintel W/8=128 fills the MXU lane tile); neither margin is
-    # measured on today's code.  'chunked' is the memory-efficient
-    # blockwise path
-    # (reference AlternateCorrBlock + alt_cuda_corr, corr.py:63-91);
+    # 'allpairs' materializes the pyramid (reference CorrBlock,
+    # corr.py:12-60); which lookup samples it -- the fused Mosaic kernel
+    # over a query-minor pyramid, or XLA's batched einsums over a
+    # query-major one -- is chosen when the model traces, from the
+    # platform and the map's shape (``models/raft.py corr_impl_at`` ->
+    # ``ops/pallas_corr.pyramid_lookup_path``; PERF.md sections 4-6 hold
+    # what each costs in every cell).  'allpairs_pallas' is the same
+    # choice, and besides keeps the kernel in the Pallas interpreter off
+    # TPU under ``pallas_offtpu='interpret'`` (the CPU tests' way to run
+    # the shipped kernel).  'chunked' is the memory-efficient blockwise
+    # path (reference AlternateCorrBlock + alt_cuda_corr, corr.py:63-91);
     # 'pallas' is the fused TPU kernel version of 'chunked'.
     corr_impl: str = "allpairs"
     # Pixels per block for the chunked/pallas on-demand correlation path.
@@ -309,27 +311,6 @@ class RAFTConfig:
             _warn_pallas_fallback("upsample_loss_kernel='pallas'", "xla")
             return "xla"
         return self.upsample_loss_kernel
-
-    @property
-    def resolved_fused_lookup_encoder(self) -> bool:
-        """``fused_lookup_encoder`` with its preconditions applied.
-
-        True only when the knob is on AND the resolved corr impl is the
-        materialized-pyramid Pallas path ('allpairs_pallas' — the fused
-        kernel samples that pyramid layout) AND Pallas dispatch is
-        available (TPU, or pallas_offtpu='interpret').  Off-TPU with
-        the default fallback this resolves False through
-        ``resolved_corr_impl``'s own substitution, so default configs
-        stay bit-identical to the unfused path.
-        """
-        if not self.fused_lookup_encoder:
-            return False
-        if self.resolved_corr_impl != "allpairs_pallas":
-            _warn_pallas_fallback(
-                "fused_lookup_encoder=True (requires "
-                "corr_impl='allpairs_pallas')", "unfused lookup+conv")
-            return False
-        return True
 
     @property
     def resolved_fused_gru(self) -> bool:

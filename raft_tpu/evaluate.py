@@ -54,28 +54,25 @@ def make_inference_model(model_cfg: RAFTConfig, *,
     Every inference entry point (the validators here, the serving engine
     in ``raft_tpu/serve``) funnels through this function so the overrides
     live once: the scan unroll is forced to 1 (the config default tunes
-    the training backward pass; at 32 forward-only iterations unroll 6
-    measured 10.8 vs 11.9 frames/s on v5e), and the training-optimized
-    ``allpairs_pallas`` impl maps back to ``allpairs`` (10.4 vs 12.0
-    frames/s at the Sintel eval shape, whose W/8=128 rows fill the MXU
-    lane tile).  Explicit memory-saving choices (``chunked`` /
-    ``pallas``) are respected.
+    the training backward pass).  ``corr_impl`` passes through as it
+    is: which lookup samples a materialized pyramid is chosen where the
+    model traces, from the platform and each bucket's shape
+    (``models.raft.corr_impl_at``; the engine builds ONE model for every
+    bucket, so the choice cannot be made here); PERF.md section 5 has
+    what each lookup costs in the serve cells.
 
     The per-hardware tuning registry (raft_tpu/tuning.py) is consulted
     first for ``tuning_kind`` (default 'eval'; the serve engine passes
     ('serve', 'eval')): knobs left at their RAFTConfig defaults take the
     autotuned winner for ``(bucket_hw, batch)`` — or the nearest /
     most-recent entry when the shape isn't known yet, as here where the
-    jit compiles per streamed shape.  The inference overrides above are
-    applied AFTER tuning, so they hold unconditionally."""
+    jit compiles per streamed shape.  The inference override above is
+    applied AFTER tuning, so it holds unconditionally."""
     from raft_tpu import tuning
 
     model_cfg, _ = tuning.resolve_config(model_cfg, tuning_kind,
                                          bucket_hw, batch)
-    overrides = {"scan_unroll": 1}
-    if model_cfg.corr_impl == "allpairs_pallas":
-        overrides["corr_impl"] = "allpairs"
-    return RAFT(model_cfg.replace(**overrides))
+    return RAFT(model_cfg.replace(scan_unroll=1))
 
 
 def make_eval_fn(model_cfg: RAFTConfig, iters: int):

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from raft_tpu.config import RAFTConfig
+from raft_tpu.config import RAFTConfig, _warn_pallas_fallback
 from raft_tpu.models.extractor import BasicEncoder, SmallEncoder
 from raft_tpu.models.update import (BasicUpdateBlock, FusedCorrLookup,
                                     MaskHead, SmallUpdateBlock)
@@ -58,6 +58,40 @@ from raft_tpu.ops.corr import (
 from raft_tpu.ops.sampler import coords_grid, upflow8
 from raft_tpu.ops.upsample import (convex_upsample, convex_upsample_flat,
                                    space_to_depth_flow)
+from raft_tpu.parallel.mesh import image_rows_split
+
+
+def corr_impl_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
+    """The correlation implementation ``cfg`` runs at a ``(H/8, W/8)``
+    feature map: ``cfg.resolved_corr_impl``, with the lookup of a
+    materialized pyramid ('allpairs' / 'allpairs_pallas', one choice
+    under two names) picked by ``ops.pallas_corr.pyramid_lookup_path``
+    from what this process can observe -- 'allpairs_pallas' where the
+    Mosaic kernel runs (a TPU, its block inside the VMEM budget, whole
+    images a device), 'allpairs' (XLA) otherwise.
+
+    Everything that builds or samples the pyramid, or has to know which
+    of the two a program will hold, asks here while it traces, where
+    shapes are static: :func:`_build_corr_state` and
+    :class:`RefinementStep` (so the two layouts cannot disagree),
+    ``make_train_step``'s row-split check, the serve engine's AOT key,
+    ``cli/train.py``'s banner."""
+    impl = cfg.resolved_corr_impl
+    if cfg.corr_impl not in ("allpairs", "allpairs_pallas"):
+        return impl
+    from raft_tpu.ops.pallas_corr import pyramid_lookup_path
+
+    # pallas_offtpu='interpret' stands in for the chip where a test
+    # asked for the kernel by name; nobody else gets the interpreter.
+    on_chip = jax.default_backend() == "tpu" or impl == "allpairs_pallas"
+    path = pyramid_lookup_path(
+        "tpu" if on_chip else jax.default_backend(), h8, w8,
+        levels=cfg.corr_levels, radius=cfg.corr_radius,
+        block_q=cfg.lookup_block_q,
+        storage_bytes={"float32": 4, "bfloat16": 2}.get(
+            cfg.resolved_corr_dtype, 1),
+        rows_split=image_rows_split())
+    return "allpairs_pallas" if path == "mosaic" else "allpairs"
 
 
 def _remat_wrap(target, cfg):
@@ -109,8 +143,15 @@ class RefinementStep(nn.Module):
 
         coords1 = jax.lax.stop_gradient(coords1)
 
-        corr_impl = cfg.resolved_corr_impl
-        if cfg.resolved_fused_lookup_encoder:
+        # The same question _build_corr_state asked of the same shape:
+        # the lookup has to match the layout the pyramid was built in.
+        corr_impl = corr_impl_at(cfg, coords1.shape[1], coords1.shape[2])
+        if cfg.fused_lookup_encoder and corr_impl != "allpairs_pallas":
+            _warn_pallas_fallback(
+                "fused_lookup_encoder=True (samples the Mosaic lookup's "
+                f"pyramid; this map runs corr_impl={corr_impl!r})",
+                "unfused lookup+conv")
+        if cfg.fused_lookup_encoder and corr_impl == "allpairs_pallas":
             # Defer the lookup INTO the motion encoder: the fused Pallas
             # kernel (ops/pallas_corr.pallas_pyramid_lookup_encode)
             # samples the pyramid and applies convc1 in one VMEM pass —
@@ -312,8 +353,12 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
     ``corr_impl``.  Shared by the cold encode (:func:`_encode_state`)
     and the streaming warm encode (:class:`RAFTEncodeWarm`, which feeds
     a *carried* ``fmap1`` from the previous frame), so the corr-state
-    pytree structure cannot drift between the two admit programs."""
-    corr_impl = cfg.resolved_corr_impl
+    pytree structure cannot drift between the two admit programs.
+
+    A materialized pyramid comes query-minor for the Mosaic lookup or
+    query-major for XLA's, as :func:`corr_impl_at` picks for this map's
+    shape; :class:`RefinementStep` asks it the same."""
+    corr_impl = corr_impl_at(cfg, fmap1.shape[1], fmap1.shape[2])
     if corr_impl == "allpairs":
         # corr_dtype (storage) applies here too: the XLA lookup
         # re-accumulates fp32 in _sample_windows regardless.

@@ -42,7 +42,8 @@ def _tconv(features, kernel, cin, dtype, name):
 class FusedCorrLookup:
     """Deferred correlation lookup (``fused_lookup_encoder`` path).
 
-    When ``RAFTConfig.resolved_fused_lookup_encoder`` is on, the
+    When ``RAFTConfig.fused_lookup_encoder`` is on and the map runs the
+    Mosaic lookup (``models/raft.py corr_impl_at``), the
     refinement step hands the motion encoder THIS instead of the
     materialized ``(B, H/8, W/8, levels*(2r+1)^2)`` corr-feature tensor;
     the encoder then runs ``ops/pallas_corr.pallas_pyramid_lookup_encode``,

@@ -538,6 +538,66 @@ def _auto_interpret() -> bool:
 
 _ROW_TILE = 8
 
+# Per-instance VMEM the fused lookup may plan on, by the estimate below,
+# under the 100 MiB ``vmem_limit_bytes`` every kernel here declares.  Held
+# against Mosaic's own accounting for a v5e at a 136x240 bf16 map
+# (``tests/test_chip_compile.py``): 71.5 MiB estimated (block 384)
+# compiles; 95.4 and 119 MiB (block 512, 640) are refused "in memory
+# space vmem" by the rolled forward, whose (k, wl, BQ) expressions take
+# room of their own (the unrolled forward and the backward still take
+# 95.4, and fp32 at block 256, 89.6).  So the estimate follows what
+# Mosaic allocates, and the budget (the figure the on-demand backward
+# already plans with) is the largest reading known to compile plus a
+# tenth.
+_PYR_LOOKUP_BUDGET = _FUSED_BWD_BUDGET
+
+
+def pyramid_lookup_vmem_bytes(h8: int, w8: int, levels: int, radius: int,
+                              block_q: int, storage_bytes: int) -> int:
+    """Per-instance VMEM residency of the fused pyramid lookup at a
+    ``(H/8, W/8)`` map: every level's ``(hl, wl, block_q)`` block,
+    double-buffered by the pipeline (level 0 dominates: 55x128 bf16 is
+    2 x 1.8 MB, 136x240 2 x 8.4 MB), the ``(k*wl, block_q)`` fp32 tap
+    accumulators, and the tap and coordinate blocks.  ``wl`` counts as
+    the sublane tile it pads to.  The backward's level-0 call writes one
+    block of the same shape where the forward reads one, so one figure
+    covers both directions."""
+    k = 2 * radius + 1
+    sub = 8 * max(4 // storage_bytes, 1)     # sublane tile of the storage
+    total = 2 * (levels * k * k + 2) * block_q * 4
+    for lvl in range(levels):
+        hl, wl = h8 >> lvl, w8 >> lvl
+        if not hl or not wl:
+            break
+        total += 2 * hl * (-(-wl // sub) * sub) * block_q * storage_bytes
+        total += k * (-(-wl // 8) * 8) * block_q * 4
+    return total
+
+
+def pyramid_lookup_path(platform: str, h8: int, w8: int, *, levels: int,
+                        radius: int, block_q: int, storage_bytes: int,
+                        rows_split: bool = False) -> str:
+    """Which lookup samples a MATERIALIZED pyramid: ``'mosaic'`` (the
+    fused kernel below, query-minor pyramid) or ``'xla'``
+    (``ops.corr.corr_lookup``'s batched einsums, query-major pyramid).
+
+    The one place this is decided, from what the code can observe when
+    it traces: the platform, the map's shape and whether image rows are
+    split over devices.  Mosaic where it can run -- on a TPU, with the
+    kernel's per-block residency inside its VMEM budget, whole images on
+    each device -- because where both were measured it is the faster
+    one (55x128 rows, radius 4 and 3: 0.30 / 0.24 ms an iteration where
+    XLA's eight batched M=9 mat-muls and their relayouts take 1.5 / 1.1;
+    the train step has run it at 46x62 since before the benchmark:
+    PERF.md sections 5 and 6, PR 27); XLA everywhere else: off TPU the
+    kernel only runs in the interpreter, a block over the budget does
+    not compile, and GSPMD cannot partition a Mosaic call over rows."""
+    if platform != "tpu" or rows_split:
+        return "xla"
+    fits = pyramid_lookup_vmem_bytes(
+        h8, w8, levels, radius, block_q, storage_bytes) <= _PYR_LOOKUP_BUDGET
+    return "mosaic" if fits else "xla"
+
 
 def _pyr_fwd_level_body(corr_ref, c_ref, out_ref, acc_ref, lvl, out_off,
                         hl, wl, k):
@@ -603,6 +663,70 @@ def _pyr_fwd_level_body(corr_ref, c_ref, out_ref, acc_ref, lvl, out_off,
             out_ref[0, out_off + i * k + j:out_off + i * k + j + 1, :] = \
                 jnp.sum(wx[i] * acc_ref[j * wl:(j + 1) * wl, :], axis=0,
                         keepdims=True).astype(out_ref.dtype)
+
+
+def _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, acc_ref, lvl, hl, wl,
+                          k):
+    """:func:`_pyr_fwd_level_body` rolled up: the same taps from the same
+    products, accumulated in the same order (bit for bit the same flow
+    on the chip), in a kernel a twentieth as long to trace and lower.
+
+    The inference programs run this one (the primal of
+    :func:`_pyramid_lookup`; a differentiated call keeps the unrolled
+    body, the program the train cell has run since PR 25).  jax traces
+    and lowers a kernel again in every process, for every program that
+    holds it, to find the program's compile-cache key: unrolled, the
+    four levels are ~8,800 equations, 10 s of the chip host's time for
+    each of the four batch sizes a serve engine warms, and set-up time
+    is an end-to-end metric (PERF.md section 6, PR 27).
+
+    Rows: a ``fori_loop`` over just the rows some window of this block
+    reaches (``[lo, hi)``; the unrolled body tests tiles of 8); one row
+    an iteration updates all ``k`` y-offset accumulators in one
+    ``(k, wl, BQ)`` expression that Mosaic, not Python, unrolls.  Taps:
+    a loop over the x offset ``i`` reduces that accumulator to the
+    ``k`` taps of the offset and writes them as entry ``lvl*k + i`` of
+    ``tap_ref``, an fp32 ``(L*k, k, 1, BQ)`` scratch whose dynamic index
+    falls on an untiled dimension; the kernel casts the whole scratch
+    to the output block once.  Measured against two other ways of
+    rolling it (PERF.md section 6): loops over ``j`` too (0.74 ms an
+    iteration at 55x128 for this one's 0.36), and those loops unrolled
+    where Mosaic lowers them (0.28 ms, but 0.5 s more a program to
+    lower, which the serve cells' ``setup_s`` could not carry)."""
+    bq = c_ref.shape[2]
+    r = (k - 1) // 2
+    lvl_div = 1.0 / (2.0 ** lvl)
+    cx = c_ref[0, 0:1, :] * lvl_div      # (1, BQ)
+    cy = c_ref[0, 1:2, :] * lvl_div
+    posx = jax.lax.broadcasted_iota(jnp.int32, (wl, bq), 0) \
+        .astype(jnp.float32)
+    # Row y holds a tap of a query at cy iff |cy + (j - r) - y| < 1 for
+    # some j.  Padded queries sit at -1e6: they relax the lower bound
+    # but never extend the upper one.
+    lo = jnp.clip(jnp.floor(jnp.min(cy)) - r, 0, hl).astype(jnp.int32)
+    hi = jnp.clip(jnp.floor(jnp.max(cy)) + (r + 2), 0,
+                  hl).astype(jnp.int32)
+
+    acc_ref[...] = jnp.zeros((k, wl, bq), jnp.float32)
+    joff = (jax.lax.broadcasted_iota(jnp.int32, (k, 1, 1), 0)
+            - r).astype(jnp.float32)
+
+    def row_body(y, _):
+        # fp32 accumulation regardless of the stored pyramid dtype
+        row = corr_ref[0, y, :, :].astype(jnp.float32)       # (wl, BQ)
+        yf = y.astype(jnp.float32)
+        acc_ref[...] += _tap_weight(cy[None], joff, yf) * row[None]
+        return 0
+
+    jax.lax.fori_loop(lo, hi, row_body, 0)
+
+    def tap_body(i, _):
+        wx = _tap_weight(cx, (i - r).astype(jnp.float32), posx)  # (wl, BQ)
+        tap_ref[lvl * k + i] = jnp.sum(wx[None] * acc_ref[...], axis=1,
+                                       keepdims=True)
+        return 0
+
+    jax.lax.fori_loop(0, k, tap_body, 0)
 
 
 def _pyr_bwd_level_body(c_ref, g_ref, dcorr_ref, lvl, g_off, hl, wl, k):
@@ -680,6 +804,36 @@ def _pyr_multi_fwd_kernel(*refs, levels, k, kk_total):
                                             out_ref.dtype)
 
 
+def _pyr_multi_fwd_rolled_body(*refs, levels, k, kk_total):
+    """:func:`_pyr_multi_fwd_kernel` over the rolled level body; refs as
+    there, plus the fp32 ``(kk_total // k, k, 1, BQ)`` tap scratch last."""
+    nl = len(levels)
+    c_ref, out_ref, tap_ref = refs[nl], refs[nl + 1], refs[-1]
+    for (lvl, _, hl, wl), corr_ref, acc_ref in zip(levels, refs[:nl],
+                                                   refs[nl + 2:-1]):
+        _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, acc_ref, lvl, hl,
+                              wl, k)
+    if nl * k * k < kk_total:  # empty (over-pooled) trailing levels
+        tap_ref[nl * k:] = jnp.zeros(
+            (kk_total // k - nl * k, k, 1, c_ref.shape[2]), jnp.float32)
+    out_ref[0] = tap_ref[...].reshape(kk_total, c_ref.shape[2]).astype(
+        out_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _rolled_kernel(levels, k, kk_total):
+    """The rolled kernel as ONE jitted callable a signature: pallas_call
+    traces its kernel anew at every call site, and a process that serves
+    holds one an ``iter`` program (four batch sizes a bucket); through
+    ``jax.jit`` the later ones find the first one's jaxpr."""
+    @jax.jit
+    def _pyr_multi_fwd_rolled_kernel(*refs):
+        _pyr_multi_fwd_rolled_body(*refs, levels=levels, k=k,
+                                   kk_total=kk_total)
+
+    return _pyr_multi_fwd_rolled_kernel
+
+
 def _pyr_multi_bwd_kernel(*refs, levels, k):
     """Fused transpose over every non-empty level; refs =
     [c, g, dcorr_0..dcorr_{n-1}]."""
@@ -689,20 +843,26 @@ def _pyr_multi_bwd_kernel(*refs, levels, k):
 
 
 def _pyr_levels_fwd(pyramid, coords_p, radius, block_q, interpret,
-                    out_dtype=jnp.float32):
+                    out_dtype=jnp.float32, rolled=False):
     """All levels in ONE pallas_call -> (B, L*k*k, Npad) taps.
 
     Query-minor layout throughout: ``pyramid`` levels are
     ``(B, hl, wl, Npad)`` and ``coords_p`` is ``(B, 2, Npad)`` — queries
     in lanes, so every VMEM/HBM tile is dense (Npad is a multiple of
-    128) and the per-tap contraction is a sublane reduction."""
+    128) and the per-tap contraction is a sublane reduction.
+
+    ``rolled``: the kernel of :func:`_pyr_fwd_level_rolled` (what a call
+    that is not differentiated runs) in place of the unrolled one."""
     B = pyramid[0].shape[0]
     Npad = pyramid[0].shape[3]
     k = 2 * radius + 1
     L = len(pyramid)
     nonempty, levels = _odm_levels(pyramid, k)
-    kern = functools.partial(_pyr_multi_fwd_kernel, levels=levels, k=k,
-                             kk_total=L * k * k)
+    if rolled:
+        kern = _rolled_kernel(tuple(levels), k, L * k * k)
+    else:
+        kern = functools.partial(_pyr_multi_fwd_kernel, levels=levels, k=k,
+                                 kk_total=L * k * k)
     in_specs = [
         pl.BlockSpec((1, c.shape[1], c.shape[2], block_q),
                      lambda b, i: (b, 0, 0, i),
@@ -719,9 +879,11 @@ def _pyr_levels_fwd(pyramid, coords_p, radius, block_q, interpret,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B, L * k * k, Npad), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((k * c.shape[2], block_q), jnp.float32)
+            pltpu.VMEM((k, c.shape[2], block_q) if rolled
+                       else (k * c.shape[2], block_q), jnp.float32)
             for _, c in nonempty
-        ],
+        ] + ([pltpu.VMEM((L * k, k, 1, block_q), jnp.float32)]
+             if rolled else []),
         interpret=interpret,
     )(*[c for _, c in nonempty], coords_p)
 
@@ -818,13 +980,15 @@ def pallas_pyramid_lookup(pyramid, coords, radius: int = 4,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def _pyramid_lookup(pyramid, coords, radius, block_q, interpret,
                     out_dtype):
+    # the primal: no gradient is asked of this call (inference), so it
+    # runs the forward kernel that is short to trace
     out, _ = _pyr_fwd(pyramid, coords, radius, block_q, interpret,
-                      out_dtype)
+                      out_dtype, rolled=True)
     return out
 
 
 def _pyr_fwd(pyramid, coords, radius, block_q, interpret,
-             out_dtype=jnp.float32):
+             out_dtype=jnp.float32, rolled=False):
     if interpret is None:
         interpret = _auto_interpret()
     B, H1, W1, _ = coords.shape
@@ -840,7 +1004,7 @@ def _pyr_fwd(pyramid, coords, radius, block_q, interpret,
     c = _pad_coords_oor(coords.reshape(B, N, 2).astype(jnp.float32),
                         Npad).transpose(0, 2, 1)
     out = _pyr_levels_fwd(list(pyramid), c, radius, block_q, interpret,
-                          out_dtype)
+                          out_dtype, rolled)
     out = out[:, :, :N].reshape(B, len(pyramid) * k * k, H1, W1)
     # The bwd needs each level's shape AND stored dtype (cotangents must
     # match the primal dtypes, which may differ per level); dtypes aren't
@@ -934,7 +1098,7 @@ def _pyramid_lookup_quantized(pyramid, coords, radius, block_q, interpret,
     # Accumulate + emit fp32 from the kernel; the per-level dequant
     # multiply below needs full precision before the consumer cast.
     out = _pyr_levels_fwd(values, c, radius, block_q, interpret,
-                          jnp.float32)                 # (B, L*k*k, Npad)
+                          jnp.float32, rolled=True)    # (B, L*k*k, Npad)
     scale = jnp.concatenate(
         [s.reshape(B, 1) for s in scales], axis=1)     # (B, L)
     out = out.reshape(B, L, k * k, Npad) * scale[:, :, None, None]

@@ -44,27 +44,42 @@ def make_mesh(num_data: Optional[int] = None, num_spatial: int = 1,
 
 _KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
     "raft_kernel_mesh", default=None)
+_ROWS_SPLIT: contextvars.ContextVar = contextvars.ContextVar(
+    "raft_rows_split", default=False)
 
 
 @contextlib.contextmanager
-def data_parallel_kernels(mesh: Optional[Mesh]):
+def data_parallel_kernels(mesh: Optional[Mesh], rows_split: bool = False):
     """While active, the Pallas entry points (``ops/pallas_util.py``
     ``per_data_shard``) run per ``data`` shard of ``mesh``.  Entered by
     ``make_train_step`` around the trace of a step whose batch is
     sharded over more than one device; a mesh whose ``data`` axis has
-    one device is the single-device program and changes nothing."""
+    one device is the single-device program and changes nothing.
+
+    ``rows_split``: the step also splits image height over the
+    ``spatial`` axis (``shard_spatial``).  A trace sees no shardings, so
+    this is how the choice of lookup (``models.raft.corr_impl_at``)
+    learns that a whole-image kernel cannot run here."""
     if mesh is not None and mesh.shape[DATA_AXIS] == 1:
         mesh = None
     token = _KERNEL_MESH.set(mesh)
+    rows_token = _ROWS_SPLIT.set(bool(rows_split))
     try:
         yield
     finally:
+        _ROWS_SPLIT.reset(rows_token)
         _KERNEL_MESH.reset(token)
 
 
 def kernel_mesh() -> Optional[Mesh]:
     """The mesh of the enclosing :func:`data_parallel_kernels`, if any."""
     return _KERNEL_MESH.get()
+
+
+def image_rows_split() -> bool:
+    """Whether the enclosing :func:`data_parallel_kernels` traces a step
+    that splits image rows over devices."""
+    return _ROWS_SPLIT.get()
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

@@ -45,7 +45,7 @@ import json
 import os
 import pickle
 import tempfile
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 MANIFEST = "manifest.json"
 TREES = "trees.pkl"
@@ -98,13 +98,20 @@ def _env_stamp() -> dict:
 
 
 def export_executables(executables: Dict[tuple, object], path: str, *,
-                       fingerprint: str) -> dict:
+                       fingerprint: str,
+                       corr_impl: Optional[Callable[[tuple], str]] = None
+                       ) -> dict:
     """Serialize ``{(bucket, lanes, program): Compiled}`` into
     directory ``path`` (atomic per file: tmp + rename, so a concurrent
     importer never sees a torn blob).  Returns the manifest written.
     Keys already exported with identical bytes are overwritten in
     place — export is idempotent and may be re-run as the compile
-    cache grows."""
+    cache grows.
+
+    ``corr_impl(bucket)``: the correlation implementation the exporting
+    engine's model resolves to at that bucket; it is written beside each
+    key, for :func:`import_executables` to hold against the importer's
+    own."""
     from jax.experimental import serialize_executable as se
 
     if not executables:
@@ -121,6 +128,8 @@ def export_executables(executables: Dict[tuple, object], path: str, *,
         _atomic_write(os.path.join(path, blob), ser)
         keys.append({"bucket": list(key[0]), "batch": int(key[1]),
                      "program": str(key[2]), "file": blob,
+                     "corr_impl": (corr_impl(key[0]) if corr_impl
+                                   else None),
                      "sha256": hashlib.sha256(ser).hexdigest(),
                      "bytes": len(ser)})
     _atomic_write(os.path.join(path, TREES), pickle.dumps(trees))
@@ -162,7 +171,8 @@ def read_manifest(path: str) -> dict:
 
 def import_executables(path: str, *, fingerprint: str,
                        execution_devices=None,
-                       keys: Optional[Tuple[tuple, ...]] = None
+                       keys: Optional[Tuple[tuple, ...]] = None,
+                       corr_impl: Optional[Callable[[tuple], str]] = None
                        ) -> Dict[tuple, object]:
     """Load ``{(bucket, lanes, program): Compiled}`` from an artifact
     directory, gated on ``fingerprint`` + backend + jax version.
@@ -175,7 +185,16 @@ def import_executables(path: str, *, fingerprint: str,
     The device assignment is baked into a blob: it loads on the device
     id it was compiled for and on no other (``KeyError`` from the
     unpickler, surfaced as :class:`AOTImportError`).  ``keys`` restricts
-    the import (default: everything in the manifest).  Raises
+    the import (default: everything in the manifest).
+    ``corr_impl(bucket)`` is the correlation implementation the
+    importing engine's model resolves to at that bucket: for a
+    materialized pyramid it is chosen from platform and shape when a
+    program traces (``models.raft.corr_impl_at``), not by the config
+    the fingerprint hashes, and the two lookups keep the pyramid in
+    different layouts, so a key recorded under another one (or under
+    none: an artifact from before the choice existed) is refused — an
+    imported ``enc`` beside a freshly built ``iter`` would otherwise
+    disagree on the slot state.  Raises
     :class:`AOTImportError` on any mismatch or
     corruption — partial results are never returned (an artifact
     either warm-starts the whole ladder or is refused)."""
@@ -212,6 +231,13 @@ def import_executables(path: str, *, fingerprint: str,
                str(entry["program"]))
         if wanted is not None and key not in wanted:
             continue
+        if (corr_impl is not None
+                and entry.get("corr_impl") != corr_impl(key[0])):
+            raise AOTImportError(
+                f"AOT blob {entry['file']} was built with corr_impl "
+                f"{entry.get('corr_impl')!r}, this engine builds "
+                f"{key[0][0]}x{key[0][1]} with {corr_impl(key[0])!r} "
+                "(re-run export on this build)")
         blob_path = os.path.join(path, entry["file"])
         try:
             with open(blob_path, "rb") as f:

@@ -531,6 +531,9 @@ class InferenceEngine:
         # constants.
         self._executables: Dict[tuple, object] = {}
         self._programs: Dict[tuple, _Programs] = {}
+        # "<H>x<W>/b<lanes>" -> "mosaic" | "xla": the correlation lookup
+        # found in each iter executable taken into use (stats()["lookup"]).
+        self._lookup: Dict[str, str] = {}
         self._compile_lock = threading.Lock()
         # Crash/stop state: ``crashed`` holds the reason string once the
         # device worker hit a fatal (replica-killing) fault — the fleet
@@ -746,7 +749,8 @@ class InferenceEngine:
             exes = aot_mod.import_executables(
                 directory, fingerprint=self._aot_fingerprint,
                 execution_devices=jax.tree_util.tree_leaves(
-                    self._variables)[0].devices())
+                    self._variables)[0].devices(),
+                corr_impl=self._corr_impl_at)
         except aot_mod.AOTImportError as e:
             # A warm-start MISS, not a serve failure: log it and fall
             # back to lazy JIT compiles.
@@ -759,6 +763,16 @@ class InferenceEngine:
         self.aot_info.update(ok=True, imported=len(exes))
         self._sink.emit("aot_import", dir=directory, keys=len(exes))
 
+    def _corr_impl_at(self, bucket: tuple) -> str:
+        """The correlation implementation this engine's model picks
+        for ``bucket`` when a program traces (``models.raft.
+        corr_impl_at``): part of what an AOT artifact has to match,
+        because it sets the layout of the slot state's pyramid."""
+        from raft_tpu.models.raft import corr_impl_at
+
+        return corr_impl_at(self._model_cfg, bucket[0] // 8,
+                            bucket[1] // 8)
+
     def export_aot(self, directory: str) -> dict:
         """Serialize every compiled ``(bucket, lanes, program)``
         executable into ``directory`` (atomic per file) so a fresh
@@ -770,7 +784,8 @@ class InferenceEngine:
         with self._compile_lock:
             exes = dict(self._executables)
         manifest = aot_mod.export_executables(
-            exes, directory, fingerprint=self._aot_fingerprint)
+            exes, directory, fingerprint=self._aot_fingerprint,
+            corr_impl=self._corr_impl_at)
         self._sink.emit("aot_export", dir=directory,
                         keys=len(manifest["keys"]))
         return manifest
@@ -1297,6 +1312,12 @@ class InferenceEngine:
         }
         out["num_buckets"] = len(
             {k[0] for k in self.compile_counter.counts()})
+        # Which correlation lookup is IN each iter program in use, as
+        # read off the executable when it was taken into use
+        # (_get_programs): "mosaic" where it holds a Mosaic call.
+        # (dict(dict) is one step under the GIL: no lock, so a scrape
+        # never waits out a compile.)
+        out["lookup"] = dict(self._lookup)
         # Stage clock (obs/stages.py): where the device worker's batch
         # cycles went, cumulative seconds by stage — the very counter
         # /metrics renders as raft_stage_seconds_total{loop="serve"}.
@@ -1483,11 +1504,24 @@ class InferenceEngine:
                 self._executables[(bucket, lanes, "enc")] = enc
                 self.compile_counter.record((bucket, lanes, "enc"))
             it = self._executables.get((bucket, lanes, "iter"))
+            imported, t_build = it is not None, time.perf_counter()
             if it is None:
                 it = self._iter_jit.lower(
                     self._variables, state_spec, thr).compile()
                 self._executables[(bucket, lanes, "iter")] = it
                 self.compile_counter.record((bucket, lanes, "iter"))
+            # Which lookup is in the executable, read off its own text
+            # (not asked of the selection again): a program that fell
+            # back to XLA, or was imported from elsewhere, says so.
+            # Kept for stats(), and noted once beside jax's own records
+            # of a build (0 s when the program was imported).
+            built_s = time.perf_counter() - t_build
+            lookup = ("mosaic" if "tpu_custom_call" in it.as_text()
+                      else "xla")
+            self._lookup[f"{H}x{W}/b{lanes}"] = lookup
+            stages.note("compile", "program", built_s,
+                        name=f"{H}x{W}/b{lanes}/iter", imported=imported,
+                        lookup=lookup)
             # Stamp compile-time cost under the executables' own ledger
             # keys — pure host metadata off the Compiled objects (works
             # for AOT-imported executables too; never runs the program).

@@ -20,7 +20,7 @@ import optax
 from jax.sharding import Mesh
 
 from raft_tpu.config import RAFTConfig, TrainConfig
-from raft_tpu.models.raft import RAFT
+from raft_tpu.models.raft import RAFT, corr_impl_at
 from raft_tpu.obs.health import tree_all_finite, tree_select
 from raft_tpu.parallel.mesh import (batch_sharding, data_parallel_kernels,
                                     replicated_sharding,
@@ -155,9 +155,14 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
 
     if shard_spatial:
         mc = model.config
+        # what the row-split trace below will be told, asked here first:
+        # a materialized pyramid keeps the XLA lookup under either of its
+        # names, the on-demand kernel has nothing to fall back to
+        with data_parallel_kernels(mesh, rows_split=True):
+            impl = corr_impl_at(mc, cfg.image_size[0] // 8,
+                                cfg.image_size[1] // 8)
         pallas = [name for name, on in (
-            (f"corr_impl={mc.resolved_corr_impl!r}",
-             mc.resolved_corr_impl in ("allpairs_pallas", "pallas")),
+            (f"corr_impl={impl!r}", impl in ("allpairs_pallas", "pallas")),
             ("upsample_loss_kernel='pallas'",
              mc.resolved_upsample_loss_kernel == "pallas"),
             ("fused_gru=True", mc.resolved_fused_gru)) if on]
@@ -246,7 +251,7 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
         return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
 
     def mesh_step_fn(state, batch, rng):
-        with data_parallel_kernels(mesh):
+        with data_parallel_kernels(mesh, rows_split=shard_spatial):
             return step_fn(state, batch, rng)
 
     repl = replicated_sharding(mesh)

@@ -97,12 +97,17 @@ def _lookup_operands(batch, h8, w8, sharding, channels=256):
     return _with_sharding((fmap, fmap, pyramid, coords), sharding)
 
 
-# What the train step runs at the chairs crop (368x496 -> 46x62), what a
-# Pallas lookup would meet at the Sintel eval shape (440x1024 -> 55x128),
-# and the on-demand kernel `evaluate --alternate_corr` picks on TPU.
+# What the train step runs at the chairs crop (368x496 -> 46x62: the
+# `bwd` case compiles the unrolled forward a differentiated call keeps
+# and both transpose calls), what validate and both serve cells run at the
+# Sintel shape (440x1024 -> 55x128; RAFT-small samples radius 3, which no
+# train cell compiles) -- the `fwd` cases are undifferentiated calls and
+# so the rolled-up forward --, a 1088x1920 map (136x240), and the
+# on-demand kernel `evaluate --alternate_corr` picks on TPU.
 @pytest.mark.parametrize("case", [
     "pyramid_lookup_fwd_46x62", "pyramid_lookup_bwd_46x62",
-    "pyramid_lookup_fwd_55x128", "ondemand_corr_fwd_55x128"])
+    "pyramid_lookup_fwd_55x128", "pyramid_lookup_fwd_55x128_r3",
+    "pyramid_lookup_fwd_136x240", "ondemand_corr_fwd_55x128"])
 def test_main_path_kernel_compiles_for_v5e(case, one_chip):
     import jax
     import jax.numpy as jnp
@@ -111,13 +116,18 @@ def test_main_path_kernel_compiles_for_v5e(case, one_chip):
     from raft_tpu.ops.pallas_corr import (pallas_corr_lookup,
                                           pallas_pyramid_lookup)
 
-    h8, w8 = (46, 62) if "46x62" in case else (55, 128)
-    fmap1, fmap2, pyramid, coords = _lookup_operands(2, h8, w8, one_chip)
+    h8, w8 = next(hw for tag, hw in (("46x62", (46, 62)),
+                                     ("55x128", (55, 128)),
+                                     ("136x240", (136, 240))) if tag in case)
+    radius = 3 if case.endswith("_r3") else 4
+    fmap1, fmap2, pyramid, coords = _lookup_operands(
+        1 if h8 > 100 else 2, h8, w8, one_chip)
 
     def pyramid_lookup(pyr, c):
         # interpret=False explicitly: jax.default_backend() is "cpu"
         # here and would pick the interpreter.
-        return pallas_pyramid_lookup(pyr, c, 4, 128, False, jnp.bfloat16)
+        return pallas_pyramid_lookup(pyr, c, radius, 128, False,
+                                     jnp.bfloat16)
 
     if case.startswith("ondemand"):
         def fn(f1, f2, c):
@@ -135,6 +145,43 @@ def test_main_path_kernel_compiles_for_v5e(case, one_chip):
         fn, args = pyramid_lookup, (pyramid, coords)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The selection's VMEM estimate against Mosaic's own accounting, on both
+# sides of the budget, at a 1088x1920 map (2.1 GB of bf16 pyramid: HBM is
+# not what refuses).  Block 384 -> 71.5 MiB estimated, inside the 78 MiB
+# budget: handed to the kernel, and it compiles.  Block 512 -> 95.4 MiB
+# and block 640 -> 119 MiB: refused by the selection, and by Mosaic under
+# the repo-wide 100 MiB ``vmem_limit_bytes``.
+@pytest.mark.parametrize("block_q,path,compiles", [
+    (384, "mosaic", True), (512, "xla", False), (640, "xla", False)])
+def test_lookup_vmem_budget_brackets_what_mosaic_takes(block_q, path,
+                                                       compiles, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.corr import build_corr_pyramid_flat
+    from raft_tpu.ops.pallas_corr import (pallas_pyramid_lookup,
+                                          pyramid_lookup_path)
+
+    h8, w8 = 136, 240
+    assert pyramid_lookup_path("tpu", h8, w8, levels=4, radius=4,
+                               block_q=block_q, storage_bytes=2) == path
+    fmap = jax.ShapeDtypeStruct((1, h8, w8, 8), jnp.float32)
+    pyramid = jax.eval_shape(
+        functools.partial(build_corr_pyramid_flat, num_levels=4,
+                          pad_q=block_q, out_dtype=jnp.bfloat16),
+        fmap, fmap)
+    coords = jax.ShapeDtypeStruct((1, h8, w8, 2), jnp.float32)
+    lowered = jax.jit(
+        lambda pyr, c: pallas_pyramid_lookup(pyr, c, 4, block_q, False,
+                                             jnp.bfloat16)
+    ).lower(*_with_sharding((pyramid, coords), one_chip))
+    if compiles:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="memory space vmem"):
+            lowered.compile()
 
 
 def test_lookup_partitions_over_data_mesh(dp_mesh):
@@ -175,16 +222,20 @@ def test_lookup_partitions_over_data_mesh(dp_mesh):
             pyramid, coords)
 
 
-def test_raft_full_eval_forward_compiles_for_v5e(one_chip):
+def test_raft_full_eval_forward_compiles_for_v5e(one_chip, monkeypatch):
     """The program validate and serve run at the Sintel shape (436x1024
-    padded to 440x1024, 32 iterations, bf16) compiles for one v5e and
-    fits its 16 GB."""
+    padded to 440x1024, 32 iterations, bf16) compiles for one v5e, fits
+    its 16 GB, and samples its pyramid with the Mosaic lookup: the
+    default configuration, nothing asked for by name."""
     import jax
     import jax.numpy as jnp
 
     from raft_tpu.config import RAFTConfig
     from raft_tpu.evaluate import make_inference_model
 
+    # The program asks the backend which lookup to build, and here that
+    # is the CPU the tests run on: answer for the chip being compiled for.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = make_inference_model(RAFTConfig.full(compute_dtype="bfloat16"))
     image = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
                                  sharding=one_chip)
@@ -204,3 +255,4 @@ def test_raft_full_eval_forward_compiles_for_v5e(one_chip):
     assert max(need, ma.peak_memory_in_bytes) < 16 * 2 ** 30
     _, flow_up = compiled.out_info
     assert flow_up.shape == (1, 440, 1024, 2)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,0 +1,424 @@
+"""Which lookup samples a materialized correlation pyramid, and that the
+serve programs agree whichever it is (tier-1, CPU).
+
+One function decides, from platform and map shape
+(``ops/pallas_corr.pyramid_lookup_path``); ``models.raft.corr_impl_at``
+feeds it what the process can observe, and everything that builds or
+samples the pyramid asks there while it traces.  Pinned here:
+
+- the choice itself over platform x shape x radius x storage;
+- what the model hands it (backend, the interpreter stand-in, rows split
+  over devices, on-demand implementations untouched), and that the
+  fused lookup+encoder and the train step under either name follow it;
+- the forward kernel an undifferentiated call runs (rolled up, short to
+  trace) against the one a differentiated call keeps;
+- the serve program pair (``encode_admit`` + ``iter_step``) built with
+  the Mosaic lookup (interpreted) against the XLA pair, both radii;
+- ``engine.stats()["lookup"]``, the ``compile`` ring's ``program`` record,
+  and the AOT artifact's refusal of programs built with another lookup.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from raft_tpu.config import RAFTConfig
+from raft_tpu.models.raft import corr_impl_at
+from raft_tpu.ops.pallas_corr import (_PYR_LOOKUP_BUDGET,
+                                      pyramid_lookup_path,
+                                      pyramid_lookup_vmem_bytes)
+
+
+# (platform, h8, w8, radius, block_q, storage bytes) -> path.  46x62 is
+# the chairs crop (368x496), 55x128 Sintel (440x1024), 136x240 1088x1920.
+@pytest.mark.parametrize("platform,h8,w8,radius,block_q,store,want", [
+    ("tpu", 46, 62, 4, 128, 2, "mosaic"),
+    ("tpu", 46, 62, 3, 128, 2, "mosaic"),
+    ("tpu", 55, 128, 4, 128, 2, "mosaic"),
+    ("tpu", 55, 128, 3, 128, 2, "mosaic"),
+    ("tpu", 55, 128, 4, 128, 4, "mosaic"),     # fp32 compute stores fp32
+    ("tpu", 55, 128, 4, 128, 1, "mosaic"),     # int8 / fp8 storage
+    ("tpu", 136, 240, 4, 128, 2, "mosaic"),    # 24 MiB: inside the budget
+    ("tpu", 136, 240, 4, 512, 4, "xla"),       # 179 MiB: over it
+    ("tpu", 272, 480, 4, 128, 4, "xla"),       # 2176x3840 fp32: over it
+    ("cpu", 46, 62, 4, 128, 2, "xla"),
+    ("cpu", 55, 128, 3, 128, 2, "xla"),
+    ("gpu", 55, 128, 4, 128, 2, "xla"),
+])
+def test_lookup_path_by_platform_and_shape(platform, h8, w8, radius,
+                                           block_q, store, want):
+    got = pyramid_lookup_path(platform, h8, w8, levels=4, radius=radius,
+                              block_q=block_q, storage_bytes=store)
+    assert got == want
+    if platform == "tpu":
+        fits = pyramid_lookup_vmem_bytes(
+            h8, w8, 4, radius, block_q, store) <= _PYR_LOOKUP_BUDGET
+        assert fits == (want == "mosaic")
+    # image rows split over devices: never a whole-image kernel
+    assert pyramid_lookup_path(platform, h8, w8, levels=4, radius=radius,
+                               block_q=block_q, storage_bytes=store,
+                               rows_split=True) == "xla"
+
+
+def test_lookup_residency_counts_the_level0_block_twice():
+    """The figure the budget is held against is dominated by level 0's
+    double-buffered block: 136x240x128 bf16 is 8.4 MB, twice that in
+    flight (ISSUE 27)."""
+    block = 136 * 240 * 128 * 2
+    total = pyramid_lookup_vmem_bytes(136, 240, 4, 4, 128, 2)
+    assert 2 * block < total < 3.2 * block
+
+
+@pytest.mark.parametrize("case", [
+    "cpu_default", "cpu_named_fallback", "cpu_named_interpret",
+    "cpu_default_interpret", "tpu_default", "tpu_named", "tpu_small",
+    "tpu_over_budget", "tpu_rows_split", "tpu_chunked", "cpu_pallas"])
+def test_model_feeds_the_selection_what_it_observes(case, monkeypatch):
+    from raft_tpu.parallel.mesh import data_parallel_kernels
+
+    if case.startswith("tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    full = RAFTConfig.full
+    cfg, hw, want = {
+        # off TPU as ever: XLA, and the kernel only where a test asks for
+        # the interpreter by the kernel's name
+        "cpu_default": (full(), (55, 128), "allpairs"),
+        "cpu_named_fallback": (full(corr_impl="allpairs_pallas"),
+                               (55, 128), "allpairs"),
+        "cpu_named_interpret": (full(corr_impl="allpairs_pallas",
+                                     pallas_offtpu="interpret"),
+                                (8, 12), "allpairs_pallas"),
+        "cpu_default_interpret": (full(pallas_offtpu="interpret"),
+                                  (8, 12), "allpairs"),
+        # on TPU the default configuration runs the kernel, at the train
+        # crop and at the serve shape, for both radii
+        "tpu_default": (full(compute_dtype="bfloat16"), (55, 128),
+                        "allpairs_pallas"),
+        "tpu_named": (full(corr_impl="allpairs_pallas",
+                           compute_dtype="bfloat16"), (46, 62),
+                      "allpairs_pallas"),
+        "tpu_small": (RAFTConfig.small_model(compute_dtype="bfloat16"),
+                      (55, 128), "allpairs_pallas"),
+        "tpu_over_budget": (full(corr_impl="allpairs_pallas",
+                                 lookup_block_q=512), (136, 240),
+                            "allpairs"),
+        "tpu_rows_split": (full(), (46, 62), "allpairs"),
+        # on-demand implementations are not this function's to choose
+        "tpu_chunked": (full(corr_impl="chunked"), (55, 128), "chunked"),
+        "cpu_pallas": (full(corr_impl="pallas"), (55, 128), "chunked"),
+    }[case]
+    with data_parallel_kernels(None, rows_split=case == "tpu_rows_split"):
+        assert corr_impl_at(cfg, *hw) == want
+
+
+def test_inference_model_and_auto_keep_corr_impl_as_given():
+    """No entry point rewrites ``corr_impl`` any more: the inference
+    model passes it through (it used to map the kernel back to XLA), and
+    ``--corr_impl auto`` is the config's own default."""
+    from raft_tpu.cli.train import default_corr_impl
+    from raft_tpu.evaluate import make_inference_model
+
+    for impl in ("allpairs", "allpairs_pallas", "chunked"):
+        model = make_inference_model(RAFTConfig.full(corr_impl=impl))
+        assert model.config.corr_impl == impl
+        assert model.config.scan_unroll == 1
+    assert default_corr_impl() == RAFTConfig().corr_impl == "allpairs"
+
+
+def _pallas_kernels(jaxpr):
+    """Names of the kernel functions of every ``pallas_call`` in a
+    jaxpr, sub-jaxprs included."""
+    from jax._src import core
+
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["jaxpr"].debug_info.func_name)
+        for sub in core.jaxprs_in_params(eqn.params):
+            names += _pallas_kernels(sub)
+    return names
+
+
+@pytest.mark.parametrize("h8,w8,radius,store", [
+    (8, 12, 4, jnp.float32),       # levels 8x12 .. 1x1
+    (16, 24, 3, jnp.bfloat16),     # radius 3 (k = 7), bf16 storage
+    (23, 31, 4, jnp.bfloat16),     # odd rows: remainder tiles, 2 blocks
+    (4, 6, 4, jnp.float32),        # over-pooled: the last level is empty
+])
+def test_rolled_forward_is_the_unrolled_forward(h8, w8, radius, store):
+    """A call no gradient is asked of runs the rolled-up kernel (a tenth
+    of the equations to trace and lower, PERF.md section 6 PR 27), a
+    differentiated one keeps the unrolled kernel the train cell runs:
+    same taps from both, to fp32 rounding."""
+    from raft_tpu.ops import pallas_corr as pc
+    from raft_tpu.ops.corr import build_corr_pyramid_flat
+
+    rng = np.random.default_rng(h8)
+    B = 2
+    f1 = jnp.asarray(rng.normal(size=(B, h8, w8, 32)), jnp.float32)
+    f2 = jnp.asarray(rng.normal(size=(B, h8, w8, 32)), jnp.float32)
+    pyr = build_corr_pyramid_flat(f1, f2, num_levels=4, pad_q=128,
+                                  out_dtype=store)
+    ys, xs = np.meshgrid(np.arange(h8), np.arange(w8), indexing="ij")
+    coords = np.stack([xs, ys], -1)[None].repeat(B, 0).astype(np.float32)
+    coords += rng.normal(scale=3.0, size=coords.shape).astype(np.float32)
+    coords[0, 0, 0] = (-50.0, -50.0)        # windows wholly outside
+    coords[1, -1, -1] = (500.0, 500.0)
+    coords = jnp.asarray(coords)
+
+    def lookup(p, c):
+        return pc.pallas_pyramid_lookup(p, c, radius, 128, True,
+                                        jnp.float32)
+
+    assert _pallas_kernels(jax.make_jaxpr(lookup)(pyr, coords).jaxpr) \
+        == ["_pyr_multi_fwd_rolled_kernel"]
+    rolled = jax.jit(lookup)(pyr, coords)
+    # what a differentiated call's forward rule runs (the train-step
+    # test below finds it in a traced step)
+    unrolled, _ = jax.jit(lambda p, c: pc._pyr_fwd(
+        p, c, radius, 128, True, jnp.float32))(pyr, coords)
+    assert float(jnp.abs(unrolled).max()) > 1.0
+    np.testing.assert_allclose(np.asarray(rolled), np.asarray(unrolled),
+                               rtol=0, atol=2e-5)
+
+
+def _trace_train_step(model_cfg, monkeypatch, H=48, W=64, B=2):
+    from raft_tpu.config import TrainConfig
+    from raft_tpu.models.raft import RAFT
+    from raft_tpu.train.optim import make_optimizer
+    from raft_tpu.train.step import init_state, make_train_step
+
+    model = RAFT(model_cfg)
+    cfg = TrainConfig(num_steps=10, batch_size=B, image_size=(H, W),
+                      iters=2)
+    tx = make_optimizer(cfg.lr, cfg.num_steps, cfg.wdecay, cfg.epsilon,
+                        cfg.clip)
+    key = jax.random.PRNGKey(0)
+    state = jax.eval_shape(lambda: init_state(model, tx, key, (H, W)))
+    S = jax.ShapeDtypeStruct
+    batch = {"image1": S((B, H, W, 3), jnp.float32),
+             "image2": S((B, H, W, 3), jnp.float32),
+             "flow": S((B, H, W, 2), jnp.float32),
+             "valid": S((B, H, W), jnp.float32)}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = make_train_step(model, tx, cfg, None, donate=False)
+    return jax.make_jaxpr(step)(state, batch, key)
+
+
+def test_train_step_is_one_program_under_either_name(monkeypatch):
+    """``--corr_impl auto`` hands the model 'allpairs' now where it
+    handed 'allpairs_pallas' on a TPU: with the backend answering for
+    the chip the two trace to the same step, whose forward is the
+    unrolled kernel (traced only; the train cell's own size is compared
+    across commits by the command PERF.md section 6 names)."""
+    small = RAFTConfig.small_model
+    auto = _trace_train_step(small(scan_unroll=1), monkeypatch)
+    named = _trace_train_step(
+        small(scan_unroll=1, corr_impl="allpairs_pallas"), monkeypatch)
+    import re
+
+    text = [re.sub(r" at 0x[0-9a-f]+", "", str(j)) for j in (auto, named)]
+    assert text[0] == text[1]
+    kernels = _pallas_kernels(auto.jaxpr)
+    assert "_pyr_multi_fwd_kernel" in kernels
+    assert "_pyr_multi_bwd_kernel" in kernels
+    assert "_pyr_multi_fwd_rolled_kernel" not in kernels
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", True), ("cpu", False)])
+def test_fused_lookup_encoder_follows_the_selection(backend, want,
+                                                    monkeypatch):
+    """``fused_lookup_encoder`` samples the Mosaic lookup's pyramid, so
+    it engages exactly where the selection picks that lookup -- under
+    the default ``corr_impl`` too, which is what ``--corr_impl auto``
+    hands the model (it used to ask for 'allpairs_pallas' by name)."""
+    import warnings
+
+    from raft_tpu.models.raft import RAFT
+
+    cfg = RAFTConfig.small_model(fused_lookup_encoder=True)
+    model = RAFT(cfg)
+    img = jax.ShapeDtypeStruct((1, 64, 96, 3), jnp.float32)
+    key = jax.random.PRNGKey(0)
+    variables = jax.eval_shape(
+        lambda: RAFT(RAFTConfig.small_model()).init(
+            {"params": key, "dropout": key}, jnp.zeros((1, 64, 96, 3)),
+            jnp.zeros((1, 64, 96, 3)), iters=1))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        jaxpr = jax.make_jaxpr(
+            lambda v, a, b: model.apply(v, a, b, iters=1, test_mode=True))(
+                variables, img, img)
+    assert ("_pyr_encode_kernel" in _pallas_kernels(jaxpr.jaxpr)) is want
+
+
+# ---------------------------------------------------------------------------
+# The serve program pair with the Mosaic lookup against the XLA pair
+# ---------------------------------------------------------------------------
+
+BUCKET = (64, 96)      # -> 8x12 maps; levels 8x12, 4x6, 2x3, 1x1
+
+
+@pytest.mark.parametrize("small", [False, True],
+                         ids=["full_radius4", "small_radius3"])
+def test_serve_programs_mosaic_lookup_matches_xla(small):
+    """``encode_admit`` + two ``iter_step``s, the kernel in the Pallas
+    interpreter against the XLA programs: same state but for the
+    pyramid's layout, same flow."""
+    from raft_tpu.models.raft import RAFT
+    from raft_tpu.serve import slots
+
+    mk = RAFTConfig.small_model if small else RAFTConfig.full
+    xla = mk()
+    mosaic = mk(corr_impl="allpairs_pallas", pallas_offtpu="interpret")
+    H, W = BUCKET
+    assert corr_impl_at(xla, H // 8, W // 8) == "allpairs"
+    assert corr_impl_at(mosaic, H // 8, W // 8) == "allpairs_pallas"
+
+    rng = np.random.default_rng(27)
+    a1 = jnp.asarray(rng.uniform(0, 255, (2, H, W, 3)), jnp.float32)
+    # frame 2 is frame 1 moved: flows with something to look up
+    a2 = jnp.roll(a1, (1, 2), axis=(1, 2))
+    key = jax.random.PRNGKey(0)
+    variables = RAFT(xla).init({"params": key, "dropout": key},
+                               a1[:1], a2[:1], iters=1)
+    admit = jnp.ones((2,), jnp.bool_)
+    budgets = jnp.full((2,), 2, jnp.int32)
+    out = {}
+    for name, cfg in (("xla", xla), ("mosaic", mosaic)):
+        state = slots.state_template(cfg, variables, 2, BUCKET)
+        state = jax.jit(slots.make_encode_fn(cfg))(
+            variables, a1, a2, state, admit, budgets)
+        it = jax.jit(slots.make_iter_fn(cfg))
+        for _ in range(2):
+            state, flow_up = it(variables, state, jnp.float32(0.0))
+        assert not np.asarray(state["active"]).any()
+        out[name] = (state, np.asarray(flow_up))
+    sx, fx = out["xla"]
+    sm, fm = out["mosaic"]
+    # the layouts differ as the lookups want them: (B, N, h, w) against
+    # (B, h, w, Npad)
+    assert sx["corr"][0].shape == (2, 96, 8, 12)
+    assert sm["corr"][0].shape == (2, 8, 12, 128)
+    assert np.abs(fx).max() > 0.1
+    np.testing.assert_allclose(fm, fx, rtol=1e-4, atol=1e-4)
+    for leaf in ("net", "coords1", "delta_max"):
+        np.testing.assert_allclose(np.asarray(sm[leaf]),
+                                   np.asarray(sx[leaf]),
+                                   rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# What the engine says of it
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from raft_tpu.models.raft import RAFT
+    from raft_tpu.serve import InferenceEngine, ServeConfig
+
+    cfg = RAFTConfig.small_model()
+    img = jnp.zeros((1, 40, 56, 3))
+    key = jax.random.PRNGKey(0)
+    variables = RAFT(cfg).init({"params": key, "dropout": key}, img, img,
+                               iters=1)
+    eng = InferenceEngine(variables, cfg, ServeConfig(
+        iters=2, batch_sizes=(1,), max_batch=1, max_wait_ms=1))
+    eng.start()
+    yield eng
+    eng.stop()
+
+
+def test_engine_names_the_lookup_of_every_program(engine):
+    from raft_tpu.obs import stages
+
+    before = len(stages.recent("compile"))
+    assert engine.stats()["lookup"] == {}
+    rng = np.random.default_rng(0)
+    flow = engine.infer(
+        rng.uniform(0, 255, (36, 52, 3)).astype(np.float32),
+        rng.uniform(0, 255, (36, 52, 3)).astype(np.float32), timeout=300)
+    assert np.isfinite(flow).all()
+    # on the CPU the default configuration is the XLA lookup
+    assert engine.stats()["lookup"] == {"40x56/b1": "xla"}
+    mine = [r for r in stages.recent("compile")[before:]
+            if r["kind"] == "program"]
+    assert [(r["name"], r["lookup"]) for r in mine] == [
+        ("40x56/b1/iter", "xla")]
+    assert mine[0]["seconds"] > 0 and mine[0]["imported"] is False
+
+
+def test_aot_artifact_is_held_to_the_importers_lookup(engine, tmp_path):
+    """The fingerprint hashes the config, and the lookup is no longer in
+    it: the artifact's keys carry the correlation implementation their
+    bucket resolved to, and an engine whose model resolves the bucket to
+    another one refuses them."""
+    import json
+
+    from raft_tpu.serve import aot
+
+    if not engine.compiled_keys():
+        pytest.skip("the engine compiled nothing (test order)")
+    manifest = engine.export_aot(str(tmp_path))
+    assert {k["corr_impl"] for k in manifest["keys"]} == {"allpairs"}
+    fp = manifest["fingerprint"]
+    exes = aot.import_executables(str(tmp_path), fingerprint=fp,
+                                  corr_impl=engine._corr_impl_at)
+    assert set(exes) == set(engine.compiled_keys())
+    with pytest.raises(aot.AOTImportError, match="built with corr_impl"):
+        aot.import_executables(
+            str(tmp_path), fingerprint=fp,
+            corr_impl=lambda bucket: "allpairs_pallas")
+    # an artifact from before the keys carried it is refused as well
+    path = tmp_path / aot.MANIFEST
+    old = json.loads(path.read_text())
+    for k in old["keys"]:
+        del k["corr_impl"]
+    path.write_text(json.dumps(old))
+    with pytest.raises(aot.AOTImportError, match="built with corr_impl"):
+        aot.import_executables(str(tmp_path), fingerprint=fp,
+                               corr_impl=engine._corr_impl_at)
+
+
+def test_spatially_sharded_step_keeps_the_xla_lookup_on_tpu(monkeypatch):
+    """GSPMD cannot split a Mosaic call over image rows, and a trace sees
+    no shardings: ``make_train_step(shard_spatial=True)`` tells the
+    selection through ``data_parallel_kernels(rows_split=True)``.  Traced
+    only (a jaxpr), with the backend answering for the chip: the
+    data-parallel step holds the kernel, the row-split step does not."""
+    from raft_tpu.config import TrainConfig
+    from raft_tpu.models.raft import RAFT
+    from raft_tpu.parallel.mesh import make_mesh
+    from raft_tpu.train.optim import make_optimizer
+    from raft_tpu.train.step import init_state, make_train_step
+
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 virtual devices")
+    H, W, B = 48, 64, 4
+    model = RAFT(RAFTConfig.small_model(scan_unroll=1))
+    cfg = TrainConfig(num_steps=10, batch_size=B, image_size=(H, W),
+                      iters=2)
+    tx = make_optimizer(cfg.lr, cfg.num_steps, cfg.wdecay, cfg.epsilon,
+                        cfg.clip)
+    key = jax.random.PRNGKey(0)
+    state = jax.eval_shape(lambda: init_state(model, tx, key, (H, W)))
+    S = jax.ShapeDtypeStruct
+    batch = {"image1": S((B, H, W, 3), jnp.float32),
+             "image2": S((B, H, W, 3), jnp.float32),
+             "flow": S((B, H, W, 2), jnp.float32),
+             "valid": S((B, H, W), jnp.float32)}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    traced = {}
+    for name, mesh, split in (
+            ("data", make_mesh(num_data=4, num_spatial=1,
+                               devices=jax.devices()[:4]), False),
+            ("rows", make_mesh(num_data=2, num_spatial=2,
+                               devices=jax.devices()[:4]), True)):
+        step = make_train_step(model, tx, cfg, mesh, donate=False,
+                               shard_spatial=split)
+        traced[name] = str(jax.make_jaxpr(step)(state, batch, key))
+    assert "pallas_call" in traced["data"]
+    assert "pallas_call" not in traced["rows"]

@@ -206,7 +206,10 @@ def _model_pair():
     base = RAFTConfig.small_model(corr_impl="allpairs_pallas",
                                   pallas_offtpu="interpret")
     fused = base.replace(fused_lookup_encoder=True, fused_gru=True)
-    assert fused.resolved_fused_lookup_encoder is True
+    from raft_tpu.models.raft import corr_impl_at
+
+    # the fused lookup+encoder engages where the map runs the kernel
+    assert corr_impl_at(fused, 8, 12) == "allpairs_pallas"
     assert fused.resolved_fused_gru is True
     return base, fused
 
