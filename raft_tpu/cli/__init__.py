@@ -12,3 +12,21 @@ Run as modules::
 (or via the ``python -m raft_tpu <subcommand>`` multi-tool,
 ``raft_tpu/__main__.py``)
 """
+
+from raft_tpu.config import ARCHS
+
+
+def add_arch_argument(parser) -> None:
+    """``--arch {full,small,gma}`` with ``--small`` kept as an alias of
+    ``--arch small``; read the result with :func:`arch_from_args`."""
+    parser.add_argument("--arch", choices=ARCHS, default=None,
+                        help="model architecture (default: full)")
+    parser.add_argument("--small", action="store_true",
+                        help="alias of --arch small")
+
+
+def arch_from_args(args) -> str:
+    if args.small and args.arch not in (None, "small"):
+        raise SystemExit(f"--small and --arch {args.arch} disagree; "
+                         "give one of them")
+    return "small" if args.small else (args.arch or "full")

@@ -14,6 +14,8 @@ import glob
 import os
 import os.path as osp
 
+from raft_tpu.cli import add_arch_argument, arch_from_args
+
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description="RAFT-TPU demo")
@@ -24,7 +26,7 @@ def parse_args(argv=None):
                         "reference fork's signature sample, demo.py:69), "
                         "else demo-frames/")
     p.add_argument("--out", default="demo-out", help="output directory")
-    p.add_argument("--small", action="store_true")
+    add_arch_argument(p)
     p.add_argument("--precision", default="bf16", choices=["bf16", "fp32"])
     p.add_argument("--alternate_corr", action="store_true")
     p.add_argument("--iters", type=int, default=20)  # demo.py:62
@@ -65,11 +67,11 @@ def main(argv=None):
     from raft_tpu.evaluate import default_alternate_corr_impl
 
     compute_dtype = "bfloat16" if args.precision == "bf16" else "float32"
-    mk = RAFTConfig.small_model if args.small else RAFTConfig.full
-    model_cfg = mk(compute_dtype=compute_dtype,
-                   corr_impl=default_alternate_corr_impl()
-                   if args.alternate_corr else "allpairs")
-    variables = load_model_variables(args.model)
+    model_cfg = RAFTConfig.preset(
+        arch_from_args(args), compute_dtype=compute_dtype,
+        corr_impl=default_alternate_corr_impl()
+        if args.alternate_corr else "allpairs")
+    variables = load_model_variables(args.model, model_cfg.arch)
     if "batch_stats" not in variables:
         variables = dict(variables, batch_stats={})
     eval_fn = make_eval_fn(model_cfg, args.iters)

@@ -11,6 +11,7 @@ import argparse
 
 # config.py is jax-free by design, so importing the validators here keeps
 # `--help` (and argparse errors) instant.
+from raft_tpu.cli import add_arch_argument, arch_from_args
 from raft_tpu.config import validate_corr_dtype, validate_corr_precision
 
 
@@ -67,7 +68,7 @@ def parse_args(argv=None):
     p.add_argument("--model", required=True, help="checkpoint directory")
     p.add_argument("--dataset", required=True,
                    choices=["chairs", "sintel", "kitti"])
-    p.add_argument("--small", action="store_true")
+    add_arch_argument(p)
     p.add_argument("--precision", default="bf16", choices=["bf16", "fp32"])
     p.add_argument("--corr_dtype", default="auto", type=_corr_dtype_arg,
                    help="correlation-volume STORAGE dtype (auto / "
@@ -133,11 +134,32 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-def load_model_variables(path: str):
+def variables_arch(variables) -> str:
+    """The architecture a variables tree is of, read off the tree itself
+    (a checkpoint carries its model in its names): GMA alone has ``att``,
+    the small model alone no convex-upsampling mask head."""
+    params = variables["params"]
+    if "att" in params:
+        return "gma"
+    return "full" if "upsampler" in params else "small"
+
+
+def load_model_variables(path: str, arch=None):
     """Variables from a bare-pytree checkpoint dir (``save_variables`` /
     the torch converter), or from the latest step of a training-run
     checkpoint directory (orbax CheckpointManager layout:
-    ``<dir>/<step>/default``)."""
+    ``<dir>/<step>/default``).  ``arch``: the architecture the caller
+    is about to run them through; a checkpoint of another one is refused
+    here, by name, and not by a shape error deep in the first trace."""
+    tree = _load_model_variables(path)
+    held = variables_arch(tree)
+    if arch is not None and held != arch:
+        raise SystemExit(f"{path} holds a {held!r} model and --arch says "
+                         f"{arch!r}; pass --arch {held}")
+    return tree
+
+
+def _load_model_variables(path: str):
     import os
 
     from raft_tpu.train import checkpoint as ckpt
@@ -175,13 +197,13 @@ def main(argv=None):
     enable_persistent_compile_cache()
 
     compute_dtype = "bfloat16" if args.precision == "bf16" else "float32"
-    mk = RAFTConfig.small_model if args.small else RAFTConfig.full
-    model_cfg = mk(compute_dtype=compute_dtype,
-                   corr_dtype=args.corr_dtype,
-                   corr_precision=args.corr_precision,
-                   corr_impl=evaluate.default_alternate_corr_impl()
-                   if args.alternate_corr else "allpairs")
-    variables = load_model_variables(args.model)
+    model_cfg = RAFTConfig.preset(
+        arch_from_args(args), compute_dtype=compute_dtype,
+        corr_dtype=args.corr_dtype,
+        corr_precision=args.corr_precision,
+        corr_impl=evaluate.default_alternate_corr_impl()
+        if args.alternate_corr else "allpairs")
+    variables = load_model_variables(args.model, model_cfg.arch)
     if "batch_stats" not in variables:
         variables = dict(variables, batch_stats={})
 

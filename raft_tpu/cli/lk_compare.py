@@ -12,6 +12,8 @@ from __future__ import annotations
 import argparse
 import os.path as osp
 
+from raft_tpu.cli import add_arch_argument, arch_from_args
+
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description="LK vs RAFT comparison")
@@ -19,7 +21,7 @@ def parse_args(argv=None):
     p.add_argument("--image1", required=True)
     p.add_argument("--image2", required=True)
     p.add_argument("--out", default="lk_vs_raft.png")
-    p.add_argument("--small", action="store_true")
+    add_arch_argument(p)
     p.add_argument("--iters", type=int, default=20)
     p.add_argument("--max_corners", type=int, default=200)
     return p.parse_args(argv)
@@ -60,9 +62,9 @@ def main(argv=None):
     from raft_tpu.ops.pad import InputPadder
     from raft_tpu.utils.flow_viz import flow_to_image
 
-    mk = RAFTConfig.small_model if args.small else RAFTConfig.full
-    model_cfg = mk(compute_dtype="bfloat16")
-    variables = load_model_variables(args.model)
+    model_cfg = RAFTConfig.preset(
+        arch_from_args(args), compute_dtype="bfloat16")
+    variables = load_model_variables(args.model, model_cfg.arch)
     if "batch_stats" not in variables:
         variables = dict(variables, batch_stats={})
     eval_fn = make_eval_fn(model_cfg, args.iters)

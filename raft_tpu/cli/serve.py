@@ -79,6 +79,8 @@ import io
 import json
 import math
 
+from raft_tpu.cli import add_arch_argument, arch_from_args
+
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(
@@ -92,8 +94,7 @@ def parse_args(argv=None):
     p.add_argument("--random-init", action="store_true",
                    help="serve randomly initialized weights (load/smoke "
                         "testing without a checkpoint)")
-    p.add_argument("--small", action="store_true",
-                   help="small RAFT variant")
+    add_arch_argument(p)
     p.add_argument("--precision", default="bf16",
                    choices=["bf16", "fp32"])
     p.add_argument("--iters", type=int, default=32,
@@ -504,13 +505,13 @@ def main(argv=None):
 
     enable_persistent_compile_cache()
 
-    mk = RAFTConfig.small_model if args.small else RAFTConfig.full
-    model_cfg = mk(compute_dtype="bfloat16" if args.precision == "bf16"
-                   else "float32")
+    model_cfg = RAFTConfig.preset(
+        arch_from_args(args),
+        compute_dtype="bfloat16" if args.precision == "bf16" else "float32")
     if args.model:
         from raft_tpu.cli.evaluate import load_model_variables
 
-        variables = load_model_variables(args.model)
+        variables = load_model_variables(args.model, model_cfg.arch)
         if "batch_stats" not in variables:
             variables = dict(variables, batch_stats={})
     else:

@@ -23,6 +23,7 @@ import os.path as osp
 # config.py is jax-free by design; validating the corr knobs at the
 # argparse edge means a typo names the allowed set immediately instead
 # of dying inside ``jnp.dtype(...)`` at trace time.
+from raft_tpu.cli import add_arch_argument, arch_from_args
 from raft_tpu.config import validate_corr_dtype, validate_corr_precision
 
 
@@ -47,7 +48,7 @@ def parse_args(argv=None):
                    choices=["chairs", "things", "sintel", "kitti"])
     p.add_argument("--restore_ckpt", default=None,
                    help="orbax ckpt dir of a previous stage")
-    p.add_argument("--small", action="store_true")
+    add_arch_argument(p)
     p.add_argument("--validation", nargs="+", default=[],
                    choices=["chairs", "sintel", "kitti"])
     p.add_argument("--lr", type=float, default=4e-4)
@@ -343,20 +344,20 @@ def run(argv=None):
             f"correlation pyramid (--corr_impl allpairs or "
             f"allpairs_pallas); the on-demand {corr_impl!r} path never "
             "stores the volume, so there is nothing to quantize")
-    mk = RAFTConfig.small_model if args.small else RAFTConfig.full
-    model_cfg = mk(dropout=args.dropout, corr_impl=corr_impl,
-                   compute_dtype=compute_dtype,
-                   corr_dtype=args.corr_dtype,
-                   corr_precision=args.corr_precision,
-                   remat=args.remat != "none",
-                   remat_policy=args.remat if args.remat != "none"
-                   else "save_corr",
-                   remat_upsample=bool(args.remat_upsample),
-                   **{k: v for k, v in
-                      (("scan_unroll", args.scan_unroll),
-                       ("corr_levels", args.corr_levels),
-                       ("corr_radius", args.corr_radius))
-                      if v is not None})
+    model_cfg = RAFTConfig.preset(
+        arch_from_args(args), dropout=args.dropout, corr_impl=corr_impl,
+        compute_dtype=compute_dtype,
+        corr_dtype=args.corr_dtype,
+        corr_precision=args.corr_precision,
+        remat=args.remat != "none",
+        remat_policy=args.remat if args.remat != "none"
+        else "save_corr",
+        remat_upsample=bool(args.remat_upsample),
+        **{k: v for k, v in
+           (("scan_unroll", args.scan_unroll),
+            ("corr_levels", args.corr_levels),
+            ("corr_radius", args.corr_radius))
+           if v is not None})
     from raft_tpu.models.raft import corr_impl_at
     from raft_tpu.parallel.mesh import data_parallel_kernels
 

@@ -34,6 +34,9 @@ CORR_DTYPES = ("auto", "float32", "bfloat16", "int8",
                "float8_e4m3fn", "float8_e5m2")
 QUANTIZED_CORR_DTYPES = ("int8", "float8_e4m3fn", "float8_e5m2")
 CORR_PRECISIONS = ("auto", "default", "high", "highest")
+# The architectures the model code builds (RAFTConfig.arch, the CLIs'
+# --arch).
+ARCHS = ("full", "small", "gma")
 
 
 def validate_corr_dtype(value: str, flag: str = "corr_dtype") -> str:
@@ -78,12 +81,17 @@ def _warn_pallas_fallback(requested: str, substituted: str) -> None:
 class RAFTConfig:
     """Model hyperparameters.
 
-    Mirrors the reference's two presets (``core/raft.py:29-39``): the full
-    model (hidden 128 / context 128 / radius 4) and the small model
-    (hidden 96 / context 64 / radius 3).
+    One preset an architecture, named by ``arch``: the reference's two
+    (``core/raft.py:29-39``) -- ``full`` (hidden 128 / context 128 /
+    radius 4) and ``small`` (hidden 96 / context 64 / radius 3) -- and
+    ``gma`` (Jiang et al., ICCV 2021; PAPERS.md): ``full`` with a
+    content attention built once from the context and a global
+    aggregate of the motion features fed to a 384-wide GRU input in
+    every iteration.  Build one with :meth:`full`, :meth:`small_model`,
+    :meth:`gma` or :meth:`preset`; the widths below follow from it.
     """
 
-    small: bool = False
+    arch: str = "full"
     hidden_dim: int = 128
     context_dim: int = 128
     corr_levels: int = 4
@@ -248,17 +256,45 @@ class RAFTConfig:
     # Grads via recomputing custom_vjp.  Autotuner-ranked; default off.
     fused_gru: bool = False
 
+    def __post_init__(self):
+        if self.arch not in ARCHS:
+            raise ValueError(f"unknown arch {self.arch!r}; expected one "
+                             f"of {', '.join(ARCHS)}")
+
     @classmethod
     def full(cls, **kw) -> "RAFTConfig":
-        base = dict(small=False, hidden_dim=128, context_dim=128,
+        base = dict(arch="full", hidden_dim=128, context_dim=128,
                     corr_levels=4, corr_radius=4)
         return cls(**{**base, **kw})
 
     @classmethod
     def small_model(cls, **kw) -> "RAFTConfig":
-        base = dict(small=True, hidden_dim=96, context_dim=64,
+        base = dict(arch="small", hidden_dim=96, context_dim=64,
                     corr_levels=4, corr_radius=3)
         return cls(**{**base, **kw})
+
+    @classmethod
+    def gma(cls, **kw) -> "RAFTConfig":
+        # RAFT-full's widths; one attention head of context_dim channels
+        # (GMA core/network.py: dim_head = cdim, --num_heads 1).
+        return cls.full(**{"arch": "gma", **kw})
+
+    @classmethod
+    def preset(cls, arch: str, **kw) -> "RAFTConfig":
+        """The preset the CLIs' ``--arch`` names (an unknown name fails
+        in ``__post_init__``, with the allowed set)."""
+        makers = {"small": cls.small_model, "gma": cls.gma}
+        return makers.get(arch, cls.full)(**{**kw, "arch": arch})
+
+    @property
+    def small(self) -> bool:
+        return self.arch == "small"
+
+    @property
+    def global_motion(self) -> bool:
+        """Whether the update block aggregates motion features through
+        an attention matrix carried beside the correlation state."""
+        return self.arch == "gma"
 
     @property
     def resolved_corr_dtype(self) -> str:

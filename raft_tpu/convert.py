@@ -19,7 +19,14 @@ This maps them onto :class:`raft_tpu.models.raft.RAFT` variables:
   ``batch_stats`` collection; ``num_batches_tracked`` is dropped;
 - the GRU's separate z/r gate convs (``convz*``/``convr*``) are merged
   into our fused double-width ``convzr*`` tensors (output-axis concat,
-  z first — see update.py ConvGRU/SepConvGRU).
+  z first — see update.py ConvGRU/SepConvGRU);
+- the public GMA state dict (github.com/zacjiang/GMA ``RAFTGMA``):
+  ``att.to_qk`` -> ``att/to_qk``, ``update_block.aggregator.to_v|gamma``
+  -> ``refine/update_block/aggregator``; ``att.pos_emb.*`` (the
+  relative-position tables every GMA checkpoint carries and the
+  published content-only model never reads) is dropped.  A GMA state
+  dict and a RAFT template, or the reverse, is refused by name
+  (:func:`_check_arch`).
 
 Conversion is validated structurally: every template leaf must be written
 exactly once with a matching shape, and every torch tensor consumed.
@@ -32,6 +39,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
+from raft_tpu.cli import add_arch_argument, arch_from_args
 from raft_tpu.config import RAFTConfig
 
 
@@ -62,6 +70,8 @@ def _torch_key_to_path(key: str):
     parts = key.split(".")
 
     if parts[-1] == "num_batches_tracked":
+        return None
+    if parts[:2] == ["att", "pos_emb"]:
         return None
     if "downsample" in parts:
         # downsample.0 = conv; downsample.1 aliases norm3/norm4 (which have
@@ -98,6 +108,8 @@ def _torch_key_to_path(key: str):
         return "params", tuple(parts[:-1]) + ("<weight>",)
     if leaf == "bias":
         return "params", tuple(parts[:-1]) + ("<bias>",)
+    if leaf == "gamma":
+        return "params", tuple(parts)
     raise ValueError(f"unrecognized torch key: {key}")
 
 
@@ -124,10 +136,35 @@ def _fuse_gru_zr(state_dict: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+_GMA_KEY = re.compile(r"^(module\.)?(att\.|update_block\.aggregator\.)")
+
+
+def _check_arch(state_dict, template) -> None:
+    """A GMA state dict fills only a 'gma' template and a RAFT one only a
+    RAFT template: say which keys stand in the way, before the first
+    shape mismatch (the GRU's input width) says something less useful."""
+    gma_keys = sorted(k for k in state_dict if _GMA_KEY.match(k)
+                      and ".pos_emb." not in k)
+    wants = "att" in template["params"]
+    if gma_keys and not wants:
+        raise ValueError(
+            "the state dict is a GMA checkpoint (it holds "
+            + ", ".join(gma_keys) + ") and the model is not: the "
+            "template has no att/* or refine/update_block/aggregator/* "
+            "leaves; convert with --arch gma")
+    if wants and not gma_keys:
+        raise ValueError(
+            "the model is GMA and the state dict holds none of its "
+            "att.* / update_block.aggregator.* keys (att.to_qk.weight, "
+            "update_block.aggregator.to_v.weight, "
+            "update_block.aggregator.gamma): not a GMA checkpoint")
+
+
 def convert_state_dict(state_dict: Dict[str, Any],
                        template: Dict[str, Any]) -> Dict[str, Any]:
     """Map a reference torch ``state_dict`` (tensors or ndarrays) onto the
     flax ``template`` variables ({'params': ..., 'batch_stats': ...})."""
+    _check_arch(state_dict, template)
     state_dict = _fuse_gru_zr(state_dict)
     flat_tmpl = {("params",) + p: v
                  for p, v in _flatten(template["params"]).items()}
@@ -148,7 +185,9 @@ def convert_state_dict(state_dict: Dict[str, Any],
         # submodule; conv weight/bias live directly under the conv module.
         prefix = (coll,) + path[:-1]
         leaf = path[-1]
-        if leaf == "<weight>":
+        if leaf == "gamma":
+            candidates = [(coll,) + path]
+        elif leaf == "<weight>":
             candidates = [prefix + ("kernel",),
                           prefix + ("BatchNorm_0", "scale"),
                           prefix + ("GroupNorm_0", "scale")]
@@ -206,28 +245,28 @@ def make_template(model_cfg: RAFTConfig):
             "batch_stats": dict(variables.get("batch_stats", {}))}
 
 
-def convert_checkpoint(pth_path: str, small: bool = False):
+def convert_checkpoint(pth_path: str, arch: str = "full"):
     """Load a reference ``.pth`` and return converted flax variables."""
     import torch
 
     sd = torch.load(pth_path, map_location="cpu")
-    cfg = RAFTConfig.small_model() if small else RAFTConfig.full()
-    return convert_state_dict(sd, make_template(cfg))
+    return convert_state_dict(sd, make_template(RAFTConfig.preset(arch)))
 
 
 def main(argv=None):
     import argparse
 
     p = argparse.ArgumentParser(
-        description="Convert a reference RAFT .pth to an orbax checkpoint")
+        description="Convert a reference RAFT or GMA .pth to an orbax "
+                    "checkpoint")
     p.add_argument("pth", help="path to torch checkpoint")
     p.add_argument("out", help="output orbax checkpoint directory")
-    p.add_argument("--small", action="store_true")
+    add_arch_argument(p)
     args = p.parse_args(argv)
 
     from raft_tpu.train.checkpoint import save_variables
 
-    variables = convert_checkpoint(args.pth, small=args.small)
+    variables = convert_checkpoint(args.pth, arch=arch_from_args(args))
     save_variables(args.out, variables)
     print(f"wrote {args.out}")
 

@@ -45,7 +45,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from raft_tpu.config import RAFTConfig, _warn_pallas_fallback
 from raft_tpu.models.extractor import BasicEncoder, SmallEncoder
-from raft_tpu.models.update import (BasicUpdateBlock, FusedCorrLookup,
+from raft_tpu.models.update import (Attention, BasicUpdateBlock,
+                                    FusedCorrLookup, GMAUpdateBlock,
                                     MaskHead, SmallUpdateBlock)
 from raft_tpu.ops.corr import (
     QuantizedLevel,
@@ -94,6 +95,16 @@ def corr_impl_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
     return "allpairs_pallas" if path == "mosaic" else "allpairs"
 
 
+def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
+    """Bytes of the ``(N, N)`` attention matrices arch 'gma' builds for
+    ``pairs`` pairs at an ``(H/8, W/8)`` map and holds through the
+    refinement loop (training: saved for the backward pass; serving: in
+    the slot state); 0 for the other architectures."""
+    if not cfg.global_motion:
+        return 0
+    return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
+
+
 def _remat_wrap(target, cfg):
     """Apply ``cfg.remat`` / ``cfg.remat_policy`` to a scan body (module
     class or function form) — one dispatch shared by both training scan
@@ -104,6 +115,16 @@ def _remat_wrap(target, cfg):
         return nn.remat(target,
                         policy=jax.checkpoint_policies.dots_saveable)
     if cfg.remat_policy == "save_corr":
+        # arch 'gma': the attention matrix is built before the scan and
+        # enters the body as a broadcast input, so it is outside every
+        # remat policy here: SAVED once a step for the backward pass (one
+        # bf16 (B, N, N) array, 260 MB at the chairs crop and batch 16,
+        # which every iteration's dv = A^T dg and the softmax's backward
+        # read), never saved per iteration and never rebuilt.  Rebuilding
+        # it would buy those 260 MB for one more q k^T and softmax; the
+        # step fits the chip without (PERF.md section 4).  Inside the
+        # body the policy is unchanged: of the aggregate only 'motion' is
+        # kept, A v is recomputed with the rest of the update block.
         return nn.remat(
             target,
             policy=jax.checkpoint_policies.save_only_these_names(
@@ -139,7 +160,9 @@ class RefinementStep(nn.Module):
         cfg = self.config
         dt = cfg.dtype
         net, coords1 = carry
-        inp, coords0, corr_state = inputs
+        # attn: the (B, N, N) attention of arch 'gma', a loop invariant
+        # like the pyramid; None for the other architectures.
+        inp, coords0, corr_state, attn = inputs
 
         coords1 = jax.lax.stop_gradient(coords1)
 
@@ -217,15 +240,14 @@ class RefinementStep(nn.Module):
 
         flow = coords1 - coords0
         fused_gru = cfg.resolved_fused_gru
-        if cfg.small:
-            block = SmallUpdateBlock(cfg.hidden_dim, dt,
-                                     fused_gru=fused_gru,
-                                     name="update_block")
-        else:
-            block = BasicUpdateBlock(cfg.hidden_dim, dt,
-                                     fused_gru=fused_gru,
-                                     name="update_block")
-        net, delta_flow = block(net, inp, corr, flow.astype(dt))
+        block_cls, extra = {
+            "small": (SmallUpdateBlock, ()),
+            "full": (BasicUpdateBlock, ()),
+            "gma": (GMAUpdateBlock, (attn,)),
+        }[cfg.arch]
+        block = block_cls(cfg.hidden_dim, dt, fused_gru=fused_gru,
+                          name="update_block")
+        net, delta_flow = block(net, inp, corr, flow.astype(dt), *extra)
 
         coords1 = coords1 + delta_flow.astype(jnp.float32)
         new_flow = coords1 - coords0
@@ -332,9 +354,10 @@ class UpsampleLossStep(nn.Module):
 
 def _make_encoders(cfg: RAFTConfig):
     """Construct the two shared-weight encoders with their canonical
-    scope names (``fnet``/``cnet``).  Called from inside a compact
-    method; used by both :class:`RAFT` and the slot-serving
-    :class:`RAFTEncode` so the param tree cannot drift between them."""
+    scope names (``fnet``/``cnet``), and for arch 'gma' the attention
+    (``att``) beside them.  Called from inside a compact method; used by
+    both :class:`RAFT` and the slot-serving :class:`RAFTEncode` so the
+    param tree cannot drift between them."""
     dt = cfg.dtype
     hdim, cdim = cfg.hidden_dim, cfg.context_dim
     if cfg.small:
@@ -345,7 +368,10 @@ def _make_encoders(cfg: RAFTConfig):
         fnet = BasicEncoder(256, "instance", cfg.dropout, dt, name="fnet")
         cnet = BasicEncoder(hdim + cdim, "batch", cfg.dropout, dt,
                             name="cnet")
-    return fnet, cnet
+    if cfg.global_motion:
+        # one head as wide as the context (GMA core/network.py)
+        return fnet, cnet, Attention(cdim, dt, name="att")
+    return fnet, cnet, None
 
 
 def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
@@ -382,14 +408,15 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
     raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
 
 
-def _encode_state(cfg: RAFTConfig, fnet, cnet, image1, image2, train,
+def _encode_state(cfg: RAFTConfig, fnet, cnet, att, image1, image2, train,
                   freeze_bn, flow_init=None):
     """The pre-scan half of the forward pass: normalize → shared-weight
-    two-frame encode → correlation state → context split → initial
-    coordinate grids.  One body shared by :meth:`RAFT.__call__` and the
-    iteration-granular serving split (:class:`RAFTEncode`), so the
-    slot-mode parity pin (bit-identical to request mode) is structural
-    rather than a copy that has to be kept in sync."""
+    two-frame encode → correlation state → context split (→ attention,
+    arch 'gma') → initial coordinate grids.  One body shared by
+    :meth:`RAFT.__call__` and the iteration-granular serving split
+    (:class:`RAFTEncode`), so the slot-mode parity pin (bit-identical to
+    request mode) is structural rather than a copy that has to be kept in
+    sync."""
     dt = cfg.dtype
     hdim = cfg.hidden_dim
 
@@ -414,7 +441,8 @@ def _encode_state(cfg: RAFTConfig, fnet, cnet, image1, image2, train,
     coords1 = coords_grid(B, H8, W8)
     if flow_init is not None:
         coords1 = coords1 + flow_init
-    return net, inp, coords0, coords1, corr_state
+    attn = att(inp) if att is not None else None
+    return net, inp, coords0, coords1, corr_state, attn
 
 
 class RAFT(nn.Module):
@@ -436,15 +464,16 @@ class RAFT(nn.Module):
         the caller)."""
         cfg = self.config
 
-        fnet, cnet = _make_encoders(cfg)
-        net, inp, coords0, coords1, corr_state = _encode_state(
-            cfg, fnet, cnet, image1, image2, train, freeze_bn, flow_init)
+        fnet, cnet, att = _make_encoders(cfg)
+        net, inp, coords0, coords1, corr_state, attn = _encode_state(
+            cfg, fnet, cnet, att, image1, image2, train, freeze_bn,
+            flow_init)
         B = image1.shape[0]
 
         if (loss_targets is not None and not cfg.small and not test_mode
                 and cfg.fuse_upsample_in_scan):
             return self._fused_inscan_losses(cfg, iters, net, inp, coords0,
-                                             coords1, corr_state,
+                                             coords1, corr_state, attn,
                                              loss_targets)
 
         step = _remat_wrap(RefinementStep, cfg)
@@ -459,7 +488,7 @@ class RAFT(nn.Module):
         )(cfg, name="refine")
 
         (net, coords1), (nets, flows) = scan(
-            (net, coords1), (inp, coords0, corr_state))
+            (net, coords1), (inp, coords0, corr_state, attn))
 
         # --- Upsample stage (outside the heavy scan) ---
         if cfg.small:
@@ -555,7 +584,7 @@ class RAFT(nn.Module):
         return per_iter, metrics
 
     def _fused_inscan_losses(self, cfg, iters, net, inp, coords0, coords1,
-                             corr_state, loss_targets):
+                             corr_state, attn, loss_targets):
         """Single-scan training path (``cfg.fuse_upsample_in_scan``): the
         refinement step AND the mask head + flat convex upsample + loss
         sums run in ONE scan body, so the per-iteration GRU states are
@@ -574,7 +603,7 @@ class RAFT(nn.Module):
 
         def body(mdl, carry, _):
             carry, (net_i, flow_i) = RefinementStep(cfg, name="refine")(
-                carry, (inp, coords0, corr_state))
+                carry, (inp, coords0, corr_state, attn))
             _, sums = UpsampleLossStep(cfg, name="upsampler")(
                 None, net_i, flow_i, gt128, vmask64)
             return carry, sums[0]
@@ -648,7 +677,8 @@ class RAFT(nn.Module):
 
 class RAFTEncode(nn.Module):
     """Pre-scan half of the forward pass as a standalone program:
-    ``(image1, image2) -> (net, inp, coords0, coords1, corr_state)``.
+    ``(image1, image2) -> (net, inp, coords0, coords1, corr_state,
+    attn)``, ``attn`` None unless the arch is 'gma'.
 
     Per-sample independent in inference mode (instance norm; batch norm
     runs on stored statistics), so lanes of a slot batch can be encoded
@@ -660,8 +690,8 @@ class RAFTEncode(nn.Module):
     @nn.compact
     def __call__(self, image1, image2,
                  flow_init: Optional[jax.Array] = None):
-        fnet, cnet = _make_encoders(self.config)
-        return _encode_state(self.config, fnet, cnet, image1, image2,
+        fnet, cnet, att = _make_encoders(self.config)
+        return _encode_state(self.config, fnet, cnet, att, image1, image2,
                              False, False, flow_init)
 
 
@@ -683,7 +713,7 @@ class RAFTFrameFeatures(nn.Module):
     @nn.compact
     def __call__(self, image):
         cfg = self.config
-        fnet, cnet = _make_encoders(cfg)
+        fnet, cnet, _ = _make_encoders(cfg)
         image = 2.0 * (image.astype(jnp.float32) / 255.0) - 1.0
         fmap = fnet(image.astype(cfg.dtype), False, False)
         ctx = cnet(image.astype(cfg.dtype), False, False)
@@ -696,7 +726,7 @@ class RAFTEncodeWarm(nn.Module):
     features stand in for frame 1 of the pair.
 
     ``(image2, fmap1, ctx1, flow_init) -> (net, inp, coords0, coords1,
-    corr_state, fmap2, ctx2)`` where ``fmap1``/``ctx1`` are the carry
+    corr_state, attn, fmap2, ctx2)`` where ``fmap1``/``ctx1`` are the carry
     stashed when the previous frame was encoded (its ``fmap2``/its
     :class:`RAFTFrameFeatures` ctx), ``flow_init`` is the previous
     pair's forward-warped flow (added to the ``coords1`` grid exactly
@@ -713,7 +743,7 @@ class RAFTEncodeWarm(nn.Module):
     def __call__(self, image2, fmap1, ctx1, flow_init):
         cfg = self.config
         hdim = cfg.hidden_dim
-        fnet, cnet = _make_encoders(cfg)
+        fnet, cnet, att = _make_encoders(cfg)
         image2 = 2.0 * (image2.astype(jnp.float32) / 255.0) - 1.0
         fmap2 = fnet(image2.astype(cfg.dtype), False, False)
         ctx2 = cnet(image2.astype(cfg.dtype), False, False)
@@ -727,7 +757,8 @@ class RAFTEncodeWarm(nn.Module):
         B, H8, W8, _ = fmap1.shape
         coords0 = coords_grid(B, H8, W8)
         coords1 = coords_grid(B, H8, W8) + flow_init
-        return net, inp, coords0, coords1, corr_state, fmap2, ctx2
+        attn = att(inp) if att is not None else None
+        return net, inp, coords0, coords1, corr_state, attn, fmap2, ctx2
 
 
 class RAFTIterStep(nn.Module):
@@ -742,10 +773,10 @@ class RAFTIterStep(nn.Module):
     config: RAFTConfig = RAFTConfig()
 
     @nn.compact
-    def __call__(self, net, coords1, inp, coords0, corr_state):
+    def __call__(self, net, coords1, inp, coords0, corr_state, attn=None):
         step = _remat_wrap(RefinementStep, self.config)
         (net, coords1), _ = step(self.config, name="refine")(
-            (net, coords1), (inp, coords0, corr_state))
+            (net, coords1), (inp, coords0, corr_state, attn))
         return net, coords1
 
 

@@ -15,6 +15,11 @@ Parity notes:
 - Init: the reference applies kaiming only to the encoders; update-block
   convs keep torch's *default* Conv2d init — reproduced via
   ``torch_default_init``.
+- ``arch='gma'`` (Jiang et al., ICCV 2021; PAPERS.md has the equations):
+  :class:`Attention` builds one ``(N, N)`` content attention a pair from
+  the context features, once, before the loop; :class:`GMAUpdateBlock`
+  is :class:`BasicUpdateBlock` with :class:`Aggregate` between the motion
+  encoder and a GRU whose input is 384 wide.
 """
 
 from __future__ import annotations
@@ -23,6 +28,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from jax.ad_checkpoint import checkpoint_name
@@ -254,6 +260,71 @@ class BasicUpdateBlock(nn.Module):
     def __call__(self, net, inp, corr, flow):
         motion = BasicMotionEncoder(self.dtype, name="encoder")(flow, corr)
         x = jnp.concatenate([inp, motion], axis=-1)
+        net = SepConvGRU(self.hidden_dim, self.dtype,
+                         fused=self.fused_gru, name="gru")(net, x)
+        delta_flow = FlowHead(256, self.dtype, name="flow_head")(net)
+        return net, delta_flow
+
+
+class Attention(nn.Module):
+    """GMA's content attention (core/gma.py ``Attention``, one head, no
+    relative-position term): ``softmax_rows(d^-1/2 q k^T)`` over the
+    ``N = H/8 * W/8`` positions of the context features, ``[q, k]`` one
+    bias-free 1x1 convolution.  Built once a pair and carried through
+    the refinement loop as a loop invariant; products accumulate and the
+    softmax runs in float32, the matrix is stored in ``dtype``."""
+
+    dim_head: int = 128
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, inp):
+        d = self.dim_head
+        qk = nn.Conv(2 * d, (1, 1), use_bias=False, dtype=self.dtype,
+                     kernel_init=_torch_default_uniform, name="to_qk")(inp)
+        B, H, W, _ = qk.shape
+        q, k = jnp.split(qk.reshape(B, H * W, 2 * d), 2, axis=-1)
+        with jax.named_scope("gma_attention"):
+            sim = jnp.einsum("bnd,bmd->bnm", q * (d ** -0.5), k,
+                             preferred_element_type=jnp.float32)
+            return nn.softmax(sim, axis=-1).astype(self.dtype)
+
+
+class Aggregate(nn.Module):
+    """GMA's global motion aggregation (core/gma.py ``Aggregate``, one
+    head so no output projection): ``m + gamma * (A v)``, ``v`` a
+    bias-free 1x1 convolution of the motion features ``m``, ``gamma`` a
+    learned scalar that the paper initialises to 0."""
+
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, attn, motion):
+        B, H, W, C = motion.shape
+        v = nn.Conv(C, (1, 1), use_bias=False, dtype=self.dtype,
+                    kernel_init=_torch_default_uniform, name="to_v")(motion)
+        gamma = self.param("gamma", nn.initializers.zeros, (1,))
+        with jax.named_scope("gma_aggregate"):
+            out = jnp.einsum("bnm,bmc->bnc", attn, v.reshape(B, H * W, C),
+                             preferred_element_type=jnp.float32)
+        out = out.astype(self.dtype).reshape(B, H, W, C)
+        return motion + gamma.astype(self.dtype) * out
+
+
+class GMAUpdateBlock(nn.Module):
+    """:class:`BasicUpdateBlock` with the aggregated motion features as a
+    third part of the GRU's input (GMA core/update.py ``GMAUpdateBlock``;
+    the mask head is hoisted into :class:`MaskHead` as for RAFT-full)."""
+
+    hidden_dim: int = 128
+    dtype: Any = jnp.float32
+    fused_gru: bool = False
+
+    @nn.compact
+    def __call__(self, net, inp, corr, flow, attn):
+        motion = BasicMotionEncoder(self.dtype, name="encoder")(flow, corr)
+        glob = Aggregate(self.dtype, name="aggregator")(attn, motion)
+        x = jnp.concatenate([inp, motion, glob], axis=-1)
         net = SepConvGRU(self.hidden_dim, self.dtype,
                          fused=self.fused_gru, name="gru")(net, x)
         delta_flow = FlowHead(256, self.dtype, name="flow_head")(net)

@@ -99,8 +99,8 @@ def _env_stamp() -> dict:
 
 def export_executables(executables: Dict[tuple, object], path: str, *,
                        fingerprint: str,
-                       corr_impl: Optional[Callable[[tuple], str]] = None
-                       ) -> dict:
+                       corr_impl: Optional[Callable[[tuple], str]] = None,
+                       arch: Optional[str] = None) -> dict:
     """Serialize ``{(bucket, lanes, program): Compiled}`` into
     directory ``path`` (atomic per file: tmp + rename, so a concurrent
     importer never sees a torn blob).  Returns the manifest written.
@@ -111,7 +111,10 @@ def export_executables(executables: Dict[tuple, object], path: str, *,
     ``corr_impl(bucket)``: the correlation implementation the exporting
     engine's model resolves to at that bucket; it is written beside each
     key, for :func:`import_executables` to hold against the importer's
-    own."""
+    own.  ``arch``: the model the programs are of (``RAFTConfig.arch``),
+    written beside each key and held against the importer's likewise: a
+    program's key is ``(arch, bucket, lanes, program)`` wherever it
+    leaves the engine that built it."""
     from jax.experimental import serialize_executable as se
 
     if not executables:
@@ -128,6 +131,7 @@ def export_executables(executables: Dict[tuple, object], path: str, *,
         _atomic_write(os.path.join(path, blob), ser)
         keys.append({"bucket": list(key[0]), "batch": int(key[1]),
                      "program": str(key[2]), "file": blob,
+                     "arch": arch,
                      "corr_impl": (corr_impl(key[0]) if corr_impl
                                    else None),
                      "sha256": hashlib.sha256(ser).hexdigest(),
@@ -172,7 +176,8 @@ def read_manifest(path: str) -> dict:
 def import_executables(path: str, *, fingerprint: str,
                        execution_devices=None,
                        keys: Optional[Tuple[tuple, ...]] = None,
-                       corr_impl: Optional[Callable[[tuple], str]] = None
+                       corr_impl: Optional[Callable[[tuple], str]] = None,
+                       arch: Optional[str] = None
                        ) -> Dict[tuple, object]:
     """Load ``{(bucket, lanes, program): Compiled}`` from an artifact
     directory, gated on ``fingerprint`` + backend + jax version.
@@ -194,7 +199,9 @@ def import_executables(path: str, *, fingerprint: str,
     different layouts, so a key recorded under another one (or under
     none: an artifact from before the choice existed) is refused — an
     imported ``enc`` beside a freshly built ``iter`` would otherwise
-    disagree on the slot state.  Raises
+    disagree on the slot state.  ``arch``: the importing engine's model;
+    a key recorded under another model is refused by name, before the
+    fingerprint is looked at.  Raises
     :class:`AOTImportError` on any mismatch or
     corruption — partial results are never returned (an artifact
     either warm-starts the whole ladder or is refused)."""
@@ -202,6 +209,13 @@ def import_executables(path: str, *, fingerprint: str,
     from jax.experimental import serialize_executable as se
 
     manifest = read_manifest(path)
+    if arch is not None:
+        built = sorted({str(e.get("arch")) for e in manifest["keys"]}
+                       - {arch})
+        if built:
+            raise AOTImportError(
+                f"AOT artifact holds programs of model {', '.join(built)}"
+                f"; this engine runs {arch!r} and will not reuse them")
     env = _env_stamp()
     for field, want in (("fingerprint", fingerprint),
                         ("jax", env["jax"]),

@@ -750,7 +750,7 @@ class InferenceEngine:
                 directory, fingerprint=self._aot_fingerprint,
                 execution_devices=jax.tree_util.tree_leaves(
                     self._variables)[0].devices(),
-                corr_impl=self._corr_impl_at)
+                corr_impl=self._corr_impl_at, arch=self._model_cfg.arch)
         except aot_mod.AOTImportError as e:
             # A warm-start MISS, not a serve failure: log it and fall
             # back to lazy JIT compiles.
@@ -785,7 +785,7 @@ class InferenceEngine:
             exes = dict(self._executables)
         manifest = aot_mod.export_executables(
             exes, directory, fingerprint=self._aot_fingerprint,
-            corr_impl=self._corr_impl_at)
+            corr_impl=self._corr_impl_at, arch=self._model_cfg.arch)
         self._sink.emit("aot_export", dir=directory,
                         keys=len(manifest["keys"]))
         return manifest
@@ -1318,6 +1318,14 @@ class InferenceEngine:
         # (dict(dict) is one step under the GIL: no lock, so a scrape
         # never waits out a compile.)
         out["lookup"] = dict(self._lookup)
+        # The model the programs are of, and for arch 'gma' the bytes of
+        # attention matrix each (bucket, lanes) state holds on the
+        # device beside its pyramid.
+        out["model"] = self._model_cfg.arch
+        out["attn_bytes"] = {
+            f"{hw[0]}x{hw[1]}/b{bs}": int(p.template["attn"].nbytes)
+            for (hw, bs), p in sorted(dict(self._programs).items())
+            if "attn" in p.template}
         # Stage clock (obs/stages.py): where the device worker's batch
         # cycles went, cumulative seconds by stage — the very counter
         # /metrics renders as raft_stage_seconds_total{loop="serve"}.
@@ -1521,7 +1529,7 @@ class InferenceEngine:
             self._lookup[f"{H}x{W}/b{lanes}"] = lookup
             stages.note("compile", "program", built_s,
                         name=f"{H}x{W}/b{lanes}/iter", imported=imported,
-                        lookup=lookup)
+                        lookup=lookup, model=self._model_cfg.arch)
             # Stamp compile-time cost under the executables' own ledger
             # keys — pure host metadata off the Compiled objects (works
             # for AOT-imported executables too; never runs the program).
@@ -1833,7 +1841,8 @@ class InferenceEngine:
             rec = stages.end(
                 "serve", registry=self.registry, batch=seq, bucket=bk,
                 real=n, ballast=bs - n, retries=self._last_retries,
-                queue_s=[t_in - r.t_submit for r in reqs], error=error)
+                queue_s=[t_in - r.t_submit for r in reqs], error=error,
+                model=self._model_cfg.arch)
             with self._pending_lock:
                 self._pending -= len(reqs)
                 self._last_batch_done = rec["t_end"]

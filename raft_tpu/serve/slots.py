@@ -27,6 +27,7 @@ reproducible across processes for AOT export):
 key                     shape/dtype                meaning
 ======================  =========================  =======================
 ``active``              ``(S,) bool``              lane holds a live request
+``attn``                ``(S, N, N)``              arch 'gma' only: attention
 ``budget``              ``(S,) int32``             per-lane max iterations
 ``converged``           ``(S,) bool``              early-exit predicate fired
 ``coords0``             ``(S, H/8, W/8, 2) f32``   base coordinate grid
@@ -93,10 +94,14 @@ def _lane_select(mask, new, old):
 
 
 def _pack_state(net, inp, coords0, coords1, corr_state, active, budget,
-                converged, delta_max, iters_done):
+                converged, delta_max, iters_done, attn=None):
     # Plain dict: insertion order is irrelevant to jax (dict pytrees
-    # flatten in sorted key order), matching the docstring table.
+    # flatten in sorted key order), matching the docstring table.  The
+    # ``attn`` key exists only where the model builds one (arch 'gma'),
+    # so the other architectures' state trees are what they were.
+    state = {} if attn is None else {"attn": attn}
     return {
+        **state,
         "active": active,
         "budget": budget,
         "converged": converged,
@@ -120,7 +125,7 @@ def state_template(model_cfg: RAFTConfig, variables, slots: int,
     ``encode_admit`` actually produces."""
     H, W = bucket_hw
     spec = jax.ShapeDtypeStruct((slots, H, W, 3), jnp.float32)
-    net, inp, coords0, coords1, corr = jax.eval_shape(
+    net, inp, coords0, coords1, corr, attn = jax.eval_shape(
         RAFTEncode(model_cfg).apply, variables, spec, spec)
     zeros = lambda s: np.zeros(s.shape, dtype=s.dtype)
     lanes = lambda dt: np.zeros((slots,), dtype=dt)
@@ -128,7 +133,8 @@ def state_template(model_cfg: RAFTConfig, variables, slots: int,
         zeros(net), zeros(inp), zeros(coords0), zeros(coords1),
         jax.tree_util.tree_map(zeros, corr),
         lanes(np.bool_), lanes(np.int32), lanes(np.bool_),
-        np.full((slots,), -1.0, np.float32), lanes(np.int32))
+        np.full((slots,), -1.0, np.float32), lanes(np.int32),
+        attn=None if attn is None else zeros(attn))
 
 
 def make_encode_fn(model_cfg: RAFTConfig):
@@ -137,7 +143,7 @@ def make_encode_fn(model_cfg: RAFTConfig):
     enc = RAFTEncode(model_cfg)
 
     def encode_admit(variables, image1, image2, state, admit, budgets):
-        net, inp, coords0, coords1, corr = enc.apply(
+        net, inp, coords0, coords1, corr, attn = enc.apply(
             variables, image1, image2)
         sel = lambda new, old: _lane_select(admit, new, old)
         return _pack_state(
@@ -151,6 +157,7 @@ def make_encode_fn(model_cfg: RAFTConfig):
             state["converged"] & ~admit,
             jnp.where(admit, jnp.float32(-1.0), state["delta_max"]),
             jnp.where(admit, jnp.int32(0), state["iters_done"]),
+            attn=None if attn is None else sel(attn, state["attn"]),
         )
 
     return encode_admit
@@ -209,7 +216,7 @@ def make_warm_encode_fn(model_cfg: RAFTConfig):
 
     def encode_warm(variables, image2, carry, state, admit, budgets):
         flow_init = forward_warp_flow(state["coords1"] - state["coords0"])
-        net, inp, coords0, coords1, corr, fmap2, ctx2 = enc.apply(
+        net, inp, coords0, coords1, corr, attn, fmap2, ctx2 = enc.apply(
             variables, image2, carry["fmap"], carry["ctx"], flow_init)
         sel = lambda new, old: _lane_select(admit, new, old)
         new_state = _pack_state(
@@ -223,6 +230,7 @@ def make_warm_encode_fn(model_cfg: RAFTConfig):
             state["converged"] & ~admit,
             jnp.where(admit, jnp.float32(-1.0), state["delta_max"]),
             jnp.where(admit, jnp.int32(0), state["iters_done"]),
+            attn=None if attn is None else sel(attn, state["attn"]),
         )
         new_carry = {"ctx": sel(ctx2, carry["ctx"]),
                      "fmap": sel(fmap2, carry["fmap"])}
@@ -247,7 +255,7 @@ def make_iter_fn(model_cfg: RAFTConfig):
         active = state["active"]
         net, coords1 = step.apply(
             variables, state["net"], state["coords1"], state["inp"],
-            state["coords0"], state["corr"])
+            state["coords0"], state["corr"], state.get("attn"))
         # Masked commit: inactive lanes keep their state bit-for-bit
         # (free lanes carry zeros; a retired lane's state is dead until
         # the next admit overwrites it, but must not drift meanwhile).
@@ -277,7 +285,8 @@ def make_iter_fn(model_cfg: RAFTConfig):
         new_state = _pack_state(
             net, state["inp"], state["coords0"], coords1,
             state["corr"], active & ~done, state["budget"],
-            state["converged"] | converged, dmax, iters_done)
+            state["converged"] | converged, dmax, iters_done,
+            attn=state.get("attn"))
         return new_state, flow_up
 
     return iter_step

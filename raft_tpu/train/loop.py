@@ -33,7 +33,7 @@ import numpy as np
 from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
-from raft_tpu.models.raft import RAFT
+from raft_tpu.models.raft import RAFT, attention_bytes
 from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
@@ -290,6 +290,16 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
             recent_records=telem.recent_records)
         watchdog.start()
     t0, steps_t0 = time.time(), step
+    # What arch 'gma' carries through a step beside the pyramid: one
+    # attention matrix a pair (0 for the other architectures).  Quoted by
+    # every step's stage record and summed in the registry.
+    attn_bytes = attention_bytes(model_cfg, cfg.batch_size,
+                                 cfg.image_size[0] // 8,
+                                 cfg.image_size[1] // 8)
+    attn_counter = telem.registry.counter(
+        "raft_attention_bytes_total",
+        "bytes of global-motion attention matrix built and held "
+        "through the refinement loop (arch gma), summed over units")
     first_dispatched = False
     run_step, compiled = step_fn, None
     try:
@@ -382,7 +392,10 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 step += 1
                 logger.push(step - 1, metrics)
             rec = stages.end("train", registry=telem.registry,
-                             step=step - 1)
+                             step=step - 1, model=model_cfg.arch,
+                             attn_bytes=attn_bytes)
+            if attn_bytes:
+                attn_counter.inc(attn_bytes, loop="train")
             # step_time_s covers queue wait + dispatch.  Dispatch is
             # async, so once the pipeline fills this converges to the
             # device step time without ever forcing a transfer.

@@ -256,3 +256,58 @@ def test_raft_full_eval_forward_compiles_for_v5e(one_chip, monkeypatch):
     _, flow_up = compiled.out_info
     assert flow_up.shape == (1, 440, 1024, 2)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gma_serve_programs_compile_for_v5e(one_chip, monkeypatch):
+    """arch 'gma' at the Sintel bucket, as the engine would build it (one
+    lane, bf16): ``encode_admit`` and ``iter_step`` compile for one v5e
+    and fit it, the slot state holds the ``(N, N)`` attention beside the
+    pyramid (bf16, 99 MB a lane at 55x128), and the iteration still
+    samples the pyramid with the Mosaic lookup.  No cell serves GMA yet
+    (PERF.md section 7): this is what keeps that path compiling."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.evaluate import make_inference_model
+    from raft_tpu.serve import slots
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = make_inference_model(
+        RAFTConfig.gma(compute_dtype="bfloat16")).config
+    small = jnp.zeros((1, 64, 96, 3), jnp.float32)
+    rng = jax.random.PRNGKey(0)
+    from raft_tpu.models.raft import RAFT
+
+    shapes = jax.eval_shape(
+        lambda: RAFT(cfg).init({"params": rng, "dropout": rng}, small,
+                               small, iters=1))
+    template = slots.state_template(cfg, shapes, 1, (440, 1024))
+    n = 55 * 128
+    assert template["attn"].shape == (1, n, n)
+    assert template["attn"].nbytes == 2 * n * n
+
+    def spec(tree):
+        return _with_sharding(jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree),
+            one_chip)
+
+    variables, state = spec(shapes), spec(template)
+    image = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
+                                 sharding=one_chip)
+    lane = lambda dt: jax.ShapeDtypeStruct((1,), dt, sharding=one_chip)
+    enc = jax.jit(slots.make_encode_fn(cfg)).lower(
+        variables, image, image, state, lane(jnp.bool_),
+        lane(jnp.int32)).compile()
+    it = jax.jit(slots.make_iter_fn(cfg)).lower(
+        variables, state, jax.ShapeDtypeStruct(
+            (), jnp.float32, sharding=one_chip)).compile()
+    for compiled in (enc, it):
+        ma = compiled.memory_analysis()
+        need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                + ma.temp_size_in_bytes)
+        assert max(need, ma.peak_memory_in_bytes) < 16 * 2 ** 30
+    assert "tpu_custom_call" in it.as_text()
+    new_state, flow_up = it.out_info
+    assert new_state["attn"].shape == (1, n, n)
+    assert flow_up.shape == (1, 440, 1024, 2)
