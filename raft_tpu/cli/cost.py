@@ -86,7 +86,6 @@ def collect_costs(model_cfg, train_hw, batch, iters, bucket, lanes,
     from raft_tpu.models.raft import RAFT
     from raft_tpu.obs import cost as cost_mod
     from raft_tpu.parallel.mesh import make_mesh, shard_batch
-    from raft_tpu.serve import slots as slots_mod
     from raft_tpu.train.optim import make_optimizer
     from raft_tpu.train.step import init_state, make_train_step, step_cost
 
@@ -131,7 +130,22 @@ def collect_costs(model_cfg, train_hw, batch, iters, bucket, lanes,
     img = jax.ShapeDtypeStruct((1, H, W, 3), jnp.float32)
     costs.append(fwd.capture_cost(variables, img, img))
 
-    # --- serve slot programs (the engine's enc/iter compile ledger) ---
+    costs.extend(serve_costs(model_cfg, variables, bucket, lanes))
+    return costs
+
+
+def serve_costs(model_cfg, variables, bucket, lanes):
+    """The engine's ``enc``/``iter`` compile ledger at ``(bucket,
+    lanes)``, lowered as ``serve/engine.py _get_programs`` lowers it.
+    ``iter`` is a device loop with a runtime step count, which XLA
+    counts as ONE pass of its body: a pair served with ``n`` iterations
+    costs ``enc + n x iter``."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.obs import cost as cost_mod
+    from raft_tpu.serve import slots as slots_mod
+
     bh, bw = bucket
     template = slots_mod.state_template(model_cfg, variables, lanes,
                                         (bh, bw))
@@ -141,17 +155,14 @@ def collect_costs(model_cfg, train_hw, batch, iters, bucket, lanes,
     mask = jax.ShapeDtypeStruct((lanes,), jnp.bool_)
     budg = jax.ShapeDtypeStruct((lanes,), jnp.int32)
     thr = jax.ShapeDtypeStruct((), jnp.float32)
+    steps = jax.ShapeDtypeStruct((), jnp.int32)
     enc = jax.jit(slots_mod.make_encode_fn(model_cfg)).lower(
         variables, im, im, state_spec, mask, budg).compile()
-    costs.append(cost_mod.program_cost(
-        enc, program=f"serve_enc_{bh}x{bw}_b{lanes}",
-        pairs_per_call=lanes))
     it = jax.jit(slots_mod.make_iter_fn(model_cfg)).lower(
-        variables, state_spec, thr).compile()
-    costs.append(cost_mod.program_cost(
-        it, program=f"serve_iter_{bh}x{bw}_b{lanes}",
-        pairs_per_call=lanes))
-    return costs
+        variables, state_spec, thr, steps).compile()
+    return [cost_mod.program_cost(
+        exe, program=f"serve_{prog}_{bh}x{bw}_b{lanes}",
+        pairs_per_call=lanes) for prog, exe in (("enc", enc), ("iter", it))]
 
 
 def main(argv=None) -> int:

@@ -28,8 +28,12 @@ corr impl/dtype.
 
 Compatibility gate: an artifact is refused (``AOTImportError``) unless
 its fingerprint — model config + variables tree structure/shapes/dtypes
-+ iters — AND backend AND jax version match the importing engine.  A
-stale artifact must fall back to lazy JIT compiles, never feed a
++ iters — AND backend AND jax version match the importing engine, and
+each key's recorded *call* (``iter_step(variables, state, threshold,
+steps)``: the program's function and arguments) is the call the
+importing engine makes: an executable built for another argument list
+is refused by its program's name, not called with the wrong arguments.
+A stale artifact must fall back to lazy JIT compiles, never feed a
 request through the wrong program.  The engine treats import failure as
 a warm-start miss (``aot_import_error`` event), not a serve failure.
 
@@ -53,6 +57,11 @@ TREES = "trees.pkl"
 # iteration-granular serving split).  v1 artifacts (whole-forward
 # executables) are refused and the engine falls back to lazy compiles.
 FORMAT_VERSION = 2
+
+#: What a key that records no ``call`` was built as.  Manifests carry
+#: the call since ``iter_step`` took its step count at run time; of the
+#: programs exported before that, only this one has changed since.
+_UNRECORDED_CALLS = {"iter": "iter_step(variables, state, threshold)"}
 
 
 class AOTImportError(RuntimeError):
@@ -100,7 +109,8 @@ def _env_stamp() -> dict:
 def export_executables(executables: Dict[tuple, object], path: str, *,
                        fingerprint: str,
                        corr_impl: Optional[Callable[[tuple], str]] = None,
-                       arch: Optional[str] = None) -> dict:
+                       arch: Optional[str] = None,
+                       calls: Optional[Dict[str, str]] = None) -> dict:
     """Serialize ``{(bucket, lanes, program): Compiled}`` into
     directory ``path`` (atomic per file: tmp + rename, so a concurrent
     importer never sees a torn blob).  Returns the manifest written.
@@ -114,7 +124,9 @@ def export_executables(executables: Dict[tuple, object], path: str, *,
     own.  ``arch``: the model the programs are of (``RAFTConfig.arch``),
     written beside each key and held against the importer's likewise: a
     program's key is ``(arch, bucket, lanes, program)`` wherever it
-    leaves the engine that built it."""
+    leaves the engine that built it.  ``calls``: ``{program: "fn(args)"}``
+    — how the exporting engine calls each program, written beside each
+    key for the importer to hold against the call it makes itself."""
     from jax.experimental import serialize_executable as se
 
     if not executables:
@@ -132,6 +144,7 @@ def export_executables(executables: Dict[tuple, object], path: str, *,
         keys.append({"bucket": list(key[0]), "batch": int(key[1]),
                      "program": str(key[2]), "file": blob,
                      "arch": arch,
+                     "call": calls.get(str(key[2])) if calls else None,
                      "corr_impl": (corr_impl(key[0]) if corr_impl
                                    else None),
                      "sha256": hashlib.sha256(ser).hexdigest(),
@@ -177,7 +190,8 @@ def import_executables(path: str, *, fingerprint: str,
                        execution_devices=None,
                        keys: Optional[Tuple[tuple, ...]] = None,
                        corr_impl: Optional[Callable[[tuple], str]] = None,
-                       arch: Optional[str] = None
+                       arch: Optional[str] = None,
+                       calls: Optional[Dict[str, str]] = None
                        ) -> Dict[tuple, object]:
     """Load ``{(bucket, lanes, program): Compiled}`` from an artifact
     directory, gated on ``fingerprint`` + backend + jax version.
@@ -201,7 +215,10 @@ def import_executables(path: str, *, fingerprint: str,
     imported ``enc`` beside a freshly built ``iter`` would otherwise
     disagree on the slot state.  ``arch``: the importing engine's model;
     a key recorded under another model is refused by name, before the
-    fingerprint is looked at.  Raises
+    fingerprint is looked at.  ``calls``: ``{program: "fn(args)"}`` as
+    the importing engine calls each program; a key recorded under
+    another call (an ``iter`` from before it took ``steps``) is refused
+    with both spelled out.  Raises
     :class:`AOTImportError` on any mismatch or
     corruption — partial results are never returned (an artifact
     either warm-starts the whole ladder or is refused)."""
@@ -252,6 +269,14 @@ def import_executables(path: str, *, fingerprint: str,
                 f"{entry.get('corr_impl')!r}, this engine builds "
                 f"{key[0][0]}x{key[0][1]} with {corr_impl(key[0])!r} "
                 "(re-run export on this build)")
+        if calls is not None:
+            want = calls[key[2]]
+            got = entry.get("call") or _UNRECORDED_CALLS.get(key[2], want)
+            if got != want:
+                raise AOTImportError(
+                    f"AOT blob {entry['file']} is the program {key[2]!r} "
+                    f"built to be called as {got}; this engine calls "
+                    f"{want} (re-run export on this build)")
         blob_path = os.path.join(path, entry["file"])
         try:
             with open(blob_path, "rb") as f:

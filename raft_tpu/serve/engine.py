@@ -36,7 +36,9 @@ live here:
    inputs exit early (SEA-RAFT-style).  Whole-request mode stays the
    default (``batching="request"``) and is the parity oracle: BOTH
    modes drive the same two compiled ``encode``/``iter_step``
-   executables (request mode in whole-batch lockstep), so with early
+   executables — ``iter_step`` is a device loop that takes its step
+   count at run time: request mode passes ``cfg.iters`` (two program
+   calls a batch), slot mode ``1`` — so with early
    exit disabled their outputs are bit-identical by construction —
    XLA specializes fusion/reduction order per program, so this is the
    only robust way to pin parity (see models/raft.py).
@@ -82,6 +84,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import inspect
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -396,17 +399,24 @@ class _StreamSession:
         self.closed = False
 
 
+#: The step count slot mode passes the iteration program: one host look
+#: at ``active`` a step.
+_ONE_STEP = np.int32(1)
+
+
 class _Programs:
     """One ``(bucket, lanes)``'s compiled ``encode``/``iter_step`` pair
     plus cached call constants: the all-zeros device-resident state the
     lockstep (request-mode) pipeline restarts from, the all-lanes admit
-    mask, the full-budget vector, and the disabled threshold.  The
-    compiled programs are pure — ``state0`` is an input, never mutated —
-    so one ``_Programs`` serves every batch of its shape."""
+    mask, the full-budget vector, the disabled threshold and the step
+    count request mode passes ``it`` (``steps_full``: the whole budget
+    in one call; slot mode passes ``_ONE_STEP``).  The compiled programs
+    are pure — ``state0`` is an input, never mutated — so one
+    ``_Programs`` serves every batch of its shape."""
 
     __slots__ = ("enc", "it", "template", "state0", "mask_all",
-                 "budget_full", "thr_off", "bucket", "lanes", "wenc",
-                 "stash", "carry0")
+                 "budget_full", "thr_off", "steps_full", "bucket",
+                 "lanes", "wenc", "stash", "carry0")
 
     def __init__(self, enc, it, template, bucket, lanes, full_iters):
         self.enc = enc
@@ -416,6 +426,7 @@ class _Programs:
         self.mask_all = np.ones((lanes,), bool)
         self.budget_full = np.full((lanes,), full_iters, np.int32)
         self.thr_off = np.float32(0.0)
+        self.steps_full = np.int32(full_iters)
         self.bucket = bucket
         self.lanes = lanes
         # Streaming programs (warm encode / carry stash) + the zero
@@ -552,6 +563,10 @@ class InferenceEngine:
         # thread only) — stamped onto traced requests' device spans and
         # the tail-keep trigger for retried batches.
         self._last_retries = 0
+        # Program calls the request-mode pipeline has issued (device-
+        # worker thread only): a batch's stage record quotes the
+        # difference as ``calls``.
+        self._launch_calls = 0
         # One registry per engine: every stats/exposition figure below
         # reads these same metric objects (see serve/stats.py), and
         # cli/serve.py renders them at GET /metrics.
@@ -750,7 +765,8 @@ class InferenceEngine:
                 directory, fingerprint=self._aot_fingerprint,
                 execution_devices=jax.tree_util.tree_leaves(
                     self._variables)[0].devices(),
-                corr_impl=self._corr_impl_at, arch=self._model_cfg.arch)
+                corr_impl=self._corr_impl_at, arch=self._model_cfg.arch,
+                calls=self._program_calls())
         except aot_mod.AOTImportError as e:
             # A warm-start MISS, not a serve failure: log it and fall
             # back to lazy JIT compiles.
@@ -773,6 +789,17 @@ class InferenceEngine:
         return corr_impl_at(self._model_cfg, bucket[0] // 8,
                             bucket[1] // 8)
 
+    def _program_calls(self) -> Dict[str, str]:
+        """``{program: "fn(args)"}`` for every program this engine
+        lowers, read off the functions themselves: what an AOT artifact
+        records beside each key and an importer holds against its own,
+        so a program whose argument list changed is refused by name."""
+        return {prog: f"{fn.__name__}{inspect.signature(fn)}"
+                for prog, fn in (("enc", self._encode_jit),
+                                 ("iter", self._iter_jit),
+                                 ("stash", self._stash_jit),
+                                 ("wenc", self._warm_jit))}
+
     def export_aot(self, directory: str) -> dict:
         """Serialize every compiled ``(bucket, lanes, program)``
         executable into ``directory`` (atomic per file) so a fresh
@@ -785,7 +812,8 @@ class InferenceEngine:
             exes = dict(self._executables)
         manifest = aot_mod.export_executables(
             exes, directory, fingerprint=self._aot_fingerprint,
-            corr_impl=self._corr_impl_at, arch=self._model_cfg.arch)
+            corr_impl=self._corr_impl_at, arch=self._model_cfg.arch,
+            calls=self._program_calls())
         self._sink.emit("aot_export", dir=directory,
                         keys=len(manifest["keys"]))
         return manifest
@@ -1504,6 +1532,7 @@ class InferenceEngine:
             mask = jax.ShapeDtypeStruct((lanes,), jnp.bool_)
             budg = jax.ShapeDtypeStruct((lanes,), jnp.int32)
             thr = jax.ShapeDtypeStruct((), jnp.float32)
+            steps = jax.ShapeDtypeStruct((), jnp.int32)
             enc = self._executables.get((bucket, lanes, "enc"))
             if enc is None:
                 enc = self._encode_jit.lower(
@@ -1515,7 +1544,7 @@ class InferenceEngine:
             imported, t_build = it is not None, time.perf_counter()
             if it is None:
                 it = self._iter_jit.lower(
-                    self._variables, state_spec, thr).compile()
+                    self._variables, state_spec, thr, steps).compile()
                 self._executables[(bucket, lanes, "iter")] = it
                 self.compile_counter.record((bucket, lanes, "iter"))
             # Which lookup is in the executable, read off its own text
@@ -1624,23 +1653,25 @@ class InferenceEngine:
         ``(variables, a1, a2) -> (None, flow_up)``.
 
         A thin lockstep pipeline over the SAME compiled program pair
-        slot mode runs — admit all lanes into a fresh zero state, run
-        exactly ``cfg.iters`` iter_steps with the threshold disabled;
-        every lane retires on the final step, which upsamples in-graph.
-        Pipelining through the pair (instead of one monolithic forward)
-        is what makes slot-vs-request parity bit-exact: XLA specializes
-        fusion/reduction order per program, so only sharing the
-        executables pins the bits (models/raft.py)."""
+        slot mode runs — admit all lanes into a fresh zero state, then
+        ONE call of the iteration program with ``steps = cfg.iters`` and
+        the threshold disabled: two program calls a request, the 32
+        iterations a device loop.  Every lane retires on the final step,
+        which upsamples in-graph.  Going through the pair (instead of
+        one monolithic forward) is what makes slot-vs-request parity
+        bit-exact: XLA specializes fusion/reduction order per program,
+        so only sharing the executables pins the bits (models/raft.py);
+        slot mode passes the same executable ``steps = 1``."""
         progs = self._get_programs(bucket, batch_size)
         iters = self.cfg.iters
 
         def pipeline(variables, a1, a2):
             state = progs.enc(variables, a1, a2, progs.state0,
                               progs.mask_all, progs.budget_full)
-            flow_up = None
-            for _ in range(iters):
-                state, flow_up = progs.it(variables, state,
-                                          progs.thr_off)
+            _, flow_up = progs.it(variables, state, progs.thr_off,
+                                  progs.steps_full)
+            self._launch_calls += 2
+            self._counters.add_iter_call(iters)
             return None, flow_up
 
         return pipeline
@@ -1792,6 +1823,7 @@ class InferenceEngine:
         unit = stages.begin("serve", t_start=self._last_batch_done or t_in)
         unit.add("wait", unit.t_start, t_in)
         self._last_retries = 0
+        calls0 = self._launch_calls
         self._batch_seq += 1
         seq = self._batch_seq
         bk = f"{bucket[0]}x{bucket[1]}"
@@ -1841,6 +1873,7 @@ class InferenceEngine:
             rec = stages.end(
                 "serve", registry=self.registry, batch=seq, bucket=bk,
                 real=n, ballast=bs - n, retries=self._last_retries,
+                calls=self._launch_calls - calls0,
                 queue_s=[t_in - r.t_submit for r in reqs], error=error,
                 model=self._model_cfg.arch)
             with self._pending_lock:
@@ -2202,11 +2235,13 @@ class InferenceEngine:
         t0 = time.perf_counter()
 
         def thunk():
-            state, flow_up = pool.progs.it(self._variables, pool.state,
-                                           thr)
-            active = np.asarray(state["active"])
-            iters_done = np.asarray(state["iters_done"])
-            return state, flow_up, active, iters_done
+            moved, flow_up = pool.progs.it(self._variables, pool.state,
+                                           thr, _ONE_STEP)
+            self._counters.add_iter_call(1)
+            active = np.asarray(moved["active"])
+            iters_done = np.asarray(moved["iters_done"])
+            return (self._slots_mod.advance(pool.state, moved), flow_up,
+                    active, iters_done)
 
         try:
             state, flow_up, active, iters_done = self._retry_call(

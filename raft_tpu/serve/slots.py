@@ -11,14 +11,26 @@ compiled programs instead of one whole-request forward:
   independent (instance norm / stored batch statistics), so encoding a
   batch that carries ballast in the non-admitted lanes produces the
   same bits for the admitted lanes as any other batch content would.
-- ``iter_step(variables, state, threshold)``: one GRU refinement
-  iteration (:class:`RAFTIterStep`) over every active lane, masked with
-  ``lax``-selects so retired/free lanes are no-ops.  The per-lane
-  convergence predicate (max flow-update magnitude below ``threshold``,
+- ``iter_step(variables, state, threshold, steps)``: a device loop of
+  ``steps`` GRU refinement iterations (:class:`RAFTIterStep`) over every
+  active lane, masked with ``lax``-selects so retired/free lanes are
+  no-ops.  ``steps`` is a runtime int32 scalar (``lax.fori_loop`` with a
+  traced bound), so ONE executable serves every count: request mode
+  passes ``cfg.iters`` and a request is two program calls; slot mode,
+  streaming and :class:`EarlyExitRunner` pass ``1`` and look at
+  ``active`` on the host after every step.  The per-lane convergence
+  predicate (max flow-update magnitude below ``threshold``,
   SEA-RAFT-style early exit) and the iteration-budget check both run
-  in-graph; lanes retiring THIS call get their flow upsampled
-  (:class:`RAFTUpsample`) inside the same program, guarded by a
-  ``lax.cond`` so iterations with no retiree skip the upsample.
+  in-graph, every step; lanes retiring at a step get their flow
+  upsampled (:class:`RAFTUpsample`) there, guarded by a ``lax.cond`` so
+  steps with no retiree skip the upsample, and written into their rows
+  of the carried ``flow_up``.  The loop carries only what a step moves
+  (``net``, ``coords1``, the lane bookkeeping, ``flow_up``) and the
+  program returns only that (:func:`advance` puts it back into the
+  state): the pyramid, ``inp``, ``coords0``, ``budget`` and
+  ``attn`` are read, never copied — not in the loop, and not out of the
+  program either, which would have to copy an input it was not donated
+  (138 MB a lane at 440x1024) to hand it back.
 
 The slot state is a flat dict pytree (sorted keys, so treedefs are
 reproducible across processes for AOT export):
@@ -40,13 +52,13 @@ key                     shape/dtype                meaning
 ======================  =========================  =======================
 
 Both serve batching modes drive these same two compiled programs
-(``batching=request`` in whole-batch lockstep — admit everyone, run
-exactly ``iters`` steps with the threshold disabled — ``slot``
-continuously), which is what makes slot-vs-request bitwise parity
-structural rather than numerical luck: XLA specializes reduction and
-fusion order per program, so the same math compiled into two different
-programs can differ in the last ulp (see the note in
-``models/raft.py``).
+(``batching=request`` in whole-batch lockstep — admit everyone, one
+``iter_step`` call of ``iters`` steps with the threshold disabled —
+``slot`` continuously, a step a call), which is what makes
+slot-vs-request bitwise parity structural rather than numerical luck:
+XLA specializes reduction and fusion order per program, so the same
+math compiled into two different programs can differ in the last ulp
+(see the note in ``models/raft.py``).
 
 ``threshold`` is a runtime f32 scalar, not a compile-time constant, so
 sweeping it (``evaluate.py --early_exit_threshold``, autotune) never
@@ -55,7 +67,7 @@ max of norms, hence ``>= 0``, and the predicate is a strict ``<``.
 
 Streaming sessions (docs/SERVING.md "Streaming sessions") add two more
 programs over the SAME slot state — the state pytree above is untouched,
-so ``iter_step`` and the AOT artifacts stay byte-compatible:
+so ``iter_step`` is the same program with or without them:
 
 - ``stash_carry(variables, image2, carry, admit)``: after a session's
   first (cold) pair is admitted through the unmodified ``encode_admit``
@@ -239,55 +251,89 @@ def make_warm_encode_fn(model_cfg: RAFTConfig):
     return encode_warm
 
 
-def make_iter_fn(model_cfg: RAFTConfig):
-    """``iter_step(variables, state, threshold) -> (state', flow_up)``
-    (pure; the engine jits/lowers it).
+def advance(state: dict, moved: dict) -> dict:
+    """The slot state after an ``iter_step`` call: ``moved`` over
+    ``state``.  The leaves the program only reads stay the very arrays
+    they were (no device work)."""
+    return {**state, **moved}
 
-    ``flow_up`` is the full-resolution ``(S, H, W, 2)`` flow; only rows
-    whose lane retired THIS call (``active`` flipping true -> false)
-    are meaningful — the engine reads exactly those.  When no lane
-    retires, the upsample branch is skipped entirely (``lax.cond``) and
-    ``flow_up`` is zeros."""
+
+def make_iter_fn(model_cfg: RAFTConfig):
+    """``iter_step(variables, state, threshold, steps) -> (moved,
+    flow_up)`` (pure; the engine jits/lowers it).  ``moved`` holds the
+    state leaves a step moves (``active``, ``converged``, ``coords1``,
+    ``delta_max``, ``iters_done``, ``net``) as they stand after the
+    call; ``advance(state, moved)`` is the whole state.
+
+    ``steps`` is a runtime int32 scalar: the program is a device loop
+    (``lax.fori_loop`` with a traced bound, i.e. a ``while``) whose body
+    is ONE refinement iteration, so one executable serves every count
+    and ``steps=k`` is bit-identical to ``k`` calls with ``steps=1``
+    (the same compiled body runs either way).
+
+    ``flow_up`` is the full-resolution ``(S, H, W, 2)`` flow, carried
+    through the loop: a lane's row is written at the step the lane
+    retires (``active`` flipping true -> false), so it holds the flow at
+    ITS retirement iteration whichever step of the call that was; rows
+    of lanes that did not retire in this call are zeros.  Steps with no
+    retiree skip the upsample entirely (``lax.cond``).
+
+    What the body only reads — the corr pyramid, ``inp``, ``coords0``,
+    ``budget`` and GMA's ``attn`` — is closed over, not carried, and not
+    returned: the loop body holds no copy of a pyramid level, there is
+    no program boundary between iterations to copy it across, and the
+    program has no un-donated input to copy into an output."""
     step = RAFTIterStep(model_cfg)
     upsample = RAFTUpsample(model_cfg)
 
-    def iter_step(variables, state, threshold):
-        active = state["active"]
-        net, coords1 = step.apply(
-            variables, state["net"], state["coords1"], state["inp"],
-            state["coords0"], state["corr"], state.get("attn"))
-        # Masked commit: inactive lanes keep their state bit-for-bit
-        # (free lanes carry zeros; a retired lane's state is dead until
-        # the next admit overwrites it, but must not drift meanwhile).
-        net = _lane_select(active, net, state["net"])
-        coords1 = _lane_select(active, coords1, state["coords1"])
+    def iter_step(variables, state, threshold, steps):
+        inp, coords0, corr = state["inp"], state["coords0"], state["corr"]
+        budget, attn = state["budget"], state.get("attn")
 
-        delta = coords1 - state["coords1"]
-        dmax = jnp.max(jnp.sqrt(jnp.sum(delta * delta, axis=-1)),
-                       axis=(1, 2))
-        dmax = jnp.where(active, dmax, state["delta_max"])
-        iters_done = state["iters_done"] + active.astype(jnp.int32)
-        converged = active & (dmax < threshold)
-        done = active & (converged | (iters_done >= state["budget"]))
+        def body(_, carry):
+            (net0, coords1_0, active, was_converged, dmax0, iters_done,
+             flow_up) = carry
+            net, coords1 = step.apply(variables, net0, coords1_0, inp,
+                                      coords0, corr, attn)
+            # Masked commit: inactive lanes keep their state bit-for-bit
+            # (free lanes carry zeros; a retired lane's state is dead
+            # until the next admit overwrites it, but must not drift
+            # meanwhile).
+            net = _lane_select(active, net, net0)
+            coords1 = _lane_select(active, coords1, coords1_0)
 
-        flow_low = coords1 - state["coords0"]
-        S, H8, W8 = flow_low.shape[0], flow_low.shape[1], flow_low.shape[2]
+            delta = coords1 - coords1_0
+            dmax = jnp.max(jnp.sqrt(jnp.sum(delta * delta, axis=-1)),
+                           axis=(1, 2))
+            dmax = jnp.where(active, dmax, dmax0)
+            iters_done = iters_done + active.astype(jnp.int32)
+            converged = active & (dmax < threshold)
+            done = active & (converged | (iters_done >= budget))
 
-        def _upsample(operands):
-            n, f = operands
-            return upsample.apply(variables, n, f)
+            def _upsample(operands):
+                n, f, up = operands
+                return _lane_select(done, upsample.apply(variables, n, f),
+                                    up)
 
-        def _skip(operands):
-            return jnp.zeros((S, H8 * 8, W8 * 8, 2), jnp.float32)
+            def _skip(operands):
+                return operands[2]
 
-        flow_up = jax.lax.cond(jnp.any(done), _upsample, _skip,
-                               (net, flow_low))
-        new_state = _pack_state(
-            net, state["inp"], state["coords0"], coords1,
-            state["corr"], active & ~done, state["budget"],
-            state["converged"] | converged, dmax, iters_done,
-            attn=state.get("attn"))
-        return new_state, flow_up
+            flow_up = jax.lax.cond(jnp.any(done), _upsample, _skip,
+                                   (net, coords1 - coords0, flow_up))
+            return (net, coords1, active & ~done,
+                    was_converged | converged, dmax, iters_done, flow_up)
+
+        S, H8, W8 = coords0.shape[:3]
+        (net, coords1, active, converged, dmax, iters_done,
+         flow_up) = jax.lax.fori_loop(
+            0, steps, body,
+            (state["net"], state["coords1"], state["active"],
+             state["converged"], state["delta_max"], state["iters_done"],
+             jnp.zeros((S, H8 * 8, W8 * 8, 2), jnp.float32)))
+        moved = {"active": active, "converged": converged,
+                 "coords1": coords1, "delta_max": dmax,
+                 "iters_done": iters_done, "net": net}
+        return moved, flow_up
 
     return iter_step
 
@@ -327,13 +373,14 @@ class EarlyExitRunner:
                                tuple(np.asarray(image1).shape[1:3]))
         state = self._encode(variables, image1, image2, state, admit,
                              budgets)
-        thr = jnp.float32(threshold)
+        thr, one = jnp.float32(threshold), jnp.int32(1)
         out = None
         prev_active = np.ones((B,), bool)
         iters_used = np.zeros((B,), np.int32)
         residuals = np.full((B,), -1.0, np.float32)
         for _ in range(int(iters)):
-            state, flow_up = self._iter(variables, state, thr)
+            moved, flow_up = self._iter(variables, state, thr, one)
+            state = advance(state, moved)
             active = np.asarray(state["active"])
             newly = prev_active & ~active
             if newly.any():

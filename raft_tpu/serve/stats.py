@@ -133,6 +133,15 @@ class Counters:
             "raft_serve_slot_occupancy",
             "active/total slot lanes over the engine lifetime "
             "(continuous-batching mode)")
+        # The iteration program takes its step count at run time
+        # (serve/slots.py): request mode is 1 call of cfg.iters steps a
+        # batch, slot mode 1 step a call — steps/calls says which.
+        self._iter_calls = r.counter(
+            "raft_serve_iter_calls_total",
+            "calls of the iteration program (either batching mode)")
+        self._iter_steps = r.counter(
+            "raft_serve_iter_steps_total",
+            "refinement steps those calls were asked to run")
         self._uptime = r.gauge("raft_serve_uptime_seconds",
                                "seconds since the engine started")
         self._lock = threading.Lock()
@@ -185,6 +194,12 @@ class Counters:
             self._slot_occ.set(
                 round(self._slot_active.value() / total, 4))
 
+    def add_iter_call(self, steps: int) -> None:
+        """One call of the iteration program asked for ``steps`` steps
+        (counted when issued: a retried call counts again)."""
+        self._iter_calls.inc()
+        self._iter_steps.inc(steps)
+
     def snapshot(self, num_chips: int) -> Dict[str, float]:
         uptime = self._uptime_s()
         completed = self._completed.value()
@@ -210,6 +225,8 @@ class Counters:
             "retries": self._retries.value(),
             "batches": batches,
             "slot_steps": self._slot_steps.value(),
+            "iter_calls": self._iter_calls.value(),
+            "iter_steps": self._iter_steps.value(),
             "failed_lanes": failed_lanes,
             "mean_batch_fill": round(real_lanes / batches, 3)
             if batches else 0.0,

@@ -192,9 +192,17 @@ def main(argv=None) -> int:
         gen0 = r1.generation
 
         # -- 1b. partition: failover, zero drops ----------------------
-        heal_at = 16
+        # The partition starts one wire operation after it is installed.
+        # The router reads r1's health (cached health_cache_s) before
+        # every pick; on a loaded host the cache has run out by now, and
+        # a refresh that met the partition would sideline r1 before any
+        # request reached it: nothing dropped, and no failover to count.
+        # This way whoever goes first gets through — a refresh leaves the
+        # cache fresh for the burst's picks, a request is served — and
+        # the next request to r1 meets the partition.
+        heal_at = 17
         chaos.install(chaos.FaultPlan.parse(
-            f"net_partition@step=0,heal={heal_at}", seed=args.seed))
+            f"net_partition@step=1,heal={heal_at}", seed=args.seed))
         futures = [router.submit(frame(), frame())
                    for _ in range(burst_n)]
         results = [f.result(timeout=120) for f in futures]

@@ -258,6 +258,110 @@ def test_raft_full_eval_forward_compiles_for_v5e(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _serve_program_specs(cfg, one_chip, lanes=1, bucket=(440, 1024)):
+    """``(variables, state, image, lane, scalar)`` shape specs on
+    ``one_chip`` for the engine's programs of ``cfg`` at ``bucket``, plus
+    the host template they were read off."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.models.raft import RAFT
+    from raft_tpu.serve import slots
+
+    small = jnp.zeros((1, 64, 96, 3), jnp.float32)
+    rng = jax.random.PRNGKey(0)
+    shapes = jax.eval_shape(
+        lambda: RAFT(cfg).init({"params": rng, "dropout": rng}, small,
+                               small, iters=1))
+    template = slots.state_template(cfg, shapes, lanes, bucket)
+
+    def spec(tree):
+        return _with_sharding(jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree),
+            one_chip)
+
+    image = jax.ShapeDtypeStruct((lanes,) + bucket + (3,), jnp.float32,
+                                 sharding=one_chip)
+    lane = lambda dt: jax.ShapeDtypeStruct((lanes,), dt, sharding=one_chip)
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    return spec(shapes), spec(template), image, lane, scalar, template
+
+
+def _loop_body(text):
+    """``(inside, outside)``: the text of the one ``while`` loop's body
+    with every computation it calls, and the rest of the module."""
+    import re
+
+    blocks = {}
+    for m in re.finditer(r"^(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)^\}",
+                         text, re.M | re.S):
+        blocks[m.group(1)] = m.group(2)
+    loops = re.findall(r" while\([^\n]*?body=(%[\w.\-]+)", text)
+    assert len(loops) == 1, loops
+    inside, todo = set(), [loops[0]]
+    while todo:
+        name = todo.pop()
+        if name in inside:
+            continue
+        inside.add(name)
+        todo += [c for c in re.findall(r"%[\w.\-]+", blocks[name])
+                 if c in blocks]
+    return ("\n".join(blocks[n] for n in sorted(inside)),
+            "\n".join(b for n, b in sorted(blocks.items())
+                      if n not in inside))
+
+
+def _hbm_copies(text, shape):
+    """The instructions of ``text`` that write a copy of a ``shape``
+    array to HBM: a ``copy`` or ``copy-done`` whose result layout names
+    no memory space (``S(1)`` is the chip's VMEM: the prefetch of an
+    operand for the operation that reads it, not a second array)."""
+    import re
+
+    return [m.group(0) for m in re.finditer(
+        r"= " + re.escape(shape) + r"\{([^}]*)\} (?:copy|copy-done)\(",
+        text) if "S(" not in m.group(1)]
+
+
+def test_full_iter_program_is_one_device_loop_that_copies_no_pyramid(
+        one_chip, monkeypatch):
+    """``raft_full``'s iteration program at the Sintel bucket as the
+    engine builds it (one lane, bf16, the step count a runtime scalar):
+    the Mosaic lookup sits inside the body of a ``while``, and neither
+    that body nor the rest of the program copies a level of the 132 MB
+    pyramid — it is an input the loop only reads and the program does
+    not return (the parent handed it back un-donated, a copy in every
+    one of a request's 32 programs: PERF.md section 5)."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.evaluate import make_inference_model
+    from raft_tpu.serve import slots
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = make_inference_model(
+        RAFTConfig.full(compute_dtype="bfloat16")).config
+    variables, state, _, _, scalar, template = _serve_program_specs(
+        cfg, one_chip)
+    assert [tuple(a.shape) for a in template["corr"][:2]] == [
+        (1, 55, 128, 7040), (1, 27, 64, 7040)]
+    it = jax.jit(slots.make_iter_fn(cfg)).lower(
+        variables, state, scalar(jnp.float32),
+        scalar(jnp.int32)).compile()
+    inside, outside = _loop_body(it.as_text())
+    assert "tpu_custom_call" in inside
+    assert "tpu_custom_call" not in outside
+    for level in ("bf16[1,55,128,7040]", "bf16[1,27,64,7040]"):
+        assert _hbm_copies(inside, level) == []
+        assert _hbm_copies(outside, level) == []
+    moved, flow_up = it.out_info
+    assert flow_up.shape == (1, 440, 1024, 2)
+    assert "corr" not in moved and moved["net"].shape == (1, 55, 128, 128)
+    ma = it.memory_analysis()
+    assert ma.output_size_in_bytes < 2 ** 23 < ma.argument_size_in_bytes
+
+
 def test_gma_serve_programs_compile_for_v5e(one_chip, monkeypatch):
     """arch 'gma' at the Sintel bucket, as the engine would build it (one
     lane, bf16): ``encode_admit`` and ``iter_step`` compile for one v5e
@@ -275,39 +379,31 @@ def test_gma_serve_programs_compile_for_v5e(one_chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = make_inference_model(
         RAFTConfig.gma(compute_dtype="bfloat16")).config
-    small = jnp.zeros((1, 64, 96, 3), jnp.float32)
-    rng = jax.random.PRNGKey(0)
-    from raft_tpu.models.raft import RAFT
-
-    shapes = jax.eval_shape(
-        lambda: RAFT(cfg).init({"params": rng, "dropout": rng}, small,
-                               small, iters=1))
-    template = slots.state_template(cfg, shapes, 1, (440, 1024))
+    variables, state, image, lane, scalar, template = \
+        _serve_program_specs(cfg, one_chip)
     n = 55 * 128
     assert template["attn"].shape == (1, n, n)
     assert template["attn"].nbytes == 2 * n * n
-
-    def spec(tree):
-        return _with_sharding(jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree),
-            one_chip)
-
-    variables, state = spec(shapes), spec(template)
-    image = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
-                                 sharding=one_chip)
-    lane = lambda dt: jax.ShapeDtypeStruct((1,), dt, sharding=one_chip)
     enc = jax.jit(slots.make_encode_fn(cfg)).lower(
         variables, image, image, state, lane(jnp.bool_),
         lane(jnp.int32)).compile()
     it = jax.jit(slots.make_iter_fn(cfg)).lower(
-        variables, state, jax.ShapeDtypeStruct(
-            (), jnp.float32, sharding=one_chip)).compile()
+        variables, state, scalar(jnp.float32),
+        scalar(jnp.int32)).compile()
     for compiled in (enc, it):
         ma = compiled.memory_analysis()
         need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes)
         assert max(need, ma.peak_memory_in_bytes) < 16 * 2 ** 30
-    assert "tpu_custom_call" in it.as_text()
-    new_state, flow_up = it.out_info
-    assert new_state["attn"].shape == (1, n, n)
+    # the loop reads the attention as it reads the pyramid: the lookup
+    # is in its body, and the program copies neither anywhere (VMEM
+    # prefetches of the aggregate's operand aside) nor returns them
+    inside, outside = _loop_body(it.as_text())
+    assert "tpu_custom_call" in inside
+    for leaf in (f"bf16[1,{n},{n}]", "bf16[1,55,128,7040]"):
+        assert _hbm_copies(inside, leaf) == []
+        assert _hbm_copies(outside, leaf) == []
+    moved, flow_up = it.out_info
+    assert "attn" not in moved and "corr" not in moved
+    assert enc.out_info["attn"].shape == (1, n, n)
     assert flow_up.shape == (1, 440, 1024, 2)

@@ -264,8 +264,9 @@ BUCKET = (64, 96)      # -> 8x12 maps; levels 8x12, 4x6, 2x3, 1x1
 @pytest.mark.parametrize("small", [False, True],
                          ids=["full_radius4", "small_radius3"])
 def test_serve_programs_mosaic_lookup_matches_xla(small):
-    """``encode_admit`` + two ``iter_step``s, the kernel in the Pallas
-    interpreter against the XLA programs: same state but for the
+    """``encode_admit`` + one ``iter_step`` of two steps (the kernel
+    inside the device loop), the kernel in the Pallas interpreter
+    against the XLA programs: same state but for the
     pyramid's layout, same flow."""
     from raft_tpu.models.raft import RAFT
     from raft_tpu.serve import slots
@@ -291,9 +292,9 @@ def test_serve_programs_mosaic_lookup_matches_xla(small):
         state = slots.state_template(cfg, variables, 2, BUCKET)
         state = jax.jit(slots.make_encode_fn(cfg))(
             variables, a1, a2, state, admit, budgets)
-        it = jax.jit(slots.make_iter_fn(cfg))
-        for _ in range(2):
-            state, flow_up = it(variables, state, jnp.float32(0.0))
+        moved, flow_up = jax.jit(slots.make_iter_fn(cfg))(
+            variables, state, jnp.float32(0.0), jnp.int32(2))
+        state = slots.advance(state, moved)
         assert not np.asarray(state["active"]).any()
         out[name] = (state, np.asarray(flow_up))
     sx, fx = out["xla"]
@@ -381,6 +382,89 @@ def test_aot_artifact_is_held_to_the_importers_lookup(engine, tmp_path):
     with pytest.raises(aot.AOTImportError, match="built with corr_impl"):
         aot.import_executables(str(tmp_path), fingerprint=fp,
                                corr_impl=engine._corr_impl_at)
+
+
+def test_aot_artifact_of_another_iteration_call_is_refused_by_name(
+        engine, tmp_path):
+    """The iteration program took its step count at run time in PR 29:
+    an artifact records how each program is called, and one whose
+    ``iter`` was built for the earlier call — a manifest from before the
+    field existed, as the parent commit wrote them, or one that names
+    another argument list — is refused with the program and both calls
+    spelled out, not imported and called with the wrong arguments."""
+    import json
+
+    from raft_tpu.serve import InferenceEngine, ServeConfig, aot
+
+    if not engine.compiled_keys():
+        pytest.skip("the engine compiled nothing (test order)")
+    manifest = engine.export_aot(str(tmp_path))
+    calls = engine._program_calls()
+    assert calls["iter"] == "iter_step(variables, state, threshold, steps)"
+    assert {k["program"]: k["call"] for k in manifest["keys"]} == {
+        "enc": calls["enc"], "iter": calls["iter"]}
+    fp = manifest["fingerprint"]
+    assert set(aot.import_executables(
+        str(tmp_path), fingerprint=fp, calls=calls)) == set(
+            engine.compiled_keys())
+    path = tmp_path / aot.MANIFEST
+    parents = json.loads(path.read_text())
+    for k in parents["keys"]:
+        del k["call"]
+    path.write_text(json.dumps(parents))
+    refusal = (r"exe-40x56-b1-iter\.bin is the program 'iter' built to "
+               r"be called as iter_step\(variables, state, threshold\); "
+               r"this engine calls iter_step\(variables, state, "
+               r"threshold, steps\)")
+    with pytest.raises(aot.AOTImportError, match=refusal):
+        aot.import_executables(str(tmp_path), fingerprint=fp, calls=calls)
+    # an engine pointed at it falls back to building its own programs
+    cold = InferenceEngine(engine._variables, RAFTConfig.small_model(),
+                           ServeConfig(iters=2, batch_sizes=(1,),
+                                       max_batch=1,
+                                       aot_dir=str(tmp_path)))
+    assert cold.aot_info["ok"] is False and cold.aot_info["imported"] == 0
+    assert "'iter'" in cold.aot_info["error"]
+    # and any other recorded call is held to the importer's likewise
+    for k in parents["keys"]:
+        k["call"] = calls[k["program"]].replace("budgets", "budget")
+    path.write_text(json.dumps(parents))
+    with pytest.raises(aot.AOTImportError, match="program 'enc' built"):
+        aot.import_executables(str(tmp_path), fingerprint=fp, calls=calls)
+
+
+def test_cost_of_a_pair_is_enc_plus_steps_times_one_loop_body(engine):
+    """XLA counts a loop of unknown length once, so the ``iter`` entry of
+    the cost ledger is ONE refinement step whatever ``steps`` a call
+    passes: a pair costs ``enc + iters x iter`` as before, and
+    ``python -m raft_tpu cost`` stamps the same two programs."""
+    from raft_tpu.cli import cost as cli
+    from raft_tpu.models.raft import RAFTIterStep, RAFTUpsample
+
+    if not engine.compiled_keys():
+        pytest.skip("the engine compiled nothing (test order)")
+    bucket, cfg, v = (40, 56), engine._model_cfg, engine._variables
+    enc = engine.cost_book.get((bucket, 1, "enc"))
+    it = engine.cost_book.get((bucket, 1, "iter"))
+    attrs = engine._pipeline_cost_attrs(bucket, 1, 2, 0.1)
+    assert attrs["flops"] == enc.flops + 2 * it.flops
+    # one body: the step and the guarded upsample, once
+    tpl = engine._programs[(bucket, 1)].template
+
+    def one_step(variables, state):
+        net, coords1 = RAFTIterStep(cfg).apply(
+            variables, state["net"], state["coords1"], state["inp"],
+            state["coords0"], state["corr"])
+        return RAFTUpsample(cfg).apply(variables, net,
+                                       coords1 - state["coords0"])
+
+    body = jax.jit(one_step).lower(v, tpl).compile().cost_analysis()
+    assert 0.98 * body["flops"] < it.flops < 1.1 * body["flops"]
+    # the CLI lowers the same functions with the same arguments
+    rows = cli.serve_costs(cfg, v, bucket, 1)
+    assert [c.program for c in rows] == ["serve_enc_40x56_b1",
+                                         "serve_iter_40x56_b1"]
+    assert rows[0].flops == enc.flops and rows[1].flops == it.flops
 
 
 def test_spatially_sharded_step_keeps_the_xla_lookup_on_tpu(monkeypatch):

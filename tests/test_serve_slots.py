@@ -397,3 +397,141 @@ def test_slot_parity_with_fused_gru(variables, request_flows):
     for r, g in zip(ref, got):
         np.testing.assert_allclose(r, g, rtol=1e-5, atol=1e-5)
     assert stats["batching"] == "slot" and stats["completed"] == 4
+
+
+# ---------------------------------------------------------------------------
+# The iteration program's runtime step count (PR 29)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def stepped(variables):
+    """One jitted ``encode_admit`` / ``iter_step`` pair over three lanes
+    and the admitted state to start from: per-lane budgets that differ
+    (1, ITERS, 2), so lanes retire at different steps of one call."""
+    import jax
+
+    from raft_tpu.serve import slots
+
+    rng = np.random.default_rng(29)
+    im1 = np.stack([_images(rng, (40, 56))[0] for _ in range(3)])
+    im2 = np.stack([_images(rng, (40, 56))[0] for _ in range(3)])
+    it = jax.jit(slots.make_iter_fn(CFG))
+    state = jax.jit(slots.make_encode_fn(CFG))(
+        variables, im1, im2,
+        slots.state_template(CFG, variables, 3, (40, 56)),
+        np.ones((3,), bool), np.array([1, ITERS, 2], np.int32))
+    # A threshold between two lanes' first updates: one of the lanes
+    # with budget left converges at step 1, the other runs on.  Read
+    # off one step, not guessed.
+    first, _ = it(variables, state, np.float32(0.0), np.int32(1))
+    assert sorted(first) == ["active", "converged", "coords1",
+                             "delta_max", "iters_done", "net"]
+    d = np.asarray(first["delta_max"])
+    assert d[1] != d[2]
+    early = np.float32((d[1] + d[2]) / 2)
+    return it, state, early
+
+
+def _one_step_at_a_time(it, variables, state, thr, k):
+    """``k`` calls with ``steps=1``, the caller putting what each call
+    moved back into the state and keeping each lane's row of ``flow_up``
+    from the call the lane retired in (what the slot engine and
+    ``EarlyExitRunner`` do)."""
+    from raft_tpu.serve import slots
+
+    prev = np.asarray(state["active"])
+    out = None
+    for _ in range(k):
+        moved, flow_up = it(variables, state, thr, np.int32(1))
+        state = slots.advance(state, moved)
+        flow_up = np.asarray(flow_up)
+        out = np.zeros_like(flow_up) if out is None else out
+        active = np.asarray(state["active"])
+        newly = prev & ~active
+        out[newly] = flow_up[newly]
+        # a row of a lane that did not retire in this call says nothing
+        assert not flow_up[~newly].any()
+        prev = active
+    return state, out
+
+
+@pytest.mark.parametrize("case", ["budgets", "early_exit"])
+@pytest.mark.parametrize("k", [1, 2, ITERS])
+def test_iter_step_k_steps_equal_k_calls_bitwise(variables, stepped, k,
+                                                 case):
+    """``iter_step(..., steps=k)`` is ``k`` calls with ``steps=1`` bit
+    for bit — every state leaf and every lane's ``flow_up`` row, a lane
+    retiring at whichever step of the call its budget or the threshold
+    says — because both run the same compiled loop body.  What the loop
+    only reads is not returned at all: the state after a call holds the
+    very arrays it held before."""
+    import jax
+
+    from raft_tpu.serve import slots
+
+    it, state, early = stepped
+    thr = np.float32(0.0) if case == "budgets" else early
+    moved, got_flow = it(variables, state, thr, np.int32(k))
+    got_state = slots.advance(state, moved)
+    for leaf in ("inp", "coords0", "budget"):
+        assert got_state[leaf] is state[leaf]
+    assert all(a is b for a, b in zip(got_state["corr"], state["corr"]))
+    want_state, want_flow = _one_step_at_a_time(it, variables, state,
+                                                thr, k)
+    got = jax.tree_util.tree_flatten_with_path(got_state)[0]
+    want = jax.tree_util.tree_flatten_with_path(want_state)[0]
+    assert [p for p, _ in got] == [p for p, _ in want]
+    for (path, a), (_, b) in zip(got, want):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=jax.tree_util.keystr(path))
+    np.testing.assert_array_equal(np.asarray(got_flow), want_flow)
+    # the lanes retire at different steps of the one call: by their
+    # budgets, and under the threshold one of them before its budget
+    budgets = np.array([1, ITERS, 2])
+    done = np.asarray(got_state["iters_done"])
+    converged = np.asarray(got_state["converged"])
+    if case == "budgets":
+        assert done.tolist() == np.minimum(budgets, k).tolist()
+        assert not converged.any()
+        assert (~np.asarray(got_state["active"])).all() == (k == ITERS)
+    else:
+        assert (converged[1:] & (done[1:] < budgets[1:])).any()
+        assert done[1] != done[2] or k == 1
+    retired = ~np.asarray(got_state["active"])
+    assert retired[0]
+    assert [bool(np.asarray(got_flow)[i].any()) for i in range(3)] \
+        == retired.tolist()
+
+
+def test_request_mode_is_one_call_of_iters_steps_slot_mode_one_step(
+        variables):
+    """The counters that say the mechanism engages: a request-mode batch
+    is 2 program calls (``enc``, ``iter``) and its one ``iter`` call
+    runs ``cfg.iters`` steps; slot mode calls the same program with one
+    step a call."""
+    from raft_tpu.obs import stages
+
+    rng = np.random.default_rng(31)
+    pairs = [_images(rng) for _ in range(2)]
+    newest = max([r["n"] for r in stages.recent("serve")], default=0)
+    eng = InferenceEngine(variables, CFG, ServeConfig(
+        iters=ITERS, max_batch=1, batch_sizes=(1,), max_wait_ms=1))
+    with eng:
+        for a, b in pairs:
+            eng.infer(a, b, timeout=120)
+        stats = eng.stats()
+    recs = [r for r in stages.recent("serve") if r["n"] > newest]
+    assert len(recs) == 2 and all(r["calls"] == 2 for r in recs)
+    assert stats["iter_calls"] == 2
+    assert stats["iter_steps"] / stats["iter_calls"] == ITERS
+
+    slot = InferenceEngine(variables, CFG, ServeConfig(
+        iters=ITERS, batching="slot", slots=1))
+    with slot:
+        for a, b in pairs:
+            slot.infer(a, b, timeout=120)
+        stats = slot.stats()
+    assert stats["iter_calls"] == stats["slot_steps"] == 2 * ITERS
+    assert stats["iter_steps"] / stats["iter_calls"] == 1
