@@ -9,8 +9,8 @@ for at runtime (see docs/ANALYSIS.md for the rule catalog):
   attribute discipline and lock-acquisition-order cycles;
 - :mod:`raft_tpu.analysis.telemetry` — ``TEL301..TEL305``: emission
   sites vs the OBSERVABILITY.md catalog vs regression-gate keys;
-- :mod:`raft_tpu.analysis.contracts` — ``CFG401..CFG403``: argparse
-  flags vs config dataclasses vs tuning-registry knobs.
+- :mod:`raft_tpu.analysis.contracts` — ``CFG401/CFG402``: argparse
+  flags vs the code that reads them vs the docs that name them.
 
 Entry points: ``python -m raft_tpu lint`` (CLI) and
 ``scripts/lint_repo.py`` (bench-style JSON record + ``--fix``).
@@ -36,7 +36,7 @@ CHECKER_FAMILIES = {
     "telemetry": ("raft_tpu.analysis.telemetry",
                   ("TEL301", "TEL302", "TEL303", "TEL304", "TEL305")),
     "contracts": ("raft_tpu.analysis.contracts",
-                  ("CFG401", "CFG402", "CFG403")),
+                  ("CFG401", "CFG402")),
 }
 
 

@@ -1,15 +1,10 @@
-"""Config/CLI drift checker (rules CFG401..CFG403).
+"""CLI drift checker (rules CFG401, CFG402).
 
-Three registries describe the same knob surface and nothing but
-convention keeps them aligned: the frozen config dataclasses
-(``RAFTConfig`` / ``TrainConfig`` in ``config.py``, ``ServeConfig`` in
-``serve/engine.py``), the argparse flags in ``cli/*.py`` and
-``scripts/*.py``, and the tuning-registry knob tuples in ``tuning.py``.
-Drift here is user-facing: a flag that parses but is never read
-silently ignores the user's intent; a doc that names a flag the CLI
-dropped sends them to ``error: unrecognized arguments``; a tunable not
-backed by a config field makes ``autotune.py`` persist winners nothing
-consumes.
+The argparse flags in ``cli/*.py`` and ``scripts/*.py`` and the docs
+that name them are kept aligned by nothing but convention.  Drift here
+is user-facing: a flag that parses but is never read silently ignores
+the user's intent; a doc that names a flag the CLI dropped sends them
+to ``error: unrecognized arguments``.
 
 Rules:
 
@@ -22,9 +17,6 @@ Rules:
 - ``CFG402`` phantom doc flag: ``--flag`` named inside a backtick
   span in ``README.md`` / ``docs/*.md`` that no argparse declaration
   anywhere in the repo provides.
-- ``CFG403`` orphan tunable: a name in ``TUNABLE_KNOBS`` that is not
-  a ``RAFTConfig`` field, or in ``SERVE_TUNABLE_KNOBS`` that is not a
-  ``ServeConfig`` field — ``resolve_config`` would silently drop it.
 """
 
 from __future__ import annotations
@@ -39,16 +31,6 @@ from raft_tpu.analysis.core import Finding, Workspace
 CLI_SCOPE = ("raft_tpu/cli", "scripts", "raft_tpu/convert.py",
              "chip_smoke.py")
 DOC_SCOPE = ("README.md", "docs")
-CONFIG_CLASSES = {
-    "RAFTConfig": "raft_tpu/config.py",
-    "TrainConfig": "raft_tpu/config.py",
-    "ServeConfig": "raft_tpu/serve/engine.py",
-}
-TUNING_PATH = "raft_tpu/tuning.py"
-KNOB_REGISTRIES = {
-    "TUNABLE_KNOBS": "RAFTConfig",
-    "SERVE_TUNABLE_KNOBS": "ServeConfig",
-}
 
 #: ``--flag`` / ``--flag_name`` inside a backtick span.
 _BACKTICK_RE = re.compile(r"`([^`]+)`")
@@ -59,20 +41,6 @@ def _str_const(node: ast.AST) -> Optional[str]:
     if isinstance(node, ast.Constant) and isinstance(node.value, str):
         return node.value
     return None
-
-
-def dataclass_fields(ws: Workspace, cls_name: str,
-                     relpath: str) -> Set[str]:
-    """Annotated field names of a (frozen) dataclass, by AST."""
-    sf = ws.get(relpath)
-    if sf is None or sf.tree is None:
-        return set()
-    for node in sf.tree.body:
-        if isinstance(node, ast.ClassDef) and node.name == cls_name:
-            return {item.target.id for item in node.body
-                    if isinstance(item, ast.AnnAssign)
-                    and isinstance(item.target, ast.Name)}
-    return set()
 
 
 class _Flag:
@@ -138,18 +106,8 @@ def _module_consumes(sf) -> Tuple[Set[str], bool]:
 
 def check(ws: Workspace,
           cli_scope: Sequence[str] = CLI_SCOPE,
-          doc_scope: Sequence[str] = DOC_SCOPE,
-          config_classes: Optional[Dict[str, str]] = None,
-          tuning_path: str = TUNING_PATH,
-          knob_registries: Optional[Dict[str, str]] = None,
-          ) -> List[Finding]:
+          doc_scope: Sequence[str] = DOC_SCOPE) -> List[Finding]:
     findings: List[Finding] = []
-    config_classes = (CONFIG_CLASSES if config_classes is None
-                      else config_classes)
-    knob_registries = (KNOB_REGISTRIES if knob_registries is None
-                       else knob_registries)
-    fields = {cls: dataclass_fields(ws, cls, rel)
-              for cls, rel in config_classes.items()}
     flags = collect_flags(ws, cli_scope)
 
     # ------------------------------ CFG401 ----------------------------
@@ -205,29 +163,4 @@ def check(ws: Workspace,
                         "declaration under "
                         f"{'/'.join(cli_scope)} provides it — "
                         "readers get `unrecognized arguments`"))
-    # ------------------------------ CFG403 ----------------------------
-    sf = ws.get(tuning_path)
-    if sf is not None and sf.tree is not None:
-        for node in sf.tree.body:
-            if not isinstance(node, ast.Assign):
-                continue
-            for tgt in node.targets:
-                if not (isinstance(tgt, ast.Name)
-                        and tgt.id in knob_registries):
-                    continue
-                cls = knob_registries[tgt.id]
-                valid = fields.get(cls, set())
-                if not valid:
-                    continue
-                if isinstance(node.value, (ast.Tuple, ast.List)):
-                    for elt in node.value.elts:
-                        knob = _str_const(elt)
-                        if knob and knob not in valid:
-                            findings.append(Finding(
-                                "CFG403", tuning_path, elt.lineno,
-                                f"{tgt.id}:{knob}",
-                                f"tunable `{knob}` in {tgt.id} is "
-                                f"not a {cls} field — autotune "
-                                "would persist winners "
-                                "`resolve_config` silently drops"))
     return findings

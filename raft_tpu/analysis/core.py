@@ -4,8 +4,8 @@ The analysis package (docs/ANALYSIS.md) is a repo-specific static pass
 over exactly the defect classes this codebase has paid for at runtime:
 host impurity inside jit-traced code, lock-discipline violations in the
 hand-rolled threading seams, telemetry emissions drifting from the
-documented catalog, and CLI/config/tuning-registry drift.  Every rule
-is a tier-1 failure here instead of a production incident.
+documented catalog, and CLI/doc drift.  Every rule is a tier-1 failure
+here instead of a production incident.
 
 Three escape hatches, in order of preference:
 

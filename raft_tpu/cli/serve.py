@@ -108,8 +108,7 @@ def parse_args(argv=None):
                         "--slots persistent device lanes "
                         "(docs/SERVING.md 'Continuous batching')")
     p.add_argument("--slots", type=int, default=8,
-                   help="slot mode: persistent device lanes per bucket "
-                        "(tunable via scripts/autotune.py --kind serve)")
+                   help="slot mode: persistent device lanes per bucket")
     p.add_argument("--stream-ttl-s", type=float, default=60.0,
                    help="streaming sessions: evict a session (and free "
                         "its pinned lane) after this long without a "

@@ -119,10 +119,11 @@ class RAFTConfig:
     # (allpairs_pallas AND allpairs): 'bfloat16' halves the HBM traffic
     # of the lookup reads, the dcorr writes and the cross-iteration gradient
     # accumulation (the pyramid is the largest tensor in the step, ~537 MB
-    # at chairs batch 16; measured +6.9% train throughput on v5e).  The
-    # correlation MATH stays fp32 — the einsum accumulates fp32
-    # (corr_precision) and the Pallas kernels convert tiles to fp32 on
-    # load; only the stored values round.  'auto' (default): bfloat16
+    # at chairs batch 16; every PERF.md cell runs bf16 storage, none
+    # times float32).  The correlation MATH stays fp32 — the einsum
+    # accumulates fp32 (corr_precision) and the Pallas kernels convert
+    # tiles to fp32 on load; only the stored values round.
+    # 'auto' (default): bfloat16
     # when compute_dtype is bfloat16 — the refinement step already rounds
     # the lookup output to bf16 before the motion encoder consumes it
     # (raft.py corr.astype(dt)), so bf16 storage adds no new precision
@@ -138,7 +139,7 @@ class RAFTConfig:
     # anything.
     # Real-data full-stage EPE remains the definitive test
     # (docs/REAL_WEIGHTS_RUNBOOK.md); quality-critical runs can still
-    # pin 'float32' (~7% throughput give-back).
+    # pin 'float32'.
     # 'int8' / 'float8_e4m3fn' / 'float8_e5m2' store the pyramid
     # QUANTIZED with a per-level symmetric scale calibrated from the
     # correlation row maxima; lookups dequantize in the sampling pass
@@ -152,13 +153,11 @@ class RAFTConfig:
     corr_dtype: str = "auto"
     # MXU precision for the correlation matmul + window-sampling einsums:
     # 'default' (1 bf16 pass), 'high' (bf16x3), 'highest' (fp32), or
-    # 'auto' (= 'highest').  Counterintuitive v5e measurements, twice
-    # confirmed: 'highest' beats 'high' (round 1) AND beats 'default'
-    # (round 4: 76.0 vs 74.1 pairs/s end-to-end) — even though under
-    # bf16 compute the fmaps are bf16-exact and 'default' is bitwise
-    # identical in VALUE (verified: max abs diff exactly 0.0), the
-    # inserted converts break XLA's einsum fusions and cost more than
-    # the extra MXU passes save.  Keep 'highest'.
+    # 'auto' (= 'highest': fp32 correlation, as the reference keeps it,
+    # corr.py:50).  Every PERF.md cell runs 'highest'; no cell times
+    # the cheaper settings.  Under bf16 compute the fmaps are bf16-exact
+    # and 'default' is bitwise identical in VALUE (verified: max abs
+    # diff exactly 0.0).
     corr_precision: str = "auto"
     # bf16 compute for encoders + update block (replaces the reference's
     # torch.cuda.amp autocast, raft.py:11-21,99,110,127); correlation
@@ -170,17 +169,15 @@ class RAFTConfig:
     remat: bool = True
     # Remat policy: 'save_corr' keeps the per-iteration sampled corr
     # windows + motion-encoder outputs (small; skips ~half the backward
-    # recompute — measured 15.8 vs 14.4 pairs/s/chip over 'full' on v5e);
+    # recompute; what PERF.md's train cells run, 7.4-9.9 GB of 16);
     # 'full' recomputes everything (lowest memory); 'dots' saves all
-    # einsum outputs (measured slower: HBM pressure).
+    # einsum outputs.  No cell times the other two.
     remat_policy: str = "save_corr"
     # Refinement-scan unroll factor (lax.scan unroll): trades compile
-    # time/code size for less per-iteration loop overhead.  Round-1
-    # sweep (heavier body): 1/2/3/4/6 -> 15.8/16.2/16.2/16.1/18.7,
-    # 12 OOM.  Round 2 (flat fused loss + query-minor pyramid freed the
-    # HBM the unrolled backward needs): batch 16 unroll 6 -> 54.3,
-    # unroll 12 -> 56.0 pairs/s/chip — full unroll now fits and wins;
-    # re-measure if the body changes.
+    # time/code size for less per-iteration loop overhead.  12 is a
+    # full unroll of the training budget and what PERF.md's train cells
+    # run; it was picked by sweeps of step bodies that no longer exist
+    # (ROADMAP S5/D5) and no cell times another value.
     scan_unroll: int = 12
     # Rematerialize the upsample stage (mask head + convex upsample, which
     # runs in its own scan *after* the GRU refinement scan) in backward.
@@ -189,8 +186,8 @@ class RAFTConfig:
     remat_upsample: bool = True
     # Compute dtype for the flat convex-upsample + fused-loss chain
     # (training path only; eval always upsamples fp32).  'bfloat16'
-    # halves the HBM traffic of the 9-tap softmax/FMA chain — measured
-    # +9.3% train throughput on v5e — at ~0.4% relative rounding on the
+    # halves the HBM traffic of the 9-tap softmax/FMA chain (what
+    # PERF.md's train cells run) at ~0.4% relative rounding on the
     # upsampled flow (loss 33.5360 vs 33.5361, grad-norm 63.50 vs 63.39
     # on the bench shape).  'auto' (default): bfloat16 when
     # compute_dtype is bfloat16 (the flow predictions entering the
@@ -201,9 +198,9 @@ class RAFTConfig:
     # Iterations folded into the batch axis per upsample-scan step (the
     # mask-head convs and the flat convex combination run at
     # ``upsample_group * B`` batch).  Must divide ``iters``; values that
-    # don't are rounded down to the nearest divisor.  Round-1 sweep at
-    # g=1/2/3/4/6 -> 13.7/14.4/13.9/14.1/12.8 pairs/s/chip picked 2;
-    # re-sweep when the upsample body or memory balance changes.
+    # don't are rounded down to the nearest divisor.  2 is what
+    # PERF.md's train cells run; it was picked against a step body that
+    # no longer exists (ROADMAP S5/D5) and no cell times another value.
     upsample_group: int = 2
     # Unroll factor for the upsample scan (lax.scan unroll over the
     # iters/upsample_group steps) — the refinement scan's unroll lesson
@@ -244,16 +241,16 @@ class RAFTConfig:
     # dequant folds into the conv weights per (batch, level); the
     # stop-gradient boundary is unchanged (fnet gets zero grad through
     # the volume, conv weights/bias and the rest of the update block
-    # still learn).  Requires corr_impl='allpairs_pallas'; autotuner-
-    # ranked (scripts/autotune.py), default off so untuned runs are
-    # bit-identical to the unfused path.
+    # still learn).  Requires corr_impl='allpairs_pallas'.  Default
+    # off, no chip timing, and no CLI flag sets it (ROADMAP S11).
     fused_lookup_encoder: bool = False
     # Fuse the ConvGRU gate chains (models/update.py ConvGRU/SepConvGRU)
     # with Pallas elementwise kernels (ops/pallas_gru.py): sigmoid(r)*h
     # and the (1-sigmoid(z))*h + sigmoid(z)*tanh(q) blend each become
     # one VMEM pass instead of an XLA elementwise chain with HBM
     # round-trips; the convs stay XLA (convq's input depends on r).
-    # Grads via recomputing custom_vjp.  Autotuner-ranked; default off.
+    # Grads via recomputing custom_vjp.  Default off, no chip timing,
+    # and no CLI flag sets it (ROADMAP S11).
     fused_gru: bool = False
 
     def __post_init__(self):
@@ -314,7 +311,7 @@ class RAFTConfig:
     def resolved_corr_precision(self) -> str:
         validate_corr_precision(self.corr_precision)
         if self.corr_precision == "auto":
-            return "highest"   # measured fastest on v5e (see above)
+            return "highest"   # fp32 correlation (see above)
         return self.corr_precision
 
     def _pallas_dispatchable(self) -> bool:

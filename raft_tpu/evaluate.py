@@ -46,9 +46,7 @@ def default_alternate_corr_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "chunked"
 
 
-def make_inference_model(model_cfg: RAFTConfig, *,
-                         bucket_hw=None, batch=None,
-                         tuning_kind=("eval",)) -> RAFT:
+def make_inference_model(model_cfg: RAFTConfig) -> RAFT:
     """The RAFT module with the inference-only config overrides applied.
 
     Every inference entry point (the validators here, the serving engine
@@ -59,19 +57,7 @@ def make_inference_model(model_cfg: RAFTConfig, *,
     model traces, from the platform and each bucket's shape
     (``models.raft.corr_impl_at``; the engine builds ONE model for every
     bucket, so the choice cannot be made here); PERF.md section 5 has
-    what each lookup costs in the serve cells.
-
-    The per-hardware tuning registry (raft_tpu/tuning.py) is consulted
-    first for ``tuning_kind`` (default 'eval'; the serve engine passes
-    ('serve', 'eval')): knobs left at their RAFTConfig defaults take the
-    autotuned winner for ``(bucket_hw, batch)`` — or the nearest /
-    most-recent entry when the shape isn't known yet, as here where the
-    jit compiles per streamed shape.  The inference override above is
-    applied AFTER tuning, so it holds unconditionally."""
-    from raft_tpu import tuning
-
-    model_cfg, _ = tuning.resolve_config(model_cfg, tuning_kind,
-                                         bucket_hw, batch)
+    what each lookup costs in the serve cells."""
     return RAFT(model_cfg.replace(scan_unroll=1))
 
 
@@ -79,8 +65,7 @@ def make_eval_fn(model_cfg: RAFTConfig, iters: int):
     """Jitted ``(variables, image1, image2, flow_init) -> (flow_low,
     flow_up)`` test-mode forward.  ``flow_init`` may be None (traced as a
     static branch via two separate jit entries).  Inference-only config
-    overrides (and the tuning-registry consult) are applied by
-    :func:`make_inference_model`."""
+    overrides are applied by :func:`make_inference_model`."""
     model = make_inference_model(model_cfg)
 
     @jax.jit
@@ -97,7 +82,7 @@ def make_eval_fn(model_cfg: RAFTConfig, iters: int):
         """Compile-time cost of the no-init forward at this shape
         (obs/cost.py) — one extra ``lower().compile()`` (a full
         compile of the forward); host metadata only.  The cost
-        CLI and bench.py's eval arm call this directly."""
+        CLI calls this directly."""
         from raft_tpu.obs import cost as cost_mod
 
         compiled = fwd.lower(variables, image1, image2).compile()

@@ -10,7 +10,7 @@ Three composable pieces, shared by train/eval/serve:
 - :class:`EventSink` — structured JSONL event log under
   ``RAFT_TELEMETRY_DIR`` (or ``--telemetry-dir``); one record per
   event with wall+monotonic timestamps, step, and process index.
-  ``scripts/telemetry_summary.py`` folds a log into bench.py JSON.
+  ``scripts/telemetry_summary.py`` folds a log into a one-line JSON summary.
 - :class:`Tracer` / :func:`trace_span` — distributed request/step
   trace trees emitted as ``trace_span`` events through the sink
   (``obs.trace``; reconstructed by ``scripts/trace_report.py``).

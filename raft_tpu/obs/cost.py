@@ -85,9 +85,9 @@ def peak_spec(device_kind: Optional[str] = None) -> PeakSpec:
     normalized substring matching — libtpu spells v5e both ``TPU v5e``
     and ``TPU v5 lite`` depending on version."""
     if device_kind is None:
-        from raft_tpu import tuning
+        import jax
 
-        device_kind = tuning.device_kind()
+        device_kind = jax.devices()[0].device_kind
     dk = str(device_kind).lower()
     if "v5e" in dk or "v5 lite" in dk or "v5lite" in dk:
         return PEAK_SPECS["v5e"]
@@ -259,9 +259,9 @@ def program_cost(compiled_or_fn, *args, program: str,
     compiled = (compiled_or_fn if not args
                 else compiled_or_fn.lower(*args).compile())
     if device_kind is None:
-        from raft_tpu import tuning
+        import jax
 
-        device_kind = tuning.device_kind()
+        device_kind = jax.devices()[0].device_kind
     got = xla_cost(compiled)
     if got is not None:
         return ProgramCost(program=program, flops=got["flops"],

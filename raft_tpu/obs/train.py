@@ -27,10 +27,10 @@ Per step it records/emits:
   BASELINE.json north-star metric as a continuously measured number.
 
 One-time events: ``run_config`` (what scripts/telemetry_summary.py
-needs to fold the log into bench.py JSON), ``compile`` (the first
-executed step's dispatch time, which is dominated by trace+compile; what
-XLA itself built or loaded, with seconds, is in the stage clock's
-``compile`` ring and ``raft_compile_seconds_total``), ``hbm_usage``
+needs to fold the log into its one-line JSON summary), ``compile`` (the
+first executed step's dispatch time, which is dominated by
+trace+compile; what XLA itself built or loaded, with seconds, is in the
+stage clock's ``compile`` ring and ``raft_compile_seconds_total``), ``hbm_usage``
 (XLA memory analysis of the compiled step — the loop AOT-compiles the
 step once and runs that executable, so this costs no second compile;
 disable with ``RAFT_TELEMETRY_HBM=0``), and
@@ -71,8 +71,7 @@ class TrainTelemetry:
                  batch_size: int, num_devices: int,
                  image_size: Tuple[int, int],
                  registry: Optional[MetricRegistry] = None,
-                 hbm: Optional[bool] = None,
-                 tuning_stamp: Optional[dict] = None):
+                 hbm: Optional[bool] = None):
         directory = directory or os.environ.get("RAFT_TELEMETRY_DIR") or None
         self.sink = EventSink(directory)
         self.enabled = self.sink.enabled
@@ -80,11 +79,6 @@ class TrainTelemetry:
         self.batch_size = int(batch_size)
         self.num_devices = max(int(num_devices), 1)
         self.image_size = tuple(int(x) for x in image_size)
-        # Tuning-registry provenance (raft_tpu/tuning.py TuningInfo
-        # .stamp()): rides the run_config event so
-        # scripts/telemetry_summary.py can say whether the run's knobs
-        # were autotuned or hand-set.
-        self.tuning_stamp = dict(tuning_stamp or {"tuned": False})
         if hbm is None:
             hbm = os.environ.get("RAFT_TELEMETRY_HBM", "1") == "1"
         self.hbm_enabled = self.enabled and hbm
@@ -208,8 +202,7 @@ class TrainTelemetry:
                        batch_size=self.batch_size,
                        num_devices=self.num_devices,
                        image_size=list(self.image_size),
-                       num_steps=int(num_steps),
-                       **self.tuning_stamp)
+                       num_steps=int(num_steps))
 
     def record_step(self, rec: dict, feed: Optional[dict] = None) -> None:
         """One closed ``train`` unit of the stage clock (``rec``,

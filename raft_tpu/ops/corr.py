@@ -172,11 +172,9 @@ def _store_level(corr: jax.Array, out_dtype, quant_spec) -> CorrLevel:
 def resolve_precision(precision) -> jax.lax.Precision:
     """'default' | 'high' | 'highest' -> lax.Precision.
 
-    Measured on v5e (bench.py): 'highest' (fp32) is FASTER than 'high'
-    (bf16x3) for the correlation path and is the config default
-    (RAFTConfig.corr_precision); 'default' (one bf16 pass) bought <2%
-    end-to-end, so there is no reason to give up fp32 correlation
-    (which the reference also keeps, corr.py:50).
+    'highest' (fp32) is the config default (RAFTConfig.corr_precision):
+    the reference keeps fp32 correlation too (corr.py:50), and every
+    cell of PERF.md runs it; no cell times 'high' or 'default'.
     """
     if isinstance(precision, jax.lax.Precision):
         return precision

@@ -27,8 +27,8 @@ Layout: operands are flattened, zero-padded to a whole number of
 ``(256, 128)`` fp32-tile-aligned blocks and processed on a 1-D grid —
 elementwise math has no spatial structure worth preserving, and the
 flat layout keeps every block full-lane regardless of the (B, H, W, C)
-shape.  Gate selection is ``RAFTConfig.fused_gru`` (autotuner-ranked,
-default off — see docs/PERFORMANCE.md "Fused kernels").
+shape.  Gate selection is ``RAFTConfig.fused_gru`` (default off, never
+timed on the chip — see docs/PERFORMANCE.md "Fused kernels").
 """
 
 from __future__ import annotations

@@ -170,8 +170,7 @@ class ServeConfig:
     (flow units at 1/8 resolution) drops below this; ``0`` disables
     (full budget always runs).  Slot mode honors a per-request
     ``iters`` budget (capped at ``cfg.iters``); request mode runs every
-    lane to ``cfg.iters`` (lockstep).  All three are tuning-registry
-    knobs (``scripts/autotune.py --kind serve``).
+    lane to ``cfg.iters`` (lockstep).
     ``quality_sample_rate``: fraction of retiring slot-mode requests
     scored with the label-free photometric quality proxy
     (``raft_tpu/obs/quality.py``; docs/OBSERVABILITY.md "Flow
@@ -496,24 +495,11 @@ class InferenceEngine:
                  sink: Optional[EventSink] = None):
         # Deferred import: evaluate.py pulls the dataset stack, and the
         # dependency is one function (the shared inference overrides).
-        from raft_tpu import tuning
         from raft_tpu.evaluate import make_inference_model
         from raft_tpu.serve import slots as slots_mod
 
-        # Per-hardware tuning registry consult ('serve' entries first,
-        # 'eval' as fallback): one model serves every bucket, so the
-        # lookup is shape-agnostic (nearest/most-recent entry) — the
-        # applied knobs and provenance surface in stats()["tuning"].
-        # A 'serve' entry may also carry ServeConfig knobs (batching /
-        # slots / early_exit_threshold / iters) — applied to whatever
-        # the caller left at its dataclass default, so explicit flags
-        # always win (raft_tpu/tuning.py precedence).
-        cfg, self.serve_tuning_info = tuning.resolve_serve_config(cfg)
         self.cfg = cfg
-        _, self.tuning_info = tuning.resolve_config(
-            model_cfg, ("serve", "eval"))
-        model = make_inference_model(model_cfg,
-                                     tuning_kind=("serve", "eval"))
+        model = make_inference_model(model_cfg)
         # The serve hot path is the encode/iter_step program pair
         # (serve/slots.py) for BOTH batching modes — request mode drives
         # them in lockstep so slot mode is bit-identical to it by
@@ -1360,13 +1346,6 @@ class InferenceEngine:
         out["stage_seconds"] = {
             dict(key)["stage"]: round(v, 6) for key, v in
             self.registry.counter("raft_stage_seconds_total").items()}
-        # Tuning-registry provenance (raft_tpu/tuning.py): which knobs
-        # this replica autotuned, so a fleet operator can tell a tuned
-        # replica from one running hand-rolled defaults.
-        out["tuning"] = dict(self.tuning_info.stamp(),
-                             applied=dict(self.tuning_info.applied),
-                             serve_applied=dict(
-                                 self.serve_tuning_info.applied))
         # AOT warm-start provenance: how many executables this engine
         # imported instead of compiling (docs/SERVING.md fleet section).
         out["aot"] = dict(self.aot_info)

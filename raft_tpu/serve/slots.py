@@ -61,7 +61,7 @@ math compiled into two different programs can differ in the last ulp
 (see the note in ``models/raft.py``).
 
 ``threshold`` is a runtime f32 scalar, not a compile-time constant, so
-sweeping it (``evaluate.py --early_exit_threshold``, autotune) never
+sweeping it (``evaluate.py --early_exit_threshold``) never
 recompiles.  ``threshold <= 0`` disables early exit: ``delta_max`` is a
 max of norms, hence ``>= 0``, and the predicate is a strict ``<``.
 

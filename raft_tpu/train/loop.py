@@ -143,23 +143,6 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         "pass exactly one of batches= or loader="
     _PREEMPT.clear()  # a new run starts unpreempted
     mesh = mesh or make_mesh()
-    # Per-hardware tuning registry (raft_tpu/tuning.py): fill every knob
-    # the user left at its RAFTConfig default from the autotuned winner
-    # for (train, device_kind, image_size, per-chip batch).  Resolved
-    # HERE (not only inside make_train_step, which re-resolves
-    # idempotently) so the telemetry run_config can stamp what actually
-    # ran, and the printout tells the operator which knobs moved.
-    from raft_tpu import tuning
-
-    model_cfg, tuning_info = tuning.resolve_config(
-        model_cfg, "train", tuple(cfg.image_size),
-        max(cfg.batch_size // max(jax.device_count(), 1), 1))
-    if tuning_info.applied:
-        print("tuning registry "
-              f"[{tuning_info.key}{'' if tuning_info.exact else ', nearest'}"
-              f"]: " + ", ".join(f"{k}={v}" for k, v in
-                                 sorted(tuning_info.applied.items())),
-              flush=True)
     model = RAFT(model_cfg)
     tx = make_optimizer(cfg.lr, cfg.num_steps, cfg.wdecay, cfg.epsilon,
                         cfg.clip)
@@ -178,8 +161,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     # fallback happens BEFORE the first step is ever timed.
     telem = TrainTelemetry(telemetry_dir, batch_size=cfg.batch_size,
                            num_devices=max(jax.device_count(), 1),
-                           image_size=cfg.image_size,
-                           tuning_stamp=tuning_info.stamp())
+                           image_size=cfg.image_size)
     if loader is not None and telem.enabled:
         loader.sink = telem.sink
         loader.registry = telem.registry

@@ -132,26 +132,7 @@ def make_train_step(model: RAFT, tx: optax.GradientTransformation,
     ``param_norm`` / ``update_ratio`` (the optax-update tap) and the
     per-iteration ``loss_iter``/``epe_iter`` curves — all riding the
     existing metrics dict, zero added device syncs.
-
-    Tuning registry (raft_tpu/tuning.py): by default the step consults
-    the persisted per-hardware registry for the ``(train, device_kind,
-    image_size, per-chip batch)`` key and applies the autotuned winners
-    to every ``RAFTConfig`` knob still at its class default — explicit
-    knobs always win, ``RAFT_TUNING=0`` disables, and a caller that
-    already resolved tuning upstream (train/loop.py, bench.py) sees an
-    idempotent no-op.
     """
-
-    from raft_tpu import tuning
-
-    if tuning.enabled():
-        n_dev = (mesh.devices.size if mesh is not None
-                 else max(jax.device_count(), 1))
-        tuned_cfg, info = tuning.resolve_config(
-            model.config, "train", tuple(cfg.image_size),
-            max(cfg.batch_size // max(n_dev, 1), 1))
-        if info.applied:
-            model = RAFT(tuned_cfg)
 
     if shard_spatial:
         mc = model.config
@@ -273,7 +254,7 @@ def step_cost(compiled, batch_size: int, num_devices: int):
     ``flops_per_pair`` mesh-shape-invariant (the figure the
     ``--max-flops-per-pair-growth`` gate compares across runs).
     Host-side metadata only; the compile site owns calling this
-    (train/loop.py first-dispatch block, bench.py's timed arm).
+    (train/loop.py first-dispatch block).
     """
     from raft_tpu.obs import cost as cost_mod
 

@@ -9,10 +9,9 @@ both arms: the overlapped pipeline at ``--depth`` and the serial path
 (depth 0), so the JSON line answers "what does background device
 prefetch buy at this shape?" without a second invocation.
 
-Prints ONE bench.py-format JSON line (metric / value / unit /
-vs_baseline) with the metric name from bench.py's shared
-``_input_metric_name`` mapping — the same sharing rule that keeps
-telemetry_summary.py's series from drifting.  ``value`` is the
+Prints ONE JSON line (metric / value / unit / vs_baseline) on a
+per-stage metric name built from ``scripts/telemetry_summary.py``'s
+stage table, so both series name a crop alike.  ``value`` is the
 overlapped arm's pairs/sec; the serial arm and the queue-wait split
 land in ``config``.
 
@@ -138,9 +137,9 @@ def main(argv=None):
 
     import jax
 
-    from bench import _input_metric_name
     from raft_tpu.parallel.mesh import make_batch_sharder, make_mesh
     from raft_tpu.train.loop import add_image_noise
+    from scripts.telemetry_summary import _stage_name
 
     h, w = (int(x) for x in args.image.lower().split("x"))
     mesh = make_mesh()
@@ -161,7 +160,7 @@ def main(argv=None):
     del warm
 
     print(json.dumps({
-        "metric": _input_metric_name(h, w),
+        "metric": f"input_pipeline_{_stage_name(h, w)}_{h}x{w}",
         "value": round(value, 3),
         "unit": "image-pairs/sec",
         # No external input-pipeline baseline exists (the reference's

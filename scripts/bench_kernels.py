@@ -1,6 +1,6 @@
 """Per-kernel microbench: fused Pallas kernels vs their unfused arms.
 
-Benchmarks the two autotuner-ranked fused kernels in isolation, outside
+Benchmarks the two default-off fused kernels in isolation, outside
 the full model step:
 
 - ``lookup_encoder`` — ``ops/pallas_corr.pallas_pyramid_lookup_encode``
@@ -11,11 +11,9 @@ the full model step:
   chains around the XLA convs vs the all-XLA ConvGRU cell, the pair
   ``RAFTConfig.fused_gru`` toggles.
 
-Both arms of each kernel land in ONE bench.py-format JSON line
-(metric / value / unit / vs_baseline); per-kernel timings, speedups and
-whether the tuning registry currently SELECTS the fused form on this
-device go under ``config.kernels`` — the record
-``scripts/check_regression.py --max-kernel-slowdown`` gates on.
+Both arms of each kernel land in ONE JSON line (metric / value / unit /
+vs_baseline); per-kernel timings and speedups go under
+``config.kernels``.
 
 ``--tiny``: CPU interpret-mode smoke (tiny shapes, 1 rep) wired into
 the test tier (tests/test_bench_kernels.py)::
@@ -198,27 +196,11 @@ def _bench_gru(args, h8, w8, dims, interpret):
     }, (unfused_c, fused_c, analytic)
 
 
-_KNOB_BY_KERNEL = {"lookup_encoder": "fused_lookup_encoder",
-                   "gru": "fused_gru"}
-
-
-def _registry_selected(kernel, hw, batch):
-    """(selected?, kind) — does any registry entry for this device pick
-    the fused form of ``kernel`` at this bucket/batch?"""
-    from raft_tpu import tuning
-
-    knob = _KNOB_BY_KERNEL[kernel]
-    for kind in ("train", "eval", "serve"):
-        hit = tuning.lookup(kind, hw, batch)
-        if hit and hit[1].get("knobs", {}).get(knob):
-            return True, kind
-    return False, None
-
-
 def main(argv=None):
     args = parse_args(argv)
 
-    from raft_tpu import tuning
+    import jax
+
     from raft_tpu.obs import cost as cost_mod
 
     h, w = (int(x) for x in args.image.lower().split("x"))
@@ -242,8 +224,6 @@ def main(argv=None):
             rec["unfused_ms"] / max(rec["fused_ms"], 1e-9), 3)
         rec["unfused_ms"] = round(rec["unfused_ms"], 4)
         rec["fused_ms"] = round(rec["fused_ms"], 4)
-        rec["selected"], rec["selected_kind"] = _registry_selected(
-            name, (h, w), args.batch)
         # Per-arm cost accounting (obs/cost.py): XLA's count where it
         # sees the body (interpret mode, unfused arm), the analytic
         # formula on real-TPU custom_call arms; MFU only on known
@@ -277,7 +257,7 @@ def main(argv=None):
         # the comparison (speedup 1.0 == parity with unfused).
         "vs_baseline": 0.0,
         "config": {
-            "device_kind": tuning.device_kind(),
+            "device_kind": jax.devices()[0].device_kind,
             "interpret": bool(args.interpret),
             "image": [h, w], "batch": args.batch, "model": args.model,
             "corr_dtype": args.corr_dtype, "reps": args.reps,

@@ -2,7 +2,7 @@
 
 Drives ``raft_tpu.serve.InferenceEngine`` in-process (no HTTP overhead in
 the measurement) with mixed-resolution synthetic frame pairs and prints
-ONE JSON line per run in the ``bench.py`` format (metric / value / unit /
+ONE check_regression-format JSON line per run (metric / value / unit /
 vs_baseline), plus the client-observed latency percentiles and the
 engine's compile ledger.
 
@@ -58,8 +58,7 @@ slot-vs-request comparison — stays testable without hardware::
 
 There is no external serving baseline (the reference repo has no request
 path at all); ``vs_baseline`` is 0.0 until a measured TPU number lands
-in a ``BENCH_SERVE_r*.json`` and becomes the bar, like bench.py's eval
-mode did in round 3.
+in a ``BENCH_SERVE_r*.json`` and becomes the bar.
 """
 
 from __future__ import annotations

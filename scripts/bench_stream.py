@@ -12,7 +12,7 @@ in two arms over the SAME frames:
 - **independent**: every consecutive pair submitted as a stateless
   request at the full budget — the arm serving today's API.
 
-Prints ONE JSON line in the ``bench.py`` format.  The headline value
+Prints ONE check_regression-format JSON line.  The headline value
 is the stream arm's frames/sec/chip; the record also carries the
 cold-vs-warm ``iters_used`` histograms (separable because retirements
 are ``warm``-tagged), the two figures the regression gates consume

@@ -23,7 +23,7 @@ Finally the telemetry log is folded through
 ``quarantined_total`` / ``ckpt_fallback_total`` reach the
 ``check_regression.py`` gate fields.
 
-Prints one bench.py-format JSON line (``metric: chaos_smoke``,
+Prints one check_regression-format JSON line (``metric: chaos_smoke``,
 ``value`` 1.0 = all scenarios healed); exit 0/1.
 
 ::

@@ -2,7 +2,7 @@
 prior series and fail loudly.
 
 Inputs are the one-line JSON records the repo's measurement tools
-already produce — ``bench.py`` / ``scripts/bench_input.py`` /
+already produce — ``scripts/bench_input.py`` /
 ``scripts/bench_serve.py`` output, driver ``BENCH_*.json`` wrappers
 (the record under their ``parsed`` key), and
 ``scripts/telemetry_summary.py`` output (whose ``config`` block now
@@ -149,24 +149,12 @@ def parse_args(argv=None):
                         "repeatable.  Also fails when NO record carries "
                         "the figure — a latency gate must not pass "
                         "because tracing silently turned off")
-    p.add_argument("--max-kernel-slowdown", action="append",
-                   default=[], metavar="NAME:PCT",
-                   help="fail when a newest bench_kernels record shows "
-                        "fused kernel NAME (config.kernels[NAME], from "
-                        "scripts/bench_kernels.py) more than PCT%% "
-                        "slower than its unfused arm WHILE the tuning "
-                        "registry selects it on this device "
-                        "(kernels[NAME].selected); repeatable.  Also "
-                        "fails when NO non-interpret record carries the "
-                        "figure — a kernel-perf gate must not pass "
-                        "because the microbench silently didn't run "
-                        "(interpret-mode smoke records don't count)")
     p.add_argument("--min-mfu", action="append", default=[],
                    metavar="NAME:PCT",
                    help="fail when the newest record of a metric series "
                         "containing NAME posts MFU below PCT%% "
                         "(config.mfu or top-level mfu, from the "
-                        "obs/cost.py accounting in bench.py / "
+                        "obs/cost.py accounting in "
                         "bench_serve.py / telemetry_summary.py); "
                         "repeatable.  Interpret-mode records and "
                         "records with no MFU (unknown device peak, "
@@ -227,12 +215,6 @@ def parse_args(argv=None):
                         "scripts/fabric_smoke.py) exceeds PCT; also "
                         "fails when NO record carries the figure "
                         "(unset = no check)")
-    p.add_argument("--require-tuned", action="store_true",
-                   help="fail when a newest record's config lacks "
-                        "`tuned: true` — i.e. its knobs did NOT come "
-                        "from the per-hardware tuning registry "
-                        "(scripts/autotune.py); keeps a BENCH series "
-                        "from silently drifting back to hand-set knobs")
     p.add_argument("--lint-report", default=None, metavar="PATH",
                    help="fail when the raftlint JSON report at PATH "
                         "(scripts/lint_repo.py --json, or `python -m "
@@ -300,7 +282,7 @@ def parse_cp_gates(items):
 
 
 def _rec_flops_per_pair(rec):
-    """A record's flops_per_pair (bench.py/telemetry_summary put it in
+    """A record's flops_per_pair (telemetry_summary.py puts it in
     ``config``, bench_serve.py at the top level); None when absent."""
     cfg = rec.get("config") or {}
     v = cfg.get("flops_per_pair", rec.get("flops_per_pair"))
@@ -308,10 +290,10 @@ def _rec_flops_per_pair(rec):
 
 
 def check(series, max_drop_pct=10.0, window=3, min_vs_baseline=None,
-          max_quarantined=0, max_ckpt_fallback=0, require_tuned=False,
+          max_quarantined=0, max_ckpt_fallback=0,
           max_serve_error_rate=0.0, max_critical_path_ms=None,
-          max_early_exit_epe_delta=None, max_kernel_slowdown=None,
-          min_mfu=None, max_flops_per_pair_growth=None,
+          max_early_exit_epe_delta=None, min_mfu=None,
+          max_flops_per_pair_growth=None,
           max_quality_drift=None, max_canary_proxy_delta=None,
           min_warm_iters_saved_frac=None, max_stream_epe_delta=None,
           max_incidents=None, max_slo_burn=None, max_scale_flaps=None,
@@ -324,8 +306,6 @@ def check(series, max_drop_pct=10.0, window=3, min_vs_baseline=None,
     inc_seen = set()
     slo_gates = dict(max_slo_burn or {})
     slo_seen = set()
-    ker_gates = dict(max_kernel_slowdown or {})
-    ker_seen = set()
     mfu_gates = dict(min_mfu or {})
     mfu_seen = set()
     ee_seen = False
@@ -342,11 +322,6 @@ def check(series, max_drop_pct=10.0, window=3, min_vs_baseline=None,
         cfg = newest.get("config") or {}
         entry = {"metric": metric, "value": value,
                  "path": newest.get("_path"), "n_records": len(recs)}
-        if require_tuned and cfg.get("tuned") is not True:
-            failures.append(
-                f"{metric}: config.tuned is not true — knobs did not "
-                "come from the tuning registry (run scripts/autotune.py "
-                "or drop --require-tuned)")
         nf = cfg.get("nonfinite_steps_total")
         if isinstance(nf, (int, float)) and nf > 0:
             failures.append(
@@ -393,32 +368,6 @@ def check(series, max_drop_pct=10.0, window=3, min_vs_baseline=None,
                         failures.append(
                             f"{metric}: critical-path {name} p95 "
                             f"{v:g}ms > budget {budget:g}ms")
-        # Fused-kernel perf gate (scripts/bench_kernels.py records): the
-        # tuning registry must not keep SELECTING a fused kernel that
-        # the microbench shows slower than its unfused arm on this
-        # device.  Interpret-mode smoke records are skipped — the
-        # interpreter's timings say nothing about hardware.
-        kers = cfg.get("kernels")
-        if isinstance(kers, dict) and not cfg.get("interpret"):
-            for name, budget in ker_gates.items():
-                k = kers.get(name)
-                if not isinstance(k, dict):
-                    continue
-                fu, un = k.get("fused_ms"), k.get("unfused_ms")
-                if (isinstance(fu, (int, float))
-                        and isinstance(un, (int, float)) and un > 0):
-                    ker_seen.add(name)
-                    if k.get("selected"):
-                        slow = (fu / un - 1.0) * 100.0
-                        if slow > budget:
-                            failures.append(
-                                f"{metric}: fused kernel {name!r} is "
-                                f"{slow:.1f}% slower than unfused "
-                                f"({fu:g}ms vs {un:g}ms, budget "
-                                f"{budget:g}%) yet the tuning registry "
-                                f"selects it "
-                                f"({k.get('selected_kind')}) — re-run "
-                                "scripts/autotune.py on this device")
         # Hardware-utilization floor (obs/cost.py MFU): interpret-mode
         # records and unknown-peak records (CPU — mfu is null there by
         # design, never a fabricated ratio) are EXCLUDED from
@@ -619,12 +568,6 @@ def check(series, max_drop_pct=10.0, window=3, min_vs_baseline=None,
             f"critical-path gate {name!r}: no record carries "
             f"config.critical_path_ms[{name!r}] — tracing is off or "
             "the span never appeared; the gate cannot pass vacuously")
-    for name in sorted(set(ker_gates) - ker_seen):
-        failures.append(
-            f"kernel gate {name!r}: no non-interpret record carries "
-            f"config.kernels[{name!r}] timings — the microbench "
-            "(scripts/bench_kernels.py) did not run on hardware; the "
-            "gate cannot pass vacuously")
     for name in sorted(set(mfu_gates) - mfu_seen):
         failures.append(
             f"mfu gate {name!r}: no qualifying record carries an MFU "
@@ -771,13 +714,6 @@ def _selftest() -> int:
          run([30.0, 31.0, 30.5], last_cfg={"quarantined_total": 0,
                                            "ckpt_fallback_total": 0}),
          False),
-        ("require-tuned fails untuned",
-         run([30.0, 31.0, 30.5], require_tuned=True), True),
-        ("require-tuned passes tuned",
-         run([30.0, 31.0, 30.5], last_cfg={"tuned": True},
-             require_tuned=True), False),
-        ("untuned passes without the gate",
-         run([30.0, 31.0, 30.5], last_cfg={"tuned": False}), False),
         ("serve error_rate fails",
          run([30.0, 31.0, 30.5],
              last_top={"error_rate": 0.125, "errors": 2, "timeouts": 1}),
@@ -831,36 +767,6 @@ def _selftest() -> int:
         ("early-exit delta without the gate passes",
          run([30.0, 31.0, 30.5],
              last_cfg={"early_exit_epe_delta": 9.0}), False),
-        ("selected fused kernel within budget passes",
-         run([30.0, 31.0, 30.5],
-             last_cfg={"kernels": {"gru": {
-                 "fused_ms": 9.0, "unfused_ms": 10.0, "selected": True}}},
-             max_kernel_slowdown={"gru": 5.0}), False),
-        ("selected fused kernel slower fails",
-         run([30.0, 31.0, 30.5],
-             last_cfg={"kernels": {"gru": {
-                 "fused_ms": 12.0, "unfused_ms": 10.0,
-                 "selected": True, "selected_kind": "train"}}},
-             max_kernel_slowdown={"gru": 5.0}), True),
-        ("unselected slower fused kernel passes",
-         run([30.0, 31.0, 30.5],
-             last_cfg={"kernels": {"gru": {
-                 "fused_ms": 12.0, "unfused_ms": 10.0,
-                 "selected": False}}},
-             max_kernel_slowdown={"gru": 5.0}), False),
-        ("kernel gate without record fails",
-         run([30.0, 31.0, 30.5], max_kernel_slowdown={"gru": 5.0}),
-         True),
-        ("interpret-only kernel record fails the gate",
-         run([30.0, 31.0, 30.5],
-             last_cfg={"interpret": True, "kernels": {"gru": {
-                 "fused_ms": 9.0, "unfused_ms": 10.0, "selected": True}}},
-             max_kernel_slowdown={"gru": 5.0}), True),
-        ("slow kernel record without the gate passes",
-         run([30.0, 31.0, 30.5],
-             last_cfg={"kernels": {"gru": {
-                 "fused_ms": 99.0, "unfused_ms": 10.0,
-                 "selected": True}}}), False),
         ("mfu above floor passes",
          run([30.0, 31.0, 30.5], last_cfg={"mfu": 0.45},
              min_mfu={"train_throughput": 40.0}), False),
@@ -1065,16 +971,11 @@ def main(argv=None):
                              min_vs_baseline=args.min_vs_baseline,
                              max_quarantined=args.max_quarantined,
                              max_ckpt_fallback=args.max_ckpt_fallback,
-                             require_tuned=args.require_tuned,
                              max_serve_error_rate=args.max_serve_error_rate,
                              max_critical_path_ms=parse_cp_gates(
                                  args.max_critical_path_ms),
                              max_early_exit_epe_delta=(
                                  args.max_early_exit_epe_delta),
-                             max_kernel_slowdown=parse_named_gates(
-                                 args.max_kernel_slowdown,
-                                 "--max-kernel-slowdown",
-                                 ("PCT", "gru:5")),
                              min_mfu=parse_named_gates(
                                  args.min_mfu, "--min-mfu",
                                  ("PCT", "train_throughput:40")),

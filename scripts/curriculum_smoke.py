@@ -31,7 +31,7 @@ telemetry (``chaos_inject`` = 3, ``ckpt_fallback`` = 1, every
 directory to check the torn step is reported CORRUPT alongside its
 saved-topology stamp.
 
-Prints one bench.py-format JSON line (``metric: curriculum_smoke``,
+Prints one check_regression-format JSON line (``metric: curriculum_smoke``,
 ``value`` 1.0 = converged); exit 0/1.
 
 ::

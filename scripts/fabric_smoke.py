@@ -29,7 +29,7 @@ elastic autoscaling on, and walks the four promises docs/SERVING.md's
    in-flight work drains, and the stream continues with monotone frame
    numbering.  Zero dropped requests across the whole drill.
 
-Prints one bench.py-format JSON line (``metric: fabric_smoke``,
+Prints one check_regression-format JSON line (``metric: fabric_smoke``,
 ``value`` 1.0 = every promise held) whose config carries the
 ``scale_flaps`` / ``net_retry_rate`` keys the
 ``check_regression.py --max-scale-flaps / --max-net-retry-rate`` gates

@@ -18,7 +18,7 @@ SLOs" section makes:
    the event window, at least one captured trace tree, the metric
    snapshot, and the engine/fleet stats + resolved configs.
 
-Prints one bench.py-format JSON line (``metric: incident_smoke``,
+Prints one check_regression-format JSON line (``metric: incident_smoke``,
 ``value`` 1.0 = both promises held); exit 0, or an assertion failure.
 
 ::
@@ -177,6 +177,19 @@ def main(argv=None) -> int:
         bundles = sorted(os.listdir(inc_root))
         assert len(bundles) == 1, f"expected 1 bundle, got {bundles}"
         bdir = os.path.join(inc_root, bundles[0])
+
+        def bundle_written():
+            # the manager's state closes before its writer thread has
+            # rewritten the bundle; stats.json is the last file of the
+            # final pass, so once it parses every file is whole
+            try:
+                with open(os.path.join(bdir, "stats.json")) as f:
+                    json.load(f)
+                return True
+            except (OSError, ValueError):
+                return False
+
+        _wait_for(bundle_written, 30, "the closed incident's bundle")
         with open(os.path.join(bdir, "incident.json")) as f:
             inc = json.load(f)
         assert inc["status"] == "closed", inc

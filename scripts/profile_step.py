@@ -1,14 +1,14 @@
 """Capture an op-level XProf profile of the bench training step.
 
 Round-1 tuning worked from whole-step ablations only; this script closes
-that gap: it runs the exact bench.py training configuration under a
+that gap: it runs the chairs-crop training step (batch 16, bf16) under a
 ``jax.profiler`` trace and converts the captured xplane with the local
 ``xprof`` package into per-HLO-op statistics (no TensorBoard UI needed —
 this box is headless).
 
 Usage:
     python scripts/profile_step.py [outdir]
-Env: same knobs as bench.py (BENCH_BATCH, BENCH_IMAGE, BENCH_CORR_IMPL...).
+Env: BENCH_BATCH, BENCH_IMAGE, BENCH_CORR_IMPL... (read in ``main``).
 
 Outputs in <outdir> (default ``$RAFT_TELEMETRY_DIR/xprof/bench-<ts>``
 when telemetry is configured, else ``/tmp/raft_prof``) — the same

@@ -23,7 +23,7 @@ docs/OBSERVABILITY.md's "Flow quality" section makes:
    ``quality_drift``, which the fleet supervisor surfaces as
    ``fleet_quality_drift``.
 
-Prints one bench.py-format JSON line (``metric: quality_smoke``,
+Prints one check_regression-format JSON line (``metric: quality_smoke``,
 ``value`` 1.0 = both promises held) whose config block carries the
 ``quality_drift_score`` / ``canary_proxy_delta_pct`` figures that
 ``scripts/check_regression.py --max-quality-drift`` /

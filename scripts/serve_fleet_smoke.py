@@ -20,7 +20,7 @@ walks the three promises docs/SERVING.md's fleet section makes:
    verify gate — the fleet keeps serving the good version.  A
    NaN-poisoned weight set is refused at the canary gate.
 
-Prints one bench.py-format JSON line (``metric: serve_fleet_smoke``,
+Prints one check_regression-format JSON line (``metric: serve_fleet_smoke``,
 ``value`` 1.0 = every promise held); exit 0, or an assertion failure.
 
 ::

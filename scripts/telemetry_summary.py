@@ -1,13 +1,12 @@
-"""Fold a JSONL telemetry log into bench.py-format JSON.
+"""Fold a JSONL telemetry log into one JSON line.
 
 Any instrumented run (``python -m raft_tpu train --telemetry_dir ...``
 or ``RAFT_TELEMETRY_DIR=...``) leaves ``telemetry-p*.jsonl`` files; this
-script turns the per-step ``train_step`` stream of one run into the ONE
-JSON line bench.py prints — same ``metric``/``value``/``unit``/
-``vs_baseline`` schema, same metric-name mapping (imported from
-bench.py, so the series cannot drift) — letting BENCH_* trajectories be
-produced from any real training run instead of only the synthetic
-bench::
+script turns the per-step ``train_step`` stream of one run into ONE
+JSON line of the ``metric``/``value``/``unit``/``vs_baseline``/
+``config`` schema every script here prints and
+``scripts/check_regression.py`` reads; the per-stage metric names are
+defined below (``scripts/bench_input.py`` imports the stage table)::
 
     python scripts/telemetry_summary.py runs/telemetry/
     python scripts/telemetry_summary.py runs/telemetry/telemetry-p0.jsonl
@@ -29,16 +28,26 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from bench import (  # noqa: E402
-    BASELINE_PAIRS_PER_SEC_PER_CHIP,
-    _stage_name,
-    _train_metric_name,
-)
+# BASELINE.json north_star (v5e, chairs crop).
+BASELINE_PAIRS_PER_SEC_PER_CHIP = 30.0
+
+# Training-stage names for the reference curriculum's crop shapes
+# (train_standard.sh): one mapping for every script's metric series.
+_STAGE_NAMES = {(368, 496): "flyingchairs", (400, 720): "flyingthings",
+                (368, 768): "sintelstage", (288, 960): "kittistage"}
+
+
+def _stage_name(h: int, w: int) -> str:
+    return _STAGE_NAMES.get((h, w), "custom")
+
+
+def _train_metric_name(h: int, w: int) -> str:
+    return f"train_throughput_{_stage_name(h, w)}_{h}x{w}_bf16_iters12"
 
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(
-        description="telemetry JSONL -> bench.py JSON")
+        description="telemetry JSONL -> one JSON line")
     p.add_argument("path", help="telemetry-*.jsonl file, or a directory "
                                 "of them (a multi-host run's per-process "
                                 "files are merged by step)")
@@ -428,16 +437,6 @@ def summarize(run_cfg, steps, health=None, faults=None, spans=None,
         health_cfg["ckpt_fallback_total"] = faults.get("ckpt_fallback", 0)
         if faults.get("chaos_inject"):
             health_cfg["chaos_injected_total"] = faults["chaos_inject"]
-    # Tuning-registry provenance (raft_tpu/tuning.py): the run_config
-    # event carries whether the run's knobs came from the autotune
-    # registry (tuned/tuning_key/tuning_registry_hash); fold it through
-    # so summarized runs gate under check_regression --require-tuned
-    # exactly like bench.py records.  Old logs predate the field — they
-    # summarize as untuned.
-    health_cfg["tuned"] = bool(run_cfg.get("tuned", False))
-    for k in ("tuning_key", "tuning_registry_hash", "tuning_fallback"):
-        if k in run_cfg:
-            health_cfg[k] = run_cfg[k]
     # Distributed-tracing fold (docs/OBSERVABILITY.md "Distributed
     # tracing"): per-span-name duration percentiles + how many traces
     # completed and what fraction erred.  Absent without trace events.

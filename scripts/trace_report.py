@@ -20,7 +20,7 @@ slow but whose winner was fast correctly attributes to the winner.
 
 ``--perfetto`` exports Chrome/Perfetto ``trace_event`` JSON (load in
 https://ui.perfetto.dev or chrome://tracing).  ``--json`` prints one
-bench.py-format line whose config block carries ``critical_path_ms``
+check_regression-format line whose config block carries ``critical_path_ms``
 (per span name, p95 self-time on the critical path) and
 ``serve_span_names`` — the inputs for ``scripts/check_regression.py
 --max-critical-path-ms`` and its span-coverage check.  ``--tiny``
@@ -60,7 +60,7 @@ def parse_args(argv=None):
                    help="export all loaded traces as Chrome/Perfetto "
                         "trace_event JSON")
     p.add_argument("--json", action="store_true",
-                   help="print one bench.py-format JSON line "
+                   help="print one check_regression-format JSON line "
                         "(critical_path_ms + serve_span_names in the "
                         "config block) instead of waterfalls")
     p.add_argument("--roofline", action="store_true",
@@ -282,7 +282,7 @@ def _p95(vals):
 
 
 def bench_record(traces):
-    """One bench.py-format record: ``critical_path_ms`` maps span name
+    """One check_regression-format record: ``critical_path_ms`` maps span name
     -> p95 self-time ms over every trace's critical path (what
     ``check_regression --max-critical-path-ms NAME:MS`` gates);
     ``serve_span_names`` lists every span name observed inside

@@ -22,7 +22,7 @@ section makes:
    (``critical_path_ms`` + full ``queue``/``pad``/``device`` span
    coverage, the shape scripts/check_regression.py gates on).
 
-Prints one bench.py-format JSON line (``metric: trace_smoke``,
+Prints one check_regression-format JSON line (``metric: trace_smoke``,
 ``value`` 1.0 = every promise held); exit 0, or an assertion failure.
 
 ::

@@ -53,17 +53,6 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Hermetic tuning registry: the per-hardware autotune registry
-# (raft_tpu/tuning.py) is consulted BY DEFAULT by make_train_step /
-# make_eval_fn / ServeEngine, and its default path lives in ~/.cache —
-# a developer (or CI container) that has run `scripts/autotune.py`
-# would otherwise change test behavior machine-by-machine.  Point it
-# at a nonexistent per-session path; tests that exercise the registry
-# pass explicit paths or set the env themselves.
-os.environ["RAFT_TUNING_REGISTRY"] = os.path.join(
-    os.environ.get("TMPDIR", "/tmp"),
-    f"raft-test-tuning-{os.getpid()}.json")
-
 import pytest  # noqa: E402
 
 

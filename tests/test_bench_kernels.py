@@ -1,9 +1,7 @@
 """scripts/bench_kernels.py --tiny: the tier-1 CPU interpret smoke.
 
 Runs both fused kernels' microbench arms (fused vs unfused) once in
-interpret mode and checks the one-line bench.py-format record — the
-same record shape ``check_regression.py --max-kernel-slowdown`` gates
-on, so this pins the producer side of that contract.
+interpret mode and checks the one-line JSON record.
 """
 
 import importlib.util
@@ -35,22 +33,6 @@ def test_bench_kernels_tiny_smoke(capsys):
     for k in kers.values():
         assert k["fused_ms"] > 0 and k["unfused_ms"] > 0
         assert k["speedup"] > 0
-        # interpret-mode smoke: the registry must not claim a fused
-        # selection on the CPU backend (nothing to re-baseline here)
-        assert k["selected"] is False and k["selected_kind"] is None
-
-    # the record feeds the kernel-slowdown gate: interpret smoke
-    # records must NOT satisfy it (no vacuous hardware passes) ...
-    cr = _load_script("check_regression")
-    failures, _ = cr.check({"kernel_fused_speedup_min": [rec]},
-                           max_kernel_slowdown={"gru": 5.0})
-    assert any("no non-interpret record" in f for f in failures)
-    # ... while a hardware-shaped record with the same layout does.
-    hw = dict(rec, config=dict(cfg, interpret=False))
-    failures, _ = cr.check({"kernel_fused_speedup_min": [hw]},
-                           max_kernel_slowdown={"gru": 5.0,
-                                                "lookup_encoder": 5.0})
-    assert not failures
 
 
 def test_bench_kernels_rejects_unknown_kernel():

@@ -496,3 +496,19 @@ def test_check_regression_tiny_selftest(capsys):
     out = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert out["metric"] == "check_regression_selftest"
     assert out["value"] == 1.0
+
+
+def test_early_exit_gate():
+    cr = _load_script("check_regression")
+    rec = {"metric": "m", "value": 30.0,
+           "config": {"early_exit_epe_delta": 0.02}}
+    failures, _ = cr.check({"m": [rec]}, max_early_exit_epe_delta=0.05)
+    assert not failures
+    rec2 = {"metric": "m", "value": 30.0,
+            "config": {"early_exit_epe_delta": 0.2}}
+    failures, _ = cr.check({"m": [rec2]}, max_early_exit_epe_delta=0.05)
+    assert failures and "early-exit" in failures[0]
+    # the gate refuses to pass vacuously
+    failures, _ = cr.check({"m": [{"metric": "m", "value": 1.0}]},
+                           max_early_exit_epe_delta=0.05)
+    assert failures and "did not run" in failures[0]

@@ -360,7 +360,7 @@ def test_loop_data_wait_and_no_per_step_sync(tmp_path, monkeypatch,
     artificially slow iterator shows up in ``queue_wait_s``; the ONLY
     host transfers are the Logger's once-per-interval flushes
     (telemetry adds zero, and the flush cadence is unchanged); and
-    scripts/telemetry_summary.py folds the log into bench.py JSON.
+    scripts/telemetry_summary.py folds the log into one JSON line.
     Serial pipeline (device_prefetch=0) so the slow fetch lands on a
     deterministic step; the overlapped attribution is covered in
     tests/test_prefetch.py."""
@@ -418,7 +418,7 @@ def test_loop_data_wait_and_no_per_step_sync(tmp_path, monkeypatch,
     assert steps[2]["queue_wait_s"] >= 0.04
     assert steps[3]["queue_wait_s"] < 0.04
 
-    # JSONL -> bench.py JSON (same schema + metric-name mapping).
+    # JSONL -> one JSON line (schema + metric-name mapping).
     spec = importlib.util.spec_from_file_location(
         "telemetry_summary", osp.join(REPO, "scripts",
                                       "telemetry_summary.py"))

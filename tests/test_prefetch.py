@@ -420,8 +420,8 @@ def test_accum_peak_memory_scales_down():
 
 def test_bench_input_tiny_smoke(capsys):
     """scripts/bench_input.py --tiny: the tier-1 CPU smoke — runs both
-    arms and prints one bench.py-format JSON line on the registered
-    input-pipeline metric series."""
+    arms and prints one JSON line on the registered input-pipeline
+    metric series."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
@@ -431,9 +431,7 @@ def test_bench_input_tiny_smoke(capsys):
     mod.main(["--tiny"])
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
-    from bench import _input_metric_name
-
-    assert rec["metric"] == _input_metric_name(32, 48)
+    assert rec["metric"] == "input_pipeline_custom_32x48"
     assert rec["unit"] == "image-pairs/sec" and rec["value"] > 0
     assert rec["config"]["overlapped"]["pairs_per_sec"] > 0
     assert rec["config"]["serial"]["pairs_per_sec"] > 0
