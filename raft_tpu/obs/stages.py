@@ -86,6 +86,19 @@ def begin(loop: str, t_start: Optional[float] = None) -> Unit:
     return unit
 
 
+def detach(loop: str) -> Optional[Unit]:
+    """Take this thread's open unit of ``loop`` off it, still open, so
+    that another thread can :func:`attach` and close it (a serve batch
+    is issued on one thread and answered on another)."""
+    return _units().pop(loop, None)
+
+
+def attach(loop: str, unit: Unit) -> None:
+    """Make ``unit`` this thread's open unit of ``loop``: later stages
+    and :func:`end` on this thread book into it."""
+    _units()[loop] = unit
+
+
 class stage:
     """``with stage(loop, name):`` — time the block into this thread's
     open unit of ``loop`` and annotate it for the profiler.  With no

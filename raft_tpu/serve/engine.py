@@ -43,16 +43,24 @@ live here:
    XLA specializes fusion/reduction order per program, so this is the
    only robust way to pin parity (see models/raft.py).
 
-Architecture (three kinds of thread, one device):
+Architecture (four kinds of thread, one device):
 
 - caller threads: ``submit()`` — bucket lookup, backpressure check,
   handoff to the engine's event loop.  Returns a
   ``concurrent.futures.Future``.
 - the engine's asyncio loop thread: per-bucket dispatcher tasks coalesce
   micro-batches.  Pure bookkeeping, never touches the device.
-- one device-worker thread: pads/stacks the batch, runs the compiled
-  executable, unpads per-request results.  Single-threaded by
-  construction so device work serializes instead of interleaving.
+- one device-worker thread: in request mode the ISSUING side — it
+  pads/stacks a batch, uploads it and issues its two program calls
+  (nothing awaited), in the order the dispatchers cut them, so device
+  work serializes instead of interleaving; in slot mode it runs whole
+  awaited cycles.
+- one completing thread (request mode only): waits for each issued
+  batch in the order of issue, copies its flow back, unpads and answers.
+  While it waits for batch n the issuing side uploads and issues batch
+  n+1, which queues on the device behind n: at most
+  ``_MAX_IN_FLIGHT`` (2) batches are issued and unanswered, and a batch
+  with nothing behind it is answered as soon as the device is done.
 
 Backpressure: a bounded in-flight count (``max_queue``).  ``submit()``
 beyond it raises :class:`QueueFullError` (the HTTP layer maps it to 429)
@@ -85,6 +93,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import inspect
+import queue
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -364,6 +373,37 @@ class _Request:
         self.trace = trace.current()
 
 
+#: Request-mode batches issued and not yet answered: one running on the
+#: device and one queued behind it.  A constant, not an option: in a
+#: device-bound cycle a third buys nothing, holds another request's
+#: state on the device and delays the discovery of an error.
+_MAX_IN_FLIGHT = 2
+
+
+class _Issued:
+    """One request-mode batch on its way from the issuing side
+    (``_run_batch``) to the completing side (``_complete``): what was
+    uploaded and launched, and the open stage unit that travels with
+    it."""
+
+    __slots__ = ("exe", "a1", "a2", "flow", "issued", "error", "calls",
+                 "bucket", "reqs", "lanes", "seq", "t_in", "t_handed",
+                 "ahead", "unit")
+
+    def __init__(self, exe=None, a1=None, a2=None):
+        self.exe, self.a1, self.a2 = exe, a1, a2
+        # ``flow`` is the launched batch's device result.  ``issued``
+        # says the issuing side has run h2d + launch, so the first
+        # attempt of the retry thunk only drains (or re-raises
+        # ``error``, what the issue raised, for the ladder to judge).
+        self.flow = None
+        self.issued = False
+        self.error: Optional[BaseException] = None
+        self.calls = 0
+        # 1 when another batch was issued and unanswered at the issue
+        self.ahead = 0
+
+
 class _StreamSession:
     """One streaming session's host-side record.  Device state (coords,
     carry) lives in the pinned lane; this object holds the bookkeeping
@@ -545,14 +585,12 @@ class InferenceEngine:
         # Seeded per-engine jitter source for the retry backoff ladder
         # (chaos drills must replay the recorded backoff_s values).
         self._retry_rng = np.random.default_rng(0)
-        # Retries the most recent _call_device performed (device-worker
-        # thread only) — stamped onto traced requests' device spans and
-        # the tail-keep trigger for retried batches.
+        # Retries the most recent _retry_call performed (read on the
+        # thread that made it: the completing thread in request mode,
+        # the device worker in slot mode) — stamped onto traced
+        # requests' device spans and the tail-keep trigger for retried
+        # batches.
         self._last_retries = 0
-        # Program calls the request-mode pipeline has issued (device-
-        # worker thread only): a batch's stage record quotes the
-        # difference as ``calls``.
-        self._launch_calls = 0
         # One registry per engine: every stats/exposition figure below
         # reads these same metric objects (see serve/stats.py), and
         # cli/serve.py renders them at GET /metrics.
@@ -715,6 +753,21 @@ class InferenceEngine:
         # Device-batch ordinal (1-based; device-worker thread only) —
         # the `device_err@batch=N` chaos trigger context.
         self._batch_seq = 0
+        # Request mode, issuing side -> completing side: the batches
+        # issued and not yet answered, in the order of issue (None is
+        # the completing thread's signal to exit).  ``_in_flight``
+        # counts them, under ``_flight``; the issuing side waits there
+        # while it reads _MAX_IN_FLIGHT.
+        self._issued: "queue.Queue[Optional[_Issued]]" = queue.Queue()
+        self._flight = threading.Condition()
+        self._in_flight = 0
+        self._completer: Optional[threading.Thread] = None
+        # When the issuing side last handed a batch over (its thread
+        # only): where the next batch's ``wait`` starts.
+        self._issuer_free: Optional[float] = None
+        # Set once stop() no longer waits for the backlog: a batch cut
+        # but not yet issued then fails with 'engine stopped'.
+        self._abandon = False
 
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
@@ -828,6 +881,11 @@ class InferenceEngine:
                                         daemon=True)
         self._thread.start()
         started.wait()
+        if self.cfg.batching == "request":
+            self._completer = threading.Thread(
+                target=self._complete_loop, name="raft-serve-complete",
+                daemon=True)
+            self._completer.start()
         self._counters.mark_started()
         self._t_started = time.perf_counter()
         self._accepting = True
@@ -836,8 +894,11 @@ class InferenceEngine:
     def stop(self, drain: bool = True, timeout: float = 60.0) -> None:
         """Stop accepting, optionally drain in-flight work, shut down.
 
-        Queued requests that cannot complete (``drain=False`` or drain
-        timeout) fail with ``RuntimeError('engine stopped')``."""
+        Batches already issued to the device are answered either way.
+        Requests that are still queued once the drain is over
+        (``drain=False``, or the drain timed out) — with a dispatcher
+        or cut into a batch that has not been issued — fail with
+        ``RuntimeError('engine stopped')``."""
         with self._stop_lock:
             self._stop_locked(drain, timeout)
 
@@ -854,6 +915,7 @@ class InferenceEngine:
                     if self._pending == 0:
                         break
                 time.sleep(0.005)
+        self._abandon = True
 
         async def _cancel_all():
             tasks = list(self._dispatchers.values())
@@ -864,6 +926,12 @@ class InferenceEngine:
         asyncio.run_coroutine_threadsafe(
             _cancel_all(), self._loop).result(timeout=10)
         self._device_pool.shutdown(wait=True)
+        if self._completer is not None:
+            # Everything the issuing side handed over is ahead of the
+            # sentinel: the completing thread answers it, then exits.
+            self._issued.put(None)
+            self._completer.join(timeout=10)
+            self._completer = None
         self._loop.call_soon_threadsafe(self._loop.stop)
         self._thread.join(timeout=10)
         self._loop.close()
@@ -1391,10 +1459,15 @@ class InferenceEngine:
     async def _dispatcher(self, bucket: tuple, q: asyncio.Queue) -> None:
         """Coalesce one bucket's requests into micro-batches forever.
 
-        The device call is NOT awaited: the single worker thread
-        serializes device work, and not awaiting lets the next batch
-        fill while the previous one runs (pipelining the host-side
-        pad/stack with device execution)."""
+        A batch is cut ``max_wait_ms`` after its first request (or at
+        ``max_batch``) and handed to the device worker's queue; the
+        hand-over is NOT awaited, so the next batch fills meanwhile and
+        cut batches wait their turn there, whatever their bucket.  The
+        worker is the issuing side only (:meth:`_run_batch`: pad,
+        upload, the two program calls); the completing thread answers
+        (:meth:`_complete`).  That is what overlaps a batch's host work
+        with the device: batch n+1 is padded, uploaded and issued while
+        batch n runs, and starts the moment n ends."""
         batch: List[_Request] = []
         try:
             while True:
@@ -1417,12 +1490,7 @@ class InferenceEngine:
             leftovers = batch
             while not q.empty():
                 leftovers.append(q.get_nowait())
-            for r in leftovers:
-                if not r.future.done():
-                    r.future.set_exception(RuntimeError("engine stopped"))
-            if leftovers:
-                with self._pending_lock:
-                    self._pending -= len(leftovers)
+            self._fail_stopped(leftovers)
             raise
 
     async def _slot_dispatcher(self, bucket: tuple,
@@ -1470,13 +1538,17 @@ class InferenceEngine:
             pool.reset()
             while not q.empty():
                 leftovers.append(q.get_nowait())
-            for r in leftovers:
-                if not r.future.done():
-                    r.future.set_exception(RuntimeError("engine stopped"))
-            if leftovers:
-                with self._pending_lock:
-                    self._pending -= len(leftovers)
+            self._fail_stopped(leftovers)
             raise
+
+    def _fail_stopped(self, reqs: List[_Request]) -> None:
+        """Fail requests a stopping engine will not serve."""
+        for r in reqs:
+            if not r.future.done():
+                r.future.set_exception(RuntimeError("engine stopped"))
+        if reqs:
+            with self._pending_lock:
+                self._pending -= len(reqs)
 
     def _pin_poll_s(self, pool: _SlotPool) -> float:
         """Idle-poll interval while lanes are pinned: sleep until the
@@ -1649,30 +1721,58 @@ class InferenceEngine:
                               progs.mask_all, progs.budget_full)
             _, flow_up = progs.it(variables, state, progs.thr_off,
                                   progs.steps_full)
-            self._launch_calls += 2
             self._counters.add_iter_call(iters)
             return None, flow_up
 
+        # Program calls one run of it issues: what a batch's stage
+        # record adds up, over its launches, as ``calls``.
+        pipeline.calls = 2
         return pipeline
 
-    def _call_device(self, exe, a1: np.ndarray, a2: np.ndarray,
-                     bucket: tuple, seq: int) -> np.ndarray:
-        """Run one compiled batch with transient-error retry (the
-        request-mode thunk over :meth:`_retry_call`)."""
+    def _issue(self, item: _Issued) -> None:
+        """Upload ``item``'s batch and issue its program calls; nothing
+        is awaited.  Runs on the issuing side, and again on the
+        completing side when the retry ladder re-runs the batch whole
+        (each run adds to the unit's ``h2d`` and ``launch``)."""
+        # The upload gets an edge of its own (it used to hide in the
+        # first jitted call's argument handling).
+        with stages.stage("serve", "h2d"):
+            d1, d2 = jax.device_put(item.a1), jax.device_put(item.a2)
+        with stages.stage("serve", "launch"):
+            _, item.flow = item.exe(self._variables, d1, d2)
+        item.calls += getattr(item.exe, "calls", 0)
+
+    def _drain(self, item: _Issued, bucket: tuple,
+               seq: int) -> np.ndarray:
+        """Wait for ``item``'s flow and copy it back, with
+        transient-error retry (the request-mode thunk over
+        :meth:`_retry_call`).  The first attempt takes what the issuing
+        side launched (or raises what its upload or launch raised);
+        every later one runs the batch whole — ``h2d``, ``launch``,
+        ``drain`` — on this thread.  The programs are pure and nothing
+        is donated, so a batch issued behind this one is not disturbed
+        by the re-run."""
 
         def thunk():
-            # The upload gets an edge of its own (it used to hide in the
-            # first jitted call's argument handling); nothing is awaited.
-            with stages.stage("serve", "h2d"):
-                d1, d2 = jax.device_put(a1), jax.device_put(a2)
-            with stages.stage("serve", "launch"):
-                _, flow_up = exe(self._variables, d1, d2)
+            if item.issued:
+                item.issued = False
+                if item.error is not None:
+                    raise item.error
+            else:
+                self._issue(item)
             # np.asarray blocks on the transfer — async dispatch
             # errors surface here, inside the retry scope.
             with stages.stage("serve", "drain"):
-                return np.asarray(flow_up)
+                return np.asarray(item.flow)
 
         return self._retry_call(bucket, seq, thunk)
+
+    def _call_device(self, exe, a1: np.ndarray, a2: np.ndarray,
+                     bucket: tuple, seq: int) -> np.ndarray:
+        """Run one compiled batch serially on this thread — upload,
+        launch, drain — with transient-error retry: :meth:`_drain` of a
+        batch nobody has issued yet."""
+        return self._drain(_Issued(exe, a1, a2), bucket, seq)
 
     def _retry_call(self, bucket: tuple, seq: int, thunk):
         """Run one device call (``thunk``) with transient-error retry.
@@ -1788,23 +1888,100 @@ class InferenceEngine:
                 n=n)
 
     def _run_batch(self, bucket: tuple, reqs: List[_Request]) -> None:
-        """One request-mode batch on the device worker, timed by the
-        stage clock (obs/stages.py): ``wait`` (the worker's previous
-        batch ended -> this one entered), ``pad``, then ``h2d`` /
-        ``launch`` / ``drain`` inside the retry thunk
-        (:meth:`_call_device`), ``reply``.  The batch's record is the
-        one set of stamps: the per-request ``queue`` / ``pad`` /
-        ``device`` trace spans, the ``serve_batch`` event and the ring
-        all read it."""
+        """The issuing side of one request-mode batch, on the device
+        worker: ``wait`` (this side had nothing to issue), ``pad``,
+        ``hold`` (only while :data:`_MAX_IN_FLIGHT` batches are issued
+        and unanswered), ``h2d``, ``launch`` — then the batch and its
+        open stage unit go to the completing thread (:meth:`_complete`)
+        and this side takes the next batch: its pad, upload and program
+        calls run while the device works on this one.  A batch that
+        fails here (a chaos fault, the pad, a compile) is handed over
+        all the same, so that answers leave in the order of issue."""
         n = len(reqs)
-        bs = next((s for s in self._batch_sizes if s >= n), n)
-        t_in = time.perf_counter()
-        unit = stages.begin("serve", t_start=self._last_batch_done or t_in)
-        unit.add("wait", unit.t_start, t_in)
-        self._last_retries = 0
-        calls0 = self._launch_calls
+        if self._abandon:
+            self._fail_stopped(reqs)
+            return
+        item = _Issued()
+        item.bucket, item.reqs, item.t_in = bucket, reqs, time.perf_counter()
+        item.lanes = next((s for s in self._batch_sizes if s >= n), n)
+        # ``wait`` starts where this side handed the last batch over,
+        # or where that batch's record closed if it already has: with
+        # nothing overlapping (one caller) a record's stages then stay
+        # inside its own cycle, as before.
+        item.unit = stages.begin("serve", t_start=max(
+            self._issuer_free or item.t_in, self._last_batch_done or 0.0))
+        item.unit.add("wait", item.unit.t_start, item.t_in)
         self._batch_seq += 1
-        seq = self._batch_seq
+        item.seq = self._batch_seq
+        try:
+            self._chaos_replica_faults(item.seq)
+            item.exe = self._get_executable(bucket, item.lanes)
+            with stages.stage("serve", "pad"):
+                im1 = [r.padder.pad_np(r.image1) for r in reqs]
+                im2 = [r.padder.pad_np(r.image2) for r in reqs]
+                if item.lanes > n:  # ballast keeps the compiled shape
+                    im1 += [im1[-1]] * (item.lanes - n)
+                    im2 += [im2[-1]] * (item.lanes - n)
+                item.a1, item.a2 = np.stack(im1), np.stack(im2)
+        except Exception as e:
+            item.error = e
+        with self._flight:
+            if self._in_flight >= _MAX_IN_FLIGHT:
+                with stages.stage("serve", "hold"):
+                    self._flight.wait_for(
+                        lambda: self._in_flight < _MAX_IN_FLIGHT)
+            ahead = self._in_flight > 0
+            self._in_flight += 1
+        if item.error is None:
+            item.issued, item.ahead = True, int(ahead)
+            if ahead:
+                self._counters.add_issued_ahead()
+            try:
+                self._issue(item)
+            except Exception as e:   # the completing side's ladder judges
+                item.error = e
+        stages.detach("serve")
+        self._issuer_free = item.t_handed = time.perf_counter()
+        self._issued.put(item)
+
+    def _complete_loop(self) -> None:
+        """The completing thread: answer issued batches in the order of
+        issue until stop() sends None."""
+        while True:
+            item = self._issued.get()
+            if item is None:
+                return
+            try:
+                self._complete(item)
+            except Exception as e:   # a bug past the batch's own
+                # handling must not take the thread, and every later
+                # answer, with it
+                self._sink.emit(
+                    "serve_batch_error",
+                    bucket=f"{item.bucket[0]}x{item.bucket[1]}",
+                    real=len(item.reqs), error=f"{type(e).__name__}: {e}")
+
+    def _complete(self, item: _Issued) -> None:
+        """The completing side of one request-mode batch: ``drain``
+        (the wait for the device and the flow's copy back, inside the
+        retry ladder of :meth:`_drain`), ``reply``, then the batch's
+        stage record is closed — it starts where the previous batch's
+        ended, so the records tile the worker's time — and its place in
+        flight is given back.  The record is the one set of stamps: the
+        per-request ``queue`` / ``pad`` / ``device`` trace spans, the
+        ``serve_batch`` event and the ring all read it."""
+        bucket, reqs, unit = item.bucket, item.reqs, item.unit
+        n, bs, seq, t_in = len(reqs), item.lanes, item.seq, item.t_in
+        stages.attach("serve", unit)
+        if item.issued:
+            # ``drain`` is the wait for the device from the moment this
+            # batch is the oldest one unanswered: handed over, and the
+            # batch before it answered (the hop between the two threads
+            # is part of the wait, not a hole in the record).
+            unit.add("drain", max(item.t_handed,
+                                  self._last_batch_done or 0.0),
+                     time.perf_counter())
+        self._last_retries = 0
         bk = f"{bucket[0]}x{bucket[1]}"
         # Requests carrying a trace context get per-request queue/pad/
         # device child spans; a batch with no traced request pays only
@@ -1812,16 +1989,9 @@ class InferenceEngine:
         traced = [r for r in reqs if r.trace is not None]
         error = None
         try:
-            self._chaos_replica_faults(seq)
-            exe = self._get_executable(bucket, bs)
-            with stages.stage("serve", "pad"):
-                im1 = [r.padder.pad_np(r.image1) for r in reqs]
-                im2 = [r.padder.pad_np(r.image2) for r in reqs]
-                if bs > n:  # ballast lanes keep the compiled batch shape
-                    im1 += [im1[-1]] * (bs - n)
-                    im2 += [im2[-1]] * (bs - n)
-                a1, a2 = np.stack(im1), np.stack(im2)
-            flow_up = self._call_device(exe, a1, a2, bucket, seq)
+            if not item.issued and item.error is not None:
+                raise item.error    # failed before its device call
+            flow_up = self._drain(item, bucket, seq)
             with stages.stage("serve", "reply"):
                 t_done = unit.spans["drain"][1]
                 for j, r in enumerate(reqs):
@@ -1849,15 +2019,20 @@ class InferenceEngine:
             self._sink.emit("serve_batch_error", bucket=bk, real=n,
                             error=f"{error}: {e}")
         finally:
+            if self._last_batch_done is not None:
+                unit.t_start = self._last_batch_done
             rec = stages.end(
                 "serve", registry=self.registry, batch=seq, bucket=bk,
                 real=n, ballast=bs - n, retries=self._last_retries,
-                calls=self._launch_calls - calls0,
+                calls=item.calls, ahead=item.ahead,
                 queue_s=[t_in - r.t_submit for r in reqs], error=error,
                 model=self._model_cfg.arch)
             with self._pending_lock:
                 self._pending -= len(reqs)
                 self._last_batch_done = rec["t_end"]
+            with self._flight:
+                self._in_flight -= 1
+                self._flight.notify()
         if traced:
             self._trace_batch(traced, rec, bucket)
 
@@ -1879,9 +2054,12 @@ class InferenceEngine:
                                   batch=seq)
             return
         t_dev0, t_dev1 = spans["h2d"][0], spans["drain"][1]
+        # A batch issued ahead spent the first part of its ``device``
+        # span queued behind the batch before it: ``mfu`` is taken over
+        # the part after that batch's record closed.
         cost_attrs = self._pipeline_cost_attrs(
             bucket, rec["real"] + rec["ballast"], self.cfg.iters,
-            t_dev1 - t_dev0)
+            t_dev1 - max(t_dev0, rec["t_start"]))
         for r in traced:
             trace.record_span(r.trace, "pad", *spans["pad"],
                               real=rec["real"], ballast=rec["ballast"])

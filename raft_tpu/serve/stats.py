@@ -111,6 +111,10 @@ class Counters:
             "(docs/ROBUSTNESS.md)")
         self._batches = r.counter("raft_serve_batches_total",
                                   "device batches dispatched")
+        self._issued_ahead = r.counter(
+            "raft_serve_batches_issued_ahead_total",
+            "request-mode batches uploaded and launched while an "
+            "earlier one was still unanswered")
         self._ballast = r.counter("raft_serve_lanes_ballast_total",
                                   "batch lanes filled with repeated "
                                   "ballast to reach a compiled size")
@@ -171,6 +175,9 @@ class Counters:
         else:
             self._completed.inc(real)
 
+    def add_issued_ahead(self) -> None:
+        self._issued_ahead.inc()
+
     def add_completed(self, n: int = 1) -> None:
         """Slot-mode retirement: requests complete one at a time, not
         per batch (batch accounting happens in :meth:`add_slot_step`)."""
@@ -224,6 +231,7 @@ class Counters:
             "errors": self._errors.value(),
             "retries": self._retries.value(),
             "batches": batches,
+            "issued_ahead": self._issued_ahead.value(),
             "slot_steps": self._slot_steps.value(),
             "iter_calls": self._iter_calls.value(),
             "iter_steps": self._iter_steps.value(),

@@ -539,6 +539,59 @@ def test_call_device_retry_budget_exhausts():
     assert eng.stats()["retries"] == 2
 
 
+@pytest.mark.parametrize("transient", [True, False])
+def test_drain_error_of_batch_n_leaves_batch_n_plus_1_alone(tmp_path,
+                                                            transient):
+    """The error of batch n surfaces at its drain, with batch n+1
+    already uploaded and launched behind it.  A transient one re-runs n
+    whole, once, on the completing side (one ``serve_retry`` event,
+    retries counted once) and both are answered, in order; a
+    deterministic one fails n only."""
+    from tests.fake_device import FakeDevice
+
+    eng = _engine_shell(tmp_path, device_retries=2, max_batch=1,
+                        batch_sizes=(1,), max_wait_ms=1)
+    dev = FakeDevice(gated=True).install(eng)
+    dev.drain_errors[0] = [InjectedDeviceError("flake at the drain")
+                           if transient else ValueError("bad program")]
+    eng.start()
+    try:
+        im = np.zeros((36, 52, 3), np.float32)
+        f0 = eng.submit(im, im)
+        assert dev.wait_launched(1)
+        f1 = eng.submit(im, im)
+        assert dev.wait_launched(2)        # n+1 issued, n not yet read
+        assert dev.drained == []
+        dev.gate.set()
+        # answers name their launch: batch 1 is launch 1, and the
+        # re-run of batch 0 is the third launch
+        assert f1.result(timeout=30)[0, 0, 0] == 1.0
+        if transient:
+            assert f0.result(timeout=30)[0, 0, 0] == 2.0
+        else:
+            with pytest.raises(ValueError, match="bad program"):
+                f0.result(timeout=30)
+        assert [k for k, _ in dev.drained] == ([2, 1] if transient
+                                               else [1])
+    finally:
+        dev.gate.set()
+        eng.stop()
+    stats = eng.stats()
+    assert stats["retries"] == int(transient)
+    assert stats["errors"] == int(not transient)
+    assert stats["completed"] == 1 + int(transient)
+    evs = [e for e in _events(str(tmp_path))
+           if e["event"] == "serve_retry"]
+    assert len(evs) == int(transient)
+    from raft_tpu.obs import stages
+
+    r0, r1 = stages.recent("serve")[-2:]
+    assert (r0["retries"], r1["retries"]) == (int(transient), 0)
+    assert (r0["calls"], r1["calls"]) == (0, 0)   # the fake issues none
+    assert r0["error"] == (None if transient else "ValueError")
+    assert r1["error"] is None and r1["ahead"] == 1
+
+
 # ---------------------------------------------------------------------
 # chaos_smoke: the end-to-end acceptance criterion (train completes
 # under corrupt sample + torn ckpt + resume; serve survives a

@@ -518,3 +518,199 @@ def test_http_429_is_structured(variables):
     finally:
         server.shutdown()
         server.server_close()
+
+
+# ---------------------------------------------------------------------
+# Request mode issues batch n+1 while batch n is on the device, and a
+# second thread answers in the order of issue.  tests/fake_device.py
+# stands in for the compiled pipeline, so "on the device" lasts as long
+# as a test wants (no compile); one real engine of single-request
+# batches holds the answers to their serial values.
+# ---------------------------------------------------------------------
+
+def _ahead_engine(variables, dev, **cfg_kw):
+    from raft_tpu.obs import stages
+
+    kw = dict(iters=ITERS, max_batch=1, batch_sizes=(1,), max_wait_ms=1,
+              max_queue=64)
+    kw.update(cfg_kw)
+    eng = InferenceEngine(variables, CFG, ServeConfig(**kw))
+    dev.install(eng)
+    eng.start()
+    return eng, len(stages.recent("serve"))
+
+
+def _settled(eng, n0, n):
+    """The engine's ``n`` newest stage records, once its batches have
+    closed them (a future resolves inside ``reply``, the record closes
+    just after; the module's engines run one after another, so the
+    ring's newest records are this engine's)."""
+    import time
+
+    from raft_tpu.obs import stages
+
+    deadline = time.perf_counter() + 10
+    while eng.stats()["pending"] and time.perf_counter() < deadline:
+        time.sleep(0.002)
+    recs = stages.recent("serve")[n0:]
+    assert len(recs) == n, (len(recs), n)
+    return recs
+
+
+def test_next_batch_is_issued_while_the_last_is_on_the_device(variables):
+    from tests.fake_device import FakeDevice
+
+    dev = FakeDevice(gated=True)
+    eng, n0 = _ahead_engine(variables, dev)
+    try:
+        im1, im2 = _images(np.random.default_rng(11), 36, 52)
+        f0 = eng.submit(im1, im2)
+        assert dev.wait_launched(1)
+        f1 = eng.submit(im1, im2)
+        # batch 1 is uploaded and launched while batch 0's result is
+        # still on the device: nothing of it has been read back
+        assert dev.wait_launched(2)
+        assert dev.drained == [] and not f0.done() and not f1.done()
+        dev.gate.set()
+        assert f0.result(timeout=30)[0, 0, 0] == 0.0
+        assert f1.result(timeout=30)[0, 0, 0] == 1.0
+        r0, r1 = _settled(eng, n0, 2)
+        assert (r0["ahead"], r1["ahead"]) == (0, 1)
+        assert r1["spans"]["h2d"][1] <= r1["spans"]["launch"][1] \
+            < r0["spans"]["drain"][1]
+        stats = eng.stats()
+        assert stats["issued_ahead"] == 1 and stats["batches"] == 2
+        assert "raft_serve_batches_issued_ahead_total 1" \
+            in eng.metrics_text()
+    finally:
+        dev.gate.set()
+        eng.stop()
+
+
+def test_a_lone_request_is_answered_with_nobody_behind_it(engine):
+    """One caller: the batch is drained as soon as the device is done,
+    its record says so (``ahead`` 0, no ``hold``, stages inside its own
+    cycle), and nothing counts as issued ahead."""
+    from raft_tpu.obs import stages
+
+    before = engine.stats()["issued_ahead"]
+    n0 = len(stages.recent("serve"))
+    im1, im2 = _images(np.random.default_rng(12), 36, 52)
+    for _ in range(2):
+        assert engine.infer(im1, im2, timeout=120).shape == (36, 52, 2)
+    r0, r1 = _settled(engine, n0, 2)
+    for r in (r0, r1):
+        assert r["ahead"] == 0 and "hold" not in r["stages"]
+        assert set(r["stages"]) == {"wait", "pad", "h2d", "launch",
+                                    "drain", "reply"}
+    assert r1["t_start"] == r0["t_end"] == r1["spans"]["wait"][0]
+    assert abs(sum(r1["stages"].values())
+               - (r1["t_end"] - r1["t_start"])) < 5e-3
+    assert engine.stats()["issued_ahead"] == before
+
+
+def test_burst_keeps_two_in_flight_and_answers_in_order(variables):
+    """8 single-request batches at once: never more than two issued and
+    unanswered, answers in the order of issue, and each flow equal, bit
+    for bit, to the same pair's served alone."""
+    eng = InferenceEngine(variables, CFG, ServeConfig(
+        iters=ITERS, max_batch=1, batch_sizes=(1,), max_wait_ms=1,
+        max_queue=64))
+    eng.start()
+    try:
+        eng.warmup([(36, 52)])
+        rng = np.random.default_rng(13)
+        pairs = [_images(rng, 36, 52) for _ in range(8)]
+        in_flight, issue = [], eng._issue
+
+        def watched(item):
+            in_flight.append(eng._in_flight)
+            return issue(item)
+
+        eng._issue = watched
+        order = []
+        futs = [eng.submit(a, b) for a, b in pairs]
+        for i, f in enumerate(futs):
+            f.add_done_callback(lambda _f, i=i: order.append(i))
+        burst = [f.result(timeout=120) for f in futs]
+        assert len(in_flight) == 8 and max(in_flight) == 2
+        assert order == list(range(8))
+        assert eng.stats()["issued_ahead"] >= 1
+        alone = [eng.infer(a, b, timeout=120) for a, b in pairs]
+        for got, want in zip(burst, alone):
+            assert np.array_equal(got, want)
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_stop_with_two_batches_in_flight(variables, drain):
+    """Two batches on the device, a third held behind them, a fourth
+    cut and waiting.  ``stop(drain=True)`` answers all four;
+    ``stop(drain=False)`` answers what has been issued and fails the
+    rest; either way no future stays pending and no thread alive."""
+    import time
+
+    from tests.fake_device import FakeDevice
+
+    dev = FakeDevice(gated=True)
+    eng, _ = _ahead_engine(variables, dev)
+    im1, im2 = _images(np.random.default_rng(14), 36, 52)
+    futs = [eng.submit(im1, im2) for _ in range(4)]
+    assert dev.wait_launched(2)
+    time.sleep(0.1)       # the third pads and is held; the fourth waits
+    assert len(dev.launched) == 2 and eng._in_flight == 2
+    threads = [eng._thread, eng._completer]
+    stopper = threading.Thread(target=eng.stop,
+                               kwargs=dict(drain=drain, timeout=30))
+    stopper.start()
+    time.sleep(0.1)
+    dev.gate.set()        # the device finishes what it was given
+    stopper.join(timeout=30)
+    threads += list(eng._device_pool._threads)
+    assert not stopper.is_alive()
+    assert len(threads) == 3 and not any(t.is_alive() for t in threads)
+    assert all(f.done() for f in futs) and eng.stats()["pending"] == 0
+    assert [f.result()[0, 0, 0] for f in futs[:3]] == [0.0, 1.0, 2.0]
+    if drain:
+        assert futs[3].result()[0, 0, 0] == 3.0
+    else:
+        with pytest.raises(RuntimeError, match="engine stopped"):
+            futs[3].result()
+        assert len(dev.launched) == 3
+
+
+def test_stage_records_tile_and_wait_leaves_hold_out(variables):
+    """A burst of 6 over a device that takes 30 ms a batch: one record a
+    batch, each starting where the one before ended; the time the
+    issuing side is held behind two unanswered batches is ``hold``, not
+    ``wait``; ``pad`` / ``h2d`` / ``launch`` of a batch issued ahead lie
+    before its record starts."""
+    from tests.fake_device import FakeDevice
+
+    dev = FakeDevice(work_s=0.03)
+    eng, n0 = _ahead_engine(variables, dev)
+    try:
+        im1, im2 = _images(np.random.default_rng(15), 36, 52)
+        futs = [eng.submit(im1, im2) for _ in range(6)]
+        assert [f.result(timeout=30)[0, 0, 0] for f in futs] == [
+            0.0, 1.0, 2.0, 3.0, 4.0, 5.0]
+        recs = _settled(eng, n0, 6)
+    finally:
+        eng.stop()
+    assert [r["batch"] for r in recs] == [1, 2, 3, 4, 5, 6]
+    for prev, cur in zip(recs, recs[1:]):
+        assert cur["t_start"] == prev["t_end"]
+    assert [r["ahead"] for r in recs] == [0, 1, 1, 1, 1, 1]
+    held = [r for r in recs if "hold" in r["stages"]]
+    assert len(held) >= 3           # batches 3.. find two in flight
+    for r in held:
+        sp = r["spans"]
+        assert sp["wait"][1] <= sp["pad"][0] <= sp["pad"][1] \
+            <= sp["hold"][0] <= sp["hold"][1] <= sp["h2d"][0]
+        assert r["stages"]["hold"] > 0.01
+        assert r["stages"]["hold"] > r["stages"]["wait"]
+        assert sp["launch"][1] < r["t_start"] < sp["drain"][1]
+    # the cycle is the device's 30 ms, not 30 ms + the host's work
+    cycles = [r["t_end"] - r["t_start"] for r in recs[2:]]
+    assert 0.025 < sorted(cycles)[len(cycles) // 2] < 0.08
