@@ -17,7 +17,7 @@ from raft_tpu.config import ARCHS
 
 
 def add_arch_argument(parser) -> None:
-    """``--arch {full,small,gma}`` with ``--small`` kept as an alias of
+    """``--arch {full,small,gma,searaft}`` with ``--small`` kept as an alias of
     ``--arch small``; read the result with :func:`arch_from_args`."""
     parser.add_argument("--arch", choices=ARCHS, default=None,
                         help="model architecture (default: full)")

@@ -137,10 +137,13 @@ def parse_args(argv=None):
 def variables_arch(variables) -> str:
     """The architecture a variables tree is of, read off the tree itself
     (a checkpoint carries its model in its names): GMA alone has ``att``,
-    the small model alone no convex-upsampling mask head."""
+    SEA-RAFT alone ``init_conv``, the small model alone no
+    convex-upsampling mask head."""
     params = variables["params"]
     if "att" in params:
         return "gma"
+    if "init_conv" in params:
+        return "searaft"
     return "full" if "upsampler" in params else "small"
 
 

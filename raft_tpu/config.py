@@ -36,7 +36,7 @@ QUANTIZED_CORR_DTYPES = ("int8", "float8_e4m3fn", "float8_e5m2")
 CORR_PRECISIONS = ("auto", "default", "high", "highest")
 # The architectures the model code builds (RAFTConfig.arch, the CLIs'
 # --arch).
-ARCHS = ("full", "small", "gma")
+ARCHS = ("full", "small", "gma", "searaft")
 
 
 def validate_corr_dtype(value: str, flag: str = "corr_dtype") -> str:
@@ -87,8 +87,14 @@ class RAFTConfig:
     ``gma`` (Jiang et al., ICCV 2021; PAPERS.md): ``full`` with a
     content attention built once from the context and a global
     aggregate of the motion features fed to a 384-wide GRU input in
-    every iteration.  Build one with :meth:`full`, :meth:`small_model`,
-    :meth:`gma` or :meth:`preset`; the widths below follow from it.
+    every iteration; and ``searaft`` (SEA-RAFT (M), Wang et al., ECCV
+    2024; PAPERS.md): ResNet-34 encoders with batch norm, a context that
+    reads both images, a first flow regressed before the loop, two
+    ConvNeXt blocks where the GRU stood, a flow head of 6 channels
+    (flow, mixture logits, log-scales) outside the update block and a
+    mixture-of-Laplace loss over ``iters + 1`` predictions.  Build one
+    with :meth:`full`, :meth:`small_model`, :meth:`gma`, :meth:`searaft`
+    or :meth:`preset`; the widths below follow from it.
     """
 
     arch: str = "full"
@@ -277,10 +283,19 @@ class RAFTConfig:
         return cls.full(**{"arch": "gma", **kw})
 
     @classmethod
+    def searaft(cls, **kw) -> "RAFTConfig":
+        # SEA-RAFT (M): dim 128 for the hidden state and the context,
+        # radius 4, 4 levels (config/train/Tartan-C.json); what else the
+        # architecture fixes (ResNet-34 stages, 2 ConvNeXt blocks, the
+        # 6-channel head) follows from ``arch`` in models/.
+        return cls.full(**{"arch": "searaft", **kw})
+
+    @classmethod
     def preset(cls, arch: str, **kw) -> "RAFTConfig":
         """The preset the CLIs' ``--arch`` names (an unknown name fails
         in ``__post_init__``, with the allowed set)."""
-        makers = {"small": cls.small_model, "gma": cls.gma}
+        makers = {"small": cls.small_model, "gma": cls.gma,
+                  "searaft": cls.searaft}
         return makers.get(arch, cls.full)(**{**kw, "arch": arch})
 
     @property
@@ -292,6 +307,26 @@ class RAFTConfig:
         """Whether the update block aggregates motion features through
         an attention matrix carried beside the correlation state."""
         return self.arch == "gma"
+
+    @property
+    def regressed_first_flow(self) -> bool:
+        """Whether the loop starts from a flow regressed from the context
+        (prediction 0 of ``iters + 1``) and not from zero."""
+        return self.arch == "searaft"
+
+    @property
+    def mixture_head(self) -> bool:
+        """Whether the flow head gives 6 channels a prediction (the flow,
+        2 mixture logits, 2 log-scales: ``info``), upsampled together,
+        and the sequence loss is their mixture-of-Laplace likelihood in
+        place of L1."""
+        return self.arch == "searaft"
+
+    @property
+    def context_reads_pair(self) -> bool:
+        """Whether the context is a function of both images: a frame's
+        context then cannot be cached for the next pair (streaming)."""
+        return self.arch == "searaft"
 
     @property
     def resolved_corr_dtype(self) -> str:

@@ -26,7 +26,18 @@ This maps them onto :class:`raft_tpu.models.raft.RAFT` variables:
   relative-position tables every GMA checkpoint carries and the
   published content-only model never reads) is dropped.  A GMA state
   dict and a RAFT template, or the reverse, is refused by name
-  (:func:`_check_arch`).
+  (:func:`_check_arch`);
+- the public SEA-RAFT state dict (github.com/princeton-vl/SEA-RAFT
+  ``RAFT``): in both ResNet trunks ``bnN`` -> ``normN`` and
+  ``final_conv`` -> ``conv2``; ``init_conv`` as it is; the Sequentials
+  ``flow_head.0|2`` -> ``flow_head/conv1|2`` and ``upsample_weight.0|2``
+  -> ``upsampler/mask_head/mask_conv1|2``; ``update_block.refine.N`` ->
+  ``refine/update_block/refine_N`` with the depthwise kernel
+  ``(C, 1, 7, 7)`` -> ``(7, 7, 1, C)``, the LayerNorm's ``weight`` ->
+  ``scale`` and the two ``nn.Linear`` weights ``(out, in)`` -> 1x1
+  kernels ``(1, 1, in, out)``.  Such a state dict and a template of
+  another architecture, or the reverse, is refused naming the first key
+  that has no place.
 
 Conversion is validated structurally: every template leaf must be written
 exactly once with a matching shape, and every torch tensor consumed.
@@ -97,6 +108,19 @@ def _torch_key_to_path(key: str):
         conv = {"0": "mask_conv1", "2": "mask_conv2"}[parts[i + 1]]
         parts = ["upsampler", "mask_head", conv] + parts[i + 2:]
 
+    # SEA-RAFT: the two heads' Sequentials, the trunks' names, the blocks
+    if parts[0] == "flow_head" and parts[1] in ("0", "2"):
+        parts = ["flow_head", {"0": "conv1", "2": "conv2"}[parts[1]]] \
+            + parts[2:]
+    if parts[0] == "upsample_weight":
+        parts = ["upsampler", "mask_head",
+                 {"0": "mask_conv1", "2": "mask_conv2"}[parts[1]]] + parts[2:]
+    if parts[0] in ("fnet", "cnet"):
+        parts = [re.sub(r"^bn(\d)$", r"norm\1", p) for p in parts]
+        parts = ["conv2" if p == "final_conv" else p for p in parts]
+    if parts[:2] == ["update_block", "refine"]:
+        parts = ["update_block", f"refine_{parts[2]}"] + parts[3:]
+
     if parts[0] == "update_block":
         parts = ["refine"] + parts
 
@@ -137,6 +161,8 @@ def _fuse_gru_zr(state_dict: Dict[str, Any]) -> Dict[str, Any]:
 
 
 _GMA_KEY = re.compile(r"^(module\.)?(att\.|update_block\.aggregator\.)")
+_SEA_KEY = re.compile(r"^(module\.)?(init_conv\.|flow_head\.|"
+                      r"upsample_weight\.|update_block\.refine\.)")
 
 
 def _check_arch(state_dict, template) -> None:
@@ -158,6 +184,21 @@ def _check_arch(state_dict, template) -> None:
             "att.* / update_block.aggregator.* keys (att.to_qk.weight, "
             "update_block.aggregator.to_v.weight, "
             "update_block.aggregator.gamma): not a GMA checkpoint")
+    sea_keys = sorted(k for k in state_dict if _SEA_KEY.match(k))
+    wants = "init_conv" in template["params"]
+    if sea_keys and not wants:
+        raise ValueError(
+            f"the state dict is a SEA-RAFT checkpoint ({sea_keys[0]!r} is "
+            f"the first of {len(sea_keys)} keys that have no place) and "
+            "the model is not: the template has no init_conv/*, "
+            "flow_head/* or refine/update_block/refine_N/* leaves; "
+            "convert with --arch searaft")
+    if wants and not sea_keys:
+        raise ValueError(
+            "the model is SEA-RAFT and the state dict is not: "
+            "'init_conv.weight' is the first key missing (then "
+            "flow_head.0.weight, update_block.refine.0.dwconv.weight, "
+            "upsample_weight.0.weight)")
 
 
 def convert_state_dict(state_dict: Dict[str, Any],
@@ -190,7 +231,8 @@ def convert_state_dict(state_dict: Dict[str, Any],
         elif leaf == "<weight>":
             candidates = [prefix + ("kernel",),
                           prefix + ("BatchNorm_0", "scale"),
-                          prefix + ("GroupNorm_0", "scale")]
+                          prefix + ("GroupNorm_0", "scale"),
+                          prefix + ("scale",)]      # a LayerNorm's own
         elif leaf == "<bias>":
             candidates = [prefix + ("bias",),
                           prefix + ("BatchNorm_0", "bias"),
@@ -205,6 +247,8 @@ def convert_state_dict(state_dict: Dict[str, Any],
 
         if full[-1] == "kernel" and arr.ndim == 4:
             arr = arr.transpose(2, 3, 1, 0)  # OIHW -> HWIO
+        elif full[-1] == "kernel" and arr.ndim == 2:
+            arr = arr.T[None, None]          # nn.Linear (out, in) -> 1x1
         want = flat_tmpl[full].shape
         if tuple(arr.shape) != tuple(want):
             raise ValueError(
@@ -230,7 +274,9 @@ def convert_state_dict(state_dict: Dict[str, Any],
 
 
 def make_template(model_cfg: RAFTConfig):
-    """Init-shape variables tree for the converter to fill."""
+    """Init-shape variables tree for the converter to fill: the names,
+    shapes and dtypes of the model's ``init`` (``eval_shape``: nothing is
+    computed), as zeros."""
     import jax
     import jax.numpy as jnp
 
@@ -238,9 +284,11 @@ def make_template(model_cfg: RAFTConfig):
 
     model = RAFT(model_cfg)
     img = jnp.zeros((1, 48, 64, 3))
-    variables = model.init({"params": jax.random.PRNGKey(0),
-                            "dropout": jax.random.PRNGKey(0)},
-                           img, img, iters=1)
+    shapes = jax.eval_shape(
+        lambda k: model.init({"params": k, "dropout": k}, img, img, iters=1),
+        jax.random.PRNGKey(0))
+    variables = jax.tree_util.tree_map(
+        lambda s: np.zeros(s.shape, s.dtype), dict(shapes))
     return {"params": variables["params"],
             "batch_stats": dict(variables.get("batch_stats", {}))}
 
@@ -257,7 +305,7 @@ def main(argv=None):
     import argparse
 
     p = argparse.ArgumentParser(
-        description="Convert a reference RAFT or GMA .pth to an orbax "
+        description="Convert a reference RAFT, GMA or SEA-RAFT .pth to an orbax "
                     "checkpoint")
     p.add_argument("pth", help="path to torch checkpoint")
     p.add_argument("out", help="output orbax checkpoint directory")

@@ -7,7 +7,11 @@ Re-designs the reference's ``core/extractor.py:118-267``:
 - ``SmallEncoder``: bottleneck blocks, 32 -> 64/s2 -> 96/s2
   (extractor.py:212-227).
 
-Both take frames stacked on the batch axis for the shared-weight two-frame
+- ``ResNetEncoder`` (arch 'searaft'; SEA-RAFT core/extractor.py
+  ``ResNetFPN``): the first three stages of ResNet-34, 3 + 4 + 6 basic
+  blocks of 64 / 128 / 256 channels, batch norm throughout.
+
+The first two take frames stacked on the batch axis for the shared-weight two-frame
 encode (the reference's list-input trick, extractor.py:168-174, becomes an
 explicit ``jnp.concatenate`` at the caller).  Dropout is channel-wise
 (torch Dropout2d, extractor.py:186-187) -> flax Dropout broadcast over the
@@ -19,6 +23,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from raft_tpu.models.layers import (BottleneckBlock,
@@ -114,3 +119,76 @@ class SmallEncoder(nn.Module):
             x = nn.Dropout(self.dropout, broadcast_dims=(1, 2),
                            deterministic=not train)(x)
         return x
+
+
+class ResNetBlock(nn.Module):
+    """ResNet's basic block as SEA-RAFT's ``BasicBlock`` has it:
+    ``relu(s(x) + bn2(conv3x3(relu(bn1(conv3x3_stride(x))))))``, ``s`` the
+    identity, or ``bn(conv1x1_stride(x))`` where the stride or the width
+    changes.  Unlike :class:`ResidualBlock` (RAFT's) there is no ReLU
+    between the second norm and the sum.  Same leaf names."""
+
+    planes: int
+    stride: int = 1
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False, freeze_bn: bool = False):
+        cin, dt = x.shape[-1], self.dtype
+
+        def bn(name, y):
+            return Norm("batch", self.planes, dtype=dt, name=name)(
+                y, train, freeze_bn)
+
+        y = conv(self.planes, 3, self.stride, dt, name="conv1",
+                 in_features=cin)(x)
+        y = nn.relu(bn("norm1", y))
+        y = conv(self.planes, 3, 1, dt, name="conv2",
+                 in_features=self.planes)(y)
+        y = bn("norm2", y)
+        if self.stride != 1 or cin != self.planes:
+            x = conv(self.planes, 1, self.stride, dt,
+                     name="downsample_conv", in_features=cin)(x)
+            x = bn("norm3", x)
+        return nn.relu(x + y)
+
+
+class ResNetEncoder(nn.Module):
+    """SEA-RAFT's ``ResNetFPN`` at its (M) setting (``pretrain
+    resnet34``, ``initial_dim 64``, ``block_dims [64, 128, 256]``): 7x7/s2
+    stem, batch norm, ReLU, then 3 / 4 / 6 :class:`ResNetBlock` at 1/2,
+    1/4 and 1/8 resolution, then a 1x1 projection.  Batch norm in every
+    call: a caller that wants two images normalised apart calls twice.
+    Each stage traces under ``jax.named_scope("resnet_stage<n>")``."""
+
+    output_dim: int = 256
+    dtype: Any = jnp.float32
+    # Rematerialize the blocks of the first stage in a training call's
+    # backward pass: their 64-channel activations at 1/2 resolution are
+    # the largest arrays of the step (93 MB each at the chairs crop and
+    # batch 16, a dozen a call, three calls a step), and keeping only
+    # each block's input is what leaves the step room on a 16 GB chip
+    # (PERF.md section 4).  Follows ``RAFTConfig.remat``.
+    remat_stage1: bool = False
+    stages = ((64, 3, 1), (128, 4, 2), (256, 6, 2))
+
+    @nn.compact
+    def __call__(self, x, train: bool = False, freeze_bn: bool = False):
+        dt = self.dtype
+        x = x.astype(dt)
+        block1 = ResNetBlock
+        if self.remat_stage1 and train:
+            block1 = nn.remat(ResNetBlock, static_argnums=(2, 3))
+        with jax.named_scope("resnet_stage1"):
+            x = conv(64, 7, 2, dt, name="conv1",
+                     in_features=x.shape[-1])(x)
+            x = nn.relu(Norm("batch", 64, dtype=dt, name="norm1")(
+                x, train, freeze_bn))
+        for s, (planes, blocks, stride) in enumerate(self.stages, start=1):
+            with jax.named_scope(f"resnet_stage{s}"):
+                for i in range(blocks):
+                    x = (block1 if s == 1 else ResNetBlock)(
+                        planes, stride if i == 0 else 1, dt,
+                        name=f"layer{s}_{i}")(x, train, freeze_bn)
+        return conv(self.output_dim, 1, 1, dt, name="conv2",
+                    in_features=x.shape[-1])(x)

@@ -44,10 +44,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from raft_tpu.config import RAFTConfig, _warn_pallas_fallback
-from raft_tpu.models.extractor import BasicEncoder, SmallEncoder
-from raft_tpu.models.update import (Attention, BasicUpdateBlock,
+from raft_tpu.models.extractor import (BasicEncoder, ResNetEncoder,
+                                       SmallEncoder)
+from raft_tpu.models.layers import conv
+from raft_tpu.models.update import (Attention, BasicUpdateBlock, FlowHead,
                                     FusedCorrLookup, GMAUpdateBlock,
-                                    MaskHead, SmallUpdateBlock)
+                                    MaskHead, SEARAFTUpdateBlock,
+                                    SmallUpdateBlock)
 from raft_tpu.ops.corr import (
     QuantizedLevel,
     build_corr_pyramid,
@@ -57,7 +60,9 @@ from raft_tpu.ops.corr import (
     pool_fmap_pyramid,
 )
 from raft_tpu.ops.sampler import coords_grid, upflow8
-from raft_tpu.ops.upsample import (convex_upsample, convex_upsample_flat,
+from raft_tpu.ops.upsample import (convex_combine_flat, convex_upsample,
+                                   convex_upsample_data,
+                                   convex_upsample_flat,
                                    space_to_depth_flow)
 from raft_tpu.parallel.mesh import image_rows_split
 
@@ -103,6 +108,31 @@ def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
     if not cfg.global_motion:
         return 0
     return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
+
+
+def predictions(cfg: RAFTConfig, iters: int) -> int:
+    """Flow predictions a pair for ``iters`` refinement iterations: one an
+    iteration, and one more where the loop starts from a regressed first
+    flow (arch 'searaft')."""
+    return iters + 1 if cfg.regressed_first_flow else iters
+
+
+def batch_norm_calls(cfg: RAFTConfig) -> int:
+    """Encoder calls a forward pass that normalise with batch statistics
+    in training: the context encoder's one for 'full' and 'gma', none for
+    'small', and three for 'searaft' (the pair's context, and the feature
+    encoder once an image, each over its own batch)."""
+    return {"small": 0, "searaft": 3}.get(cfg.arch, 1)
+
+
+def _flow_head(cfg: RAFTConfig, name=None) -> FlowHead:
+    """Arch 'searaft': the 6-channel head (flow update, 2 mixture logits,
+    2 log-scales).  It is applied to the first hidden state before the
+    loop and to every iteration's in it, with one set of weights: the
+    module is built (named) where a program's top level is, and the loop
+    body applies an unnamed copy to the parameters handed in."""
+    return FlowHead(2 * cfg.hidden_dim, cfg.dtype, out_channels=6,
+                    name=name)
 
 
 def _remat_wrap(target, cfg):
@@ -161,8 +191,10 @@ class RefinementStep(nn.Module):
         dt = cfg.dtype
         net, coords1 = carry
         # attn: the (B, N, N) attention of arch 'gma', a loop invariant
-        # like the pyramid; None for the other architectures.
-        inp, coords0, corr_state, attn = inputs
+        # like the pyramid; None for the other architectures.  head: the
+        # flow head's parameters (arch 'searaft', see _flow_head), else
+        # None.
+        inp, coords0, corr_state, attn, head = inputs
 
         coords1 = jax.lax.stop_gradient(coords1)
 
@@ -239,6 +271,13 @@ class RefinementStep(nn.Module):
             corr = checkpoint_name(corr.astype(dt), "corr")
 
         flow = coords1 - coords0
+        if cfg.arch == "searaft":
+            net = SEARAFTUpdateBlock(cfg.hidden_dim, dt,
+                                     name="update_block")(
+                net, inp, corr, flow.astype(dt))
+            delta = _flow_head(cfg).apply({"params": head}, net)
+            coords1 = coords1 + delta[..., :2].astype(jnp.float32)
+            return (net, coords1), (net, coords1 - coords0, delta[..., 2:])
         fused_gru = cfg.resolved_fused_gru
         block_cls, extra = {
             "small": (SmallUpdateBlock, ()),
@@ -266,11 +305,34 @@ class UpsampleStep(nn.Module):
     config: RAFTConfig
 
     @nn.compact
-    def __call__(self, carry, net, flow):
+    def __call__(self, carry, net, flow, info=None):
+        """``info``: arch 'searaft' only, the 4 channels beside the flow,
+        upsampled with the flow's weights and returned beside it."""
         cfg = self.config
         mask = MaskHead(cfg.hidden_dim, cfg.dtype, name="mask_head")(net)
         flow_up = convex_upsample(flow, mask.astype(jnp.float32))
+        if info is not None:
+            return carry, (flow_up, convex_upsample_data(
+                info.astype(jnp.float32), mask.astype(jnp.float32)))
         return carry, flow_up
+
+
+def _fsum(x):
+    """Sums always accumulate fp32 (5.8M terms at training shapes — bf16
+    accumulation would lose the loss signal entirely)."""
+    return jnp.sum(x, axis=(1, 2, 3, 4), dtype=jnp.float32)
+
+
+def _epe_sums(vm, dx, dy):
+    """``[epe_sum, 1px_sum, 3px_sum, 5px_sum]`` a folded iteration over the
+    masked elements.  Metrics need no gradient; without stop_gradient the
+    sqrt's derivative at exactly-zero dx²+dy² injects inf·0 = NaN into the
+    remat'd backward even though the metric cotangents are zero."""
+    dx = jax.lax.stop_gradient(dx)
+    dy = jax.lax.stop_gradient(dy)
+    epe = jnp.sqrt(dx * dx + dy * dy)
+    return [_fsum(vm * epe), _fsum(vm * (epe < 1.0)),
+            _fsum(vm * (epe < 3.0)), _fsum(vm * (epe < 5.0))]
 
 
 class UpsampleLossStep(nn.Module):
@@ -295,7 +357,14 @@ class UpsampleLossStep(nn.Module):
     config: RAFTConfig
 
     @nn.compact
-    def __call__(self, carry, net, flow, gt128, vmask64):
+    def __call__(self, carry, net, flow, gt128, vmask64, info=None):
+        """``info`` (arch 'searaft'; ``(gB, H/8, W/8, 4)``: two mixture
+        logits, two raw log-scales): the flow and ``info`` are upsampled
+        with one set of weights and the term is the mixture-of-Laplace
+        likelihood (``train/loss.py mixture_nll``) in place of L1, float32
+        at full resolution, still in space-to-depth layout and still
+        reduced here.  Emits ``(g, 6)``: the five sums above with the
+        likelihood's sum first, and the count of elements it ran over."""
         cfg = self.config
         udt = jnp.dtype(cfg.resolved_upsample_dtype)
         B = gt128.shape[0]
@@ -304,6 +373,9 @@ class UpsampleLossStep(nn.Module):
         # Tagged so remat_policy='save_corr_upsample' can pin the logits
         # (no-op under the other policies / outside remat).
         mask = checkpoint_name(mask, "mask")
+        if info is not None:
+            return carry, self._mixture_sums(flow, info, mask, gt128,
+                                             vmask64, g, udt)
         if cfg.resolved_upsample_loss_kernel == "pallas":
             from raft_tpu.ops.pallas_upsample import \
                 pallas_upsample_loss_sums
@@ -330,26 +402,42 @@ class UpsampleLossStep(nn.Module):
         dx = out[..., :64] - gt128[None, ..., :64]
         dy = out[..., 64:] - gt128[None, ..., 64:]
         vm = vmask64[None]
-        # Sums always accumulate fp32 (5.8M terms at training shapes —
-        # bf16 accumulation would lose the loss signal entirely).
-        def _fsum(x):
-            return jnp.sum(x, axis=(1, 2, 3, 4), dtype=jnp.float32)
         l1 = _fsum(vm * (jnp.abs(dx) + jnp.abs(dy)))
-        # Metrics need no gradient; without stop_gradient the sqrt's
-        # derivative at exactly-zero dx²+dy² injects inf·0 = NaN into
-        # the remat'd backward even though the metric cotangents are
-        # zero.
-        dx = jax.lax.stop_gradient(dx)
-        dy = jax.lax.stop_gradient(dy)
-        epe = jnp.sqrt(dx * dx + dy * dy)
-        sums = jnp.stack([
-            l1,
-            _fsum(vm * epe),
-            _fsum(vm * (epe < 1.0)),
-            _fsum(vm * (epe < 3.0)),
-            _fsum(vm * (epe < 5.0)),
-        ], axis=-1)                                   # (g, 5)
+        sums = jnp.stack([l1, *_epe_sums(vm, dx, dy)], axis=-1)   # (g, 5)
         return carry, sums
+
+    def _mixture_sums(self, flow, info, mask, gt128, vmask64, g, udt):
+        from raft_tpu.train.loss import mixture_nll
+
+        cfg = self.config
+        if cfg.resolved_upsample_loss_kernel != "xla":
+            raise ValueError(
+                "upsample_loss_kernel='pallas' computes the L1 term; arch "
+                f"{cfg.arch!r} trains on a mixture likelihood: use 'xla'")
+        B = gt128.shape[0]
+        out = convex_combine_flat(
+            jnp.concatenate([8.0 * flow.astype(udt), info.astype(udt)],
+                            axis=-1), mask, compute_dtype=udt)
+        # float32 from here on, as the L1 compare above and for the same
+        # reason; (g, B, H, W, 6 * 64): flow x, y, logits, log-scales
+        out = out.astype(jnp.float32).reshape((g, B) + out.shape[1:])
+        ch = [out[..., k * 64:(k + 1) * 64] for k in range(5)]
+        dx = ch[0] - gt128[None, ..., :64]
+        dy = ch[1] - gt128[None, ..., 64:]
+        # Each flow channel under the pixel's one mixture, as two terms of
+        # the 64-lane layout: stacking them into one (2, g, B, ...) array
+        # read 1.7 % slower a step on the chip (62.59 against 63.68
+        # pairs/s/chip, PERF.md section 6 PR 32).
+        nll = [mixture_nll(jnp.abs(d), ch[2], ch[3], ch[4]) for d in (dx, dy)]
+        vm = vmask64[None]
+        keep = [jnp.isfinite(jax.lax.stop_gradient(t)) & (vm > 0.5)
+                for t in nll]
+        sums = [_fsum(jnp.where(keep[0], nll[0], 0.0)
+                      + jnp.where(keep[1], nll[1], 0.0)),
+                *_epe_sums(vm, dx, dy),
+                _fsum(keep[0].astype(jnp.float32)
+                      + keep[1].astype(jnp.float32))]
+        return jnp.stack(sums, axis=-1)               # (g, 6)
 
 
 def _make_encoders(cfg: RAFTConfig):
@@ -360,6 +448,14 @@ def _make_encoders(cfg: RAFTConfig):
     param tree cannot drift between them."""
     dt = cfg.dtype
     hdim, cdim = cfg.hidden_dim, cfg.context_dim
+    if cfg.arch == "searaft":
+        # SEA-RAFT (M): two ResNet-34 trunks of 256 channels out, then
+        # init_conv to [net, context] and the shared flow head
+        return (ResNetEncoder(256, dt, cfg.remat, name="fnet"),
+                ResNetEncoder(256, dt, cfg.remat, name="cnet"), None,
+                (conv(hdim + cdim, 3, 1, dt, name="init_conv",
+                      torch_default_init=True, in_features=256),
+                 _flow_head(cfg, name="flow_head")))
     if cfg.small:
         fnet = SmallEncoder(128, "instance", cfg.dropout, dt, name="fnet")
         cnet = SmallEncoder(hdim + cdim, "none", cfg.dropout, dt,
@@ -370,8 +466,8 @@ def _make_encoders(cfg: RAFTConfig):
                             name="cnet")
     if cfg.global_motion:
         # one head as wide as the context (GMA core/network.py)
-        return fnet, cnet, Attention(cdim, dt, name="att")
-    return fnet, cnet, None
+        return fnet, cnet, Attention(cdim, dt, name="att"), None
+    return fnet, cnet, None, None
 
 
 def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
@@ -408,41 +504,72 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
     raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
 
 
-def _encode_state(cfg: RAFTConfig, fnet, cnet, att, image1, image2, train,
-                  freeze_bn, flow_init=None):
+def _encode_state(cfg: RAFTConfig, fnet, cnet, att, start, image1, image2,
+                  train, freeze_bn, flow_init=None):
     """The pre-scan half of the forward pass: normalize → shared-weight
     two-frame encode → correlation state → context split (→ attention,
     arch 'gma') → initial coordinate grids.  One body shared by
     :meth:`RAFT.__call__` and the iteration-granular serving split
     (:class:`RAFTEncode`), so the slot-mode parity pin (bit-identical to
     request mode) is structural rather than a copy that has to be kept in
-    sync."""
+    sync.
+
+    ``start`` (arch 'searaft', else None): ``(init_conv, flow head)``.
+    There the feature encoder is called once an image (batch norm: a
+    call's statistics are its own), the context encoder reads both
+    images, ``net`` and ``inp`` come from ``init_conv`` with no ``tanh``
+    or ReLU, and ``coords1`` starts at the grid plus the flow the head
+    regresses from ``net``.  Returns a seventh value: None, or that
+    first prediction's ``info`` (B, H/8, W/8, 4)."""
     dt = cfg.dtype
     hdim = cfg.hidden_dim
 
     image1 = 2.0 * (image1.astype(jnp.float32) / 255.0) - 1.0
     image2 = 2.0 * (image2.astype(jnp.float32) / 255.0) - 1.0
-
-    # Shared-weight two-frame encode: stack on batch.
-    both = jnp.concatenate([image1, image2], axis=0)
-    fmaps = fnet(both.astype(dt), train, freeze_bn)
     B = image1.shape[0]
-    fmap1 = fmaps[:B].astype(jnp.float32)
-    fmap2 = fmaps[B:].astype(jnp.float32)
+
+    if start is not None:
+        # fnet has batch norm: one call an image, each over its own batch
+        fmap1 = fnet(image1.astype(dt), train, freeze_bn).astype(
+            jnp.float32)
+        fmap2 = fnet(image2.astype(dt), train, freeze_bn).astype(
+            jnp.float32)
+    else:
+        # Shared-weight two-frame encode: stack on batch.
+        both = jnp.concatenate([image1, image2], axis=0)
+        fmaps = fnet(both.astype(dt), train, freeze_bn)
+        fmap1 = fmaps[:B].astype(jnp.float32)
+        fmap2 = fmaps[B:].astype(jnp.float32)
 
     corr_state = _build_corr_state(cfg, fmap1, fmap2)
+
+    _, H8, W8, _ = fmap1.shape
+    if start is not None:
+        if flow_init is not None:
+            raise ValueError(
+                f"arch {cfg.arch!r} regresses its own first flow from the "
+                "pair's context; it takes no flow_init (warm start)")
+        init_conv, head = start
+        ctx = cnet(jnp.concatenate([image1, image2], axis=-1).astype(dt),
+                   train, freeze_bn)
+        with jax.named_scope("searaft_init"):
+            ctx = init_conv(ctx)
+            net, inp = ctx[..., :hdim], ctx[..., hdim:]
+            first = head(net)
+        coords0 = coords_grid(B, H8, W8)
+        coords1 = coords0 + first[..., :2].astype(jnp.float32)
+        return net, inp, coords0, coords1, corr_state, None, first[..., 2:]
 
     ctx = cnet(image1.astype(dt), train, freeze_bn)
     net = jnp.tanh(ctx[..., :hdim])
     inp = nn.relu(ctx[..., hdim:])
 
-    _, H8, W8, _ = fmap1.shape
     coords0 = coords_grid(B, H8, W8)
     coords1 = coords_grid(B, H8, W8)
     if flow_init is not None:
         coords1 = coords1 + flow_init
     attn = att(inp) if att is not None else None
-    return net, inp, coords0, coords1, corr_state, attn
+    return net, inp, coords0, coords1, corr_state, attn, None
 
 
 class RAFT(nn.Module):
@@ -461,17 +588,33 @@ class RAFT(nn.Module):
         space-to-depth layout; the full-res per-iteration flows never
         reach HBM) and returns ``(per_iter_losses (iters,), metrics
         dict)`` instead of stacked flows (the γ-weighting is applied by
-        the caller)."""
+        the caller).
+
+        Arch 'searaft' makes ``iters + 1`` predictions (the regressed
+        first flow, then one an iteration), each a flow and 4 channels of
+        ``info`` (2 mixture logits, 2 raw log-scales): the per-prediction
+        terms are its mixture likelihood and come ``(iters + 1,)``; the
+        stacked call returns ``{"final", "flow", "info"}`` as the
+        published model does; ``test_mode`` returns ``(flow_low,
+        flow_up)`` like every architecture (``info`` is read off the
+        stacked call: no served reply carries it, docs/SERVING.md
+        "--arch searaft")."""
         cfg = self.config
 
-        fnet, cnet, att = _make_encoders(cfg)
-        net, inp, coords0, coords1, corr_state, attn = _encode_state(
-            cfg, fnet, cnet, att, image1, image2, train, freeze_bn,
-            flow_init)
+        fnet, cnet, att, start = _make_encoders(cfg)
+        (net, inp, coords0, coords1, corr_state, attn,
+         info0) = _encode_state(cfg, fnet, cnet, att, start, image1, image2,
+                                train, freeze_bn, flow_init)
         B = image1.shape[0]
+        head = None if start is None else start[1].variables["params"]
 
         if (loss_targets is not None and not cfg.small and not test_mode
                 and cfg.fuse_upsample_in_scan):
+            if cfg.regressed_first_flow:
+                raise ValueError(
+                    f"fuse_upsample_in_scan is not built for arch "
+                    f"{cfg.arch!r} (its first prediction is made before "
+                    "the scan); leave it off")
             return self._fused_inscan_losses(cfg, iters, net, inp, coords0,
                                              coords1, corr_state, attn,
                                              loss_targets)
@@ -487,8 +630,16 @@ class RAFT(nn.Module):
             unroll=cfg.scan_unroll,
         )(cfg, name="refine")
 
-        (net, coords1), (nets, flows) = scan(
-            (net, coords1), (inp, coords0, corr_state, attn))
+        first = (net, coords1 - coords0) if cfg.regressed_first_flow else ()
+        (net, coords1), outs = scan(
+            (net, coords1), (inp, coords0, corr_state, attn, head))
+        nets, flows = outs[:2]
+        infos = outs[2] if cfg.mixture_head else None
+        if cfg.regressed_first_flow:
+            # prediction 0 stands in front of the loop's
+            nets = jnp.concatenate([first[0][None], nets])
+            flows = jnp.concatenate([first[1][None], flows])
+            infos = jnp.concatenate([info0[None], infos])
 
         # --- Upsample stage (outside the heavy scan) ---
         if cfg.small:
@@ -513,7 +664,7 @@ class RAFT(nn.Module):
         # Rematerialized (cfg.remat_upsample): the backward keeps only the
         # stacked (iters, B, H/8, W/8, hdim) GRU states and recomputes two
         # convs + a softmax per group.
-        I = iters
+        I = predictions(cfg, iters)
         # Largest divisor of I that is <= upsample_group (clamped to
         # [1, I] so misconfigured knobs degrade instead of raising a
         # bare StopIteration from inside the trace).
@@ -522,6 +673,12 @@ class RAFT(nn.Module):
                  if I % g == 0)
         nets_r = nets.reshape((I // g, g * B) + nets.shape[2:])
         flows_r = flows.reshape((I // g, g * B) + flows.shape[2:])
+        # arch 'searaft': info rides beside the flow as one more scanned
+        # input of either upsample scan
+        more, more_axes = (), ()
+        if infos is not None:
+            more = (infos.reshape((I // g, g * B) + infos.shape[2:]),)
+            more_axes = (0,)
 
         if loss_targets is not None:
             # Sequence loss fused into the upsample scan: the full-res
@@ -539,13 +696,14 @@ class RAFT(nn.Module):
                 up_step,
                 variable_broadcast="params",
                 split_rngs={"params": False, "dropout": True},
-                in_axes=(0, 0, nn.broadcast, nn.broadcast),
+                in_axes=(0, 0, nn.broadcast, nn.broadcast) + more_axes,
                 out_axes=0,
                 length=I // g,
                 unroll=max(1, min(cfg.upsample_unroll, I // g)),
             )(cfg, name="upsampler")
-            _, sums = up_scan(None, nets_r, flows_r, gt128, vmask64)
-            return self._loss_outputs(sums.reshape(I, 5), gt128, vmask64, B)
+            _, sums = up_scan(None, nets_r, flows_r, gt128, vmask64, *more)
+            return self._loss_outputs(sums.reshape(I, sums.shape[-1]),
+                                      gt128, vmask64, B)
 
         up_step = UpsampleStep
         if cfg.remat_upsample:
@@ -559,12 +717,16 @@ class RAFT(nn.Module):
             length=I // g,
             unroll=max(1, min(cfg.upsample_unroll, I // g)),
         )(cfg, name="upsampler")
-        _, flow_ups = up_scan(None, nets_r, flows_r)
+        _, flow_ups = up_scan(None, nets_r, flows_r, *more)
+        if infos is not None:
+            flow_ups, info_ups = (x.reshape((I, B) + x.shape[2:])
+                                  for x in flow_ups)
+            return {"final": flow_ups[-1], "flow": flow_ups,
+                    "info": info_ups}
         flow_ups = flow_ups.reshape((I, B) + flow_ups.shape[2:])
         return flow_ups
 
-    @staticmethod
-    def _loss_outputs(sums, gt128, vmask64, B):
+    def _loss_outputs(self, sums, gt128, vmask64, B):
         """Normalize the per-iteration ``(iters, 5)`` partial sums into
         per-iteration mean losses + final-iteration metrics (reference
         sequence_loss semantics, train.py:47-72).  The per-iteration EPE
@@ -575,7 +737,12 @@ class RAFT(nn.Module):
         _, H8s, W8s, _ = gt128.shape
         n_all = B * H8s * W8s * 128              # loss mean incl. zeroed
         n_valid = jnp.maximum(jnp.sum(vmask64), 1.0)
-        per_iter = sums[:, 0] / n_all
+        if self.config.mixture_head:
+            # the mixture likelihood (``(iters + 1, 6)`` sums): a mean
+            # over the elements it ran over, counted in the sixth column
+            per_iter = sums[:, 0] / jnp.maximum(sums[:, 5], 1.0)
+        else:
+            per_iter = sums[:, 0] / n_all
         metrics = {"epe": sums[-1, 1] / n_valid,
                    "1px": sums[-1, 2] / n_valid,
                    "3px": sums[-1, 3] / n_valid,
@@ -603,7 +770,7 @@ class RAFT(nn.Module):
 
         def body(mdl, carry, _):
             carry, (net_i, flow_i) = RefinementStep(cfg, name="refine")(
-                carry, (inp, coords0, corr_state, attn))
+                carry, (inp, coords0, corr_state, attn, None))
             _, sums = UpsampleLossStep(cfg, name="upsampler")(
                 None, net_i, flow_i, gt128, vmask64)
             return carry, sums[0]
@@ -690,9 +857,22 @@ class RAFTEncode(nn.Module):
     @nn.compact
     def __call__(self, image1, image2,
                  flow_init: Optional[jax.Array] = None):
-        fnet, cnet, att = _make_encoders(self.config)
-        return _encode_state(self.config, fnet, cnet, att, image1, image2,
-                             False, False, flow_init)
+        fnet, cnet, att, start = _make_encoders(self.config)
+        # the first prediction's info (arch 'searaft') is not served
+        return _encode_state(self.config, fnet, cnet, att, start, image1,
+                             image2, False, False, flow_init)[:6]
+
+
+def refuse_frame_cache(cfg: RAFTConfig) -> None:
+    """Streaming caches a frame's feature map AND its context for the next
+    pair.  Where the context is a function of both frames there is no
+    per-frame context to cache: refused by name, not served wrong."""
+    if cfg.context_reads_pair:
+        raise ValueError(
+            f"arch {cfg.arch!r} computes its context from both frames of "
+            "a pair, so a streaming session has no per-frame context to "
+            "carry to the next pair: streaming sessions are not "
+            f"available for --arch {cfg.arch}; send whole pairs")
 
 
 class RAFTFrameFeatures(nn.Module):
@@ -713,7 +893,8 @@ class RAFTFrameFeatures(nn.Module):
     @nn.compact
     def __call__(self, image):
         cfg = self.config
-        fnet, cnet, _ = _make_encoders(cfg)
+        refuse_frame_cache(cfg)
+        fnet, cnet, _, _ = _make_encoders(cfg)
         image = 2.0 * (image.astype(jnp.float32) / 255.0) - 1.0
         fmap = fnet(image.astype(cfg.dtype), False, False)
         ctx = cnet(image.astype(cfg.dtype), False, False)
@@ -743,7 +924,8 @@ class RAFTEncodeWarm(nn.Module):
     def __call__(self, image2, fmap1, ctx1, flow_init):
         cfg = self.config
         hdim = cfg.hidden_dim
-        fnet, cnet, att = _make_encoders(cfg)
+        refuse_frame_cache(cfg)
+        fnet, cnet, att, _ = _make_encoders(cfg)
         image2 = 2.0 * (image2.astype(jnp.float32) / 255.0) - 1.0
         fmap2 = fnet(image2.astype(cfg.dtype), False, False)
         ctx2 = cnet(image2.astype(cfg.dtype), False, False)
@@ -774,9 +956,13 @@ class RAFTIterStep(nn.Module):
 
     @nn.compact
     def __call__(self, net, coords1, inp, coords0, corr_state, attn=None):
-        step = _remat_wrap(RefinementStep, self.config)
-        (net, coords1), _ = step(self.config, name="refine")(
-            (net, coords1), (inp, coords0, corr_state, attn))
+        cfg = self.config
+        head = None
+        if cfg.mixture_head:
+            head = _flow_head(cfg, name="flow_head").variables["params"]
+        step = _remat_wrap(RefinementStep, cfg)
+        (net, coords1), _ = step(cfg, name="refine")(
+            (net, coords1), (inp, coords0, corr_state, attn, head))
         return net, coords1
 
 

@@ -20,6 +20,12 @@ Parity notes:
   the context features, once, before the loop; :class:`GMAUpdateBlock`
   is :class:`BasicUpdateBlock` with :class:`Aggregate` between the motion
   encoder and a GRU whose input is 384 wide.
+- ``arch='searaft'`` (Wang et al., ECCV 2024; PAPERS.md):
+  :class:`SEARAFTUpdateBlock` is the same motion encoder followed by two
+  :class:`ConvNeXtBlock` in place of the GRU; it returns the hidden state
+  alone, because the flow head (:class:`FlowHead` with 6 channels: flow,
+  mixture logits, log-scales) is also applied before the loop and so
+  lives beside the block, not in it (``models/raft.py``).
 """
 
 from __future__ import annotations
@@ -106,12 +112,16 @@ def _fused_corr_encode(fused: "FusedCorrLookup", kernel, bias, features,
 class FlowHead(nn.Module):
     hidden_dim: int = 256
     dtype: Any = jnp.float32
+    # 2: a flow update; 6 (arch 'searaft'): a flow update, two mixture
+    # logits and two log-scales
+    out_channels: int = 2
 
     @nn.compact
     def __call__(self, x):
         cin = x.shape[-1]
         x = nn.relu(_tconv(self.hidden_dim, 3, cin, self.dtype, "conv1")(x))
-        return _tconv(2, 3, self.hidden_dim, self.dtype, "conv2")(x)
+        return _tconv(self.out_channels, 3, self.hidden_dim, self.dtype,
+                      "conv2")(x)
 
 
 class ConvGRU(nn.Module):
@@ -329,6 +339,59 @@ class GMAUpdateBlock(nn.Module):
                          fused=self.fused_gru, name="gru")(net, x)
         delta_flow = FlowHead(256, self.dtype, name="flow_head")(net)
         return net, delta_flow
+
+
+class ConvNeXtBlock(nn.Module):
+    """SEA-RAFT's ``ConvNextBlock`` (core/layer.py):
+    ``final(u + gamma * W2 gelu(W1 LN(dw7x7(u))))`` for ``u`` of ``C``
+    channels -- a depthwise 7x7 convolution with bias, a LayerNorm over
+    the channels (eps 1e-6, statistics in float32), ``W1: C -> 4 out``
+    and ``W2: 4 out -> C`` with biases (``nn.Linear`` there, 1x1
+    convolutions here: the same product, and a leaf of rank 4 like every
+    other kernel), the exact (erf) GELU, a learned vector ``gamma`` that
+    the paper initialises to 1e-6, and ``final`` a 1x1 convolution
+    ``C -> out``."""
+
+    out_dim: int = 128
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        dt, C = self.dtype, u.shape[-1]
+        with jax.named_scope("convnext_block"):
+            x = nn.Conv(C, (7, 7), padding=[(3, 3), (3, 3)],
+                        feature_group_count=C, dtype=dt,
+                        kernel_init=_torch_default_uniform,
+                        bias_init=torch_bias_init(49), name="dwconv")(u)
+            x = nn.LayerNorm(epsilon=1e-6, dtype=dt, name="norm")(x)
+            x = _tconv(4 * self.out_dim, 1, C, dt, "pwconv1")(x)
+            x = nn.gelu(x, approximate=False)
+            x = _tconv(C, 1, 4 * self.out_dim, dt, "pwconv2")(x)
+            gamma = self.param("gamma", nn.initializers.constant(1e-6),
+                               (C,))
+            return _tconv(self.out_dim, 1, C, dt, "final")(
+                u + gamma.astype(dt) * x)
+
+
+class SEARAFTUpdateBlock(nn.Module):
+    """SEA-RAFT's ``BasicUpdateBlock`` (core/update.py): RAFT-full's
+    motion encoder, then ``net <- ConvNeXt(cat[net, inp, motion])`` twice
+    with separate weights; no gates, and no flow head (see the module
+    docstring)."""
+
+    hidden_dim: int = 128
+    dtype: Any = jnp.float32
+    num_blocks: int = 2
+
+    @nn.compact
+    def __call__(self, net, inp, corr, flow):
+        motion = BasicMotionEncoder(self.dtype, name="encoder")(flow, corr)
+        x = jnp.concatenate([inp, motion], axis=-1)
+        for i in range(self.num_blocks):
+            net = ConvNeXtBlock(self.hidden_dim, self.dtype,
+                                name=f"refine_{i}")(
+                jnp.concatenate([net, x], axis=-1))
+        return net
 
 
 class MaskHead(nn.Module):

@@ -30,6 +30,25 @@ def _extract_3x3_patches(x: jax.Array) -> jax.Array:
     return jnp.stack(taps, axis=3)
 
 
+def convex_upsample_data(x: jax.Array, mask: jax.Array, factor: int = 8,
+                         scale: float = 1) -> jax.Array:
+    """Upsample ``(B, H, W, C)`` coarse values to ``(B, 8H, 8W, C)``: each
+    fine pixel a convex combination (softmax of ``mask`` over the 9 taps)
+    of the 3x3 coarse neighbours of ``scale * x``.  With ``scale == 1``
+    this is what SEA-RAFT's ``upsample_data`` does to the 4 channels of
+    ``info`` beside a flow (the flow's weights, no factor on the values).
+
+    ``mask``: ``(B, H, W, 9 * factor * factor)`` unnormalized weights."""
+    B, H, W, C = x.shape
+    f = factor
+    m = mask.reshape(B, H, W, 9, f, f)
+    m = jax.nn.softmax(m, axis=3)
+
+    patches = _extract_3x3_patches(x if scale == 1 else scale * x)
+    up = jnp.einsum("bhwkpq,bhwkc->bhpwqc", m, patches.astype(m.dtype))
+    return up.reshape(B, f * H, f * W, C)
+
+
 def convex_upsample(flow: jax.Array, mask: jax.Array,
                     factor: int = 8) -> jax.Array:
     """Upsample ``(B, H, W, 2)`` flow to ``(B, 8H, 8W, 2)``.
@@ -39,21 +58,16 @@ def convex_upsample(flow: jax.Array, mask: jax.Array,
         reference ``raft.py:77``).
       mask: ``(B, H, W, 9 * factor * factor)`` unnormalized weights.
     """
-    B, H, W, _ = flow.shape
-    f = factor
-    m = mask.reshape(B, H, W, 9, f, f)
-    m = jax.nn.softmax(m, axis=3)
-
-    patches = _extract_3x3_patches(factor * flow)  # (B, H, W, 9, 2)
-    up = jnp.einsum("bhwkpq,bhwkc->bhpwqc", m, patches.astype(m.dtype))
-    return up.reshape(B, f * H, f * W, 2)
+    return convex_upsample_data(flow, mask, factor, scale=factor)
 
 
-def convex_upsample_flat(flow: jax.Array, mask: jax.Array,
-                         factor: int = 8,
-                         compute_dtype=jnp.float32) -> jax.Array:
-    """:func:`convex_upsample` in space-to-depth layout — the TPU-native
-    training formulation.
+def convex_combine_flat(x: jax.Array, mask: jax.Array, factor: int = 8,
+                        compute_dtype=jnp.float32,
+                        scale: float = 1) -> jax.Array:
+    """:func:`convex_upsample_data` in space-to-depth layout — the
+    TPU-native training formulation — for ``C`` channels that share one
+    set of weights: ``x`` is ``(B, H, W, C)`` coarse values as they are to
+    be combined, times ``scale`` (a flow: ``factor``).
 
     The 6-D ``(B, H, W, 9, 8, 8)`` shapes of the direct einsum put 2- and
     8-wide trailing dims in the lanes, which on TPU lowers to tiny-tile
@@ -62,14 +76,14 @@ def convex_upsample_flat(flow: jax.Array, mask: jax.Array,
     stays a channels-last 2-D tile: the softmax over the 9 taps uses
     contiguous 64-channel slices (channel order is ``k*64 + p*8 + q``,
     the converter contract), and the convex combination is 9 broadcast
-    multiply-adds.
+    multiply-adds a channel; the weights are computed once for all.
 
-    Returns ``(B, H, W, 2 * factor**2)`` with channel order ``(c, p, q)``
-    — ``out[..., c*ff + p*f + q] == convex_upsample(...)[..., f*h+p,
+    Returns ``(B, H, W, C * factor**2)`` with channel order ``(c, p, q)``
+    — ``out[..., c*ff + p*f + q] == convex_upsample_data(...)[..., f*h+p,
     f*w+q, c]`` (see :func:`space_to_depth_flow` for the matching ground
     -truth layout; :func:`depth_to_space_flow` restores pixel space).
     """
-    B, H, W, _ = flow.shape
+    B, H, W, C = x.shape
     ff = factor * factor
     # compute_dtype=bfloat16 halves the HBM traffic of the 9-tap
     # exp/FMA/divide chain (the softmax weights are in [0,1] and the
@@ -89,20 +103,30 @@ def convex_upsample_flat(flow: jax.Array, mask: jax.Array,
     e = [jnp.exp(t - gmax) for t in taps]
     denom = sum(e)
 
-    f8 = jnp.pad(factor * flow.astype(compute_dtype),
+    x = x.astype(compute_dtype)
+    xp = jnp.pad(x if scale == 1 else scale * x,
                  ((0, 0), (1, 1), (1, 1), (0, 0)))
-    outx = 0.0
-    outy = 0.0
+    outs = [0.0] * C
     for k in range(9):
         di, dj = k // 3, k % 3   # unfold tap order (row-major)
-        fk = f8[:, di:di + H, dj:dj + W, :]
-        outx += e[k] * fk[..., 0:1]
-        outy += e[k] * fk[..., 1:2]
-    # One reciprocal + two muls instead of two 64-channel divides (TPU
+        xk = xp[:, di:di + H, dj:dj + W, :]
+        for c in range(C):
+            outs[c] += e[k] * xk[..., c:c + 1]
+    # One reciprocal + C muls instead of C 64-channel divides (TPU
     # divide is a multi-pass VPU op; profiled ~4 ms/step across the 12
     # iterations' forward+backward).
     inv = 1.0 / denom
-    return jnp.concatenate([outx * inv, outy * inv], axis=-1)
+    return jnp.concatenate([o * inv for o in outs], axis=-1)
+
+
+def convex_upsample_flat(flow: jax.Array, mask: jax.Array,
+                         factor: int = 8,
+                         compute_dtype=jnp.float32) -> jax.Array:
+    """:func:`convex_upsample` in space-to-depth layout
+    (:func:`convex_combine_flat` of ``factor * flow``): returns
+    ``(B, H, W, 2 * factor**2)``, channel order ``(c, p, q)``."""
+    return convex_combine_flat(flow, mask, factor, compute_dtype,
+                               scale=factor)
 
 
 def space_to_depth_flow(x: jax.Array, factor: int = 8) -> jax.Array:

@@ -545,6 +545,12 @@ class InferenceEngine:
         # them in lockstep so slot mode is bit-identical to it by
         # construction (the parity pin, tests/test_serve_slots.py).
         self._model_cfg = model.config
+        # flow predictions a request's programs make (iters, or iters + 1
+        # where ``enc`` regresses a first flow): quoted by the compile
+        # ring's ``program`` records
+        from raft_tpu.models.raft import predictions
+
+        self._predictions = predictions(self._model_cfg, cfg.iters)
         self._slots_mod = slots_mod
         self._encode_jit = jax.jit(slots_mod.make_encode_fn(
             self._model_cfg))
@@ -1048,6 +1054,12 @@ class InferenceEngine:
             raise ValueError(
                 "streaming sessions require batching='slot' (a session "
                 "is a pinned lane in the slot pool)")
+        if self._model_cfg.context_reads_pair:
+            # a session carries a frame's context to the next pair; this
+            # model has none a frame (models/raft.py says it by name)
+            from raft_tpu.models.raft import refuse_frame_cache
+
+            refuse_frame_cache(self._model_cfg)
         if iters is not None and int(iters) < 1:
             raise ValueError(f"iters must be >= 1, got {iters}")
         if ttl_s is not None and float(ttl_s) <= 0:
@@ -1404,6 +1416,9 @@ class InferenceEngine:
         # attention matrix each (bucket, lanes) state holds on the
         # device beside its pyramid.
         out["model"] = self._model_cfg.arch
+        # whether ``enc`` regresses a first flow (arch 'searaft': the
+        # state's coords1 then starts off the grid) or starts from zero
+        out["first_flow"] = self._model_cfg.regressed_first_flow
         out["attn_bytes"] = {
             f"{hw[0]}x{hw[1]}/b{bs}": int(p.template["attn"].nbytes)
             for (hw, bs), p in sorted(dict(self._programs).items())
@@ -1609,7 +1624,8 @@ class InferenceEngine:
             self._lookup[f"{H}x{W}/b{lanes}"] = lookup
             stages.note("compile", "program", built_s,
                         name=f"{H}x{W}/b{lanes}/iter", imported=imported,
-                        lookup=lookup, model=self._model_cfg.arch)
+                        lookup=lookup, model=self._model_cfg.arch,
+                        predictions=self._predictions)
             # Stamp compile-time cost under the executables' own ledger
             # keys — pure host metadata off the Compiled objects (works
             # for AOT-imported executables too; never runs the program).

@@ -33,7 +33,8 @@ import numpy as np
 from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
-from raft_tpu.models.raft import RAFT, attention_bytes
+from raft_tpu.models.raft import (RAFT, attention_bytes, batch_norm_calls,
+                                  predictions)
 from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
@@ -282,6 +283,16 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         "raft_attention_bytes_total",
         "bytes of global-motion attention matrix built and held "
         "through the refinement loop (arch gma), summed over units")
+    # What the architecture makes of a step: flow predictions a pair
+    # (iters, or iters + 1 where the loop starts from a regressed flow)
+    # and encoder calls that normalise with batch statistics (3 for arch
+    # 'searaft', 1 for 'full' and 'gma', none where batch norm is frozen).
+    n_pred = predictions(model_cfg, cfg.iters)
+    bn_calls = 0 if cfg.freeze_bn else batch_norm_calls(model_cfg)
+    bn_counter = telem.registry.counter(
+        "raft_batch_norm_calls_total",
+        "encoder calls that normalised with their own batch statistics, "
+        "summed over train steps")
     first_dispatched = False
     run_step, compiled = step_fn, None
     try:
@@ -375,9 +386,12 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 logger.push(step - 1, metrics)
             rec = stages.end("train", registry=telem.registry,
                              step=step - 1, model=model_cfg.arch,
-                             attn_bytes=attn_bytes)
+                             attn_bytes=attn_bytes, predictions=n_pred,
+                             bn_calls=bn_calls)
             if attn_bytes:
                 attn_counter.inc(attn_bytes, loop="train")
+            if bn_calls:
+                bn_counter.inc(bn_calls, loop="train")
             # step_time_s covers queue wait + dispatch.  Dispatch is
             # async, so once the pipeline fills this converges to the
             # device step time without ever forcing a transfer.

@@ -25,7 +25,7 @@ from raft_tpu.obs.health import tree_all_finite, tree_select
 from raft_tpu.parallel.mesh import (batch_sharding, data_parallel_kernels,
                                     replicated_sharding,
                                     spatial_batch_sharding)
-from raft_tpu.train.loss import sequence_loss
+from raft_tpu.train.loss import mixture_sequence_loss, sequence_loss
 from raft_tpu.train.state import TrainState
 
 
@@ -71,11 +71,20 @@ def make_loss_fn(model: RAFT, cfg: TrainConfig) -> Callable:
                           **kwargs)
         out, new_vars = out if mutable else (out, {})
         if cfg.fused_loss:
+            # One term a prediction, the architecture's own (L1, or arch
+            # 'searaft''s mixture likelihood), and as many as the model
+            # made: ``iters``, or ``iters + 1`` where the loop starts
+            # from a regressed flow.
             per_iter, metrics = out
-            i = jnp.arange(cfg.iters, dtype=per_iter.dtype)
-            weights = cfg.gamma ** (cfg.iters - i - 1.0)
+            n = per_iter.shape[0]
+            i = jnp.arange(n, dtype=per_iter.dtype)
+            weights = cfg.gamma ** (n - i - 1.0)
             loss = jnp.sum(weights * per_iter)
             metrics = dict(metrics, loss_iter=per_iter)
+        elif model.config.mixture_head:
+            loss, metrics = mixture_sequence_loss(
+                out["flow"], out["info"], batch["flow"], batch["valid"],
+                gamma=cfg.gamma, max_flow=cfg.max_flow)
         else:
             loss, metrics = sequence_loss(
                 out, batch["flow"], batch["valid"],
