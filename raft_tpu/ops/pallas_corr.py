@@ -523,12 +523,15 @@ def _auto_interpret() -> bool:
 # The XLA window-sampling einsums (raft_tpu.ops.corr._sample_windows) are
 # batched (K, Hl) x (Hl, Wl) mat-muls per query — M=9 streaming rows and a
 # 46-of-128 contraction leave the MXU mostly idle.  Here the same math runs
-# on the VPU with queries in the sublane dim: for each image row y, each of
-# the K vertical taps accumulates ``wy_j(y) * row_y`` as one (BQ, Wl)
-# fused-multiply-add, then the K horizontal taps contract x with a lane
-# reduction — both interpolation stages fused in VMEM, the (BQ, K, Wl)
-# intermediate never touches HBM.  ~10x faster than the einsum pair in
-# isolation on v5e.
+# on the VPU with queries in the lanes: for each image row y, each of
+# the K vertical taps accumulates ``wy_j(y) * row_y`` as one (Wl, BQ)
+# fused-multiply-add, then the K horizontal taps contract x with a
+# sublane reduction — both interpolation stages fused in VMEM, the
+# (K, Wl, BQ) intermediate never touches HBM (the forward a differentiated
+# call runs, and the transpose below).  A call no gradient is asked of
+# runs a forward that gathers instead (``_pyr_fwd_level_rolled``):
+# 0.21-0.27 ms an iteration at 55x128 on a v5e against the einsum pair's
+# 1.5 (PERF.md section 6, PR 27 and PR 33).
 #
 # The backward is the exact transpose, and is race-free by construction:
 # each query owns its correlation row, so ``dcorr`` blocks never overlap
@@ -543,12 +546,12 @@ _ROW_TILE = 8
 # against Mosaic's own accounting for a v5e at a 136x240 bf16 map
 # (``tests/test_chip_compile.py``): 71.5 MiB estimated (block 384)
 # compiles; 95.4 and 119 MiB (block 512, 640) are refused "in memory
-# space vmem" by the rolled forward, whose (k, wl, BQ) expressions take
-# room of their own (the unrolled forward and the backward still take
-# 95.4, and fp32 at block 256, 89.6).  So the estimate follows what
-# Mosaic allocates, and the budget (the figure the on-demand backward
-# already plans with) is the largest reading known to compile plus a
-# tenth.
+# space vmem" by the rolled forward, whose window rows (``3k + 1`` of
+# them, fp32) take room the estimate counts a quarter of (the unrolled
+# forward and the backward still take 95.4, and fp32 at block 256,
+# 89.6).  So the estimate follows what Mosaic allocates, and the budget
+# (the figure the on-demand backward already plans with) is the largest
+# reading known to compile plus a tenth.
 _PYR_LOOKUP_BUDGET = _FUSED_BWD_BUDGET
 
 
@@ -558,7 +561,10 @@ def pyramid_lookup_vmem_bytes(h8: int, w8: int, levels: int, radius: int,
     ``(H/8, W/8)`` map: every level's ``(hl, wl, block_q)`` block,
     double-buffered by the pipeline (level 0 dominates: 55x128 bf16 is
     2 x 1.8 MB, 136x240 2 x 8.4 MB), the ``(k*wl, block_q)`` fp32 tap
-    accumulators, and the tap and coordinate blocks.  ``wl`` counts as
+    accumulators (the rolled forward holds ``3k + 1`` window rows of up
+    to 24 columns more in their place: 22.6 MiB where this counts 6.0 at
+    136x240 and block 384, which the 100 MiB limit still takes above the
+    budget's 78), and the tap and coordinate blocks.  ``wl`` counts as
     the sublane tile it pads to.  The backward's level-0 call writes one
     block of the same shape where the forward reads one, so one figure
     covers both directions."""
@@ -586,17 +592,77 @@ def pyramid_lookup_path(platform: str, h8: int, w8: int, *, levels: int,
     split over devices.  Mosaic where it can run -- on a TPU, with the
     kernel's per-block residency inside its VMEM budget, whole images on
     each device -- because where both were measured it is the faster
-    one (55x128 rows, radius 4 and 3: 0.30 / 0.24 ms an iteration where
+    one (55x128 rows, radius 4 and 3: 0.21-0.27 / 0.21 ms an iteration
+    since PR 33, from the 0.20 its pipeline takes to fetch the pyramid
+    up with the spread of a block's windows, 0.35 / 0.24 before, where
     XLA's eight batched M=9 mat-muls and their relayouts take 1.5 / 1.1;
     the train step has run it at 46x62 since before the benchmark:
-    PERF.md sections 5 and 6, PR 27); XLA everywhere else: off TPU the
-    kernel only runs in the interpreter, a block over the budget does
-    not compile, and GSPMD cannot partition a Mosaic call over rows."""
+    PERF.md sections 5 and 6, PR 27 and PR 33); XLA everywhere else: off
+    TPU the kernel only runs in the interpreter, a block over the budget
+    does not compile, and GSPMD cannot partition a Mosaic call over
+    rows."""
     if platform != "tpu" or rows_split:
         return "xla"
     fits = pyramid_lookup_vmem_bytes(
         h8, w8, levels, radius, block_q, storage_bytes) <= _PYR_LOOKUP_BUDGET
     return "mosaic" if fits else "xla"
+
+
+def _window_span(cy, hl: int, r: int, axis=None):
+    """Which rows the windows of a set of queries reach at one level.
+
+    ``cy``: window centres in the level's own rows, the block's queries
+    along ``axis`` (the whole array when ``None``: the kernel hands its
+    ``(1, BQ)`` block).  A window is rows ``y0 - r .. y0 + r + 1`` with
+    ``y0 = floor(cy)`` and touches the map iff ``-r-1 <= y0 <= hl+r-1``;
+    a query whose window does not (padded queries sit at -1e6) is not
+    ``live`` and is left out of the bounds.  Returns ``(y0, live, b,
+    top)``: the least and the greatest ``y0`` of the live queries, as
+    floats; with none, ``top < b``."""
+    y0 = jnp.floor(cy)
+    live = jnp.logical_and(y0 >= -(r + 1.0), y0 <= hl + (r - 1.0))
+    b = jnp.min(jnp.where(live, y0, hl + float(r)), axis=axis)
+    top = jnp.max(jnp.where(live, y0, -(r + 2.0)), axis=axis)
+    return y0, live, b, top
+
+
+def lookup_reach(coords, shape, levels: int, radius: int,
+                 block_q: int = 128):
+    """What the rolled forward does for a coordinate field, a block and a
+    level: the counter the kernel cannot return (its one result is the
+    tap block).  Plain ``jax.numpy``, off any timed path.
+
+    ``coords``: ``(B, H1, W1, 2)`` level-0 centres as the lookup gets
+    them; ``shape``: the ``(H/8, W/8)`` of level 0.  Returns one dict a
+    level, each entry ``(B, blocks)`` int32 over the raster-ordered
+    blocks of ``block_q`` queries: ``first`` and ``rows`` (the rows
+    ``[first, first + rows)`` of the level that the block's taps are
+    made from, the union of its windows cut to the map; a slab the
+    kernel cuts at the map's edge also loads up to ``k`` rows beyond
+    them, into window rows nothing reads), ``passes`` (the distinct
+    window starts the y stage makes a pass for, spread + 1; 0 where no
+    window touches the map) and ``held`` (the level's rows, all of which
+    the pipeline still fetches)."""
+    B = coords.shape[0]
+    c = coords.reshape(B, -1, 2).astype(jnp.float32)
+    blocks = -(-c.shape[1] // block_q)
+    cy = _pad_coords_oor(c, blocks * block_q)[..., 1].reshape(
+        B, blocks, block_q)
+    out = []
+    for lvl in range(levels):
+        hl, wl = shape[0] >> lvl, shape[1] >> lvl
+        _, _, b, top = _window_span(cy / (2.0 ** lvl), hl, radius, axis=-1)
+        passes = jnp.maximum(top - b + 1.0, 0.0)
+        first = jnp.clip(b - radius, 0, hl)
+        last = jnp.clip(top + (radius + 2.0), 0, hl)
+        if not (hl and wl):      # an over-pooled level: no kernel work
+            passes, first, last = (jnp.zeros_like(b),) * 3
+        out.append({
+            "first": first.astype(jnp.int32),
+            "rows": jnp.maximum(last - first, 0.0).astype(jnp.int32),
+            "passes": passes.astype(jnp.int32),
+            "held": jnp.full(b.shape, hl, jnp.int32)})
+    return out
 
 
 def _pyr_fwd_level_body(corr_ref, c_ref, out_ref, acc_ref, lvl, out_off,
@@ -665,68 +731,130 @@ def _pyr_fwd_level_body(corr_ref, c_ref, out_ref, acc_ref, lvl, out_off,
                         keepdims=True).astype(out_ref.dtype)
 
 
-def _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, acc_ref, lvl, hl, wl,
+def _window_rows(wl: int, k: int):
+    """Rows of the rolled forward's window scratch at a level ``wl``
+    wide: ``(wl8, nx)``.  ``wl8`` is ``wl`` padded to the fp32 sublane
+    tile; ``nx`` the whole 8-row tiles of x a run of ``k + 1`` window
+    positions can straddle, kept behind the ``wl8`` rows, or 0 where the
+    level is no wider than that and the x stage contracts all of it."""
+    wl8 = -(-wl // 8) * 8
+    nx = 8 * (-(-(k + 8) // 8))
+    return wl8, (nx if wl8 > nx else 0)
+
+
+def _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, win_ref, lvl, hl, wl,
                           k):
-    """:func:`_pyr_fwd_level_body` rolled up: the same taps from the same
-    products, accumulated in the same order (bit for bit the same flow
-    on the chip), in a kernel a twentieth as long to trace and lower.
+    """One level of the forward an undifferentiated call runs: the taps
+    of :func:`_pyr_fwd_level_body` (to fp32 rounding), from work that
+    follows what the block's windows reach and not the map, in a kernel
+    an eighth as long to trace as that one.  Both stages are a gather
+    followed by arithmetic on what was gathered.
 
-    The inference programs run this one (the primal of
-    :func:`_pyramid_lookup`; a differentiated call keeps the unrolled
-    body, the program the train cell has run since PR 25).  jax traces
-    and lowers a kernel again in every process, for every program that
-    holds it, to find the program's compile-cache key: unrolled, the
-    four levels are ~8,800 equations, 10 s of the chip host's time for
-    each of the four batch sizes a serve engine warms, and set-up time
-    is an end-to-end metric (PERF.md section 6, PR 27).
+    **The y stage gathers rows.**  A lane whose window starts at
+    ``y0 = floor(cy)`` needs the ``k + 1`` rows ``C[y0 - r + m]``,
+    ``m = 0..k``, each used by two taps.  Lanes of a block differ in
+    ``y0`` by ``o = y0 - b`` (``b`` the block's least ``y0``), so window
+    row ``m`` of a lane is ``C[b - r + o + m]``: one pass a value of
+    ``o`` present in the block (a loop of spread + 1 trips: 1-3 at
+    smooth flow) copies the slab of rows ``b - r + d + (0..k)`` into the
+    window rows of the lanes with ``o == d``.  Rows outside ``[0, hl)``
+    and lanes whose window lies wholly outside the map (padded queries
+    sit at -1e6) select nothing and stay zero: zeros padding, never a
+    clamped read; such lanes are left out of ``b`` and the spread, so a
+    padded block costs what its real lanes reach.  The kernel this
+    replaced (PR 27) updated all ``k`` accumulators of ``(wl, BQ)`` once
+    a reached row, ``~(k + 1 + spread) * k`` tile read-modify-writes
+    where this makes ``(k + 1) * (spread + 1)`` selects;
+    :func:`lookup_reach` counts rows and passes.
 
-    Rows: a ``fori_loop`` over just the rows some window of this block
-    reaches (``[lo, hi)``; the unrolled body tests tiles of 8); one row
-    an iteration updates all ``k`` y-offset accumulators in one
-    ``(k, wl, BQ)`` expression that Mosaic, not Python, unrolls.  Taps:
-    a loop over the x offset ``i`` reduces that accumulator to the
-    ``k`` taps of the offset and writes them as entry ``lvl*k + i`` of
-    ``tap_ref``, an fp32 ``(L*k, k, 1, BQ)`` scratch whose dynamic index
-    falls on an untiled dimension; the kernel casts the whole scratch
-    to the output block once.  Measured against two other ways of
-    rolling it (PERF.md section 6): loops over ``j`` too (0.74 ms an
-    iteration at 55x128 for this one's 0.36), and those loops unrolled
-    where Mosaic lowers them (0.28 ms, but 0.5 s more a program to
-    lower, which the serve cells' ``setup_s`` could not carry)."""
+    **The x stage gathers tiles, contracts them, then mixes.**  A lane's
+    ``k + 1`` window columns start at ``floor(cx) - r`` and so lie in
+    ``nx / 8`` consecutive 8-row tiles of x (3 at radius 4, 2 at radius
+    3) out of ``wl / 8``: each lane picks its tiles (a select over the
+    level's tiles, all ``k + 1`` rows at once) into the ``nx`` rows kept
+    behind the window rows.  For each x offset ``i`` those rows are
+    contracted with the offset's bilinear weights over ``nx`` positions
+    (not ``wl``), and the two y weights ``(1 - f, f)`` are applied to
+    the contracted ``(k + 1, 1, BQ)`` sums: the ``k`` y-interpolated
+    rows are never built.  A level no wider than ``nx`` is contracted
+    whole.  The ``k`` taps of the offset go to entry ``lvl*k + i`` of
+    ``tap_ref``, an fp32 ``(L*k, k, 1, BQ)`` scratch; the kernel casts
+    the whole scratch to the output block once.
+
+    Storage may be fp32, bf16 or int8/fp8 codes (the quantized lookup
+    calls this body too): rows are converted as they are read and all
+    arithmetic is fp32.  Measured on a v5e at 55x128 (PERF.md section 6,
+    PR 33; ms an iteration at 1 / 3 / 5 / 9 window starts a block):
+    0.207 / 0.215 / 0.230 / 0.271 at radius 4 where the PR 27 body took
+    0.358 / 0.373 / 0.394 / 0.425, 0.205 against 0.251 at radius 3, and
+    a body that only lets the pipeline bring the 132 MB pyramid in takes
+    0.202: at smooth flow the kernel waits for its fetch.  The loop over
+    passes stays rolled (its trip count is the block's own) and a pass
+    is one expression over its slab; the loops over x tiles and x
+    offsets are short and static, which read 0.015 and 0.02 ms faster
+    than their rolled forms for ~0.1 s more of lowering a program (jax
+    traces and lowers a kernel again in every process, for every program
+    that holds it: kernel length is set-up time, PERF.md section 6,
+    PR 27)."""
     bq = c_ref.shape[2]
     r = (k - 1) // 2
     lvl_div = 1.0 / (2.0 ** lvl)
     cx = c_ref[0, 0:1, :] * lvl_div      # (1, BQ)
     cy = c_ref[0, 1:2, :] * lvl_div
-    posx = jax.lax.broadcasted_iota(jnp.int32, (wl, bq), 0) \
-        .astype(jnp.float32)
-    # Row y holds a tap of a query at cy iff |cy + (j - r) - y| < 1 for
-    # some j.  Padded queries sit at -1e6: they relax the lower bound
-    # but never extend the upper one.
-    lo = jnp.clip(jnp.floor(jnp.min(cy)) - r, 0, hl).astype(jnp.int32)
-    hi = jnp.clip(jnp.floor(jnp.max(cy)) + (r + 2), 0,
-                  hl).astype(jnp.int32)
+    wl8, nx = _window_rows(wl, k)
 
-    acc_ref[...] = jnp.zeros((k, wl, bq), jnp.float32)
-    joff = (jax.lax.broadcasted_iota(jnp.int32, (k, 1, 1), 0)
-            - r).astype(jnp.float32)
+    y0, live, b, top = _window_span(cy, hl, r)  # no live lane: top < b
+    o = jnp.where(live, y0 - b, -1.0)                    # (1, BQ)
+    fy = cy - y0
+    w_lo = jnp.where(live, 1.0 - fy, 0.0)[None]          # (1, 1, BQ)
+    w_hi = jnp.where(live, fy, 0.0)[None]
+    base = b.astype(jnp.int32) - r
 
-    def row_body(y, _):
-        # fp32 accumulation regardless of the stored pyramid dtype
-        row = corr_ref[0, y, :, :].astype(jnp.float32)       # (wl, BQ)
-        yf = y.astype(jnp.float32)
-        acc_ref[...] += _tap_weight(cy[None], joff, yf) * row[None]
+    # Window row m is row k + m of win_ref: a pass reads its rows as one
+    # slab cut to the map and stores it shifted by what was cut, so the
+    # k rows on either side take what falls outside and are never read.
+    n = min(k + 1, hl)
+    win = win_ref.at[k:2 * k + 1]
+    win[:, 0:wl8, :] = jnp.zeros((k + 1, wl8, bq), jnp.float32)
+
+    def pass_body(d, _):
+        at = jnp.clip(base + d, 0, hl - n)
+        # fp32 whatever the stored pyramid dtype
+        slab = corr_ref[0, pl.ds(at, n), :, :].astype(jnp.float32)
+        to = pl.ds(k + at - (base + d), n)
+        pick = (o == d.astype(jnp.float32))[None]         # (1, 1, BQ)
+        win_ref[to, 0:wl, :] = jnp.where(pick, slab, win_ref[to, 0:wl, :])
         return 0
 
-    jax.lax.fori_loop(lo, hi, row_body, 0)
+    jax.lax.fori_loop(0, (top - b).astype(jnp.int32) + 1, pass_body, 0)
+
+    # what the x stage contracts: the picked tiles, or the level whole
+    cols = win.at[:, wl8:wl8 + nx] if nx else win.at[:, 0:wl8]
+    posx = jax.lax.broadcasted_iota(jnp.int32, (cols.shape[1], bq), 0) \
+        .astype(jnp.float32)
+    if nx:
+        # tile t0 + j of the level becomes tile j of the nx rows, for
+        # the lanes whose window starts in tile t0 (cut to the map: a
+        # window that leaves it finds zero weights, not other columns)
+        t0 = jnp.clip(jnp.floor((jnp.floor(cx) - r) * 0.125), 0.0,
+                      (wl8 - nx) // 8)[None]              # (1, 1, BQ)
+        tiles = [jnp.zeros((k + 1, 8, bq), jnp.float32)] * (nx // 8)
+        for t in range(wl8 // 8):
+            tile = win[:, 8 * t:8 * t + 8, :]
+            tiles = [jnp.where(t0 == float(t - j), tile, got)
+                     for j, got in enumerate(tiles)]
+        for j, got in enumerate(tiles):
+            cols[:, 8 * j:8 * j + 8, :] = got
+        posx = posx + 8.0 * t0[0]
 
     def tap_body(i, _):
-        wx = _tap_weight(cx, (i - r).astype(jnp.float32), posx)  # (wl, BQ)
-        tap_ref[lvl * k + i] = jnp.sum(wx[None] * acc_ref[...], axis=1,
-                                       keepdims=True)
+        wx = _tap_weight(cx, (i - r).astype(jnp.float32), posx)
+        s = jnp.sum(wx[None] * cols[...], axis=1,
+                    keepdims=True)                        # (k + 1, 1, BQ)
+        tap_ref[lvl * k + i] = w_lo * s[:k] + w_hi * s[1:]
         return 0
 
-    jax.lax.fori_loop(0, k, tap_body, 0)
+    jax.lax.fori_loop(0, k, tap_body, 0, unroll=True)
 
 
 def _pyr_bwd_level_body(c_ref, g_ref, dcorr_ref, lvl, g_off, hl, wl, k):
@@ -806,12 +934,14 @@ def _pyr_multi_fwd_kernel(*refs, levels, k, kk_total):
 
 def _pyr_multi_fwd_rolled_body(*refs, levels, k, kk_total):
     """:func:`_pyr_multi_fwd_kernel` over the rolled level body; refs as
-    there, plus the fp32 ``(kk_total // k, k, 1, BQ)`` tap scratch last."""
+    there, with each level's window scratch (``3k + 1`` rows of
+    :func:`_window_rows` columns) where its accumulator stood, plus the
+    fp32 ``(kk_total // k, k, 1, BQ)`` tap scratch last."""
     nl = len(levels)
     c_ref, out_ref, tap_ref = refs[nl], refs[nl + 1], refs[-1]
-    for (lvl, _, hl, wl), corr_ref, acc_ref in zip(levels, refs[:nl],
+    for (lvl, _, hl, wl), corr_ref, win_ref in zip(levels, refs[:nl],
                                                    refs[nl + 2:-1]):
-        _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, acc_ref, lvl, hl,
+        _pyr_fwd_level_rolled(corr_ref, c_ref, tap_ref, win_ref, lvl, hl,
                               wl, k)
     if nl * k * k < kk_total:  # empty (over-pooled) trailing levels
         tap_ref[nl * k:] = jnp.zeros(
@@ -879,7 +1009,9 @@ def _pyr_levels_fwd(pyramid, coords_p, radius, block_q, interpret,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B, L * k * k, Npad), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((k, c.shape[2], block_q) if rolled
+            pltpu.VMEM((3 * k + 1, sum(_window_rows(c.shape[2], k)),
+                        block_q)
+                       if rolled
                        else (k * c.shape[2], block_q), jnp.float32)
             for _, c in nonempty
         ] + ([pltpu.VMEM((L * k, k, 1, block_q), jnp.float32)]

@@ -140,32 +140,75 @@ def _pallas_kernels(jaxpr):
     return names
 
 
-@pytest.mark.parametrize("h8,w8,radius,store", [
-    (8, 12, 4, jnp.float32),       # levels 8x12 .. 1x1
-    (16, 24, 3, jnp.bfloat16),     # radius 3 (k = 7), bf16 storage
-    (23, 31, 4, jnp.bfloat16),     # odd rows: remainder tiles, 2 blocks
-    (4, 6, 4, jnp.float32),        # over-pooled: the last level is empty
-])
-def test_rolled_forward_is_the_unrolled_forward(h8, w8, radius, store):
-    """A call no gradient is asked of runs the rolled-up kernel (a tenth
-    of the equations to trace and lower, PERF.md section 6 PR 27), a
-    differentiated one keeps the unrolled kernel the train cell runs:
-    same taps from both, to fp32 rounding."""
-    from raft_tpu.ops import pallas_corr as pc
+def _lookup_case(h8, w8, store, field, seed):
+    """A pyramid and a coordinate field the rolled forward is sensitive
+    to: ``(flat pyramid, the same stored values query-major, coords)``."""
     from raft_tpu.ops.corr import build_corr_pyramid_flat
 
-    rng = np.random.default_rng(h8)
-    B = 2
+    rng = np.random.default_rng(seed)
+    B, N = 2, h8 * w8
     f1 = jnp.asarray(rng.normal(size=(B, h8, w8, 32)), jnp.float32)
     f2 = jnp.asarray(rng.normal(size=(B, h8, w8, 32)), jnp.float32)
     pyr = build_corr_pyramid_flat(f1, f2, num_levels=4, pad_q=128,
                                   out_dtype=store)
+    # the XLA sampler's layout over the very same stored values
+    major = [jnp.moveaxis(lv[..., :N], 3, 1) for lv in pyr]
     ys, xs = np.meshgrid(np.arange(h8), np.arange(w8), indexing="ij")
-    coords = np.stack([xs, ys], -1)[None].repeat(B, 0).astype(np.float32)
-    coords += rng.normal(scale=3.0, size=coords.shape).astype(np.float32)
-    coords[0, 0, 0] = (-50.0, -50.0)        # windows wholly outside
-    coords[1, -1, -1] = (500.0, 500.0)
-    coords = jnp.asarray(coords)
+    grid = np.stack([xs, ys], -1)[None].repeat(B, 0).astype(np.float32)
+    coords = grid.copy()
+    if field == "noisy":         # spread ~10 rows, some windows cut
+        coords += rng.normal(scale=3.0, size=coords.shape)
+        coords[0, 0, 0] = (-50.0, -50.0)        # windows wholly outside
+        coords[1, -1, -1] = (500.0, 500.0)
+    elif field == "constant":    # every window starts on one row
+        coords[...] = (w8 / 2 + 0.3, h8 / 2 - 0.4)
+    elif field == "straddle":    # no flow: a block spans 128 / w8 rows
+        coords += 0.25
+    elif field == "integral":    # cy on a row: the upper weight is 0
+        coords += np.float32([2.0, -1.0])
+    elif field == "above":
+        coords[..., 1] = -50.0 + 0.1 * ys[None]
+    elif field == "below":
+        coords[..., 1] = h8 + 40.0 + 0.1 * ys[None]
+    elif field == "left":
+        coords[..., 0] = -300.0
+    elif field == "far_beside_near":
+        # real queries as far out as padded ones (-1e6) in every block,
+        # beside queries whose windows lie on the map
+        coords += rng.normal(scale=0.7, size=coords.shape)
+        coords[:, :, ::5] = -1e6
+        coords[:, ::3, 1::5, 1] = 1e6
+    else:
+        raise AssertionError(field)
+    return pyr, major, jnp.asarray(coords, jnp.float32)
+
+
+@pytest.mark.parametrize("h8,w8,radius,store,field", [
+    (8, 12, 4, jnp.float32, "noisy"),       # levels 8x12 .. 1x1
+    (16, 24, 3, jnp.bfloat16, "noisy"),     # radius 3 (k = 7), bf16 storage
+    (23, 31, 4, jnp.bfloat16, "noisy"),     # odd rows, 6 blocks, the last
+                                            # with 73 real + 55 padded lanes
+    (4, 6, 4, jnp.float32, "noisy"),        # over-pooled: last level empty
+    (16, 24, 4, jnp.float32, "constant"),
+    (16, 24, 4, jnp.bfloat16, "straddle"),
+    (23, 31, 3, jnp.float32, "straddle"),
+    (16, 24, 4, jnp.float32, "integral"),
+    (16, 24, 4, jnp.float32, "above"),
+    (16, 24, 3, jnp.bfloat16, "below"),
+    (16, 24, 4, jnp.float32, "left"),
+    (23, 31, 4, jnp.float32, "far_beside_near"),
+])
+def test_rolled_forward_is_the_unrolled_forward(h8, w8, radius, store,
+                                                field):
+    """A call no gradient is asked of runs the rolled-up kernel (short to
+    trace and lower, PERF.md section 6 PR 27; since PR 33 its y stage
+    gathers the rows each window reaches), a differentiated one keeps the
+    unrolled kernel the train cells run: same taps from both, to fp32
+    rounding, and from the XLA sampler over the same stored values."""
+    from raft_tpu.ops import pallas_corr as pc
+    from raft_tpu.ops.corr import corr_lookup
+
+    pyr, major, coords = _lookup_case(h8, w8, store, field, seed=h8)
 
     def lookup(p, c):
         return pc.pallas_pyramid_lookup(p, c, radius, 128, True,
@@ -178,9 +221,109 @@ def test_rolled_forward_is_the_unrolled_forward(h8, w8, radius, store):
     # test below finds it in a traced step)
     unrolled, _ = jax.jit(lambda p, c: pc._pyr_fwd(
         p, c, radius, 128, True, jnp.float32))(pyr, coords)
-    assert float(jnp.abs(unrolled).max()) > 1.0
+    if field in ("above", "below", "left"):
+        assert float(jnp.abs(unrolled).max()) == 0.0
+    else:
+        assert float(jnp.abs(unrolled).max()) > 1.0
     np.testing.assert_allclose(np.asarray(rolled), np.asarray(unrolled),
                                rtol=0, atol=2e-5)
+    xla = jax.jit(lambda p, c: corr_lookup(p, c, radius))(major, coords)
+    np.testing.assert_allclose(np.asarray(rolled), np.asarray(xla),
+                               rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("h8,w8,radius,field", [
+    (16, 24, 4, "constant"), (16, 24, 4, "straddle"),
+    (23, 31, 3, "noisy"), (23, 31, 4, "far_beside_near"),
+    (16, 24, 4, "above"), (16, 24, 3, "integral"),
+])
+def test_lookup_reach_is_the_rows_the_kernel_reads(h8, w8, radius, field):
+    """``lookup_reach`` is the counter the kernel cannot return.  Held
+    against the kernel itself: rows outside ``[first, first + rows)`` of
+    a block may hold anything (NaN here) and the taps do not change;
+    a NaN in the first or the last reached row of a block reaches its
+    taps; and the passes are the distinct window starts of the block."""
+    from raft_tpu.ops import pallas_corr as pc
+
+    pyr, _, coords = _lookup_case(h8, w8, jnp.float32, field, seed=w8)
+    reach = pc.lookup_reach(coords, (h8, w8), 4, radius, 128)
+    k, blocks = 2 * radius + 1, pyr[0].shape[3] // 128
+    lookup = jax.jit(lambda p, c: pc.pallas_pyramid_lookup(
+        p, c, radius, 128, True, jnp.float32))
+    clean = np.asarray(lookup(pyr, coords))
+    assert np.isfinite(clean).all()
+
+    outside, edges = [], []
+    for lvl, (level, got) in enumerate(zip(pyr, reach)):
+        hl = level.shape[1]
+        first, rows, passes = (np.asarray(got[key]) for key in
+                               ("first", "rows", "passes"))
+        assert first.shape == (2, blocks) and (got["held"] == hl).all()
+        assert ((rows == 0) == (passes == 0)).all() or not hl
+        assert (rows <= np.minimum(hl, k + passes)).all()
+        # by hand: the distinct floor(cy) among windows that touch the map
+        cy = np.full((2, blocks * 128), -1e6, np.float32)
+        cy[:, :h8 * w8] = np.asarray(coords)[..., 1].reshape(2, -1)
+        y0 = np.floor(cy / 2.0 ** lvl).reshape(2, blocks, 128)
+        for b, i in np.ndindex(2, blocks):
+            live = y0[b, i][(y0[b, i] >= -radius - 1)
+                            & (y0[b, i] <= hl + radius - 1)]
+            want = int(live.max() - live.min()) + 1 if live.size and hl \
+                else 0
+            assert passes[b, i] == want, (lvl, b, i)
+        row = np.arange(hl)[None, :, None, None]
+        lo = np.repeat(first, 128, axis=1)[:, None, None, :]
+        hi = lo + np.repeat(rows, 128, axis=1)[:, None, None, :]
+        out = (row < lo) | (row >= hi)
+        outside.append(jnp.where(out, jnp.nan, level))
+        edge = ((row == lo) | (row == hi - 1)) & ~out
+        edges.append(jnp.where(edge, jnp.nan, level))
+    np.testing.assert_array_equal(np.asarray(lookup(outside, coords)), clean)
+    hit = np.isnan(np.asarray(lookup(edges, coords)))      # (B, H, W, L*k*k)
+    hit = hit.reshape(2, h8 * w8, 4, k * k).any(axis=3)
+    for lvl, got in enumerate(reach):
+        reached = np.repeat(np.asarray(got["rows"]) > 0, 128,
+                            axis=1)[:, :h8 * w8]
+        lanes = np.add.reduceat(hit[:, :, lvl], np.arange(
+            0, h8 * w8, 128), axis=1) > 0               # any lane a block
+        np.testing.assert_array_equal(
+            lanes, np.asarray(got["rows"]) > 0, err_msg=f"level {lvl}")
+        assert not hit[:, :, lvl][~reached].any()
+
+
+def test_rolled_kernel_stays_short_to_trace():
+    """jax traces and lowers a Mosaic kernel again in every process for
+    every program that holds it, so the kernel's length is ``setup_s``
+    (PERF.md section 6, PR 27 and PR 33): the rolled forward at four
+    levels, radius 4, is 779 equations by this count (253 before PR 33
+    gave it a gather of x tiles and static loops over them and the x
+    offsets, worth 0.06 ms an iteration), an eighth of the unrolled
+    one; a change that lengthens it has to show what the length buys."""
+    from jax._src import core
+
+    from raft_tpu.ops import pallas_corr as pc
+
+    def count(jaxpr):
+        return sum(1 + sum(count(sub) for sub in
+                           core.jaxprs_in_params(eqn.params))
+                   for eqn in jaxpr.eqns)
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield count(eqn.params["jaxpr"])
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub)
+
+    pyr = [jax.ShapeDtypeStruct((1, 55 >> lvl, 128 >> lvl, 7040),
+                                jnp.bfloat16) for lvl in range(4)]
+    coords = jax.ShapeDtypeStruct((1, 55, 128, 2), jnp.float32)
+    rolled, = kernels(jax.make_jaxpr(lambda p, c: pc.pallas_pyramid_lookup(
+        p, c, 4, 128, True, jnp.bfloat16))(pyr, coords).jaxpr)
+    unrolled, = kernels(jax.make_jaxpr(lambda p, c: pc._pyr_fwd(
+        p, c, 4, 128, True, jnp.bfloat16)[0])(pyr, coords).jaxpr)
+    assert rolled <= 900, rolled
+    assert unrolled > 6 * rolled, (rolled, unrolled)
 
 
 def _trace_train_step(model_cfg, monkeypatch, H=48, W=64, B=2):
