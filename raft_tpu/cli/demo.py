@@ -14,7 +14,8 @@ import glob
 import os
 import os.path as osp
 
-from raft_tpu.cli import add_arch_argument, arch_from_args
+from raft_tpu.cli import (add_arch_argument, arch_from_args,
+                          parse_with_arch)
 
 
 def parse_args(argv=None):
@@ -30,7 +31,7 @@ def parse_args(argv=None):
     p.add_argument("--precision", default="bf16", choices=["bf16", "fp32"])
     p.add_argument("--alternate_corr", action="store_true")
     p.add_argument("--iters", type=int, default=20)  # demo.py:62
-    return p.parse_args(argv)
+    return parse_with_arch(p, argv)
 
 
 def main(argv=None):
@@ -85,7 +86,7 @@ def main(argv=None):
     for file1, file2 in zip(frames[:-1], frames[1:]):
         img1 = jnp.asarray(read_image(file1), jnp.float32)[None]
         img2 = jnp.asarray(read_image(file2), jnp.float32)[None]
-        padder = InputPadder(img1.shape)
+        padder = InputPadder(img1.shape, multiple=model_cfg.pad_multiple)
         img1p, img2p = padder.pad(img1, img2)
         _, flow_up = eval_fn(variables, img1p, img2p)
         flow = np.asarray(padder.unpad(flow_up)[0])

@@ -11,7 +11,8 @@ import argparse
 
 # config.py is jax-free by design, so importing the validators here keeps
 # `--help` (and argparse errors) instant.
-from raft_tpu.cli import add_arch_argument, arch_from_args
+from raft_tpu.cli import (add_arch_argument, arch_from_args,
+                          parse_with_arch)
 from raft_tpu.config import validate_corr_dtype, validate_corr_precision
 
 
@@ -131,15 +132,17 @@ def parse_args(argv=None):
                         "spans, final eval record) into this directory; "
                         "defaults to $RAFT_TELEMETRY_DIR, unset = "
                         "disabled")
-    return p.parse_args(argv)
+    return parse_with_arch(p, argv)
 
 
 def variables_arch(variables) -> str:
     """The architecture a variables tree is of, read off the tree itself
     (a checkpoint carries its model in its names): GMA alone has ``att``,
-    SEA-RAFT alone ``init_conv``, the small model alone no
-    convex-upsampling mask head."""
+    SEA-RAFT alone ``init_conv``, GMFlow alone ``transformer``, the small
+    model alone no convex-upsampling mask head."""
     params = variables["params"]
+    if "transformer" in params:
+        return "gmflow"
     if "att" in params:
         return "gma"
     if "init_conv" in params:

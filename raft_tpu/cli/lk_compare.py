@@ -12,7 +12,8 @@ from __future__ import annotations
 import argparse
 import os.path as osp
 
-from raft_tpu.cli import add_arch_argument, arch_from_args
+from raft_tpu.cli import (add_arch_argument, arch_from_args,
+                          parse_with_arch)
 
 
 def parse_args(argv=None):
@@ -24,7 +25,7 @@ def parse_args(argv=None):
     add_arch_argument(p)
     p.add_argument("--iters", type=int, default=20)
     p.add_argument("--max_corners", type=int, default=200)
-    return p.parse_args(argv)
+    return parse_with_arch(p, argv)
 
 
 def lk_tracks(img1_rgb, img2_rgb, max_corners=200):
@@ -73,7 +74,7 @@ def main(argv=None):
     img2 = read_image(args.image2)
     j1 = jnp.asarray(img1, jnp.float32)[None]
     j2 = jnp.asarray(img2, jnp.float32)[None]
-    padder = InputPadder(j1.shape)
+    padder = InputPadder(j1.shape, multiple=model_cfg.pad_multiple)
     p1_, p2_ = padder.pad(j1, j2)
     _, flow_up = eval_fn(variables, p1_, p2_)
     flow = np.asarray(padder.unpad(flow_up)[0])

@@ -79,7 +79,8 @@ import io
 import json
 import math
 
-from raft_tpu.cli import add_arch_argument, arch_from_args
+from raft_tpu.cli import (add_arch_argument, arch_from_args,
+                          parse_with_arch)
 
 
 def parse_args(argv=None):
@@ -212,7 +213,7 @@ def parse_args(argv=None):
                         "forward-backward cycle-consistency pass per "
                         "scored request (one extra inference on the "
                         "swapped frames)")
-    return p.parse_args(argv)
+    return parse_with_arch(p, argv)
 
 
 def _parse_hw_list(spec):

@@ -23,7 +23,8 @@ import os.path as osp
 # config.py is jax-free by design; validating the corr knobs at the
 # argparse edge means a typo names the allowed set immediately instead
 # of dying inside ``jnp.dtype(...)`` at trace time.
-from raft_tpu.cli import add_arch_argument, arch_from_args
+from raft_tpu.cli import (add_arch_argument, arch_from_args,
+                          parse_with_arch)
 from raft_tpu.config import validate_corr_dtype, validate_corr_precision
 
 
@@ -243,7 +244,7 @@ def parse_args(argv=None):
     p.add_argument("--chaos-seed", type=int, default=None,
                    help="seed for probabilistic chaos rules "
                         "(default $RAFT_CHAOS_SEED or 0)")
-    return p.parse_args(argv)
+    return parse_with_arch(p, argv)
 
 
 def resolve_batch(batch_size, batch_per_chip, num_devices, lr):

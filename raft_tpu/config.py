@@ -36,7 +36,7 @@ QUANTIZED_CORR_DTYPES = ("int8", "float8_e4m3fn", "float8_e5m2")
 CORR_PRECISIONS = ("auto", "default", "high", "highest")
 # The architectures the model code builds (RAFTConfig.arch, the CLIs'
 # --arch).
-ARCHS = ("full", "small", "gma", "searaft")
+ARCHS = ("full", "small", "gma", "searaft", "gmflow")
 
 
 def validate_corr_dtype(value: str, flag: str = "corr_dtype") -> str:
@@ -92,9 +92,16 @@ class RAFTConfig:
     reads both images, a first flow regressed before the loop, two
     ConvNeXt blocks where the GRU stood, a flow head of 6 channels
     (flow, mixture logits, log-scales) outside the update block and a
-    mixture-of-Laplace loss over ``iters + 1`` predictions.  Build one
-    with :meth:`full`, :meth:`small_model`, :meth:`gma`, :meth:`searaft`
-    or :meth:`preset`; the widths below follow from it.
+    mixture-of-Laplace loss over ``iters + 1`` predictions; and
+    ``gmflow`` (GMFlow at one scale, Xu et al., CVPR 2022; PAPERS.md):
+    no hidden state, pyramid, lookup or loop, but a Transformer of
+    shifted-window attention over both feature maps, one softmax over
+    the whole correlation volume and a self-attention that propagates
+    the flow (``models/gmflow.py``; it reads none of ``hidden_dim``,
+    ``context_dim``, ``corr_levels``, ``corr_radius``, which keep
+    ``full``'s values).  Build one with :meth:`full`,
+    :meth:`small_model`, :meth:`gma`, :meth:`searaft`, :meth:`gmflow` or
+    :meth:`preset`; the widths below follow from it.
     """
 
     arch: str = "full"
@@ -291,11 +298,18 @@ class RAFTConfig:
         return cls.full(**{"arch": "searaft", **kw})
 
     @classmethod
+    def gmflow(cls, **kw) -> "RAFTConfig":
+        # GMFlow, num_scales 1: 128 feature channels, 6 Transformer
+        # blocks, one head, 2x2 windows, FFN expansion 4, global matching
+        # and propagation: fixed by ``arch`` in models/gmflow.py.
+        return cls.full(**{"arch": "gmflow", **kw})
+
+    @classmethod
     def preset(cls, arch: str, **kw) -> "RAFTConfig":
         """The preset the CLIs' ``--arch`` names (an unknown name fails
         in ``__post_init__``, with the allowed set)."""
         makers = {"small": cls.small_model, "gma": cls.gma,
-                  "searaft": cls.searaft}
+                  "searaft": cls.searaft, "gmflow": cls.gmflow}
         return makers.get(arch, cls.full)(**{**kw, "arch": arch})
 
     @property
@@ -327,6 +341,31 @@ class RAFTConfig:
         """Whether the context is a function of both images: a frame's
         context then cannot be cached for the next pair (streaming)."""
         return self.arch == "searaft"
+
+    @property
+    def refines(self) -> bool:
+        """Whether the model refines a flow in a loop of ``iters``
+        iterations over a carried state.  Where it does not (arch
+        'gmflow': one pass, two predictions in training) ``iters`` is
+        not read, a request is one program, and what needs the loop's
+        state is refused by name: ``flow_init``, slot batching,
+        streaming sessions, early exit."""
+        return self.arch != "gmflow"
+
+    @property
+    def attn_splits(self) -> int:
+        """How many windows an axis of the 1/8 map is split into for
+        attention: 2 for arch 'gmflow' (``models/gmflow.py SPLITS``), 1
+        where no attention runs over windows."""
+        return 2 if self.arch == "gmflow" else 1
+
+    @property
+    def pad_multiple(self) -> int:
+        """What the model needs H and W to be multiples of: 8 (three
+        halvings) times the windows an axis of the 1/8 map is split
+        into.  ``evaluate.py`` and the serve engine pad and bucket by
+        it."""
+        return 8 * self.attn_splits
 
     @property
     def resolved_corr_dtype(self) -> str:

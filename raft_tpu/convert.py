@@ -92,10 +92,10 @@ def _torch_key_to_path(key: str):
             return None
         parts = parts[:i] + ["downsample_conv"] + parts[i + 2:]
 
-    # layerX.Y -> layerX_Y
+    # layerX.Y -> layerX_Y; GMFlow's layers.N -> layers_N, mlp.N -> mlp_N
     merged = []
     for p in parts:
-        if merged and re.fullmatch(r"layer\d+", merged[-1]) \
+        if merged and re.fullmatch(r"layer\d+|layers|mlp", merged[-1]) \
                 and re.fullmatch(r"\d+", p):
             merged[-1] = f"{merged[-1]}_{p}"
         else:
@@ -111,6 +111,9 @@ def _torch_key_to_path(key: str):
     # SEA-RAFT: the two heads' Sequentials, the trunks' names, the blocks
     if parts[0] == "flow_head" and parts[1] in ("0", "2"):
         parts = ["flow_head", {"0": "conv1", "2": "conv2"}[parts[1]]] \
+            + parts[2:]
+    if parts[0] == "upsampler" and parts[1] in ("0", "2"):     # GMFlow's
+        parts = ["upsampler", {"0": "conv1", "2": "conv2"}[parts[1]]] \
             + parts[2:]
     if parts[0] == "upsample_weight":
         parts = ["upsampler", "mask_head",
@@ -163,6 +166,12 @@ def _fuse_gru_zr(state_dict: Dict[str, Any]) -> Dict[str, Any]:
 _GMA_KEY = re.compile(r"^(module\.)?(att\.|update_block\.aggregator\.)")
 _SEA_KEY = re.compile(r"^(module\.)?(init_conv\.|flow_head\.|"
                       r"upsample_weight\.|update_block\.refine\.)")
+_GMFLOW_KEY = re.compile(r"^(module\.)?(backbone\.|transformer\.|"
+                         r"feature_flow_attn\.|upsampler\.)")
+# BasicEncoder's bias leaves that GMFlow's bias-free convolutions leave
+# unfilled: the stem's and every 3x3's, each in front of an instance norm
+_GMFLOW_INERT_BIAS = re.compile(
+    r"^params/backbone/(conv1|layer\d_\d/conv[12])/bias$")
 
 
 def _check_arch(state_dict, template) -> None:
@@ -199,6 +208,21 @@ def _check_arch(state_dict, template) -> None:
             "'init_conv.weight' is the first key missing (then "
             "flow_head.0.weight, update_block.refine.0.dwconv.weight, "
             "upsample_weight.0.weight)")
+    gmf_keys = sorted(k for k in state_dict if _GMFLOW_KEY.match(k))
+    wants = "transformer" in template["params"]
+    if gmf_keys and not wants:
+        raise ValueError(
+            f"the state dict is a GMFlow checkpoint ({gmf_keys[0]!r} is "
+            f"the first of {len(gmf_keys)} keys that have no place) and "
+            "the model is not: the template has no backbone/*, "
+            "transformer/* or feature_flow_attn/* leaves; convert with "
+            "--arch gmflow")
+    if wants and not gmf_keys:
+        raise ValueError(
+            "the model is GMFlow and the state dict is not: "
+            "'backbone.conv1.weight' is the first key missing (then "
+            "transformer.layers.0.self_attn.q_proj.weight, "
+            "feature_flow_attn.q_proj.weight, upsampler.0.weight)")
 
 
 def convert_state_dict(state_dict: Dict[str, Any],
@@ -248,7 +272,8 @@ def convert_state_dict(state_dict: Dict[str, Any],
         if full[-1] == "kernel" and arr.ndim == 4:
             arr = arr.transpose(2, 3, 1, 0)  # OIHW -> HWIO
         elif full[-1] == "kernel" and arr.ndim == 2:
-            arr = arr.T[None, None]          # nn.Linear (out, in) -> 1x1
+            # nn.Linear (out, in) -> a Dense kernel, or a 1x1 convolution's
+            arr = arr.T if flat_tmpl[full].ndim == 2 else arr.T[None, None]
         want = flat_tmpl[full].shape
         if tuple(arr.shape) != tuple(want):
             raise ValueError(
@@ -258,6 +283,10 @@ def convert_state_dict(state_dict: Dict[str, Any],
             raise ValueError(f"duplicate write to {'/'.join(full)}")
         out[full] = arr.astype(np.asarray(flat_tmpl[full]).dtype)
 
+    if "transformer" in template["params"]:
+        for full in set(flat_tmpl) - set(out):
+            if _GMFLOW_INERT_BIAS.match("/".join(full)):
+                out[full] = np.zeros_like(np.asarray(flat_tmpl[full]))
     missing = sorted(set(flat_tmpl) - set(out))
     if missing:
         raise ValueError(
@@ -305,8 +334,8 @@ def main(argv=None):
     import argparse
 
     p = argparse.ArgumentParser(
-        description="Convert a reference RAFT, GMA or SEA-RAFT .pth to an orbax "
-                    "checkpoint")
+        description="Convert a reference RAFT, GMA, SEA-RAFT or GMFlow "
+                    ".pth to an orbax checkpoint")
     p.add_argument("pth", help="path to torch checkpoint")
     p.add_argument("out", help="output orbax checkpoint directory")
     add_arch_argument(p)

@@ -556,6 +556,28 @@ class ShardedLoader:
         start_epoch, skip = divmod(step, spe)
         return self.batches(start_epoch, skip_batches=skip)
 
+    def _samples(self, start_epoch: int, skip_batches: int):
+        """``(epoch, index, ends_epoch)`` for every sample of the stream,
+        epoch after epoch without end; ``ends_epoch`` marks the last sample
+        an epoch uses (a batch never spans two epochs)."""
+        epoch = start_epoch
+        while True:
+            idx = self.epoch_indices(epoch)
+            n = len(idx)
+            usable = (n // self.batch_size) * self.batch_size \
+                if self.drop_last else n
+            if usable == 0:
+                raise ValueError(
+                    f"per-host dataset share ({n} samples) smaller than "
+                    f"batch_size={self.batch_size} with drop_last — no "
+                    "batches would ever be produced")
+            skipped = 0
+            if epoch == start_epoch and skip_batches:
+                skipped = min(skip_batches * self.batch_size, usable)
+            for k in range(skipped, usable):
+                yield epoch, idx[k], k == usable - 1
+            epoch += 1
+
     def batches(self, start_epoch: int = 0,
                 skip_batches: int = 0) -> Iterator[Dict[str, np.ndarray]]:
         """Infinite batch stream, epoch after epoch (the reference wraps its
@@ -564,47 +586,32 @@ class ShardedLoader:
         (checkpoint resume mid-epoch)."""
         from collections import deque
 
-        epoch = start_epoch
         # Bounded prefetch: a fixed window of decode futures in flight,
         # so the workers can't race ahead of the consumer and buffer an
         # entire epoch of decoded samples in host RAM.  The depth is the
         # ``prefetch_batches`` knob (in batches); 0 keeps the legacy
-        # ~2-batch default.
+        # ~2-batch default.  The window runs on across epochs: the next
+        # epoch's first samples decode while this one's last are consumed,
+        # so a short epoch does not leave the workers idle at its end.
         window = (self.prefetch_batches * self.batch_size
                   if self.prefetch_batches > 0
                   else max(2 * self.batch_size, 2 * self.num_workers))
+        samples = self._samples(start_epoch, skip_batches)
         with ThreadPoolExecutor(max_workers=self.num_workers) as pool:
+            pending = deque()
+
+            def submit():
+                epoch, i, ends_epoch = next(samples)
+                pending.append(
+                    (pool.submit(self._load_one, epoch, i), ends_epoch))
+
+            for _ in range(window):
+                submit()
+            buf: List[Dict[str, np.ndarray]] = []
             while True:
-                idx = self.epoch_indices(epoch)
-                n = len(idx)
-                usable = (n // self.batch_size) * self.batch_size \
-                    if self.drop_last else n
-                if usable == 0:
-                    raise ValueError(
-                        f"per-host dataset share ({n} samples) smaller than "
-                        f"batch_size={self.batch_size} with drop_last — no "
-                        "batches would ever be produced")
-                if epoch == start_epoch and skip_batches:
-                    skipped = min(skip_batches * self.batch_size, usable)
-                    idx = idx[skipped:]
-                    usable -= skipped
-                pending = deque()
-                it = iter(idx[:usable])
-                for i in it:
-                    pending.append(pool.submit(self._load_one, epoch, i))
-                    if len(pending) >= window:
-                        break
-                buf: List[Dict[str, np.ndarray]] = []
-                while pending:
-                    buf.append(pending.popleft().result())
-                    nxt = next(it, None)
-                    if nxt is not None:
-                        pending.append(
-                            pool.submit(self._load_one, epoch, nxt))
-                    if len(buf) == self.batch_size:
-                        yield {k: np.stack([b[k] for b in buf])
-                               for k in buf[0]}
-                        buf = []
-                if buf and not self.drop_last:
+                future, ends_epoch = pending.popleft()
+                buf.append(future.result())
+                submit()
+                if len(buf) == self.batch_size or ends_epoch:
                     yield {k: np.stack([b[k] for b in buf]) for k in buf[0]}
-                epoch += 1
+                    buf = []

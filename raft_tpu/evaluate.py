@@ -31,7 +31,7 @@ import numpy as np
 
 from raft_tpu.config import RAFTConfig
 from raft_tpu.data import datasets, frame_utils
-from raft_tpu.models.raft import RAFT
+from raft_tpu.models.raft import RAFT, refuse_loop_state
 from raft_tpu.obs import default_sink, span
 from raft_tpu.ops.pad import InputPadder, max_bucket_hw
 from raft_tpu.utils.warp import forward_interpolate
@@ -116,15 +116,6 @@ def make_eval_fn(model_cfg: RAFTConfig, iters: int):
     return eval_fn
 
 
-def _prep(sample: Dict[str, np.ndarray], mode: str):
-    """Host sample -> padded (1,H,W,3) device arrays + padder."""
-    image1 = jnp.asarray(sample["image1"])[None]
-    image2 = jnp.asarray(sample["image2"])[None]
-    padder = InputPadder(image1.shape, mode=mode)
-    image1, image2 = padder.pad(image1, image2)
-    return image1, image2, padder
-
-
 def _peek_hw(path: str):
     """Image (H, W) from the file header only (no pixel decode)."""
     from PIL import Image
@@ -137,8 +128,9 @@ def _peek_hw(path: str):
 _BUCKET_CACHE: Dict[tuple, tuple] = {}
 
 
-def _bucket_hw(ds) -> tuple:
-    """One /8-aligned bucket shape covering every image in the dataset.
+def _bucket_hw(ds, multiple: int = 8) -> tuple:
+    """One bucket shape covering every image in the dataset, aligned to
+    ``multiple`` (the model's ``RAFTConfig.pad_multiple``).
 
     KITTI's native resolutions vary per sequence (375x1242, 370x1224, ...)
     so a per-shape jit would pay one XLA compile per distinct resolution
@@ -157,21 +149,24 @@ def _bucket_hw(ds) -> tuple:
     Header peeks are cached per image-path set: validators construct a
     fresh dataset every call (val_freq cadence), and re-opening every
     header ~20x per stage was pure waste."""
-    key = tuple(p1 for (p1, _) in ds.image_list)
+    paths = tuple(p1 for (p1, _) in ds.image_list)
+    key = (paths, multiple)
     hit = _BUCKET_CACHE.get(key)
     if hit is None:
         if len(_BUCKET_CACHE) >= 64:   # a handful of dataset variants is
             _BUCKET_CACHE.clear()      # the use case; don't grow forever
-        hit = _BUCKET_CACHE[key] = max_bucket_hw(_peek_hw(p) for p in key)
+        hit = _BUCKET_CACHE[key] = max_bucket_hw(
+            (_peek_hw(p) for p in paths), multiple)
     return hit
 
 
 def _batched_flows(variables, eval_fn, ds, mode: str, batch_size: int,
-                   target=None):
+                   target=None, multiple: int = 8):
     """Stream the dataset through the jitted forward in fixed-shape
     batches; yields ``(sample, flow (H, W, 2) np, unpadded)`` per image.
 
-    Every image is padded to ``target`` (or its own /8 shape — then all
+    Every image is padded to ``target`` (or its own shape rounded up to
+    ``multiple``, the model's ``RAFTConfig.pad_multiple`` — then all
     images must share a resolution), so the whole pass costs ONE
     compilation; the final partial batch is filled by repeating the last
     image (discarded on yield)."""
@@ -181,7 +176,8 @@ def _batched_flows(variables, eval_fn, ds, mode: str, batch_size: int,
         samples = [ds.load(i) for i in idxs]
         with span("raft_eval_pad", dataset=mode):
             padders = [InputPadder(s["image1"].shape, mode=mode,
-                                   target=target) for s in samples]
+                                   target=target, multiple=multiple)
+                       for s in samples]
             im1 = [p.pad_np(s["image1"]) for p, s in zip(padders, samples)]
             im2 = [p.pad_np(s["image2"]) for p, s in zip(padders, samples)]
             pad_n = batch_size - len(idxs)
@@ -214,7 +210,8 @@ def validate_chairs(variables, model_cfg: RAFTConfig = RAFTConfig.full(),
                                split_file=split_file)
     epe_list = []
     for sample, flow in _batched_flows(variables, eval_fn, ds, "chairs",
-                                       batch_size):
+                                       batch_size,
+                                       multiple=model_cfg.pad_multiple):
         with span("raft_eval_epe", dataset="chairs"):
             epe = np.sqrt(np.sum((flow - sample["flow"]) ** 2, axis=-1))
             epe_list.append(epe.reshape(-1))
@@ -236,9 +233,9 @@ def validate_sintel(variables, model_cfg: RAFTConfig = RAFTConfig.full(),
     for dstype in ("clean", "final"):
         ds = datasets.MpiSintel(split="training", dstype=dstype, root=root)
         epe_list = []
-        for sample, flow in _batched_flows(variables, eval_fn, ds,
-                                           "sintel", batch_size,
-                                           target=_bucket_hw(ds)):
+        for sample, flow in _batched_flows(
+                variables, eval_fn, ds, "sintel", batch_size,
+                target=_bucket_hw(ds, model_cfg.pad_multiple)):
             with span("raft_eval_epe", dataset="sintel"):
                 epe = np.sqrt(np.sum((flow - sample["flow"]) ** 2,
                                      axis=-1))
@@ -269,10 +266,12 @@ def validate_kitti(variables, model_cfg: RAFTConfig = RAFTConfig.full(),
     reference's exact per-shape padding (per-image batches)."""
     eval_fn = eval_fn or make_eval_fn(model_cfg, iters)
     ds = datasets.KITTI(split="training", root=root)
-    target, bs = (_bucket_hw(ds), batch_size) if bucket else (None, 1)
+    target, bs = ((_bucket_hw(ds, model_cfg.pad_multiple), batch_size)
+                  if bucket else (None, 1))
     epe_list, out_list = [], []
     for sample, flow in _batched_flows(variables, eval_fn, ds, "kitti",
-                                       bs, target=target):
+                                       bs, target=target,
+                                       multiple=model_cfg.pad_multiple):
         with span("raft_eval_epe", dataset="kitti"):
             epe = np.sqrt(np.sum((flow - sample["flow"]) ** 2, axis=-1))
             mag = np.sqrt(np.sum(sample["flow"] ** 2, axis=-1))
@@ -307,6 +306,8 @@ def create_sintel_submission(variables,
     lets lane restarts and non-warm-start lanes share the jit entry.
     Finished lanes repeat their last frame; outputs for those are
     discarded."""
+    if warm_start:
+        refuse_loop_state(model_cfg, "warm_start (flow_init)")
     eval_fn = eval_fn or make_eval_fn(model_cfg, iters)
     for dstype in ("clean", "final"):
         ds = datasets.MpiSintel(split="test", aug_params=None,
@@ -332,7 +333,8 @@ def create_sintel_submission(variables,
                 for j, ln in enumerate(lanes):
                     if t < len(ln):
                         s = ds.load(ln[t])
-                        p = InputPadder(s["image1"].shape, mode="sintel")
+                        p = InputPadder(s["image1"].shape, mode="sintel",
+                                        multiple=model_cfg.pad_multiple)
                         cache[j] = (s, p)
                     elif cache[j] is not None:
                         s, p = cache[j]  # finished lane: no re-decode
@@ -391,9 +393,11 @@ def create_kitti_submission(variables,
     eval_fn = eval_fn or make_eval_fn(model_cfg, iters)
     ds = datasets.KITTI(split="testing", aug_params=None, root=root)
     os.makedirs(output_path, exist_ok=True)
-    target, bs = (_bucket_hw(ds), batch_size) if bucket else (None, 1)
+    target, bs = ((_bucket_hw(ds, model_cfg.pad_multiple), batch_size)
+                  if bucket else (None, 1))
     for sample, flow in _batched_flows(variables, eval_fn, ds, "kitti",
-                                       bs, target=target):
+                                       bs, target=target,
+                                       multiple=model_cfg.pad_multiple):
         (frame_id,) = sample["extra_info"]
         frame_utils.write_flow_kitti(osp.join(output_path, frame_id), flow)
 

@@ -101,28 +101,52 @@ def corr_impl_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
 
 
 def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
-    """Bytes of the ``(N, N)`` attention matrices arch 'gma' builds for
-    ``pairs`` pairs at an ``(H/8, W/8)`` map and holds through the
-    refinement loop (training: saved for the backward pass; serving: in
-    the slot state); 0 for the other architectures."""
-    if not cfg.global_motion:
-        return 0
-    return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
+    """Bytes of attention matrix a step over ``pairs`` pairs at an
+    ``(H/8, W/8)`` map builds and holds.  Arch 'gma': one ``(N, N)``
+    matrix a pair, held through the refinement loop (training: saved for
+    the backward pass; serving: in the slot state).  Arch 'gmflow': the
+    float32 softmaxes of the matching and of the propagation (two ``(N,
+    N)`` a pair), kept from the forward pass to the backward one; the
+    twelve window attentions' ``(n, n)`` scores are not among them: under
+    the default ``RAFTConfig.remat`` each is rebuilt in the backward pass
+    (``models/gmflow.py FeatureTransformer``) and lives, ``2 * pairs * N
+    * N / 4`` float32 entries,
+    only while it runs.  0 for the other architectures."""
+    if cfg.global_motion:
+        return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
+    if not cfg.refines:
+        return 2 * pairs * (h8 * w8) ** 2 * 4
+    return 0
 
 
 def predictions(cfg: RAFTConfig, iters: int) -> int:
-    """Flow predictions a pair for ``iters`` refinement iterations: one an
+    """Flow predictions a pair a training step makes: one a refinement
     iteration, and one more where the loop starts from a regressed first
-    flow (arch 'searaft')."""
+    flow (arch 'searaft'); two whatever ``iters`` where there is no loop
+    (arch 'gmflow': the matched flow and the propagated one)."""
+    if not cfg.refines:
+        return 2
     return iters + 1 if cfg.regressed_first_flow else iters
 
 
 def batch_norm_calls(cfg: RAFTConfig) -> int:
     """Encoder calls a forward pass that normalise with batch statistics
     in training: the context encoder's one for 'full' and 'gma', none for
-    'small', and three for 'searaft' (the pair's context, and the feature
-    encoder once an image, each over its own batch)."""
-    return {"small": 0, "searaft": 3}.get(cfg.arch, 1)
+    'small' and 'gmflow', and three for 'searaft' (the pair's context, and
+    the feature encoder once an image, each over its own batch)."""
+    return {"small": 0, "gmflow": 0, "searaft": 3}.get(cfg.arch, 1)
+
+
+def refuse_loop_state(cfg: RAFTConfig, what: str) -> None:
+    """``what`` (``flow_init``, slot batching, a streaming session, early
+    exit) reads or writes the state a refinement loop carries between
+    iterations.  Where the model has no loop there is none: refused by
+    name, not ignored."""
+    if not cfg.refines:
+        raise ValueError(
+            f"arch {cfg.arch!r} computes a flow in one pass, with no "
+            f"refinement loop and no state carried between iterations: "
+            f"{what} is not available for --arch {cfg.arch}")
 
 
 def _flow_head(cfg: RAFTConfig, name=None) -> FlowHead:
@@ -598,8 +622,25 @@ class RAFT(nn.Module):
         published model does; ``test_mode`` returns ``(flow_low,
         flow_up)`` like every architecture (``info`` is read off the
         stacked call: no served reply carries it, docs/SERVING.md
-        "--arch searaft")."""
+        "--arch searaft").
+
+        Arch 'gmflow' is another body behind this signature
+        (``models/gmflow.py``): one pass with no loop, so ``iters`` is not
+        read and ``flow_init`` is refused; two predictions in training."""
         cfg = self.config
+
+        if not cfg.refines:
+            # Two bodies behind one signature rather than a zero-trip
+            # scan: nothing of the path below (context, hidden state,
+            # pyramid, RefinementStep, the stacked upsample scan) has a
+            # counterpart in this model, so threading it through would
+            # put a branch at every one of them.
+            from raft_tpu.models import gmflow
+
+            if flow_init is not None:
+                refuse_loop_state(cfg, "flow_init (warm start)")
+            return gmflow.forward(cfg, image1, image2, test_mode, train,
+                                  freeze_bn, loss_targets)
 
         fnet, cnet, att, start = _make_encoders(cfg)
         (net, inp, coords0, coords1, corr_state, attn,
@@ -857,6 +898,8 @@ class RAFTEncode(nn.Module):
     @nn.compact
     def __call__(self, image1, image2,
                  flow_init: Optional[jax.Array] = None):
+        refuse_loop_state(self.config, "the encode/iterate program pair "
+                          "(slot batching)")
         fnet, cnet, att, start = _make_encoders(self.config)
         # the first prediction's info (arch 'searaft') is not served
         return _encode_state(self.config, fnet, cnet, att, start, image1,
@@ -867,6 +910,7 @@ def refuse_frame_cache(cfg: RAFTConfig) -> None:
     """Streaming caches a frame's feature map AND its context for the next
     pair.  Where the context is a function of both frames there is no
     per-frame context to cache: refused by name, not served wrong."""
+    refuse_loop_state(cfg, "a streaming session")
     if cfg.context_reads_pair:
         raise ValueError(
             f"arch {cfg.arch!r} computes its context from both frames of "

@@ -1,6 +1,9 @@
-"""Input padding to multiples of 8 (reference ``core/utils/utils.py:7-24``).
+"""Input padding to multiples of 8 (reference ``core/utils/utils.py:7-24``),
+or of the model's own ``RAFTConfig.pad_multiple``.
 
-The model downsamples by 8, so H and W must be divisible by 8.  'sintel'
+The models downsample by 8, so H and W must be divisible by 8; a model
+that splits its 1/8 map into 2x2 windows (arch 'gmflow') needs 16, and
+its callers hand that in as ``multiple``.  'sintel'
 mode centers the height padding; every other mode puts all height padding at
 the bottom.  Width padding is always centered.  Padding is edge-replicate.
 
@@ -65,15 +68,17 @@ def max_bucket_hw(shapes: Iterable[Tuple[int, int]],
 
 
 class InputPadder:
-    """Pads NHWC images so H, W are divisible by 8 (or match ``target``);
-    unpads flow back."""
+    """Pads NHWC images so H, W are divisible by ``multiple`` (the model's
+    ``RAFTConfig.pad_multiple``; 8 for every architecture with a
+    refinement loop) or match ``target``; unpads flow back."""
 
     def __init__(self, dims, mode: str = "sintel",
-                 target: Optional[Tuple[int, int]] = None):
+                 target: Optional[Tuple[int, int]] = None,
+                 multiple: int = 8):
         self.ht, self.wd = dims[-3:-1] if len(dims) >= 3 else dims
         if target is None:
-            pad_ht = ceil_to_multiple(self.ht) - self.ht
-            pad_wd = ceil_to_multiple(self.wd) - self.wd
+            pad_ht = ceil_to_multiple(self.ht, multiple) - self.ht
+            pad_wd = ceil_to_multiple(self.wd, multiple) - self.wd
         else:
             pad_ht, pad_wd = target[0] - self.ht, target[1] - self.wd
             assert pad_ht >= 0 and pad_wd >= 0, (

@@ -327,7 +327,7 @@ class ServeConfig:
             if hw[0] % m or hw[1] % m:
                 raise ValueError(
                     f"bucket {hw} not /{m}-aligned (the model "
-                    f"downsamples by {m})")
+                    f"takes multiples of {m})")
 
     def resolved_batch_sizes(self) -> Tuple[int, ...]:
         if self.batch_sizes:
@@ -538,20 +538,36 @@ class InferenceEngine:
         from raft_tpu.evaluate import make_inference_model
         from raft_tpu.serve import slots as slots_mod
 
-        self.cfg = cfg
         model = make_inference_model(model_cfg)
         # The serve hot path is the encode/iter_step program pair
         # (serve/slots.py) for BOTH batching modes — request mode drives
         # them in lockstep so slot mode is bit-identical to it by
-        # construction (the parity pin, tests/test_serve_slots.py).
+        # construction (the parity pin, tests/test_serve_slots.py).  A
+        # model without a refinement loop (``RAFTConfig.refines`` false)
+        # is ONE program a request, ``flow`` (_get_executable); the pair
+        # below is never lowered for it, and what needs the loop's state
+        # is refused by name here.
         self._model_cfg = model.config
-        # flow predictions a request's programs make (iters, or iters + 1
-        # where ``enc`` regresses a first flow): quoted by the compile
-        # ring's ``program`` records
-        from raft_tpu.models.raft import predictions
+        from raft_tpu.models.raft import predictions, refuse_loop_state
 
-        self._predictions = predictions(self._model_cfg, cfg.iters)
+        if cfg.batching == "slot":
+            refuse_loop_state(self._model_cfg, "batching='slot'")
+        if cfg.early_exit_threshold > 0:
+            refuse_loop_state(self._model_cfg, "early exit")
+        # The bucket policy rounds to the model's pad multiple (8, or 16
+        # where the 1/8 map is split into 2x2 windows): the configured
+        # multiple is a floor, never a way under the model's.
+        m = int(np.lcm(cfg.bucket_multiple, self._model_cfg.pad_multiple))
+        if m != cfg.bucket_multiple:
+            cfg = dataclasses.replace(cfg, bucket_multiple=m)
+        self.cfg = cfg
+        # flow predictions a request's programs make (iters, or iters + 1
+        # where ``enc`` regresses a first flow; 1 where there is no
+        # loop): quoted by the compile ring's ``program`` records
+        self._predictions = (predictions(self._model_cfg, cfg.iters)
+                             if self._model_cfg.refines else 1)
         self._slots_mod = slots_mod
+        self._flow_jit = jax.jit(slots_mod.make_flow_fn(self._model_cfg))
         self._encode_jit = jax.jit(slots_mod.make_encode_fn(
             self._model_cfg))
         self._iter_jit = jax.jit(slots_mod.make_iter_fn(self._model_cfg))
@@ -831,6 +847,8 @@ class InferenceEngine:
         because it sets the layout of the slot state's pyramid."""
         from raft_tpu.models.raft import corr_impl_at
 
+        if not self._model_cfg.refines:
+            return "none"       # no pyramid in its one program
         return corr_impl_at(self._model_cfg, bucket[0] // 8,
                             bucket[1] // 8)
 
@@ -839,11 +857,12 @@ class InferenceEngine:
         lowers, read off the functions themselves: what an AOT artifact
         records beside each key and an importer holds against its own,
         so a program whose argument list changed is refused by name."""
+        progs = ((("enc", self._encode_jit), ("iter", self._iter_jit),
+                  ("stash", self._stash_jit), ("wenc", self._warm_jit))
+                 if self._model_cfg.refines
+                 else (("flow", self._flow_jit),))
         return {prog: f"{fn.__name__}{inspect.signature(fn)}"
-                for prog, fn in (("enc", self._encode_jit),
-                                 ("iter", self._iter_jit),
-                                 ("stash", self._stash_jit),
-                                 ("wenc", self._warm_jit))}
+                for prog, fn in progs}
 
     def export_aot(self, directory: str) -> dict:
         """Serialize every compiled ``(bucket, lanes, program)``
@@ -971,8 +990,10 @@ class InferenceEngine:
 
         Raises :class:`QueueFullError` immediately (never blocks) when
         ``max_queue`` requests are already in flight."""
-        if iters is not None and int(iters) < 1:
-            raise ValueError(f"iters must be >= 1, got {iters}")
+        if iters is not None:
+            self._refuse_loop_state("a per-request iters budget")
+            if int(iters) < 1:
+                raise ValueError(f"iters must be >= 1, got {iters}")
         if not self._accepting:
             # Fail FAST with the precise lifecycle state — a client
             # racing stop() must get an immediate, classifiable error
@@ -1026,6 +1047,11 @@ class InferenceEngine:
     # client API — streaming sessions (any thread)
     # ------------------------------------------------------------------
 
+    def _refuse_loop_state(self, what: str) -> None:
+        from raft_tpu.models.raft import refuse_loop_state
+
+        refuse_loop_state(self._model_cfg, what)
+
     def _check_accepting(self) -> None:
         if self._accepting:
             return
@@ -1050,6 +1076,7 @@ class InferenceEngine:
         :class:`QueueFullError` when ``max_sessions`` sessions are
         already open (after sweeping expired ones)."""
         self._check_accepting()
+        self._refuse_loop_state("a streaming session")
         if self.cfg.batching != "slot":
             raise ValueError(
                 "streaming sessions require batching='slot' (a session "
@@ -1696,18 +1723,24 @@ class InferenceEngine:
                              iters: int, seconds: float) -> dict:
         """Trace-span cost attrs for one request-mode pipeline call
         (``enc`` + ``iters`` x ``iter`` over the stamped ledger
-        entries): ``flops``/``bytes`` always, ``mfu`` when the device
-        peak is known.  ``{}`` before the programs are stamped."""
-        enc = self.cost_book.get((bucket, lanes, "enc"))
-        it = self.cost_book.get((bucket, lanes, "iter"))
-        if enc is None or it is None:
-            return {}
-        total = cost_mod.ProgramCost(
-            program=f"serve_pipeline_{bucket[0]}x{bucket[1]}_b{lanes}",
-            flops=enc.flops + iters * it.flops,
-            bytes=enc.bytes + iters * it.bytes,
-            pairs_per_call=lanes, source=enc.source,
-            device_kind=enc.device_kind)
+        entries; the one ``flow`` program where the model has no loop):
+        ``flops``/``bytes`` always, ``mfu`` when the device peak is
+        known.  ``{}`` before the programs are stamped."""
+        if not self._model_cfg.refines:
+            total = self.cost_book.get((bucket, lanes, "flow"))
+            if total is None:
+                return {}
+        else:
+            enc = self.cost_book.get((bucket, lanes, "enc"))
+            it = self.cost_book.get((bucket, lanes, "iter"))
+            if enc is None or it is None:
+                return {}
+            total = cost_mod.ProgramCost(
+                program=f"serve_pipeline_{bucket[0]}x{bucket[1]}_b{lanes}",
+                flops=enc.flops + iters * it.flops,
+                bytes=enc.bytes + iters * it.bytes,
+                pairs_per_call=lanes, source=enc.source,
+                device_kind=enc.device_kind)
         attrs = {"flops": total.flops, "bytes": total.bytes}
         m = total.mfu(seconds)
         if m is not None:
@@ -1728,7 +1761,13 @@ class InferenceEngine:
         one monolithic forward) is what makes slot-vs-request parity
         bit-exact: XLA specializes fusion/reduction order per program,
         so only sharing the executables pins the bits (models/raft.py);
-        slot mode passes the same executable ``steps = 1``."""
+        slot mode passes the same executable ``steps = 1``.
+
+        A model without a refinement loop is one program,
+        ``flow(variables, a1, a2) -> flow_up``: one call a request and
+        no iteration call counted."""
+        if not self._model_cfg.refines:
+            return self._get_flow_executable(bucket, batch_size)
         progs = self._get_programs(bucket, batch_size)
         iters = self.cfg.iters
 
@@ -1743,6 +1782,39 @@ class InferenceEngine:
         # Program calls one run of it issues: what a batch's stage
         # record adds up, over its launches, as ``calls``.
         pipeline.calls = 2
+        return pipeline
+
+    def _get_flow_executable(self, bucket: tuple, lanes: int):
+        """The one program of a model without a refinement loop for
+        ``(bucket, lanes)``, compiled (or AOT-imported) once under the
+        ledger key ``(bucket, lanes, "flow")``, noted and cost-stamped as
+        :meth:`_get_programs` does its pair."""
+        key = (bucket, lanes, "flow")
+        H, W = bucket
+        with self._compile_lock:
+            exe = self._executables.get(key)
+            imported, t_build = exe is not None, time.perf_counter()
+            if exe is None:
+                im = jax.ShapeDtypeStruct((lanes, H, W, 3), jnp.float32)
+                exe = self._flow_jit.lower(self._variables, im,
+                                           im).compile()
+                self._executables[key] = exe
+                self.compile_counter.record(key)
+            if self.cost_book.get(key) is None:
+                stages.note("compile", "program",
+                            time.perf_counter() - t_build,
+                            name=f"{H}x{W}/b{lanes}/flow",
+                            imported=imported, lookup="none",
+                            model=self._model_cfg.arch,
+                            predictions=self._predictions)
+                self.cost_book.stamp(key, cost_mod.program_cost(
+                    exe, program=f"serve_flow_{H}x{W}_b{lanes}",
+                    pairs_per_call=lanes))
+
+        def pipeline(variables, a1, a2):
+            return None, exe(variables, a1, a2)
+
+        pipeline.calls = 1
         return pipeline
 
     def _issue(self, item: _Issued) -> None:

@@ -93,7 +93,7 @@ import numpy as np
 from raft_tpu.config import RAFTConfig
 from raft_tpu.models.raft import (RAFTEncode, RAFTEncodeWarm,
                                   RAFTFrameFeatures, RAFTIterStep,
-                                  RAFTUpsample)
+                                  RAFTUpsample, refuse_loop_state)
 from raft_tpu.ops.sampler import forward_warp_flow
 
 
@@ -251,6 +251,23 @@ def make_warm_encode_fn(model_cfg: RAFTConfig):
     return encode_warm
 
 
+def make_flow_fn(model_cfg: RAFTConfig):
+    """``flow(variables, image1, image2) -> flow_up`` (pure; the engine
+    jits/lowers it): the whole request as ONE program, for a model with
+    no refinement loop (``RAFTConfig.refines`` false, arch 'gmflow').
+    There is no state to admit into and no iteration to step, so neither
+    program above is built for such a model; request mode calls this
+    once a batch."""
+    from raft_tpu.models.raft import RAFT
+
+    model = RAFT(model_cfg)
+
+    def flow(variables, image1, image2):
+        return model.apply(variables, image1, image2, test_mode=True)[1]
+
+    return flow
+
+
 def advance(state: dict, moved: dict) -> dict:
     """The slot state after an ``iter_step`` call: ``moved`` over
     ``state``.  The leaves the program only reads stay the very arrays
@@ -350,6 +367,7 @@ class EarlyExitRunner:
     sweeping thresholds re-uses one compile per batch shape."""
 
     def __init__(self, model_cfg: RAFTConfig):
+        refuse_loop_state(model_cfg, "early exit")
         self.model_cfg = model_cfg
         self._encode = jax.jit(make_encode_fn(model_cfg))
         self._iter = jax.jit(make_iter_fn(model_cfg))

@@ -277,6 +277,70 @@ def test_batches_from_step_resumes_shuffle(sintel_root):
             np.testing.assert_array_equal(a[k], b[k])
 
 
+class _CountingDataset:
+    """Samples that say who they are, and a record of what was asked for
+    before what was handed out."""
+
+    def __init__(self, n):
+        self.n, self.asked = n, []
+
+    def __len__(self):
+        return self.n
+
+    def load(self, idx, rng):
+        self.asked.append(idx)
+        return {"x": np.full((2,), idx, np.float32),
+                "r": rng.uniform(size=(1,)).astype(np.float32)}
+
+
+@pytest.mark.parametrize("drop_last", [True, False])
+def test_batches_are_each_epochs_own_across_its_end(drop_last):
+    """No batch spans two epochs, every batch holds the epoch's indices in
+    their order with the draws of ``[seed, epoch, index]``, and a short last
+    batch appears only where ``drop_last`` is off."""
+    ds = _CountingDataset(7)
+    loader = ShardedLoader(ds, batch_size=3, seed=11, num_workers=2,
+                           drop_last=drop_last)
+    it = loader.batches()
+    for epoch in range(3):
+        idx = loader.epoch_indices(epoch)
+        want = [idx[0:3], idx[3:6]] + ([] if drop_last else [idx[6:7]])
+        for rows in want:
+            got = next(it)
+            np.testing.assert_array_equal(got["x"][:, 0], rows)
+            for i, r in zip(rows, got["r"]):
+                rng = np.random.default_rng(
+                    np.random.SeedSequence([11, epoch, int(i)]))
+                assert r[0] == np.float32(rng.uniform())
+
+
+def test_decode_window_stays_full_across_an_epochs_end():
+    """The window of decode futures runs on into the next epoch: the last
+    sample of a 4-sample epoch is held until a fifth has been asked for,
+    which a loader that drains its window at the epoch's end never does."""
+    import threading
+
+    fifth = threading.Event()
+
+    class Held(_CountingDataset):
+        lock, calls, in_time = threading.Lock(), 0, True
+
+        def load(self, idx, rng):
+            with self.lock:
+                self.calls += 1
+                call = self.calls
+            if call == 5:
+                fifth.set()
+            if call == 4:
+                self.in_time = fifth.wait(timeout=20.0)
+            return super().load(idx, rng)
+
+    ds = Held(4)
+    it = ShardedLoader(ds, batch_size=2, seed=3, num_workers=2).batches()
+    next(it), next(it)                   # epoch 0, whole
+    assert ds.in_time
+
+
 @pytest.fixture
 def things_root(tmp_path):
     rng = np.random.default_rng(3)

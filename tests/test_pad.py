@@ -72,3 +72,46 @@ def test_max_bucket_hw_matches_padder_targets():
         padder = InputPadder(hw, mode="kitti", target=bucket)
         x = np.zeros(hw + (3,), np.float32)
         assert padder.pad_np(x).shape == bucket + (3,)
+
+
+def test_pad_multiple_of_the_model():
+    """A model that splits its 1/8 map into 2x2 windows (arch 'gmflow':
+    ``RAFTConfig.pad_multiple`` 16) pads Sintel to 448x1024; the four
+    architectures with a loop keep 8 and the buckets they always had."""
+    from raft_tpu.config import ARCHS, RAFTConfig
+
+    assert {a: RAFTConfig.preset(a).pad_multiple for a in ARCHS} == {
+        "full": 8, "small": 8, "gma": 8, "searaft": 8, "gmflow": 16}
+    x = np.zeros((436, 1024, 3), np.float32)
+    for arch, want in (("full", 440), ("gmflow", 448)):
+        m = RAFTConfig.preset(arch).pad_multiple
+        padder = InputPadder(x.shape, mode="sintel", multiple=m)
+        y = padder.pad_np(x)
+        assert y.shape == (want, 1024, 3)
+        assert padder.unpad(y[None]).shape == (1, 436, 1024, 3)
+        assert bucket_hw(436, 1024, m) == (want, 1024)
+        assert max_bucket_hw([(436, 1024), (375, 1242)], m) \
+            == (want, 1248)
+    # sintel mode centres the 12 rows, 6 and 6; every other mode puts
+    # them at the bottom
+    padder = InputPadder((436, 1024), mode="sintel", multiple=16)
+    assert padder._pad == [0, 0, 6, 6]
+    assert InputPadder((370, 1226), mode="kitti", multiple=16)._pad \
+        == [3, 3, 0, 14]
+
+
+def test_the_engine_buckets_by_the_model_and_never_under_it():
+    """``ServeConfig.bucket_multiple`` is a floor: the engine rounds to the
+    least common multiple of it and the model's; a ladder entry that the
+    model cannot take is refused when the engine is built."""
+    import pytest
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.serve import ServeConfig
+
+    assert ServeConfig().bucket_multiple == 8
+    assert int(np.lcm(8, RAFTConfig.preset("gmflow").pad_multiple)) == 16
+    assert int(np.lcm(32, RAFTConfig.preset("gmflow").pad_multiple)) == 32
+    assert int(np.lcm(8, RAFTConfig.full().pad_multiple)) == 8
+    with pytest.raises(ValueError, match="not /16-aligned"):
+        ServeConfig(bucket_multiple=16, buckets=((440, 1024),))
