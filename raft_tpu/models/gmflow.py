@@ -36,10 +36,12 @@ import numpy as np
 
 from raft_tpu.models.extractor import BasicEncoder
 from raft_tpu.models.layers import conv
+from raft_tpu.ops import pallas_attention
 from raft_tpu.ops.corr import all_pairs_correlation
 from raft_tpu.ops.sampler import coords_grid, upflow8
 from raft_tpu.ops.upsample import (convex_upsample, convex_upsample_flat,
                                    space_to_depth_flow)
+from raft_tpu.parallel.mesh import image_rows_split
 
 CHANNELS = 128          # feature_channels
 LAYERS = 6              # num_transformer_layers
@@ -93,11 +95,12 @@ def merge_windows(x: jax.Array, splits: int = SPLITS) -> jax.Array:
         B, splits * hk, splits * wk, C)
 
 
-def shift_mask(h: int, w: int, splits: int = SPLITS) -> np.ndarray:
-    """Swin's mask for a map rolled by half a window: ``(K * K, n, n)``
-    float32, 0 between tokens of the same region and ``MASK_VALUE``
-    otherwise; the regions are the 3 x 3 slices ``[0, -win)``, ``[-win,
-    -shift)``, ``[-shift, end)`` of each axis."""
+def shift_regions(h: int, w: int, splits: int = SPLITS) -> np.ndarray:
+    """Swin's regions of a map rolled by half a window, a window: ``(K *
+    K, n)`` int32, the id of each token's region; the regions are the 3 x 3
+    slices ``[0, -win)``, ``[-win, -shift)``, ``[-shift, end)`` of each
+    axis.  Tokens of a rolled window attend to each other iff their ids
+    agree."""
     wh, ww = h // splits, w // splits
     region = np.zeros((h, w), np.int32)
     n = 0
@@ -107,9 +110,27 @@ def shift_mask(h: int, w: int, splits: int = SPLITS) -> np.ndarray:
             region[hs, ws] = n
             n += 1
     win = region.reshape(splits, wh, splits, ww).transpose(0, 2, 1, 3)
-    win = win.reshape(splits * splits, wh * ww)
+    return win.reshape(splits * splits, wh * ww)
+
+
+def shift_mask(h: int, w: int, splits: int = SPLITS) -> np.ndarray:
+    """:func:`shift_regions` as the additive mask: ``(K * K, n, n)``
+    float32, 0 between tokens of the same region and ``MASK_VALUE``
+    otherwise."""
+    win = shift_regions(h, w, splits)
     same = win[:, :, None] == win[:, None, :]
     return np.where(same, 0.0, MASK_VALUE).astype(np.float32)
+
+
+def window_attention_path(h: int, w: int, channels: int, dtype) -> str:
+    """``'mosaic'`` or ``'xla'``: which :func:`window_attention` a program
+    traced now holds for an ``(h, w)`` map, by
+    ``ops.pallas_attention.window_attention_path`` from what this process
+    can observe (the backend, whether image rows are split over devices,
+    the window's shape)."""
+    return pallas_attention.window_attention_path(
+        jax.default_backend(), h // SPLITS, w // SPLITS, channels,
+        jnp.dtype(dtype).itemsize, rows_split=image_rows_split())
 
 
 def window_attention(q, k, v, h: int, w: int, shift: bool, dtype,
@@ -118,15 +139,28 @@ def window_attention(q, k, v, h: int, w: int, shift: bool, dtype,
     w)`` map: ``q, k, v`` are ``(B, h * w, C)``; with ``shift`` the maps
     are rolled by half a window first and back after, and tokens of
     different regions of a rolled window are masked off each other.
-    Scores and softmax float32, ``P v`` in ``dtype``.  ``recompute``: keep
-    ``q``, ``k``, ``v`` for the backward pass and rebuild the ``(n, n)``
-    scores and their softmax there."""
+    Scores and softmax float32, ``P v`` in ``dtype``.
+
+    Two bodies, one result (:func:`window_attention_path` says which): the
+    Mosaic kernels of ``ops/pallas_attention.py``, which keep a window's
+    scores in VMEM and address the windows themselves (only the roll is
+    ``jnp.roll`` there too), and the ``jnp`` one below.  ``recompute`` is read by the
+    ``jnp`` body alone: keep ``q``, ``k``, ``v`` for the backward pass and
+    rebuild the ``(n, n)`` scores and their softmax there; the kernels
+    always keep ``q``, ``k``, ``v``, the result and the rows' log-sum-exp,
+    and never hold scores outside a grid step."""
+    B, _, C = q.shape
+    sh, sw = h // (2 * SPLITS), w // (2 * SPLITS)
+    if window_attention_path(h, w, C, dtype) == "mosaic":
+        out = pallas_attention.window_attention(
+            *(x.reshape(B, h, w, C) for x in (q, k, v)), SPLITS,
+            (sh, sw) if shift else None,
+            shift_regions(h, w) if shift else None, MASK_VALUE)
+        return out.reshape(B, h * w, C)
     if recompute:
         return jax.checkpoint(
             lambda q, k, v: window_attention(q, k, v, h, w, shift, dtype))(
                 q, k, v)
-    B, _, C = q.shape
-    sh, sw = h // (2 * SPLITS), w // (2 * SPLITS)
 
     def windows(x):
         x = x.reshape(B, h, w, C)
@@ -222,8 +256,10 @@ class FeatureTransformer(nn.Module):
     """Six blocks over ``[F1; F2]``; odd blocks shift their windows.
     ``recompute`` says what the backward pass rebuilds: ``"none"``;
     ``"scores"``, each window attention's score matrix and softmax (its
-    ``q``, ``k``, ``v`` are kept); ``"block"``, every block from its input
-    (PERF.md section 4 has what each holds and costs)."""
+    ``q``, ``k``, ``v`` are kept) where the ``jnp`` body runs -- the Mosaic
+    kernels hold no scores to keep or rebuild, so there it is ``"none"``;
+    ``"block"``, every block from its input (PERF.md section 4 has what
+    each holds and costs)."""
 
     dtype: Any = jnp.float32
     recompute: str = "none"
@@ -343,7 +379,8 @@ def forward(cfg, image1, image2, test_mode: bool, train: bool,
     # RAFTConfig.remat / remat_policy, read for a model with no scan body
     # to apply them to: 'full' rebuilds every block, the other policies
     # (the CLIs' default 'save_corr' among them) only what is large and
-    # cheap to rebuild, the score matrices
+    # cheap to rebuild, the score matrices XLA would keep (the kernels of
+    # ops/pallas_attention.py keep none: window_attention)
     recompute = ("none" if not (cfg.remat and train) else
                  "block" if cfg.remat_policy == "full" else "scores")
     x = FeatureTransformer(dt, recompute, name="transformer")(x, h, w)

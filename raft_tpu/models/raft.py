@@ -107,16 +107,30 @@ def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
     the backward pass; serving: in the slot state).  Arch 'gmflow': the
     float32 softmaxes of the matching and of the propagation (two ``(N,
     N)`` a pair), kept from the forward pass to the backward one; the
-    twelve window attentions' ``(n, n)`` scores are not among them: under
-    the default ``RAFTConfig.remat`` each is rebuilt in the backward pass
-    (``models/gmflow.py FeatureTransformer``) and lives, ``2 * pairs * N
-    * N / 4`` float32 entries,
-    only while it runs.  0 for the other architectures."""
+    twelve window attentions' ``(n, n)`` scores are not among them: the
+    Mosaic kernels never hold one outside a grid step, and under the
+    default ``RAFTConfig.remat`` the ``jnp`` body rebuilds each in the
+    backward pass (``models/gmflow.py window_attention``), ``2 * pairs * N
+    * N / 4`` float32 entries that live only while it runs.  0 for the
+    other architectures."""
     if cfg.global_motion:
         return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
     if not cfg.refines:
         return 2 * pairs * (h8 * w8) ** 2 * 4
     return 0
+
+
+def window_attention_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
+    """Which body arch 'gmflow''s window attentions run at an ``(H/8,
+    W/8)`` map in a program traced now: ``'mosaic'`` or ``'xla'``
+    (``models/gmflow.py window_attention_path``: asked inside
+    ``parallel.mesh.data_parallel_kernels`` it sees a row split);
+    ``'none'`` for the architectures that have none."""
+    if cfg.refines:
+        return "none"
+    from raft_tpu.models import gmflow
+
+    return gmflow.window_attention_path(h8, w8, gmflow.CHANNELS, cfg.dtype)
 
 
 def predictions(cfg: RAFTConfig, iters: int) -> int:

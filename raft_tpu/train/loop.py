@@ -34,12 +34,13 @@ from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
 from raft_tpu.models.raft import (RAFT, attention_bytes, batch_norm_calls,
-                                  predictions)
+                                  predictions, window_attention_at)
 from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
 from raft_tpu.obs.watchdog import StallWatchdog, stack_dump_path
-from raft_tpu.parallel import make_batch_sharder, make_mesh
+from raft_tpu.parallel import (data_parallel_kernels, make_batch_sharder,
+                               make_mesh)
 from raft_tpu.train.checkpoint import CheckpointManager
 from raft_tpu.train.logger import Logger
 from raft_tpu.train.loss import sequence_loss  # noqa: F401 (re-export)
@@ -279,6 +280,12 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     attn_bytes = attention_bytes(model_cfg, cfg.batch_size,
                                  cfg.image_size[0] // 8,
                                  cfg.image_size[1] // 8)
+    # ... and which window attention the step was traced with (arch
+    # 'gmflow': 'mosaic' on a TPU, 'xla' off it; 'none' elsewhere), asked
+    # as make_train_step's trace asks it.
+    with data_parallel_kernels(mesh, rows_split=shard_spatial):
+        attn_path = window_attention_at(model_cfg, cfg.image_size[0] // 8,
+                                        cfg.image_size[1] // 8)
     attn_counter = telem.registry.counter(
         "raft_attention_bytes_total",
         "bytes of global-motion attention matrix built and held "
@@ -386,8 +393,9 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 logger.push(step - 1, metrics)
             rec = stages.end("train", registry=telem.registry,
                              step=step - 1, model=model_cfg.arch,
-                             attn_bytes=attn_bytes, predictions=n_pred,
-                             bn_calls=bn_calls)
+                             attn_bytes=attn_bytes,
+                             window_attention=attn_path,
+                             predictions=n_pred, bn_calls=bn_calls)
             if attn_bytes:
                 attn_counter.inc(attn_bytes, loop="train")
             if bn_calls:

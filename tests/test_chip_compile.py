@@ -184,6 +184,87 @@ def test_lookup_vmem_budget_brackets_what_mosaic_takes(block_q, path,
             lowered.compile()
 
 
+# GMFlow's window attention (``ops/pallas_attention.py``) as the model
+# calls it, forward and backward, plain and shifted, at the train cell's
+# shape (384x512 -> 48x64, 32 maps: windows of 24x32 = 768 tokens, a whole
+# window a grid step) and at Sintel's (448x1024 -> 56x128, windows of
+# 28x64 = 1,792 tokens).  The selection's VMEM estimate is held against
+# Mosaic's own accounting from both sides: under a limit of the estimate the
+# kernels compile, under half of it the backward is refused.
+@pytest.mark.parametrize("maps,h8,w8,dtype", [
+    (32, 48, 64, "bfloat16"), (2, 56, 128, "bfloat16"),
+    (2, 48, 48, "float32")])      # 24x24 windows: float32's tile alone
+def test_window_attention_compiles_inside_its_vmem_estimate(
+        maps, h8, w8, dtype, one_chip, monkeypatch):
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.models import gmflow
+    from raft_tpu.ops import pallas_attention
+    from raft_tpu.ops.pallas_util import tpu_pallas_call
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dtype = jnp.dtype(dtype)
+    assert gmflow.window_attention_path(h8, w8, 128, dtype) == "mosaic"
+    x = jax.ShapeDtypeStruct((maps, h8 * w8, 128), dtype, sharding=one_chip)
+
+    def compile_under(limit_mb):
+        monkeypatch.setattr(
+            pallas_attention, "tpu_pallas_call",
+            functools.partial(tpu_pallas_call, vmem_limit_mb=limit_mb))
+
+        def both(q, k, v, g):      # a new function: nothing traced is reused
+            out = []
+            for shift in (False, True):
+                o, vjp = jax.vjp(lambda q, k, v: gmflow.window_attention(
+                    q, k, v, h8, w8, shift, dtype), q, k, v)
+                out.append((o, vjp(g)))
+            return out
+
+        return jax.jit(both).lower(x, x, x, x).compile().as_text()
+
+    hk, wk = h8 // gmflow.SPLITS, w8 // gmflow.SPLITS
+    rows = pallas_attention.window_block_rows(hk, wk, 128, dtype.itemsize)
+    estimate = math.ceil(pallas_attention.window_attention_vmem_bytes(
+        rows, hk, wk, 128, dtype.itemsize) / 2 ** 20)
+    text = compile_under(estimate)
+    assert text.count("tpu_custom_call") == 4
+    # the windows are addressed by the kernels' block specs: no array of
+    # windows (``[128,768,128]``, ``[128,768,768]``) is in the program
+    assert f"[{4 * maps},{hk * wk}," not in text
+    with pytest.raises(Exception, match="memory space vmem"):
+        compile_under(estimate // 2)
+
+
+def test_window_attention_partitions_over_data_mesh(dp_mesh, monkeypatch):
+    """With the maps sharded over ``data`` the window attention lowers per
+    shard (``per_data_shard``), forward and backward, shifted: what a
+    four-chip ``--arch gmflow`` step holds."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raft_tpu.models import gmflow
+    from raft_tpu.parallel.mesh import DATA_AXIS, data_parallel_kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((32, 48 * 64, 128), jnp.bfloat16,
+                             sharding=NamedSharding(dp_mesh, P(DATA_AXIS)))
+
+    def step(q, k, v, g):
+        with data_parallel_kernels(dp_mesh):
+            o, vjp = jax.vjp(lambda q, k, v: gmflow.window_attention(
+                q, k, v, 48, 64, True, jnp.bfloat16), q, k, v)
+            return o, vjp(g)
+
+    compiled = jax.jit(step).lower(x, x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    for out in jax.tree_util.tree_leaves(compiled.output_shardings):
+        assert out.spec == P(DATA_AXIS), out
+
+
 def test_lookup_partitions_over_data_mesh(dp_mesh):
     """The regression test for the default training configuration on more
     than one chip: with the batch sharded over ``data`` the lookup must
