@@ -88,10 +88,9 @@ bumps ``raft_chaos_injections_total{fault=...}`` in the default
 registry, so an injected fault is never confusable with a real one in
 the telemetry record.
 
-Determinism caveat: ordinal-triggered rules at the sample-read seam are
-exactly reproducible only with ``num_workers=1`` (otherwise thread
-scheduling decides which sample read gets which ordinal); use ``p=``
-rules, or step-context seams, under parallel loaders.
+The loader checks its two seams in the parent process, once a sample and
+in stream order, as it hands the sample to a worker process: ordinal rules
+there replay with any number of workers (docs/ROBUSTNESS.md).
 """
 
 from __future__ import annotations

@@ -145,9 +145,8 @@ def parse_args(argv=None):
                         "directory; defaults to $RAFT_TELEMETRY_DIR, "
                         "unset = disabled")
     p.add_argument("--num_workers", type=int, default=0,
-                   help="loader prefetch threads; 0 = min(16, cpu_count) "
-                        "(the native augmentation kernels release the "
-                        "GIL, so threads scale on multi-core pod hosts)")
+                   help="loader worker processes that decode and "
+                        "augment; 0 = min(16, cpu_count)")
     p.add_argument("--accum_steps", "--accum-steps", type=int, default=1,
                    help="gradient-accumulation microbatches per step: "
                         "the per-host batch is split into this many "

@@ -505,7 +505,7 @@ class TrainConfig:
     # microbatch (identical at the default dropout=0).  1 = off.
     accum_steps: int = 1
     # Host-loader decode window in BATCHES (``ShardedLoader`` keeps this
-    # many batches of decode futures in flight); 0 = the loader's legacy
+    # many batches of samples in flight); 0 = the loader's legacy
     # default of max(2*batch, 2*workers) samples.
     prefetch_batches: int = 0
     # Device-prefetch buffer depth: batches decoded + host-prepped +

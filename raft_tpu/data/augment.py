@@ -44,8 +44,8 @@ except AttributeError:  # minimal cv2 builds
 #
 # The C kernels fuse resize+flip+crop into one inverse-mapped pass over the
 # output crop only, and run the photometric ops without float temporaries
-# (~4x per-core vs the cv2/NumPy path, and they release the GIL so the
-# loader's thread pool scales).  Every use degrades to the NumPy/cv2 code
+# (~4x per-core vs the cv2/NumPy path; each loader worker process calls
+# them from its one thread).  Every use degrades to the NumPy/cv2 code
 # below when the library is unavailable; both paths consume the RNG in the
 # same order, so seeds stay portable between them.
 # ---------------------------------------------------------------------------

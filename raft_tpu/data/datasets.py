@@ -20,8 +20,15 @@ TPU-first redesign (replaces torch DataLoader + nn.DataParallel scatter):
   ``SeedSequence([seed, epoch, index])`` — deterministic and independent of
   worker scheduling, unlike the reference's per-worker reseed
   (datasets.py:45-51).
-- Decode/augment runs in a thread pool (cv2/PIL release the GIL); no
-  process fork, which keeps the loader safe to use after JAX initializes.
+- Decode/augment runs in ``num_workers`` worker processes, each a fresh
+  interpreter started with fork + exec (``raft_tpu/data/workers.py``), that
+  write every sample straight into its row of a shared batch slot.  Not
+  threads: the Python and NumPy work between the native calls holds the
+  interpreter's lock, and eight threads were off a core 36-41 % of a
+  sample's time (PERF.md, PR 37).  Not a bare ``fork``: a forked copy of a
+  process that holds libtpu and JAX's threads inherits their locks, so the
+  loader stays safe after JAX initializes.  Chaos decisions, counters and
+  quarantine events stay in the parent (``SampleSource``).
 """
 
 from __future__ import annotations
@@ -29,9 +36,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import os.path as osp
-import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from glob import glob
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -40,7 +45,6 @@ import numpy as np
 from raft_tpu import chaos
 from raft_tpu.data import frame_utils
 from raft_tpu.data.augment import FlowAugmentor, SparseFlowAugmentor
-from raft_tpu.obs import stages
 from raft_tpu.obs.registry import default_registry
 
 
@@ -155,9 +159,6 @@ class FlowDataset:
         """
         ds, index = self._sample_parts(index)
         index = index % len(ds.image_list)
-        if chaos.should_inject("corrupt_image", point="data.sample_read"):
-            raise SampleReadError(ds.image_list[index][0], ds, index,
-                                  "chaos-injected corrupt sample")
         img1 = _read_sample(ds, index, ds.image_list[index][0],
                             lambda p: _to_rgb(frame_utils.read_gen(p)))
         img2 = _read_sample(ds, index, ds.image_list[index][1],
@@ -388,16 +389,110 @@ def fetch_dataset(stage: str, image_size: Tuple[int, int],
 
 
 # ---------------------------------------------------------------------------
+# One sample, anywhere: the part of a loader that crosses into a worker
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class SampleHeader:
+    """What became of one sample: the seconds it took, the quarantine
+    events it caused (``sample_quarantine`` fields) and the error that
+    ended it, if any."""
+
+    seconds: float
+    quarantined: List[dict]
+    error: Optional[BaseException] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class SampleSource:
+    """The dataset with the loader's seed and self-healing settings: all a
+    worker process needs to load any sample of the stream.  Its ``load``
+    touches no counter, sink or chaos plan; the parent books the header."""
+
+    dataset: FlowDataset
+    seed: int
+    retries: int
+    resamples: int
+
+    #: Seed-stream salt separating replacement-index draws from the
+    #: per-sample augmentation streams (arbitrary constant).
+    RESAMPLE_SALT = 0x51A7
+
+    def load(self, epoch: int, index: int, *, corrupt: bool = False,
+             crash: bool = False
+             ) -> Tuple[Optional[Dict[str, np.ndarray]], SampleHeader]:
+        """Load one sample, self-healing decode failures.
+
+        Healthy path: one ``dataset.load`` with the generator of
+        ``SeedSequence([seed, epoch, index])``.  On ValueError/OSError the
+        sample is retried then quarantined and a deterministic replacement
+        drawn (``ShardedLoader.sample_retries``).  ``corrupt`` and
+        ``crash`` are the chaos decisions the parent drew for this sample:
+        the first read fails as a corrupt file, or the worker fails as a
+        bug would.  Any other exception (a loader bug) propagates."""
+        t0 = time.perf_counter()
+        if crash:
+            from raft_tpu.chaos import InjectedWorkerCrash
+
+            return None, SampleHeader(0.0, [], InjectedWorkerCrash(
+                "chaos-injected loader-worker crash (not a decode "
+                "error: must fail the run, not quarantine)"))
+        index = int(index)
+        idx, last_err, quarantined = index, None, []
+        for resample in range(self.resamples + 1):
+            for attempt in range(self.retries + 1):
+                # Fresh generator per attempt: a failed load may have
+                # consumed part of the stream, and the replacement
+                # sample must see exactly the draw it would get were it
+                # drawn first-class (stream determinism).
+                rng = np.random.default_rng(
+                    np.random.SeedSequence([self.seed, epoch, idx]))
+                try:
+                    if corrupt and resample == attempt == 0:
+                        ds, i = self.dataset._sample_parts(idx)
+                        i %= len(ds.image_list)
+                        raise SampleReadError(ds.image_list[i][0], ds, i,
+                                              "chaos-injected corrupt sample")
+                    sample = self.dataset.load(idx, rng)
+                except (ValueError, OSError) as e:
+                    last_err = e
+                else:
+                    return sample, SampleHeader(time.perf_counter() - t0,
+                                                quarantined)
+            quarantined.append(dict(
+                dataset=getattr(last_err, "dataset_name", None)
+                or getattr(self.dataset, "name",
+                           type(self.dataset).__name__),
+                split=getattr(last_err, "split", None),
+                path=getattr(last_err, "path", None),
+                epoch=int(epoch), index=int(idx), original_index=index,
+                resample=resample, retries=int(self.retries),
+                error=f"{type(last_err).__name__}: {last_err}"))
+            r = np.random.default_rng(np.random.SeedSequence(
+                [self.seed, epoch, index, self.RESAMPLE_SALT, resample]))
+            idx = int(r.integers(len(self.dataset)))
+        err = RuntimeError(
+            f"sample {index} and {self.resamples} replacement "
+            f"draw(s) all failed to load — giving up (last error: "
+            f"{type(last_err).__name__}: {last_err})")
+        err.__cause__ = last_err
+        return None, SampleHeader(time.perf_counter() - t0, quarantined, err)
+
+
+# ---------------------------------------------------------------------------
 # Sharded loader
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class ShardedLoader:
-    """Globally-shuffled, per-host-sharded, thread-prefetched batch iterator.
+    """Globally-shuffled, per-host-sharded, process-prefetched batch
+    iterator.
 
     Replaces the reference's ``DataLoader(shuffle=True, num_workers=4,
-    drop_last=True)`` (datasets.py:230-231).  Batches are dicts of stacked
-    NHWC float32 arrays with leading dim = per-host batch size.
+    drop_last=True)`` (datasets.py:230-231).  Batches are dicts of NHWC
+    float32 arrays with leading dim = per-host batch size, each a view of
+    a shared batch slot that ``num_workers`` worker processes filled
+    (``raft_tpu/data/workers.py``).
     """
 
     dataset: FlowDataset
@@ -407,8 +502,8 @@ class ShardedLoader:
     host_id: int = 0
     num_workers: int = 4
     drop_last: bool = True
-    # Decode-window depth in BATCHES: how many batches of decode futures
-    # the thread pool keeps in flight ahead of the consumer.  0 = the
+    # Decode-window depth in BATCHES: how many batches of samples the
+    # workers keep in flight ahead of the consumer.  0 = the
     # legacy default of max(2*batch_size, 2*num_workers) SAMPLES.
     # Raise it when per-sample decode latency is spiky (network
     # filesystems) so a slow sample doesn't drain the window; it bounds
@@ -438,8 +533,8 @@ class ShardedLoader:
         assert self.prefetch_batches >= 0, self.prefetch_batches
         assert self.sample_retries >= 0, self.sample_retries
         assert self.sample_resamples >= 0, self.sample_resamples
+        assert self.num_workers >= 1, self.num_workers
         self.quarantined_total = 0
-        self._quarantine_lock = threading.Lock()
 
     def epoch_indices(self, epoch: int) -> np.ndarray:
         """The host's sample indices for ``epoch`` — a disjoint stride of a
@@ -449,50 +544,29 @@ class ShardedLoader:
         perm = rng.permutation(len(self.dataset))
         return perm[self.host_id::self.num_hosts]
 
-    #: Seed-stream salt separating replacement-index draws from the
-    #: per-sample augmentation streams (arbitrary constant).
-    _RESAMPLE_SALT = 0x51A7
+    def _source(self) -> SampleSource:
+        return SampleSource(self.dataset, self.seed, self.sample_retries,
+                            self.sample_resamples)
+
+    @staticmethod
+    def _chaos_draws() -> Tuple[bool, bool]:
+        """This sample's chaos decisions, drawn here in the parent where
+        the installed plan lives: ``(corrupt, crash)``."""
+        crash = chaos.should_inject("worker_err", point="data.loader_worker")
+        corrupt = chaos.should_inject("corrupt_image",
+                                      point="data.sample_read")
+        return corrupt, crash
 
     def _load_one(self, epoch: int, index: int) -> Dict[str, np.ndarray]:
-        """Load one sample, self-healing decode failures.
-
-        Healthy path: identical to the pre-chaos loader (same RNG
-        derivation, one ``dataset.load``).  On ValueError/OSError the
-        sample is retried then quarantined and a deterministic
-        replacement drawn — see the ``sample_retries`` field comment.
-        """
-        if chaos.should_inject("worker_err", point="data.loader_worker"):
-            from raft_tpu.chaos import InjectedWorkerCrash
-
-            raise InjectedWorkerCrash(
-                "chaos-injected loader-worker crash (not a decode "
-                "error: must fail the run, not quarantine)")
-        t0 = time.perf_counter()
-        index = int(index)
-        idx, last_err = index, None
-        for resample in range(self.sample_resamples + 1):
-            for _attempt in range(self.sample_retries + 1):
-                # Fresh generator per attempt: a failed load may have
-                # consumed part of the stream, and the replacement
-                # sample must see exactly the draw it would get were it
-                # drawn first-class (stream determinism).
-                rng = np.random.default_rng(
-                    np.random.SeedSequence([self.seed, epoch, idx]))
-                try:
-                    sample = self.dataset.load(idx, rng)
-                except (ValueError, OSError) as e:
-                    last_err = e
-                else:
-                    self._count_sample(time.perf_counter() - t0)
-                    return sample
-            self._quarantine(epoch, index, idx, resample, last_err)
-            r = np.random.default_rng(np.random.SeedSequence(
-                [self.seed, epoch, index, self._RESAMPLE_SALT, resample]))
-            idx = int(r.integers(len(self.dataset)))
-        raise RuntimeError(
-            f"sample {index} and {self.sample_resamples} replacement "
-            f"draw(s) all failed to load — giving up (last error: "
-            f"{type(last_err).__name__}: {last_err})") from last_err
+        """Load one sample in this process, booked as a worker's would be
+        (the stream's first sample, which fixes the batch layout)."""
+        corrupt, crash = self._chaos_draws()
+        sample, header = self._source().load(epoch, index, corrupt=corrupt,
+                                              crash=crash)
+        self._book(header)
+        if header.error is not None:
+            raise header.error
+        return sample
 
     def _metrics(self):
         """Where this loader's counters go: ``registry``, else the
@@ -500,42 +574,33 @@ class ShardedLoader:
         return self.registry if self.registry is not None \
             else default_registry()
 
-    def _count_sample(self, seconds: float) -> None:
-        """One sample decoded and augmented in ``seconds`` of a worker
-        thread: the cumulative pair behind "how busy are the loader
-        workers" (``loader_busy_share.train``; the stage clock's
-        producer records quote the process-wide totals,
-        docs/OBSERVABILITY.md)."""
-        stages.bump("data_sample_seconds", seconds)
-        stages.bump("data_samples", 1)
-        reg = self._metrics()
-        reg.counter("raft_data_sample_seconds_total",
-                    "loader-worker seconds spent loading samples "
-                    "(decode + augment)").inc(seconds)
-        reg.counter("raft_data_samples_total",
-                    "samples the loader workers delivered").inc()
-
-    def _quarantine(self, epoch: int, index: int, idx: int,
-                    resample: int, err: Exception) -> None:
+    def _book(self, header: SampleHeader) -> None:
+        """A sample's header, in the parent: its quarantine events, and
+        the seconds a worker spent loading it (decode + augment) — the
+        cumulative pair behind "how busy are the loader workers"
+        (``loader_busy_share.train``; the stage clock's producer records
+        quote the process-wide totals, docs/OBSERVABILITY.md)."""
+        from raft_tpu.obs import stages
         from raft_tpu.obs.events import default_sink
 
-        with self._quarantine_lock:
+        reg = self._metrics()
+        for event in header.quarantined:
             self.quarantined_total += 1
-        self._metrics().counter(
-            "raft_data_quarantined_total",
-            "samples skipped after repeated read failures "
-            "(replaced by a deterministic resample)").inc()
-        sink = self.sink if self.sink is not None else default_sink()
-        sink.emit("sample_quarantine",
-                  dataset=getattr(err, "dataset_name", None)
-                  or getattr(self.dataset, "name",
-                             type(self.dataset).__name__),
-                  split=getattr(err, "split", None),
-                  path=getattr(err, "path", None),
-                  epoch=int(epoch), index=int(idx),
-                  original_index=int(index), resample=int(resample),
-                  retries=int(self.sample_retries),
-                  error=f"{type(err).__name__}: {err}")
+            reg.counter(
+                "raft_data_quarantined_total",
+                "samples skipped after repeated read failures "
+                "(replaced by a deterministic resample)").inc()
+            sink = self.sink if self.sink is not None else default_sink()
+            sink.emit("sample_quarantine", **event)
+        if header.error is not None:
+            return
+        stages.bump("data_sample_seconds", header.seconds)
+        stages.bump("data_samples", 1)
+        reg.counter("raft_data_sample_seconds_total",
+                    "loader-worker seconds spent loading samples "
+                    "(decode + augment)").inc(header.seconds)
+        reg.counter("raft_data_samples_total",
+                    "samples the loader workers delivered").inc()
 
     def steps_per_epoch(self) -> int:
         """Per-host batches per epoch (constant across epochs: the global
@@ -584,34 +649,31 @@ class ShardedLoader:
         loader in an outer while-loop, train.py:161-208).  ``skip_batches``
         drops the first batches of the first epoch without decoding them
         (checkpoint resume mid-epoch)."""
-        from collections import deque
+        from raft_tpu.data.workers import Feed
 
-        # Bounded prefetch: a fixed window of decode futures in flight,
-        # so the workers can't race ahead of the consumer and buffer an
-        # entire epoch of decoded samples in host RAM.  The depth is the
-        # ``prefetch_batches`` knob (in batches); 0 keeps the legacy
-        # ~2-batch default.  The window runs on across epochs: the next
-        # epoch's first samples decode while this one's last are consumed,
-        # so a short epoch does not leave the workers idle at its end.
+        # Bounded prefetch: a fixed window of samples in flight, so the
+        # workers can't race ahead of the consumer and fill an entire
+        # epoch of slots.  The depth is the ``prefetch_batches`` knob (in
+        # batches); 0 keeps the legacy ~2-batch default.  The window runs
+        # on across epochs: the next epoch's first samples decode while
+        # this one's last are consumed, so a short epoch does not leave
+        # the workers idle at its end.
         window = (self.prefetch_batches * self.batch_size
                   if self.prefetch_batches > 0
                   else max(2 * self.batch_size, 2 * self.num_workers))
         samples = self._samples(start_epoch, skip_batches)
-        with ThreadPoolExecutor(max_workers=self.num_workers) as pool:
-            pending = deque()
-
+        epoch, i, ends_epoch = next(samples)
+        first = self._load_one(epoch, i)
+        with Feed(self._source(), first, ends_epoch, self.batch_size,
+                  self.num_workers, self._book) as feed:
             def submit():
                 epoch, i, ends_epoch = next(samples)
-                pending.append(
-                    (pool.submit(self._load_one, epoch, i), ends_epoch))
+                feed.submit(epoch, i, ends_epoch, *self._chaos_draws())
 
-            for _ in range(window):
+            for _ in range(window - 1):
                 submit()
-            buf: List[Dict[str, np.ndarray]] = []
             while True:
-                future, ends_epoch = pending.popleft()
-                buf.append(future.result())
-                submit()
-                if len(buf) == self.batch_size or ends_epoch:
-                    yield {k: np.stack([b[k] for b in buf]) for k in buf[0]}
-                    buf = []
+                rows, batch = feed.next_batch()
+                for _ in range(rows):
+                    submit()
+                yield batch

@@ -5,7 +5,7 @@ The serial loop (PRs 0-2) ran fetch -> noise -> ``shard_batch``
 decode, host prep, and the H2D enqueue all sat in the step's critical
 path — exactly the gap PR 2's ``data_wait_s`` input-bound detector made
 visible.  :class:`DevicePipeline` moves stages (2) host prep (noise
-injection / stacking) and (3) device placement onto ONE background
+injection) and (3) device placement onto ONE background
 producer thread feeding a bounded buffer, so while the device runs step
 N the producer is already prepping and transferring batches
 N+1..N+depth.  ``device_put`` dispatch is async, so "transfer" costs the
@@ -60,6 +60,13 @@ class PipelineInterrupted(Exception):
     cooperative-preemption path out of a blocked input wait
     (docs/ROBUSTNESS.md).  Not a stream error: the pipeline stays
     usable, the train loop translates it to its preemption exit."""
+
+
+def _landed(batch) -> bool:
+    """Whether every array of a placed batch holds its data (a host array,
+    where ``put_fn`` is the identity, always does)."""
+    leaves = batch.values() if isinstance(batch, dict) else (batch,)
+    return all(getattr(x, "is_ready", lambda: True)() for x in leaves)
 
 
 def _chaos_producer_point(ordinal: int) -> None:
@@ -139,6 +146,8 @@ class DevicePipeline:
         self.last_unit: Optional[dict] = None
         self._registry = registry
         self.last_host_batch = None
+        # (device batch, host batch) pairs whose copy may be in flight.
+        self._landing: list = []
         # Cumulative, for the input microbench / pipeline stats.
         self.prep_total_s = 0.0
         self.h2d_total_s = 0.0
@@ -167,10 +176,15 @@ class DevicePipeline:
         with stages.stage("input", "prep"):
             if self._prep is not None:
                 batch = self._prep(batch)
-        host = batch if self.keep_host else None
+        host = batch
         with stages.stage("input", "h2d"):
-            batch = self._put(batch)
-        return batch, host, stages.end(
+            batch = self._put(host)
+        # The loader writes a batch slot again only once the last view of
+        # it is gone: hold each host batch until its copy has landed, not
+        # merely until it was enqueued.
+        self._landing = [(d, h) for d, h in self._landing
+                         if not _landed(d)] + [(batch, host)]
+        return batch, host if self.keep_host else None, stages.end(
             "input", registry=self._registry,
             sample_seconds_total=stages.total("data_sample_seconds"),
             samples_total=stages.total("data_samples"))
@@ -261,6 +275,7 @@ class DevicePipeline:
         is observed at the next slot/batch boundary, and the thread is
         daemonic so a wedged loader cannot hang interpreter exit."""
         self._closed = True
+        self._landing = []
         if self.depth == 0:
             return
         self._stop.set()

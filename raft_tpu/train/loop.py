@@ -21,6 +21,7 @@ TPU-first shape of the loop:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import os
@@ -57,6 +58,12 @@ import threading
 
 _PREEMPT = threading.Event()
 _warned_sync = False
+
+#: Steps dispatched and not yet finished on the device, at most: one runs,
+#: one waits behind it.  Each holds its input batch in HBM, and dispatch
+#: is asynchronous: a loop fed faster than its device ran up to a Logger
+#: interval ahead (+3.6 GB at `train_gmflow_chairs`, PERF.md, PR 37).
+_MAX_IN_FLIGHT = 2
 
 
 def request_preemption() -> None:
@@ -302,6 +309,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         "summed over train steps")
     first_dispatched = False
     run_step, compiled = step_fn, None
+    in_flight = collections.deque()  # the losses of steps on the device
     try:
         while True:
             # queue_wait_s: time blocked on the input pipeline — the
@@ -378,7 +386,13 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                     run_step = compiled
             try:
                 with stages.stage("train", "dispatch"), annotate_step(step):
+                    while len(in_flight) >= _MAX_IN_FLIGHT:
+                        # Backpressure, not a stall: a step still waits
+                        # on the device behind the one this waits for.
+                        old = in_flight.popleft()
+                        jax.block_until_ready(old)  # raftlint: disable=JIT103
                     state, metrics = run_step(state, sharded, key)
+                    in_flight.append(metrics.get("loss"))
             except BaseException as e:
                 if st is not None:
                     trace.record_span(st, "step_dispatch",

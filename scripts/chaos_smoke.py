@@ -1,7 +1,7 @@
 """Chaos smoke: prove the self-healing paths actually heal (tier-1).
 
-Runs, on CPU with a tiny model and synthetic in-memory data, the three
-recovery paths docs/ROBUSTNESS.md promises — under a canned
+Runs, on CPU with a tiny model and a synthetic on-disk FlyingChairs
+tree, the three recovery paths docs/ROBUSTNESS.md promises — under a canned
 deterministic :class:`raft_tpu.chaos.FaultPlan` — and asserts each run
 COMPLETES with exactly the expected telemetry:
 
@@ -59,40 +59,32 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-def _make_dataset(n, hw):
+def _make_dataset(root, n, hw):
+    """``n`` FlyingChairs-layout pairs of ``hw`` on disk (a random frame,
+    the frame moved a pixel right, flow (1, 0)), read unaugmented by the
+    real ``FlyingChairs`` reader: the corrupt-sample fault then takes the
+    loader's own read path, in its worker processes."""
     import numpy as np
+    from PIL import Image
 
-    from raft_tpu.data.datasets import FlowDataset
+    from raft_tpu.data.datasets import FlyingChairs
+    from raft_tpu.data.frame_utils import write_flo
 
-    class SynthDataset(FlowDataset):
-        def __init__(self):
-            super().__init__()
-            self.split = "synthetic"
-            self.image_list = [(f"synth://{i}/a", f"synth://{i}/b")
-                               for i in range(n)]
-
-        def load(self, index, rng=None):
-            # The chaos seam lives in FlowDataset.load; replicate the
-            # injection check here since we synthesize instead of read.
-            from raft_tpu import chaos
-            from raft_tpu.data.datasets import SampleReadError
-
-            ds, index = self._sample_parts(index)
-            index = index % len(ds.image_list)
-            if chaos.should_inject("corrupt_image",
-                                   point="data.sample_read"):
-                raise SampleReadError(ds.image_list[index][0], ds, index,
-                                      "chaos-injected corrupt sample")
-            H, W = hw
-            r = np.random.default_rng(index)
-            img1 = r.uniform(0, 255, (H, W, 3)).astype(np.float32)
-            img2 = np.roll(img1, 1, axis=1)
-            flow = np.zeros((H, W, 2), np.float32)
-            flow[..., 0] = 1.0
-            return {"image1": img1, "image2": img2, "flow": flow,
-                    "valid": np.ones((H, W), np.float32)}
-
-    return SynthDataset()
+    data = os.path.join(root, "chairs")
+    os.makedirs(data, exist_ok=True)
+    flow = np.zeros(hw + (2,), np.float32)
+    flow[..., 0] = 1.0
+    for i in range(n):
+        img1 = np.random.default_rng(i).integers(0, 256, hw + (3,),
+                                                 dtype=np.uint8)
+        Image.fromarray(img1).save(os.path.join(data, f"{i:05d}_img1.ppm"))
+        Image.fromarray(np.roll(img1, 1, axis=1)).save(
+            os.path.join(data, f"{i:05d}_img2.ppm"))
+        write_flo(os.path.join(data, f"{i:05d}_flow.flo"), flow)
+    split = os.path.join(root, "chairs_split.txt")
+    with open(split, "w") as f:
+        f.write("1\n" * n)
+    return FlyingChairs(None, root=data, split_file=split)
 
 
 def _count_events(tdir):
@@ -153,8 +145,8 @@ def main(argv=None) -> int:
 
         # ---- scenario 1+2: train under corrupt sample + torn newest
         # checkpoint, then resume through the fallback chain ----------
-        # num_workers=1 keeps the call-ordinal trigger deterministic
-        # (docs/ROBUSTNESS.md determinism caveat).
+        # The loader draws each sample's chaos decision in this process,
+        # in stream order: the call-ordinal trigger is deterministic.
         chaos.install(chaos.FaultPlan.parse(
             f"corrupt_image@call=3;torn_ckpt@step={steps1}",
             seed=args.seed))
@@ -174,7 +166,8 @@ def main(argv=None) -> int:
         # otherwise be healed by the same-file retry (the rule is
         # exhausted by the time the retry re-reads) — this smoke wants
         # to see the QUARANTINE path, not the retry path.
-        loader = ShardedLoader(_make_dataset(n_samples, hw),
+        dataset = _make_dataset(root, n_samples, hw)
+        loader = ShardedLoader(dataset,
                                batch_size=bs, seed=7, num_workers=1,
                                sample_retries=0)
         state = train(model_cfg, make_cfg(steps1, val_freq=2),
@@ -188,7 +181,7 @@ def main(argv=None) -> int:
         # Resume: newest step is torn; fallback must restore an older
         # one and still reach the new target.  val_freq=3 keeps the
         # resumed run's saves off the torn step number.
-        loader2 = ShardedLoader(_make_dataset(n_samples, hw),
+        loader2 = ShardedLoader(dataset,
                                 batch_size=bs, seed=7, num_workers=1)
         state2 = train(model_cfg, make_cfg(steps2, val_freq=3),
                        loader=loader2, telemetry_dir=tdir)

@@ -48,24 +48,10 @@ from raft_tpu.config import RAFTConfig, TrainConfig  # noqa: E402
 from raft_tpu.data.datasets import ShardedLoader  # noqa: E402
 from raft_tpu.train.loop import train  # noqa: E402
 
+from loader_datasets import RandomPairs  # noqa: E402
+
 H, W = 48, 64
 NUM_STEPS, PREEMPT_AT, VAL_FREQ = 6, 3, 2
-
-
-class SynthDataset:
-    """16 deterministic samples keyed on index (stands in for decode+aug)."""
-
-    def __len__(self):
-        return 16
-
-    def load(self, index, rng=None):
-        r = np.random.default_rng(1000 + index)
-        return {
-            "image1": r.uniform(0, 255, (H, W, 3)).astype(np.float32),
-            "image2": r.uniform(0, 255, (H, W, 3)).astype(np.float32),
-            "flow": (4 * r.standard_normal((H, W, 2))).astype(np.float32),
-            "valid": np.ones((H, W), np.float32),
-        }
 
 
 class PreemptingLoader:
@@ -94,7 +80,7 @@ class PreemptingLoader:
 
 
 def make_loader():
-    return ShardedLoader(SynthDataset(), batch_size=2, seed=7,
+    return ShardedLoader(RandomPairs(16, (H, W)), batch_size=2, seed=7,
                          num_hosts=nproc, host_id=pid, num_workers=2)
 
 

@@ -31,6 +31,8 @@ from raft_tpu.data.datasets import (FlowDataset, SampleReadError,
 from raft_tpu.data.prefetch import DevicePipeline
 from raft_tpu.obs import EventSink, MetricRegistry
 
+from loader_datasets import SynthDataset, ledger
+
 REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
 
 
@@ -151,40 +153,16 @@ def test_sample_read_error_carries_dataset_context(tmp_path):
     assert isinstance(e.__cause__, ValueError)  # original kept chained
 
 
-class _PoisonDataset(FlowDataset):
-    """In-memory dataset; indices in ``poison`` always fail to decode."""
-
-    def __init__(self, n=13, hw=(8, 10), poison=()):
-        super().__init__()
-        self.split = "synthetic"
-        self.hw = hw
-        self.poison = set(poison)
-        self.image_list = [(f"synth://{i}/a", f"synth://{i}/b")
-                           for i in range(n)]
-        self.load_calls = []
-
-    def load(self, index, rng=None):
-        self.load_calls.append(index)
-        if index in self.poison:
-            raise SampleReadError(self.image_list[index][0], self, index,
-                                  "synthetic corruption")
-        H, W = self.hw
-        base = np.full((H, W, 3), float(index), np.float32)
-        jitter = (rng.standard_normal((H, W, 3)).astype(np.float32)
-                  if rng is not None else 0.0)
-        return {"image1": base + jitter, "image2": base * 2.0,
-                "flow": np.zeros((H, W, 2), np.float32),
-                "valid": np.ones((H, W), np.float32)}
-
-
 def test_quarantine_skips_bad_sample_and_keeps_shapes(tmp_path):
     """A corrupt sample is retried, quarantined (event + counter), and
     deterministically replaced — batches keep their shape and the run
-    keeps going."""
+    keeps going.  The sample fails inside a worker process; the event,
+    the counters and ``quarantined_total`` are the parent's."""
     reg = MetricRegistry()
     sink = EventSink(str(tmp_path))
-    ds = _PoisonDataset(n=13, poison={5})
-    loader = ShardedLoader(ds, batch_size=2, seed=7, num_workers=1,
+    calls = str(tmp_path / "calls")
+    ds = SynthDataset(n=13, poison={5}, ledger=calls)
+    loader = ShardedLoader(ds, batch_size=2, seed=7, num_workers=2,
                            sample_retries=1, sink=sink, registry=reg)
     it = loader.batches()
     batches = [next(it) for _ in range(6)]  # the full epoch
@@ -194,11 +172,13 @@ def test_quarantine_skips_bad_sample_and_keeps_shapes(tmp_path):
         assert b["image1"].shape == (2, 8, 10, 3)
     assert loader.quarantined_total == 1
     assert reg.counter("raft_data_quarantined_total").value() == 1
+    # every delivered sample was booked in the parent from its header
+    assert reg.counter("raft_data_samples_total").value() >= 12
     # the same poisoned file was retried sample_retries+1 times
-    assert ds.load_calls.count(5) == 2
+    assert ledger(calls).count(5) == 2
     (ev,) = [e for e in _events(str(tmp_path))
              if e["event"] == "sample_quarantine"]
-    assert ev["dataset"] == "_PoisonDataset"
+    assert ev["dataset"] == "SynthDataset"
     assert ev["split"] == "synthetic"
     assert ev["path"] == "synth://5/a"
     assert ev["index"] == 5 and ev["original_index"] == 5
@@ -210,8 +190,8 @@ def test_quarantine_replacement_is_deterministic():
     streams — the replacement draw is keyed on (seed, epoch, index),
     not on scheduling or wall clock."""
     def stream():
-        loader = ShardedLoader(_PoisonDataset(n=13, poison={5}),
-                               batch_size=2, seed=7, num_workers=1,
+        loader = ShardedLoader(SynthDataset(n=13, poison={5}),
+                               batch_size=2, seed=7, num_workers=2,
                                sink=EventSink(None))
         it = loader.batches()
         out = [next(it) for _ in range(6)]
@@ -230,7 +210,7 @@ def test_chaos_disabled_stream_bit_identical():
     loader + DevicePipeline is bit-identical to the plain path (the
     injection points add no RNG draws, no reordering, nothing)."""
     def stream(depth):
-        loader = ShardedLoader(_PoisonDataset(n=13), batch_size=2,
+        loader = ShardedLoader(SynthDataset(n=13), batch_size=2,
                                seed=7, num_workers=1,
                                sink=EventSink(None))
         pipe = DevicePipeline(loader.batches(), depth=depth)
@@ -251,15 +231,16 @@ def test_chaos_disabled_stream_bit_identical():
                 np.testing.assert_array_equal(x[k], y[k])
 
 
-def test_quarantine_gives_up_when_everything_is_rotten():
-    ds = _PoisonDataset(n=5, poison=set(range(5)))
+def test_quarantine_gives_up_when_everything_is_rotten(tmp_path):
+    calls = str(tmp_path / "calls")
+    ds = SynthDataset(n=5, poison=set(range(5)), ledger=calls)
     loader = ShardedLoader(ds, batch_size=2, seed=7, num_workers=1,
                            sample_retries=0, sample_resamples=3,
                            sink=EventSink(None))
     with pytest.raises(RuntimeError, match="replacement"):
         loader._load_one(0, 1)
     # 1 original + 3 replacements, each tried once
-    assert len(ds.load_calls) == 4
+    assert len(ledger(calls)) == 4
     assert loader.quarantined_total == 4
 
 
@@ -269,11 +250,64 @@ def test_worker_err_injection_propagates_not_quarantines():
     from raft_tpu.chaos import InjectedWorkerCrash
 
     chaos.install(FaultPlan.parse("worker_err@call=0"))
-    loader = ShardedLoader(_PoisonDataset(n=5), batch_size=2, seed=7,
+    loader = ShardedLoader(SynthDataset(n=5), batch_size=2, seed=7,
                            num_workers=1, sink=EventSink(None))
     with pytest.raises(InjectedWorkerCrash):
         loader._load_one(0, 1)
     assert loader.quarantined_total == 0
+
+
+def _worker_pids():
+    """This process's loader workers: children running
+    ``raft_tpu.data.workers``."""
+    pids = []
+    for d in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{d}/stat") as f:
+                ppid = int(f.read().rsplit(")", 1)[1].split()[1])
+            with open(f"/proc/{d}/cmdline", "rb") as f:
+                cmd = f.read()
+        except OSError:
+            continue
+        if ppid == os.getpid() and b"raft_tpu.data.workers" in cmd:
+            pids.append(int(d))
+    return pids
+
+
+@pytest.mark.parametrize("how", ["worker_err", "killed"])
+def test_a_failed_worker_fails_next_within_a_time_limit(how):
+    """`worker_err` (drawn in the parent at submit, raised in the worker)
+    and a worker process that dies both end the stream with an error out
+    of ``next()``, never a hang, and no worker outlives the stream."""
+    import signal
+
+    from raft_tpu.chaos import InjectedWorkerCrash
+
+    if how == "worker_err":
+        chaos.install(FaultPlan.parse("worker_err@call=6"))
+    it = ShardedLoader(SynthDataset(n=13), batch_size=2, seed=7,
+                       num_workers=2, sink=EventSink(None)).batches()
+    next(it)
+    if how == "killed":
+        victim, _ = _worker_pids()
+        os.kill(victim, signal.SIGKILL)
+        # Gone once a zombie (its sockets closed): the very next wait on
+        # the workers sees it, however fast the tiny samples come.
+        deadline = time.monotonic() + 20.0
+        while time.monotonic() < deadline:
+            with open(f"/proc/{victim}/stat") as f:
+                if f.read().rsplit(")", 1)[1].split()[0] == "Z":
+                    break
+            time.sleep(0.01)
+    t0 = time.monotonic()
+    with pytest.raises(InjectedWorkerCrash if how == "worker_err"
+                       else RuntimeError,
+                       match="crash" if how == "worker_err"
+                       else "killed by signal 9"):
+        for _ in range(12):
+            next(it)
+    assert time.monotonic() - t0 < 30.0
+    assert not _worker_pids()
 
 
 def test_corrupt_image_injection_fires_at_sample_read(tmp_path):
@@ -292,7 +326,7 @@ def test_corrupt_image_injection_fires_at_sample_read(tmp_path):
     loader = ShardedLoader(ds, batch_size=2, seed=1, num_workers=1,
                            sample_retries=0, sink=sink)
     it = loader.batches()
-    next(it)
+    next(it), next(it)  # the third sample's header reaches the parent
     it.close()
     sink.close()
     evs = [e["event"] for e in _events(str(tmp_path))]

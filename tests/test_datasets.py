@@ -17,6 +17,8 @@ from raft_tpu.data.datasets import (ConcatFlowDataset, FlyingChairs,
                                     FlyingThings3D, HD1K, KITTI,
                                     MpiSintel, ShardedLoader, fetch_dataset)
 
+from loader_datasets import CountingDataset, HeldDataset, SynthDataset
+
 H, W = 96, 128
 
 
@@ -277,28 +279,12 @@ def test_batches_from_step_resumes_shuffle(sintel_root):
             np.testing.assert_array_equal(a[k], b[k])
 
 
-class _CountingDataset:
-    """Samples that say who they are, and a record of what was asked for
-    before what was handed out."""
-
-    def __init__(self, n):
-        self.n, self.asked = n, []
-
-    def __len__(self):
-        return self.n
-
-    def load(self, idx, rng):
-        self.asked.append(idx)
-        return {"x": np.full((2,), idx, np.float32),
-                "r": rng.uniform(size=(1,)).astype(np.float32)}
-
-
 @pytest.mark.parametrize("drop_last", [True, False])
 def test_batches_are_each_epochs_own_across_its_end(drop_last):
     """No batch spans two epochs, every batch holds the epoch's indices in
     their order with the draws of ``[seed, epoch, index]``, and a short last
     batch appears only where ``drop_last`` is off."""
-    ds = _CountingDataset(7)
+    ds = CountingDataset(7)
     loader = ShardedLoader(ds, batch_size=3, seed=11, num_workers=2,
                            drop_last=drop_last)
     it = loader.batches()
@@ -314,31 +300,83 @@ def test_batches_are_each_epochs_own_across_its_end(drop_last):
                 assert r[0] == np.float32(rng.uniform())
 
 
-def test_decode_window_stays_full_across_an_epochs_end():
-    """The window of decode futures runs on into the next epoch: the last
-    sample of a 4-sample epoch is held until a fifth has been asked for,
-    which a loader that drains its window at the epoch's end never does."""
-    import threading
-
-    fifth = threading.Event()
-
-    class Held(_CountingDataset):
-        lock, calls, in_time = threading.Lock(), 0, True
-
-        def load(self, idx, rng):
-            with self.lock:
-                self.calls += 1
-                call = self.calls
-            if call == 5:
-                fifth.set()
-            if call == 4:
-                self.in_time = fifth.wait(timeout=20.0)
-            return super().load(idx, rng)
-
-    ds = Held(4)
-    it = ShardedLoader(ds, batch_size=2, seed=3, num_workers=2).batches()
+def test_decode_window_stays_full_across_an_epochs_end(tmp_path):
+    """The window of samples in flight runs on into the next epoch: the
+    last sample of a 4-sample epoch is held until a fifth has been asked
+    for, which a loader that drains its window at the epoch's end never
+    does."""
+    last = int(ShardedLoader(CountingDataset(4), batch_size=2,
+                             seed=3).epoch_indices(0)[-1])
+    it = ShardedLoader(HeldDataset(4, str(tmp_path), hold=last),
+                       batch_size=2, seed=3, num_workers=2).batches()
     next(it), next(it)                   # epoch 0, whole
-    assert ds.in_time
+    it.close()
+    assert (tmp_path / "in_time").read_text().split() == ["1"]
+
+
+@pytest.mark.parametrize("source,start_step,workers", [
+    ("synth", 0, 3), ("synth", 5, 3), ("sintel", 0, 3), ("sintel", 5, 3),
+    ("synth", 5, (os.cpu_count() or 1) + 2)])   # more workers than cores
+def test_worker_batches_equal_the_serial_loads(source, start_step, workers,
+                                               sintel_root):
+    """The workers' batches are ``np.stack`` of ``_load_one``'s samples
+    loaded one by one here, bit for bit and in order, across epoch ends
+    and from a mid-epoch ``batches_from_step``; no worker imports jax."""
+    if source == "synth":
+        ds = SynthDataset(n=13, jax_key=True)
+    else:
+        ds = MpiSintel({"crop_size": (48, 64), "min_scale": -0.1,
+                        "max_scale": 0.1, "do_flip": True},
+                       split="training", root=sintel_root, dstype="clean")
+    loader = ShardedLoader(ds, batch_size=2, seed=9, num_workers=workers)
+    it = loader.batches_from_step(start_step)
+    got = [next(it) for _ in range(8)]
+    it.close()
+    want, buf = [], []
+    epoch, skip = divmod(start_step, loader.steps_per_epoch())
+    for e, i, ends_epoch in loader._samples(epoch, skip):
+        buf.append(loader._load_one(e, i))
+        if len(buf) == 2 or ends_epoch:
+            want.append({k: np.stack([b[k] for b in buf]) for k in buf[0]})
+            buf = []
+        if len(want) == len(got):
+            break
+    for g, w in zip(got, want):
+        assert list(g) == list(w)
+        for k in g:
+            if k != "jax":
+                np.testing.assert_array_equal(g[k], w[k])
+    if source == "synth":   # the parent loads the stream's first sample
+        assert not np.concatenate([g["jax"] for g in got])[1:].any()
+
+
+def test_a_sample_of_another_layout_fails_its_batch():
+    """A batch is one slot laid out by the stream's first sample: a sample
+    of other shapes raises out of ``next()`` (as ``np.stack`` would have),
+    and is not quarantined as a corrupt file."""
+    loader = ShardedLoader(CountingDataset(5, grow=True), batch_size=2,
+                           seed=1, num_workers=2)
+    with pytest.raises(ValueError, match="layout"):
+        for _ in loader.batches():
+            pass
+    assert loader.quarantined_total == 0
+
+
+def test_a_held_batch_stays_unchanged_while_later_ones_are_made():
+    """A batch is a view of a shared slot, and the slot is not written
+    again while any view of it lives: not while later batches are made,
+    nor after the workers are gone."""
+    it = ShardedLoader(SynthDataset(n=13), batch_size=2, seed=7,
+                       num_workers=2).batches()
+    held = next(it)
+    kept = {k: v.copy() for k, v in held.items()}
+    for _ in range(13):                  # two epochs and more
+        next(it)
+    for k in held:
+        np.testing.assert_array_equal(held[k], kept[k])
+    it.close()
+    for k in held:
+        np.testing.assert_array_equal(held[k], kept[k])
 
 
 @pytest.fixture

@@ -20,32 +20,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_tpu.data.datasets import FlowDataset, ShardedLoader
+from raft_tpu.data.datasets import ShardedLoader
 from raft_tpu.data.prefetch import DevicePipeline
 
+from loader_datasets import SynthDataset, ledger
+
 REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
-
-
-class _SynthDataset(FlowDataset):
-    """In-memory dataset: deterministic f(index) content plus an
-    rng-dependent 'augmentation' draw, so stream-identity checks cover
-    the per-sample RNG plumbing too."""
-
-    def __init__(self, n=13, hw=(8, 10)):
-        super().__init__()
-        self.hw = hw
-        self.image_list = [("a", "b")] * n  # drives len()
-        self.loads = []  # (epoch-agnostic) load-call ledger
-
-    def load(self, index, rng=None):
-        self.loads.append(index)
-        H, W = self.hw
-        base = np.full((H, W, 3), float(index), np.float32)
-        jitter = (rng.standard_normal((H, W, 3)).astype(np.float32)
-                  if rng is not None else 0.0)
-        return {"image1": base + jitter, "image2": base * 2.0,
-                "flow": np.zeros((H, W, 2), np.float32),
-                "valid": np.ones((H, W), np.float32)}
 
 
 def _noise_fn(seed, start_step):
@@ -73,7 +53,7 @@ def test_prefetch_on_off_identical_streams_and_resume():
     """Acceptance: prefetch-on and prefetch-off batch streams are
     bit-identical, including mid-epoch resume via batches_from_step and
     the stateful resume-keyed noise RNG applied in the producer."""
-    ds = _SynthDataset(n=13)  # batch 2, drop_last -> 6 steps/epoch
+    ds = SynthDataset(n=13)  # batch 2, drop_last -> 6 steps/epoch
 
     def stream(depth, start_step):
         loader = ShardedLoader(ds, batch_size=2, seed=7, num_workers=2)
@@ -97,7 +77,7 @@ def test_prefetch_device_put_parity_and_sharding():
     from raft_tpu.parallel import make_batch_sharder, make_mesh
 
     put = make_batch_sharder(make_mesh())
-    ds = _SynthDataset(n=20)
+    ds = SynthDataset(n=20)
 
     def stream(depth):
         loader = ShardedLoader(ds, batch_size=8, seed=3, num_workers=2)
@@ -112,8 +92,8 @@ def test_prefetch_device_put_parity_and_sharding():
                                           np.asarray(b[k]))
 
 
-def test_loader_prefetch_batches_stream_invariant_and_window():
-    """The decode-window knob changes HOW FAR the pool runs ahead, never
+def test_loader_prefetch_batches_stream_invariant_and_window(tmp_path):
+    """The decode-window knob changes HOW FAR the workers run ahead, never
     the stream; the window actually bounds load-call runahead."""
     def batches(pb, ds):
         loader = ShardedLoader(ds, batch_size=2, seed=5, num_workers=2,
@@ -121,21 +101,22 @@ def test_loader_prefetch_batches_stream_invariant_and_window():
         it = loader.batches()
         return [next(it) for _ in range(7)]
 
-    a = batches(0, _SynthDataset(n=13))
-    b = batches(5, _SynthDataset(n=13))
+    a = batches(0, SynthDataset(n=13))
+    b = batches(5, SynthDataset(n=13))
     for x, y in zip(a, b):
         for k in x:
             np.testing.assert_array_equal(x[k], y[k])
 
     # window = prefetch_batches * batch_size = 2 samples: after pulling
     # 2 batches (4 samples), at most 4 + 2 loads may have been submitted.
-    ds = _SynthDataset(n=13)
+    calls = str(tmp_path / "calls")
+    ds = SynthDataset(n=13, ledger=calls)
     loader = ShardedLoader(ds, batch_size=2, seed=5, num_workers=2,
                            prefetch_batches=1)
     it = loader.batches()
     next(it), next(it)
-    time.sleep(0.2)  # give the pool every chance to overrun
-    assert len(ds.loads) <= 2 * 2 + 1 * 2, ds.loads
+    time.sleep(0.5)  # give the workers every chance to overrun
+    assert len(ledger(calls)) <= 2 * 2 + 1 * 2, ledger(calls)
     it.close()
 
 

@@ -18,9 +18,11 @@ import numpy as np
 import pytest
 
 from raft_tpu.config import RAFTConfig, TrainConfig
-from raft_tpu.data.datasets import FlowDataset, ShardedLoader
+from raft_tpu.data.datasets import ShardedLoader
 from raft_tpu.obs import stages, trace
 from raft_tpu.serve import InferenceEngine, ServeConfig
+
+from loader_datasets import SynthDataset
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -251,21 +253,6 @@ def test_d_a_profiler_capture_holds_the_stage_annotations(engine,
 
 # ----------------------------------------- (c) the train loop and the feed
 
-class _SynthDataset(FlowDataset):
-    def __init__(self, n=16, hw=(32, 32)):
-        super().__init__()
-        self.hw = hw
-        self.image_list = [("a", "b")] * n
-
-    def load(self, index, rng=None):
-        H, W = self.hw
-        time.sleep(0.002)                 # decode + augment take a while
-        return {"image1": np.full((H, W, 3), float(index), np.float32),
-                "image2": np.zeros((H, W, 3), np.float32),
-                "flow": np.zeros((H, W, 2), np.float32),
-                "valid": np.ones((H, W), np.float32)}
-
-
 def test_c_six_steps_leave_six_records_without_telemetry(tmp_path,
                                                          monkeypatch):
     from test_obs import _stub_loop
@@ -278,7 +265,8 @@ def test_c_six_steps_leave_six_records_without_telemetry(tmp_path,
                       image_size=(32, 32), iters=2, val_freq=100,
                       log_freq=3, ckpt_dir=str(tmp_path / "ck"),
                       device_prefetch=2)
-    loader = ShardedLoader(_SynthDataset(), batch_size=8, seed=1,
+    loader = ShardedLoader(SynthDataset(n=16, hw=(32, 32), sleep_s=0.002),
+                           batch_size=8, seed=1,
                            num_workers=2)
     state = loop_mod.train(
         RAFTConfig.small_model(corr_levels=2, corr_radius=2), cfg,
