@@ -288,13 +288,16 @@ class TrainTelemetry:
             fields["loss_iter"] = [round(float(v), 6) for v in loss_iter]
         self.sink.emit("train_health", step=step, **fields)
 
-    def record_compile(self, step: int, seconds: float, key) -> None:
+    def record_compile(self, step: int, seconds: float, key,
+                       step_builds: Optional[int] = None) -> None:
         """First dispatch of a jitted step signature: trace+compile
-        dominates its wall time, so that is the recorded figure."""
+        dominates its wall time, so that is the recorded figure.
+        ``step_builds``: lowerings of the step the run made (1 where
+        every call shares one jit cache key)."""
         if not self.enabled:
             return
         self.sink.emit("compile", step=step, key=str(key),
-                       seconds=round(seconds, 6))
+                       seconds=round(seconds, 6), step_builds=step_builds)
 
     def record_hbm(self, info: dict) -> None:
         if not self.enabled:

@@ -8,6 +8,7 @@ from raft_tpu.parallel.mesh import (  # noqa: F401
     kernel_mesh,
     make_batch_sharder,
     mesh_shape,
+    place_replicated,
     replicated_sharding,
     shard_batch,
     spatial_batch_sharding,

@@ -102,6 +102,28 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def place_replicated(tree, mesh: Mesh):
+    """``tree`` with every leaf a global array replicated over ``mesh``:
+    the placement, and the type, that the train step gives its state
+    back with (``out_shardings`` in ``train/step.py``).  A state placed
+    here enters the step's first call under the jit cache key of every
+    later call, so the step is traced, lowered and compiled once.
+
+    Single-host: one sharded ``device_put`` (a leaf already on the
+    mesh's first device keeps its buffer there).  Multi-host: each
+    process holds the whole value, and the global array is assembled
+    from those copies as :func:`make_batch_sharder` assembles a batch; a
+    leaf that already is a global array on this sharding (a resumed
+    state) is kept as it is."""
+    sh = replicated_sharding(mesh)
+    if jax.process_count() == 1:
+        return jax.device_put(tree, sh)
+    return jax.tree_util.tree_map(
+        lambda x: x if getattr(x, "sharding", None) == sh
+        else jax.make_array_from_process_local_data(sh, np.asarray(x)),
+        tree)
+
+
 def mesh_shape(mesh: Mesh) -> Dict[str, int]:
     """``{'data': N, 'spatial': K}`` — the serializable topology stamp
     checkpoints record so a restore on a DIFFERENT mesh can report what

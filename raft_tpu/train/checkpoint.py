@@ -216,7 +216,11 @@ class CheckpointManager:
         """Synchronous-path save (orbax may still flush in background;
         ``wait()`` joins it).  The train loop's hot path uses
         :meth:`save_async` instead; this is the emergency/final-flush
-        and offline-tool path."""
+        and offline-tool path.  Commits handed to :meth:`save_async`
+        finish first: orbax takes one save at a time, and the loop's
+        final flush can follow a periodic save within a step."""
+        if self._commit_q is not None:
+            self._commit_q.join()
         self._mgr.save(step, args=ocp.args.StandardSave(state), force=force)
         self._last_requested = int(step)
         self._stamp_topology(step, mesh)

@@ -41,14 +41,15 @@ from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
 from raft_tpu.obs.watchdog import StallWatchdog, stack_dump_path
 from raft_tpu.parallel import (data_parallel_kernels, make_batch_sharder,
-                               make_mesh)
+                               make_mesh, place_replicated)
 from raft_tpu.train.checkpoint import CheckpointManager
 from raft_tpu.train.logger import Logger
 from raft_tpu.train.loss import sequence_loss  # noqa: F401 (re-export)
 from raft_tpu.train.optim import make_optimizer, schedule_of
 from raft_tpu.train.state import TrainState
 from raft_tpu.train.step import init_state, make_train_step, step_cost
-from raft_tpu.utils.profiling import StepProfiler, annotate_step, hbm_usage
+from raft_tpu.utils.profiling import (StepProfiler, annotate_step, hbm_usage,
+                                     listen_for_compiles)
 
 # Cooperative preemption: a SIGTERM handler (cli/train.py) sets this and
 # the loop exits at the NEXT STEP BOUNDARY — an async exception could
@@ -64,6 +65,15 @@ _warned_sync = False
 #: is asynchronous: a loop fed faster than its device ran up to a Logger
 #: interval ahead (+3.6 GB at `train_gmflow_chairs`, PERF.md, PR 37).
 _MAX_IN_FLIGHT = 2
+
+#: The dispatch after which the loop reads how often its step was built
+#: (``step_builds``): a state whose type differs from what the step gives
+#: back builds the step a second time at the second dispatch.
+_BUILDS_SETTLED = 3
+
+#: The name the compile listener gives a lowering of the mesh train step
+#: (``mesh_step_fn`` in train/step.py, under ``jit``).
+_STEP_LOWERING = "jit(mesh_step_fn)"
 
 
 def request_preemption() -> None:
@@ -98,6 +108,21 @@ def _reached_preemption_sync(step: int) -> bool:
             print(f"preemption sync unavailable ({type(e).__name__}: {e});"
                   " falling back to no multi-host preemption", flush=True)
         return False
+
+
+def _compile_seq() -> int:
+    """Sequence number of the compile ring's newest record (0: none)."""
+    recs = stages.recent("compile")
+    return recs[-1]["n"] if recs else 0
+
+
+def _step_builds(since: int) -> int:
+    """Lowerings of the mesh train step that the compile listener booked
+    after the compile ring's record ``since``: 1 where every call of the
+    step shares one jit cache key."""
+    return sum(1 for r in stages.recent("compile")
+               if r["n"] > since and r["kind"] == "lower"
+               and r.get("name") == _STEP_LOWERING)
 
 
 def add_image_noise(rng: np.random.Generator, batch: Dict) -> Dict:
@@ -151,6 +176,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     assert (batches is None) != (loader is None), \
         "pass exactly one of batches= or loader="
     _PREEMPT.clear()  # a new run starts unpreempted
+    listen_for_compiles()  # the compile ring `step_builds` reads
     mesh = mesh or make_mesh()
     model = RAFT(model_cfg)
     tx = make_optimizer(cfg.lr, cfg.num_steps, cfg.wdecay, cfg.epsilon,
@@ -189,6 +215,13 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         topo = saved_on.get("mesh", saved_on.get("device_count"))
         print(f"resumed from step {int(state.step)}"
               + (f" (saved on {topo})" if topo else ""), flush=True)
+    # Fresh, warm-started or resumed: the state enters step 0 placed and
+    # typed as the step gives it back (replicated over the mesh), so every
+    # call shares one jit cache key.  An unplaced state is a second key:
+    # the step is traced, lowered and compiled (or loaded) twice.  The
+    # unplaced trees are dropped with their names.
+    state = place_replicated(state, mesh)
+    restore_params = resumed = None
 
     step_fn = make_train_step(model, tx, cfg, mesh,
                               shard_spatial=shard_spatial)
@@ -309,6 +342,12 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
         "summed over train steps")
     first_dispatched = False
     run_step, compiled = step_fn, None
+    # The step's builds, read from the compile ring once after the third
+    # dispatch: they ride the `train` records from then on and the
+    # `compile` event, with the first dispatch's step and seconds.
+    first_step, since = step, _compile_seq()
+    step_builds = first_compile = None
+    compile_key = ("train_step", tuple(cfg.image_size), cfg.batch_size)
     in_flight = collections.deque()  # the losses of steps on the device
     try:
         while True:
@@ -405,11 +444,16 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 profiler.maybe_stop(step, sync_on=metrics.get("loss"))
                 step += 1
                 logger.push(step - 1, metrics)
+                if step - first_step == _BUILDS_SETTLED:
+                    step_builds = _step_builds(since)
+                    telem.record_compile(*first_compile, key=compile_key,
+                                         step_builds=step_builds)
             rec = stages.end("train", registry=telem.registry,
                              step=step - 1, model=model_cfg.arch,
                              attn_bytes=attn_bytes,
                              window_attention=attn_path,
-                             predictions=n_pred, bn_calls=bn_calls)
+                             predictions=n_pred, bn_calls=bn_calls,
+                             step_builds=step_builds)
             if attn_bytes:
                 attn_counter.inc(attn_bytes, loop="train")
             if bn_calls:
@@ -422,10 +466,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                 first_dispatched = True
                 # The first dispatch of this signature traces+compiles
                 # synchronously — its wall time IS the compile figure.
-                telem.record_compile(
-                    step - 1, step_time_s,
-                    key=("train_step", tuple(cfg.image_size),
-                         cfg.batch_size))
+                first_compile = (step - 1, step_time_s)
                 if telem.hbm_enabled or telem.cost_enabled:
                     # From the executable compiled above (host-side
                     # metadata, runs once; RAFT_TELEMETRY_HBM=0 /
@@ -537,6 +578,10 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     finally:
         if watchdog is not None:
             watchdog.stop()  # first: teardown below can be slow
+        if first_compile is not None and step_builds is None:
+            # fewer than three steps ran: their builds, read at the end
+            telem.record_compile(*first_compile, key=compile_key,
+                                 step_builds=_step_builds(since))
         pipeline.close()
         mgr.wait()
         mgr.close()

@@ -133,3 +133,98 @@ def test_single_host_request_preemption_saves_and_resumes(tmp_path):
 
     state = train(mcfg, tcfg, _batches(10, tcfg))
     assert int(state.step) == 6
+
+
+# ------------------------------------------------ one build of the step
+
+def _step_builds_of(run):
+    """Run ``run()`` (a train() call) -> the compile ring's records of the
+    mesh train step it left: kinds of ``mesh_step_fn`` (traces) and of
+    ``jit(mesh_step_fn)`` (lowering, compile or cache load)."""
+    from raft_tpu.obs import stages
+    from raft_tpu.utils.profiling import listen_for_compiles
+
+    listen_for_compiles()
+    before = len(stages.recent("compile"))
+    state = run()
+    mine = stages.recent("compile")[before:]
+    return state, [r["kind"] for r in mine
+                   if r["name"] in ("mesh_step_fn", "jit(mesh_step_fn)")]
+
+
+def _tiny(tmp_path, name, num_steps, **kw):
+    return TrainConfig(name=name, lr=1e-4, num_steps=num_steps,
+                       batch_size=2, image_size=(32, 32), iters=2,
+                       val_freq=100, log_freq=100, ckpt_dir=str(tmp_path),
+                       **kw)
+
+
+ONE_BUILD = ["trace", "lower", "compile"]
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_fresh_train_builds_step_once(tmp_path, n_dev):
+    """A fresh train() of 3 steps traces, lowers and compiles the real
+    step once: the state enters step 0 with the mesh-typed sharding the
+    step gives back, so all calls share one jit cache key."""
+    from raft_tpu.parallel import make_mesh
+
+    mcfg = RAFTConfig.small_model(corr_levels=2, corr_radius=2)
+    tcfg = _tiny(tmp_path, "f", 3)
+    mesh = make_mesh(num_data=n_dev, devices=jax.devices()[:n_dev])
+    state, kinds = _step_builds_of(
+        lambda: train(mcfg, tcfg, _batches(3, tcfg), mesh=mesh))
+    assert int(state.step) == 3
+    assert kinds == ONE_BUILD
+
+
+def test_resumed_and_warm_started_train_build_step_once(tmp_path):
+    """The resumed path (restore_latest) and the warm-started one
+    (restore_params, a curriculum stage's seed) build the step once too."""
+    import dataclasses
+
+    from raft_tpu.parallel import make_mesh
+
+    mcfg = RAFTConfig.small_model(corr_levels=2, corr_radius=2)
+    mesh = make_mesh(num_data=2, devices=jax.devices()[:2])
+    tcfg = _tiny(tmp_path, "r", 2)
+    first = train(mcfg, tcfg, _batches(2, tcfg), mesh=mesh)
+    seed_params = {"params": jax.device_get(first.params),
+                   "batch_stats": jax.device_get(first.batch_stats)}
+
+    more = dataclasses.replace(tcfg, num_steps=5)
+    state, kinds = _step_builds_of(
+        lambda: train(mcfg, more, _batches(3, tcfg, seed=1), mesh=mesh))
+    assert int(state.step) == 5      # resumed at 2, three steps on
+    assert kinds == ONE_BUILD
+
+    warm = _tiny(tmp_path, "w", 3)
+    state, kinds = _step_builds_of(
+        lambda: train(mcfg, warm, _batches(3, tcfg), mesh=mesh,
+                      restore_params=seed_params))
+    assert int(state.step) == 3
+    assert kinds == ONE_BUILD
+
+
+def test_step_builds_reads_one(tmp_path, monkeypatch):
+    """``step_builds`` — the loop's own count of the step's lowerings,
+    read once after the third dispatch — is 1 on the third step's
+    `train` record and after, and on the `compile` event."""
+    import json
+
+    from raft_tpu.obs import stages
+    from raft_tpu.parallel import make_mesh
+
+    monkeypatch.setenv("RAFT_TELEMETRY_HBM", "0")
+    monkeypatch.setenv("RAFT_TELEMETRY_COST", "0")
+    mcfg = RAFTConfig.small_model(corr_levels=2, corr_radius=2)
+    tcfg = _tiny(tmp_path, "s", 4)
+    tdir = tmp_path / "telemetry"
+    train(mcfg, tcfg, _batches(4, tcfg), telemetry_dir=str(tdir),
+          mesh=make_mesh(num_data=1, devices=jax.devices()[:1]))
+    assert [r["step_builds"] for r in stages.recent("train")[-4:]] \
+        == [None, None, 1, 1]
+    (f,) = tdir.glob("telemetry-p*.jsonl")
+    recs = [json.loads(line) for line in f.read_text().splitlines()]
+    (compile_event,) = [r for r in recs if r["event"] == "compile"]
+    assert compile_event["step"] == 0 and compile_event["step_builds"] == 1

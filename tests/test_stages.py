@@ -335,6 +335,84 @@ def test_e_compile_listener_records_a_fresh_jit_once(monkeypatch):
                                                       "compile"))
 
 
+@pytest.mark.parametrize("n_dev,aot", [(1, False), (2, False), (1, True)],
+                         ids=["one_device", "two_devices", "aot"])
+def test_e_train_loop_builds_its_step_once(tmp_path, monkeypatch, n_dev,
+                                          aot):
+    """The loop's state enters step 0 typed as the step gives it back, so
+    the step has one jit cache key: one trace and one lowering of
+    ``mesh_step_fn`` in the ring, ``step_builds`` 1 on the third step's
+    record and after and on the ``compile`` event.  A stand-in step,
+    jitted with the real one's shardings (tests/test_loop.py runs the
+    real one); ``aot``: the telemetry path that compiles it ahead."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.parallel import (batch_sharding, make_mesh,
+                                   replicated_sharding)
+    from raft_tpu.train import loop as loop_mod
+    from raft_tpu.train.state import TrainState
+    from raft_tpu.utils import profiling
+
+    # the stand-in's trace and lowering take well under 50 ms
+    monkeypatch.setattr(profiling, "_MIN_BUILD_STEP_S", 0.0)
+    flag = "1" if aot else "0"
+    monkeypatch.setenv("RAFT_TELEMETRY_HBM", flag)
+    monkeypatch.setenv("RAFT_TELEMETRY_COST", flag)
+
+    def fake_init_state(model, tx, rng, size):
+        params = {"w": jnp.zeros((2, 2), jnp.float32)}
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          batch_stats={}, opt_state=tx.init(params),
+                          nonfinite_steps=jnp.zeros((), jnp.int32))
+
+    def fake_make_train_step(model, tx, cfg, mesh, shard_spatial=False):
+        def mesh_step_fn(state, batch, rng):
+            loss = jnp.mean(batch["image1"])
+            params = jax.tree_util.tree_map(lambda p: p - 1e-3 * loss,
+                                            state.params)
+            return state.replace(step=state.step + 1,
+                                 params=params), {"loss": loss}
+
+        repl = replicated_sharding(mesh)
+        return jax.jit(mesh_step_fn,
+                       in_shardings=(repl, batch_sharding(mesh), repl),
+                       out_shardings=(repl, repl), donate_argnums=(0,))
+
+    monkeypatch.setattr(loop_mod, "init_state", fake_init_state)
+    monkeypatch.setattr(loop_mod, "make_train_step", fake_make_train_step)
+    cfg = TrainConfig(name="b", num_steps=4, batch_size=2,
+                      image_size=(16, 16), iters=2, val_freq=100,
+                      log_freq=100, ckpt_dir=str(tmp_path / "ck"))
+
+    def batches():
+        for i in range(6):
+            x = np.full((2, 16, 16, 3), float(i), np.float32)
+            yield {"image1": x, "image2": x,
+                   "flow": np.zeros((2, 16, 16, 2), np.float32),
+                   "valid": np.ones((2, 16, 16), np.float32)}
+
+    before = len(stages.recent("compile"))
+    state = loop_mod.train(
+        RAFTConfig.small_model(corr_levels=2, corr_radius=2), cfg,
+        batches(), telemetry_dir=str(tmp_path / "t"),
+        mesh=make_mesh(num_data=n_dev, devices=jax.devices()[:n_dev]))
+    assert int(state.step) == 4
+    mine = stages.recent("compile")[before:]
+    assert [r["kind"] for r in mine if r["name"] == "mesh_step_fn"] \
+        == ["trace"]
+    assert [r["kind"] for r in mine if r["name"] == "jit(mesh_step_fn)"
+            and r["kind"] != "trace"] == ["lower", "compile"]
+    assert [r["step_builds"] for r in stages.recent("train")[-4:]] \
+        == [None, None, 1, 1]
+    (f,) = (tmp_path / "t").glob("telemetry-p*.jsonl")
+    events = [json.loads(line) for line in f.read_text().splitlines()]
+    (compile_event,) = [e for e in events if e["event"] == "compile"]
+    assert compile_event["step"] == 0 and compile_event["step_builds"] == 1
+
+
 # ------------------------------------------------------- (f) the six readers
 
 def _hand_built_ring():
