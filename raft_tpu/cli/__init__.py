@@ -17,8 +17,9 @@ from raft_tpu.config import ARCHS, RAFTConfig
 
 
 def add_arch_argument(parser) -> None:
-    """``--arch {full,small,gma,searaft,gmflow}`` with ``--small`` kept as an
-    alias of ``--arch small``; read the result with :func:`arch_from_args`."""
+    """``--arch {full,small,gma,searaft,gmflow,unimatch}`` with ``--small``
+    kept as an alias of ``--arch small``; read the result with
+    :func:`arch_from_args`."""
     parser.add_argument("--arch", choices=ARCHS, default=None,
                         help="model architecture (default: full)")
     parser.add_argument("--small", action="store_true",

@@ -139,10 +139,11 @@ def variables_arch(variables) -> str:
     """The architecture a variables tree is of, read off the tree itself
     (a checkpoint carries its model in its names): GMA alone has ``att``,
     SEA-RAFT alone ``init_conv``, GMFlow alone ``transformer``, the small
-    model alone no convex-upsampling mask head."""
+    model alone no convex-upsampling mask head; UniMatch's flow model is
+    GMFlow's tree with a ``refine``."""
     params = variables["params"]
     if "transformer" in params:
-        return "gmflow"
+        return "unimatch" if "refine" in params else "gmflow"
     if "att" in params:
         return "gma"
     if "init_conv" in params:

@@ -361,7 +361,9 @@ def run(argv=None):
     from raft_tpu.models.raft import corr_impl_at
     from raft_tpu.parallel.mesh import data_parallel_kernels
 
-    h8, w8 = args.image_size[0] // 8, args.image_size[1] // 8
+    # the map the lookup reads: 1/8, or 1/4 where a matching model refines
+    scale = 4 if model_cfg.global_matching else 8
+    h8, w8 = args.image_size[0] // scale, args.image_size[1] // scale
     with data_parallel_kernels(None, rows_split=args.shard_spatial > 1):
         print(f"corr_impl: {args.corr_impl} -> {corr_impl}, which at "
               f"{h8}x{w8} on {jax.default_backend()} runs "

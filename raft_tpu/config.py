@@ -36,7 +36,7 @@ QUANTIZED_CORR_DTYPES = ("int8", "float8_e4m3fn", "float8_e5m2")
 CORR_PRECISIONS = ("auto", "default", "high", "highest")
 # The architectures the model code builds (RAFTConfig.arch, the CLIs'
 # --arch).
-ARCHS = ("full", "small", "gma", "searaft", "gmflow")
+ARCHS = ("full", "small", "gma", "searaft", "gmflow", "unimatch")
 
 
 def validate_corr_dtype(value: str, flag: str = "corr_dtype") -> str:
@@ -99,8 +99,14 @@ class RAFTConfig:
     the whole correlation volume and a self-attention that propagates
     the flow (``models/gmflow.py``; it reads none of ``hidden_dim``,
     ``context_dim``, ``corr_levels``, ``corr_radius``, which keep
-    ``full``'s values).  Build one with :meth:`full`,
-    :meth:`small_model`, :meth:`gma`, :meth:`searaft`, :meth:`gmflow` or
+    ``full``'s values); and ``unimatch`` (UniMatch's flow model, GMFlow
+    at two scales with six regression refinements, Xu et al., TPAMI 2023;
+    PAPERS.md): the same Transformer matched globally at 1/8 and, over
+    image 2's features warped by the upsampled flow, locally at 1/4, then
+    refined RAFT's way at 1/4 from a one-level volume of the backbone's
+    1/4 features (``corr_levels`` 1, ``corr_radius`` 4, ``iters`` the
+    refinements).  Build one with :meth:`full`, :meth:`small_model`,
+    :meth:`gma`, :meth:`searaft`, :meth:`gmflow`, :meth:`unimatch` or
     :meth:`preset`; the widths below follow from it.
     """
 
@@ -305,11 +311,21 @@ class RAFTConfig:
         return cls.full(**{"arch": "gmflow", **kw})
 
     @classmethod
+    def unimatch(cls, **kw) -> "RAFTConfig":
+        # UniMatch's flow model (gmflow-scale2-regrefine6): GMFlow's
+        # widths at two scales (splits 2 and 8, matching radii -1 / 4,
+        # propagation radii -1 / 1) and a refinement that looks up one
+        # level of the 1/4 volume at radius 4 through RAFT's motion
+        # encoder, GRU (hidden 128) and heads.
+        return cls.full(**{"arch": "unimatch", "corr_levels": 1, **kw})
+
+    @classmethod
     def preset(cls, arch: str, **kw) -> "RAFTConfig":
         """The preset the CLIs' ``--arch`` names (an unknown name fails
         in ``__post_init__``, with the allowed set)."""
         makers = {"small": cls.small_model, "gma": cls.gma,
-                  "searaft": cls.searaft, "gmflow": cls.gmflow}
+                  "searaft": cls.searaft, "gmflow": cls.gmflow,
+                  "unimatch": cls.unimatch}
         return makers.get(arch, cls.full)(**{**kw, "arch": arch})
 
     @property
@@ -345,27 +361,49 @@ class RAFTConfig:
     @property
     def refines(self) -> bool:
         """Whether the model refines a flow in a loop of ``iters``
-        iterations over a carried state.  Where it does not (arch
-        'gmflow': one pass, two predictions in training) ``iters`` is
-        not read, a request is one program, and what needs the loop's
-        state is refused by name: ``flow_init``, slot batching,
-        streaming sessions, early exit."""
+        iterations.  Where it does not (arch 'gmflow': one pass, two
+        predictions in training) ``iters`` is not read and a CLI refuses a
+        value other than its default."""
         return self.arch != "gmflow"
 
     @property
-    def attn_splits(self) -> int:
-        """How many windows an axis of the 1/8 map is split into for
-        attention: 2 for arch 'gmflow' (``models/gmflow.py SPLITS``), 1
-        where no attention runs over windows."""
-        return 2 if self.arch == "gmflow" else 1
+    def global_matching(self) -> bool:
+        """Whether the flow comes from a Transformer's softmax matching
+        (``models/gmflow.py``: archs 'gmflow' and 'unimatch').  Such a model
+        carries no state a server can hold between calls (no hidden state,
+        pyramid or coordinates outside its one program): a request is one
+        program, and ``flow_init``, slot batching, streaming sessions and
+        early exit are refused by name."""
+        return self.arch in ("gmflow", "unimatch")
+
+    @property
+    def attn_splits(self) -> tuple:
+        """A scale's windows an axis for attention, coarsest scale first:
+        ``(2,)`` for arch 'gmflow' (``models/gmflow.py SPLITS``), ``(2,
+        8)`` for 'unimatch' (1/8, then 1/4), ``()`` where no attention
+        runs over windows."""
+        return {"gmflow": (2,), "unimatch": (2, 8)}.get(self.arch, ())
+
+    @property
+    def num_scales(self) -> int:
+        """Feature scales the matching runs at: 1/8, then 1/4."""
+        return len(self.attn_splits)
+
+    @property
+    def local_radii(self) -> tuple:
+        """``(match radius, propagation radius)`` a scale, -1 for global:
+        ``((-1, -1),)`` for arch 'gmflow', ``((-1, -1), (4, 1))`` for
+        'unimatch'."""
+        return ((-1, -1), (4, 1))[:self.num_scales]
 
     @property
     def pad_multiple(self) -> int:
-        """What the model needs H and W to be multiples of: 8 (three
-        halvings) times the windows an axis of the 1/8 map is split
-        into.  ``evaluate.py`` and the serve engine pad and bucket by
-        it."""
-        return 8 * self.attn_splits
+        """What the model needs H and W to be multiples of: at each
+        scale (1/8, 1/4) its downsampling times the windows an axis of
+        its map is split into; 8 where nothing is split.
+        ``evaluate.py`` and the serve engine pad and bucket by it."""
+        return max([8] + [(8 >> s) * k
+                          for s, k in enumerate(self.attn_splits)])
 
     @property
     def resolved_corr_dtype(self) -> str:

@@ -102,6 +102,14 @@ def _torch_key_to_path(key: str):
             merged.append(p)
     parts = merged
 
+    # UniMatch's refinement: refine.{encoder,gru,flow_head} is the update
+    # block, refine.mask.{0,2} its mask head (in the scan, beside the block)
+    if parts[0] == "refine" and parts[1] == "mask":
+        parts = ["refine", "mask_head",
+                 {"0": "mask_conv1", "2": "mask_conv2"}[parts[2]]] + parts[3:]
+    elif parts[0] == "refine":
+        parts = ["refine", "update_block"] + parts[1:]
+
     # mask Sequential -> the hoisted upsample-stage mask head
     if "mask" in parts:
         i = parts.index("mask")
@@ -126,6 +134,8 @@ def _torch_key_to_path(key: str):
 
     if parts[0] == "update_block":
         parts = ["refine"] + parts
+    if parts[-1] == "weight" and parts[-2] == "trident_conv":
+        parts[-1] = "kernel"            # a bare parameter, no conv module
 
     leaf = parts[-1]
     if leaf in ("running_mean", "running_var"):
@@ -135,7 +145,7 @@ def _torch_key_to_path(key: str):
         return "params", tuple(parts[:-1]) + ("<weight>",)
     if leaf == "bias":
         return "params", tuple(parts[:-1]) + ("<bias>",)
-    if leaf == "gamma":
+    if leaf in ("gamma", "kernel"):
         return "params", tuple(parts)
     raise ValueError(f"unrecognized torch key: {key}")
 
@@ -168,6 +178,8 @@ _SEA_KEY = re.compile(r"^(module\.)?(init_conv\.|flow_head\.|"
                       r"upsample_weight\.|update_block\.refine\.)")
 _GMFLOW_KEY = re.compile(r"^(module\.)?(backbone\.|transformer\.|"
                          r"feature_flow_attn\.|upsampler\.)")
+_UNIMATCH_KEY = re.compile(r"^(module\.)?(refine_proj\.|refine\.|"
+                           r"backbone\.trident_conv\.)")
 # BasicEncoder's bias leaves that GMFlow's bias-free convolutions leave
 # unfilled: the stem's and every 3x3's, each in front of an instance norm
 _GMFLOW_INERT_BIAS = re.compile(
@@ -208,7 +220,22 @@ def _check_arch(state_dict, template) -> None:
             "'init_conv.weight' is the first key missing (then "
             "flow_head.0.weight, update_block.refine.0.dwconv.weight, "
             "upsample_weight.0.weight)")
-    gmf_keys = sorted(k for k in state_dict if _GMFLOW_KEY.match(k))
+    uni_keys = sorted(k for k in state_dict if _UNIMATCH_KEY.match(k))
+    wants = "refine_proj" in template["params"]
+    if uni_keys and not wants:
+        raise ValueError(
+            f"the state dict is a UniMatch flow checkpoint ({uni_keys[0]!r} "
+            f"is the first of {len(uni_keys)} keys that have no place) and "
+            "the model is not: the template has no refine_proj/*, refine/* "
+            "or backbone/trident_conv leaves; convert with --arch unimatch")
+    if wants and not uni_keys:
+        raise ValueError(
+            "the model is UniMatch's flow model and the state dict is not: "
+            "'backbone.trident_conv.weight' is the first key missing (then "
+            "refine_proj.weight, refine.encoder.convc1.weight, "
+            "refine.mask.0.weight)")
+    gmf_keys = sorted(k for k in state_dict if _GMFLOW_KEY.match(k)
+                      and not _UNIMATCH_KEY.match(k))
     wants = "transformer" in template["params"]
     if gmf_keys and not wants:
         raise ValueError(
@@ -250,7 +277,7 @@ def convert_state_dict(state_dict: Dict[str, Any],
         # submodule; conv weight/bias live directly under the conv module.
         prefix = (coll,) + path[:-1]
         leaf = path[-1]
-        if leaf == "gamma":
+        if leaf in ("gamma", "kernel"):
             candidates = [(coll,) + path]
         elif leaf == "<weight>":
             candidates = [prefix + ("kernel",),
@@ -312,7 +339,7 @@ def make_template(model_cfg: RAFTConfig):
     from raft_tpu.models.raft import RAFT
 
     model = RAFT(model_cfg)
-    img = jnp.zeros((1, 48, 64, 3))
+    img = jnp.zeros((1, 64, 64, 3))      # a multiple of every pad_multiple
     shapes = jax.eval_shape(
         lambda k: model.init({"params": k, "dropout": k}, img, img, iters=1),
         jax.random.PRNGKey(0))
@@ -334,8 +361,8 @@ def main(argv=None):
     import argparse
 
     p = argparse.ArgumentParser(
-        description="Convert a reference RAFT, GMA, SEA-RAFT or GMFlow "
-                    ".pth to an orbax checkpoint")
+        description="Convert a reference RAFT, GMA, SEA-RAFT, GMFlow or "
+                    "UniMatch flow .pth to an orbax checkpoint")
     p.add_argument("pth", help="path to torch checkpoint")
     p.add_argument("out", help="output orbax checkpoint directory")
     add_arch_argument(p)

@@ -30,7 +30,7 @@ from raft_tpu.models.layers import (BottleneckBlock,
                                     FoldedEntryResidualBlock,
                                     FoldedResidualBlock, Norm,
                                     ResidualBlock, FoldedNorm,
-                                    FoldedStemConv, conv)
+                                    FoldedStemConv, conv, kaiming_out)
 
 
 class BasicEncoder(nn.Module):
@@ -43,12 +43,19 @@ class BasicEncoder(nn.Module):
     # same param tree — see layers.fold_w).  Auto-disabled when the
     # /2-res width is odd or the norm mode can't fold.
     fold_layer1: bool = True
+    # 2 (arch 'unimatch', UniMatch's ``num_output_scales=2``): the third
+    # stage keeps 1/4 (stride 1), and a trident convolution -- ONE 3x3
+    # weight, no bias, applied at stride 2 and at stride 1 -- returns the
+    # (1/8, 1/4) maps as a tuple.  1: the 1/8 map alone.
+    num_output_scales: int = 1
 
     @nn.compact
     def __call__(self, x, train: bool = False, freeze_bn: bool = False):
         dt = self.dtype
         x = x.astype(dt)
-        stages = [(64, 1), (64, 1), (96, 2), (96, 1), (128, 2), (128, 1)]
+        stride3 = 2 if self.num_output_scales == 1 else 1
+        stages = [(64, 1), (64, 1), (96, 2), (96, 1), (128, stride3),
+                  (128, 1)]
         # Stem output width is ceil(W/2) for even W (pad 3, k=7, s=2);
         # folding needs it even, i.e. W % 4 == 0 (InputPadder-padded
         # inputs always are).
@@ -89,7 +96,28 @@ class BasicEncoder(nn.Module):
         if self.dropout > 0:
             x = nn.Dropout(self.dropout, broadcast_dims=(1, 2),
                            deterministic=not train)(x)
-        return x
+        if self.num_output_scales == 1:
+            return x
+        trident = TridentConv(self.output_dim, dt, name="trident_conv")
+        return trident(x, 2), trident(x, 1)
+
+
+class TridentConv(nn.Module):
+    """UniMatch's ``MultiScaleTridentConv`` at two branches: one ``3x3``
+    kernel (pad 1, no bias) applied at each call's stride, so the 1/8 and
+    1/4 maps share their weight."""
+
+    features: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, stride: int):
+        cin = x.shape[-1]
+        kernel = self.param("kernel", kaiming_out,
+                            (3, 3, cin, self.features), jnp.float32)
+        return jax.lax.conv_general_dilated(
+            x.astype(self.dtype), kernel.astype(self.dtype), (stride, stride),
+            [(1, 1), (1, 1)], dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
 class SmallEncoder(nn.Module):

@@ -129,7 +129,7 @@ class ResidualBlock(nn.Module):
             y, train, freeze_bn)
         y = nn.relu(y)
 
-        if self.stride != 1:
+        if self.stride != 1 or cin != self.planes:
             x = conv(self.planes, 1, self.stride, self.dtype,
                      name="downsample_conv", in_features=cin)(x)
             x = Norm(self.norm, self.planes, dtype=self.dtype,
@@ -435,7 +435,7 @@ class BottleneckBlock(nn.Module):
                  name="norm3")(y, train, freeze_bn)
         y = nn.relu(y)
 
-        if self.stride != 1:
+        if self.stride != 1 or cin != self.planes:
             x = conv(self.planes, 1, self.stride, self.dtype,
                      name="downsample_conv", in_features=cin)(x)
             x = Norm(self.norm, self.planes, num_groups=groups,

@@ -104,62 +104,88 @@ def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
     """Bytes of attention matrix a step over ``pairs`` pairs at an
     ``(H/8, W/8)`` map builds and holds.  Arch 'gma': one ``(N, N)``
     matrix a pair, held through the refinement loop (training: saved for
-    the backward pass; serving: in the slot state).  Arch 'gmflow': the
-    float32 softmaxes of the matching and of the propagation (two ``(N,
-    N)`` a pair), kept from the forward pass to the backward one; the
-    twelve window attentions' ``(n, n)`` scores are not among them: the
-    Mosaic kernels never hold one outside a grid step, and under the
-    default ``RAFTConfig.remat`` the ``jnp`` body rebuilds each in the
-    backward pass (``models/gmflow.py window_attention``), ``2 * pairs * N
-    * N / 4`` float32 entries that live only while it runs.  0 for the
-    other architectures."""
+    the backward pass; serving: in the slot state).  Archs 'gmflow' and
+    'unimatch': the float32 softmaxes of the matching and of the
+    propagation at 1/8 (two ``(N, N)`` a pair), kept from the forward pass
+    to the backward one; the window attentions' ``(n, n)`` scores are not
+    among them: the Mosaic kernels never hold one outside a grid step, and
+    under the default ``RAFTConfig.remat`` the ``jnp`` body rebuilds each
+    in the backward pass (``models/gmflow.py window_attention``), and the
+    local match and propagation at 1/4 hold 81 and 9 scores a position.
+    0 for the other architectures."""
     if cfg.global_motion:
         return pairs * (h8 * w8) ** 2 * cfg.dtype.itemsize
-    if not cfg.refines:
+    if cfg.global_matching:
         return 2 * pairs * (h8 * w8) ** 2 * 4
     return 0
 
 
-def window_attention_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
-    """Which body arch 'gmflow''s window attentions run at an ``(H/8,
-    W/8)`` map in a program traced now: ``'mosaic'`` or ``'xla'``
-    (``models/gmflow.py window_attention_path``: asked inside
-    ``parallel.mesh.data_parallel_kernels`` it sees a row split);
+def window_attention_at(cfg: RAFTConfig, h8: int, w8: int):
+    """Which body the window attentions of archs 'gmflow' and 'unimatch'
+    run in a program traced now, for an ``(H/8, W/8)`` map: ``'mosaic'``
+    or ``'xla'`` (``models/gmflow.py window_attention_path``: asked inside
+    ``parallel.mesh.data_parallel_kernels`` it sees a row split), one a
+    scale -- a list where the model matches at more than one scale;
     ``'none'`` for the architectures that have none."""
-    if cfg.refines:
+    if not cfg.global_matching:
         return "none"
     from raft_tpu.models import gmflow
 
-    return gmflow.window_attention_path(h8, w8, gmflow.CHANNELS, cfg.dtype)
+    paths = [gmflow.window_attention_path(h8 << s, w8 << s, gmflow.CHANNELS,
+                                          cfg.dtype, k)
+             for s, k in enumerate(cfg.attn_splits)]
+    return paths[0] if len(paths) == 1 else paths
+
+
+def refine_counters(cfg: RAFTConfig, iters: int, h8: int, w8: int) -> dict:
+    """What the train loop's records quote of a refinement that follows a
+    matching (arch 'unimatch'): ``lookup`` (the path, the level count and
+    the map of the one-level 1/4 volume the refinements read) and
+    ``refine_iters``; ``{}`` for the other architectures."""
+    if not (cfg.global_matching and cfg.refines):
+        return {}
+    h4, w4 = 2 * h8, 2 * w8
+    path = corr_impl_at(cfg, h4, w4)
+    return {"lookup": {"path": "mosaic" if path == "allpairs_pallas"
+                       else "xla", "levels": cfg.corr_levels,
+                       "map": [h4, w4]},
+            "refine_iters": iters}
 
 
 def predictions(cfg: RAFTConfig, iters: int) -> int:
     """Flow predictions a pair a training step makes: one a refinement
     iteration, and one more where the loop starts from a regressed first
-    flow (arch 'searaft'); two whatever ``iters`` where there is no loop
-    (arch 'gmflow': the matched flow and the propagated one)."""
-    if not cfg.refines:
-        return 2
+    flow (arch 'searaft'); two a scale where the flow is matched (the
+    matched flow and the propagated one; arch 'gmflow' has no loop and
+    makes two whatever ``iters``, arch 'unimatch' four and then one a
+    refinement)."""
+    if cfg.global_matching:
+        return 2 * cfg.num_scales + (iters if cfg.refines else 0)
     return iters + 1 if cfg.regressed_first_flow else iters
 
 
 def batch_norm_calls(cfg: RAFTConfig) -> int:
     """Encoder calls a forward pass that normalise with batch statistics
     in training: the context encoder's one for 'full' and 'gma', none for
-    'small' and 'gmflow', and three for 'searaft' (the pair's context, and
-    the feature encoder once an image, each over its own batch)."""
-    return {"small": 0, "gmflow": 0, "searaft": 3}.get(cfg.arch, 1)
+    'small', 'gmflow' and 'unimatch', and three for 'searaft' (the pair's
+    context, and the feature encoder once an image, each over its own
+    batch)."""
+    return {"small": 0, "gmflow": 0, "unimatch": 0,
+            "searaft": 3}.get(cfg.arch, 1)
 
 
 def refuse_loop_state(cfg: RAFTConfig, what: str) -> None:
     """``what`` (``flow_init``, slot batching, a streaming session, early
     exit) reads or writes the state a refinement loop carries between
-    iterations.  Where the model has no loop there is none: refused by
-    name, not ignored."""
-    if not cfg.refines:
+    iterations.  Where the model has none a server can hold (a matching
+    model: no loop, or a refinement inside its one program) it is refused
+    by name, not ignored."""
+    if cfg.global_matching:
+        loop = ("no refinement loop" if not cfg.refines else
+                "its refinements inside one program")
         raise ValueError(
-            f"arch {cfg.arch!r} computes a flow in one pass, with no "
-            f"refinement loop and no state carried between iterations: "
+            f"arch {cfg.arch!r} computes a flow in one pass, with {loop} "
+            f"and no state carried between iterations: "
             f"{what} is not available for --arch {cfg.arch}")
 
 
@@ -638,12 +664,14 @@ class RAFT(nn.Module):
         stacked call: no served reply carries it, docs/SERVING.md
         "--arch searaft").
 
-        Arch 'gmflow' is another body behind this signature
-        (``models/gmflow.py``): one pass with no loop, so ``iters`` is not
-        read and ``flow_init`` is refused; two predictions in training."""
+        Archs 'gmflow' and 'unimatch' are another body behind this
+        signature (``models/gmflow.py``): one pass, so ``flow_init`` is
+        refused; 'gmflow' has no loop and does not read ``iters``, two
+        predictions in training; 'unimatch' refines ``iters`` times at 1/4,
+        ``4 + iters`` predictions."""
         cfg = self.config
 
-        if not cfg.refines:
+        if cfg.global_matching:
             # Two bodies behind one signature rather than a zero-trip
             # scan: nothing of the path below (context, hidden state,
             # pyramid, RefinementStep, the stacked upsample scan) has a
@@ -654,7 +682,7 @@ class RAFT(nn.Module):
             if flow_init is not None:
                 refuse_loop_state(cfg, "flow_init (warm start)")
             return gmflow.forward(cfg, image1, image2, test_mode, train,
-                                  freeze_bn, loss_targets)
+                                  freeze_bn, loss_targets, iters)
 
         fnet, cnet, att, start = _make_encoders(cfg)
         (net, inp, coords0, coords1, corr_state, attn,

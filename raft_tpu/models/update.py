@@ -396,14 +396,19 @@ class SEARAFTUpdateBlock(nn.Module):
 
 class MaskHead(nn.Module):
     """Convex-upsample mask head (reference update.py:122-125,135), with
-    the x0.25 scale ("to balence gradients")."""
+    the x0.25 scale ("to balence gradients").  ``factor`` and ``scale``:
+    arch 'unimatch''s head gives ``9 * 4^2`` logits for an upsampling x4
+    and has no scale in front (``scale`` 1)."""
 
     hidden_dim: int = 128
     dtype: Any = jnp.float32
+    factor: int = 8
+    scale: float = 0.25
 
     @nn.compact
     def __call__(self, net):
         mask = nn.relu(_tconv(256, 3, self.hidden_dim, self.dtype,
                               "mask_conv1")(net))
-        mask = _tconv(64 * 9, 1, 256, self.dtype, "mask_conv2")(mask)
-        return 0.25 * mask
+        mask = _tconv(self.factor ** 2 * 9, 1, 256, self.dtype,
+                      "mask_conv2")(mask)
+        return mask if self.scale == 1.0 else self.scale * mask

@@ -543,8 +543,9 @@ class InferenceEngine:
         # (serve/slots.py) for BOTH batching modes — request mode drives
         # them in lockstep so slot mode is bit-identical to it by
         # construction (the parity pin, tests/test_serve_slots.py).  A
-        # model without a refinement loop (``RAFTConfig.refines`` false)
-        # is ONE program a request, ``flow`` (_get_executable); the pair
+        # matching model (``RAFTConfig.global_matching``: archs 'gmflow'
+        # and 'unimatch', whose refinements run inside it) is ONE program
+        # a request, ``flow`` (_get_executable); the pair
         # below is never lowered for it, and what needs the loop's state
         # is refused by name here.
         self._model_cfg = model.config
@@ -564,10 +565,11 @@ class InferenceEngine:
         # flow predictions a request's programs make (iters, or iters + 1
         # where ``enc`` regresses a first flow; 1 where there is no
         # loop): quoted by the compile ring's ``program`` records
-        self._predictions = (predictions(self._model_cfg, cfg.iters)
-                             if self._model_cfg.refines else 1)
+        self._predictions = (1 if self._model_cfg.global_matching
+                             else predictions(self._model_cfg, cfg.iters))
         self._slots_mod = slots_mod
-        self._flow_jit = jax.jit(slots_mod.make_flow_fn(self._model_cfg))
+        self._flow_jit = jax.jit(slots_mod.make_flow_fn(self._model_cfg,
+                                                        cfg.iters))
         self._encode_jit = jax.jit(slots_mod.make_encode_fn(
             self._model_cfg))
         self._iter_jit = jax.jit(slots_mod.make_iter_fn(self._model_cfg))
@@ -847,8 +849,8 @@ class InferenceEngine:
         because it sets the layout of the slot state's pyramid."""
         from raft_tpu.models.raft import corr_impl_at
 
-        if not self._model_cfg.refines:
-            return "none"       # no pyramid in its one program
+        if self._model_cfg.global_matching:
+            return "none"       # no pyramid in a slot state
         return corr_impl_at(self._model_cfg, bucket[0] // 8,
                             bucket[1] // 8)
 
@@ -859,7 +861,7 @@ class InferenceEngine:
         so a program whose argument list changed is refused by name."""
         progs = ((("enc", self._encode_jit), ("iter", self._iter_jit),
                   ("stash", self._stash_jit), ("wenc", self._warm_jit))
-                 if self._model_cfg.refines
+                 if not self._model_cfg.global_matching
                  else (("flow", self._flow_jit),))
         return {prog: f"{fn.__name__}{inspect.signature(fn)}"
                 for prog, fn in progs}
@@ -1726,7 +1728,7 @@ class InferenceEngine:
         entries; the one ``flow`` program where the model has no loop):
         ``flops``/``bytes`` always, ``mfu`` when the device peak is
         known.  ``{}`` before the programs are stamped."""
-        if not self._model_cfg.refines:
+        if self._model_cfg.global_matching:
             total = self.cost_book.get((bucket, lanes, "flow"))
             if total is None:
                 return {}
@@ -1766,7 +1768,7 @@ class InferenceEngine:
         A model without a refinement loop is one program,
         ``flow(variables, a1, a2) -> flow_up``: one call a request and
         no iteration call counted."""
-        if not self._model_cfg.refines:
+        if self._model_cfg.global_matching:
             return self._get_flow_executable(bucket, batch_size)
         progs = self._get_programs(bucket, batch_size)
         iters = self.cfg.iters

@@ -251,10 +251,11 @@ def make_warm_encode_fn(model_cfg: RAFTConfig):
     return encode_warm
 
 
-def make_flow_fn(model_cfg: RAFTConfig):
+def make_flow_fn(model_cfg: RAFTConfig, iters: int = 0):
     """``flow(variables, image1, image2) -> flow_up`` (pure; the engine
-    jits/lowers it): the whole request as ONE program, for a model with
-    no refinement loop (``RAFTConfig.refines`` false, arch 'gmflow').
+    jits/lowers it): the whole request as ONE program, for a matching model
+    (``RAFTConfig.global_matching``: arch 'gmflow', with no refinement
+    loop, and arch 'unimatch', whose ``iters`` refinements run inside it).
     There is no state to admit into and no iteration to step, so neither
     program above is built for such a model; request mode calls this
     once a batch."""
@@ -263,7 +264,8 @@ def make_flow_fn(model_cfg: RAFTConfig):
     model = RAFT(model_cfg)
 
     def flow(variables, image1, image2):
-        return model.apply(variables, image1, image2, test_mode=True)[1]
+        return model.apply(variables, image1, image2, iters=iters,
+                           test_mode=True)[1]
 
     return flow
 

@@ -35,7 +35,8 @@ from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
 from raft_tpu.models.raft import (RAFT, attention_bytes, batch_norm_calls,
-                                  predictions, window_attention_at)
+                                  predictions, refine_counters,
+                                  window_attention_at)
 from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
 from raft_tpu.obs.train import TrainTelemetry
@@ -320,12 +321,17 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     attn_bytes = attention_bytes(model_cfg, cfg.batch_size,
                                  cfg.image_size[0] // 8,
                                  cfg.image_size[1] // 8)
-    # ... and which window attention the step was traced with (arch
-    # 'gmflow': 'mosaic' on a TPU, 'xla' off it; 'none' elsewhere), asked
-    # as make_train_step's trace asks it.
+    # ... and which window attention the step was traced with (archs
+    # 'gmflow' and 'unimatch', one a scale: 'mosaic' on a TPU, 'xla' off
+    # it; 'none' elsewhere), and for a refinement after a matching (arch
+    # 'unimatch') which lookup reads its volume and how many refinements
+    # run, asked as make_train_step's trace asks them.
     with data_parallel_kernels(mesh, rows_split=shard_spatial):
         attn_path = window_attention_at(model_cfg, cfg.image_size[0] // 8,
                                         cfg.image_size[1] // 8)
+        refine_facts = refine_counters(model_cfg, cfg.iters,
+                                       cfg.image_size[0] // 8,
+                                       cfg.image_size[1] // 8)
     attn_counter = telem.registry.counter(
         "raft_attention_bytes_total",
         "bytes of global-motion attention matrix built and held "
@@ -453,7 +459,7 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
                              attn_bytes=attn_bytes,
                              window_attention=attn_path,
                              predictions=n_pred, bn_calls=bn_calls,
-                             step_builds=step_builds)
+                             step_builds=step_builds, **refine_facts)
             if attn_bytes:
                 attn_counter.inc(attn_bytes, loop="train")
             if bn_calls:

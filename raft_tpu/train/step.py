@@ -33,8 +33,11 @@ def init_state(model: RAFT, tx: optax.GradientTransformation,
                rng: jax.Array, image_shape: Tuple[int, int],
                batch_size: int = 1, iters: int = 2) -> TrainState:
     """Initialize parameters + optimizer state on tiny inputs (shapes don't
-    affect conv params; iters doesn't affect the scanned weights)."""
-    H, W = image_shape
+    affect conv params; iters doesn't affect the scanned weights).  The
+    shape is rounded up to the model's ``pad_multiple`` (a model that
+    splits its maps into windows takes no other)."""
+    m = model.config.pad_multiple
+    H, W = (-(-d // m) * m for d in image_shape)
     dummy = jnp.zeros((batch_size, H, W, 3), jnp.float32)
     variables = model.init({"params": rng, "dropout": rng},
                            dummy, dummy, iters=iters, train=False)

@@ -238,6 +238,65 @@ def test_window_attention_compiles_inside_its_vmem_estimate(
         compile_under(estimate // 2)
 
 
+def test_window_attention_compiles_at_the_quarter_scale_windows(
+        one_chip, monkeypatch):
+    """Arch 'unimatch''s second scale: the 1/4 map of a 384x512 crop
+    (96x128, both images of 8 pairs) in 8x8 windows of 12x16 = 192
+    tokens, a whole window a grid step, forward and backward, plain and
+    shifted, by the same kernels as the 1/8 map's 2x2 windows."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.models import gmflow
+    from raft_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dtype = jnp.dtype(jnp.bfloat16)
+    assert gmflow.window_attention_path(96, 128, 128, dtype, 8) == "mosaic"
+    assert pallas_attention.window_block_rows(12, 16, 128, 2) == 12
+    x = jax.ShapeDtypeStruct((16, 96 * 128, 128), dtype, sharding=one_chip)
+
+    def both(q, k, v, g):
+        out = []
+        for shift in (False, True):
+            o, vjp = jax.vjp(lambda q, k, v: gmflow.window_attention(
+                q, k, v, 96, 128, shift, dtype, splits=8), q, k, v)
+            out.append((o, vjp(g)))
+        return out
+
+    text = jax.jit(both).lower(x, x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert "[1024,192," not in text     # no array of windows
+
+
+def test_one_level_lookup_compiles_at_the_quarter_map(one_chip):
+    """Arch 'unimatch''s refinement: the lookup of radius 4 into a
+    one-level volume at 96x128 (the 1/4 map of a 384x512 crop), its
+    forward and its backward, as a differentiated call keeps them."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.corr import build_corr_pyramid_flat
+    from raft_tpu.ops.pallas_corr import (pallas_pyramid_lookup,
+                                          pyramid_lookup_path)
+
+    assert pyramid_lookup_path("tpu", 96, 128, levels=1, radius=4,
+                               block_q=128, storage_bytes=2) == "mosaic"
+    fmap = jax.ShapeDtypeStruct((2, 96, 128, 128), jnp.float32)
+    pyramid = jax.eval_shape(
+        functools.partial(build_corr_pyramid_flat, num_levels=1,
+                          pad_q=128, out_dtype=jnp.bfloat16), fmap, fmap)
+    coords = jax.ShapeDtypeStruct((2, 96, 128, 2), jnp.float32)
+    pyramid, coords = _with_sharding((pyramid, coords), one_chip)
+
+    def fn(pyr, c):
+        return jax.value_and_grad(lambda p: jnp.sum(pallas_pyramid_lookup(
+            p, c, 4, 128, False, jnp.bfloat16).astype(jnp.float32)))(pyr)
+
+    text = jax.jit(fn).lower(pyramid, coords).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
 def test_window_attention_partitions_over_data_mesh(dp_mesh, monkeypatch):
     """With the maps sharded over ``data`` the window attention lowers per
     shard (``per_data_shard``), forward and backward, shifted: what a

@@ -137,7 +137,7 @@ def _faulty_gap(variables, batch, reference):
 
 def test_an_odd_block_without_its_mask_fails_the_comparison(
         variables, batch, reference, monkeypatch):
-    monkeypatch.setattr(gmflow, "shift_mask", lambda h, w: np.zeros(
+    monkeypatch.setattr(gmflow, "shift_mask", lambda h, w, splits: np.zeros(
         (4, (h // 2) * (w // 2), (h // 2) * (w // 2)), np.float32))
     loss_gap, grad_gap = _faulty_gap(variables, batch, reference)
     assert loss_gap > 100 * LOSS_RTOL and grad_gap > 10 * GRAD_RTOL
@@ -203,7 +203,7 @@ def test_counts_the_loop_and_the_engine_quote(variables):
     # two float32 (N, N) softmaxes a pair kept for the backward pass
     assert raft_mod.attention_bytes(CFG, 16, 48, 64) == 2 * 16 * 3072 ** 2 * 4
     assert not CFG.refines and CFG.pad_multiple == 16
-    assert CFG.attn_splits == gmflow.SPLITS
+    assert CFG.global_matching and CFG.attn_splits == (gmflow.SPLITS,)
     assert all(RAFTConfig.preset(a).refines
                and RAFTConfig.preset(a).pad_multiple == 8
                for a in ("full", "small", "gma", "searaft"))

@@ -76,14 +76,17 @@ def test_max_bucket_hw_matches_padder_targets():
 
 def test_pad_multiple_of_the_model():
     """A model that splits its 1/8 map into 2x2 windows (arch 'gmflow':
-    ``RAFTConfig.pad_multiple`` 16) pads Sintel to 448x1024; the four
-    architectures with a loop keep 8 and the buckets they always had."""
+    ``RAFTConfig.pad_multiple`` 16) pads Sintel to 448x1024, as does one
+    that splits its 1/4 map into 8x8 (arch 'unimatch': 32); the four
+    architectures with a RAFT loop keep 8 and the buckets they always
+    had."""
     from raft_tpu.config import ARCHS, RAFTConfig
 
     assert {a: RAFTConfig.preset(a).pad_multiple for a in ARCHS} == {
-        "full": 8, "small": 8, "gma": 8, "searaft": 8, "gmflow": 16}
+        "full": 8, "small": 8, "gma": 8, "searaft": 8, "gmflow": 16,
+        "unimatch": 32}
     x = np.zeros((436, 1024, 3), np.float32)
-    for arch, want in (("full", 440), ("gmflow", 448)):
+    for arch, want in (("full", 440), ("gmflow", 448), ("unimatch", 448)):
         m = RAFTConfig.preset(arch).pad_multiple
         padder = InputPadder(x.shape, mode="sintel", multiple=m)
         y = padder.pad_np(x)
