@@ -37,9 +37,15 @@ def parse_with_arch(parser, argv):
     """``parser.parse_args(argv)`` for a CLI that has ``--arch`` and
     ``--iters``: ``--iters`` is the length of a refinement loop, so for an
     architecture without one (``RAFTConfig.refines`` false) a value other
-    than the flag's default is refused by name instead of being ignored."""
+    than the flag's default is refused by name instead of being ignored,
+    and for one published with its own count
+    (``RAFTConfig.published_iters``) that count is the default."""
     args = parser.parse_args(argv)
     arch = arch_from_args(args)
+    iters = RAFTConfig.preset(arch).published_iters
+    if iters is not None:
+        parser.set_defaults(iters=iters)
+        args = parser.parse_args(argv)
     if (not RAFTConfig.preset(arch).refines
             and args.iters != parser.get_default("iters")):
         raise SystemExit(

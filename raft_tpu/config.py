@@ -367,6 +367,15 @@ class RAFTConfig:
         return self.arch != "gmflow"
 
     @property
+    def published_iters(self) -> Optional[int]:
+        """The refinement count the architecture is published with where
+        the CLIs' own ``--iters`` defaults (RAFT's: 12 to train, 20 for the
+        demo, 24-32 to evaluate and serve) are not it: 6 for arch
+        'unimatch' (``num_reg_refine``), else None.  The CLIs take it when
+        ``--iters`` is not given (``cli.parse_with_arch``)."""
+        return {"unimatch": 6}.get(self.arch)
+
+    @property
     def global_matching(self) -> bool:
         """Whether the flow comes from a Transformer's softmax matching
         (``models/gmflow.py``: archs 'gmflow' and 'unimatch').  Such a model

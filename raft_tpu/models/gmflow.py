@@ -46,7 +46,6 @@ Each part traces under a ``jax.named_scope``: ``gmflow_backbone``,
 
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import flax.linen as nn
@@ -449,96 +448,15 @@ def _l1_term(pred, flow_gt, vmask):
 
 def _lookup(cfg, corr_state, coords):
     """The refinement's correlation: the ``(2r+1)^2`` taps of the one-level
-    volume around ``coords`` by the lookup ``models/raft.py corr_impl_at``
-    picks for the map (the Mosaic kernel on a TPU, XLA's elsewhere), in
-    the window grid's y-major order (RAFT's lookup returns them x-major)."""
-    from raft_tpu.models.raft import corr_impl_at
-    from raft_tpu.ops.corr import corr_lookup
+    volume around ``coords`` (``models/raft.py lookup_corr``, RAFT's
+    lookup), in the window grid's y-major order (RAFT's come x-major)."""
+    from raft_tpu.models.raft import lookup_corr
 
     B, h, w, _ = coords.shape
-    impl = corr_impl_at(cfg, h, w)
-    if impl == "allpairs_pallas":
-        from raft_tpu.ops.pallas_corr import pallas_pyramid_lookup
-
-        taps = pallas_pyramid_lookup(corr_state, coords, cfg.corr_radius,
-                                     cfg.lookup_block_q, None, cfg.dtype)
-    elif impl == "allpairs":
-        taps = corr_lookup(corr_state, coords, cfg.corr_radius,
-                           cfg.resolved_corr_precision)
-    else:
-        raise ValueError(
-            f"arch {cfg.arch!r} refines from a materialized volume: "
-            f"corr_impl {cfg.corr_impl!r} is not built for it (use "
-            "'allpairs' or 'allpairs_pallas')")
+    taps = lookup_corr(cfg, corr_state, coords, cfg.dtype)
     k = 2 * cfg.corr_radius + 1
     return jnp.swapaxes(taps.reshape(B, h, w, k, k), 3, 4).reshape(
         B, h, w, k * k)
-
-
-def _split_bf16(x):
-    """``x`` (float32) as ``hi + lo``, both bfloat16: ``hi`` its rounding,
-    ``lo`` the rounding of the rest (16 bits of ``x``'s mantissa)."""
-    hi = x.astype(jnp.bfloat16)
-    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _stored_volume(spec, a, b):
-    return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.bfloat16)
-
-
-def _stored_volume_fwd(spec, a, b):
-    return _stored_volume(spec, a, b), (a, b)
-
-
-def _stored_volume_bwd(spec, res, g):
-    a, b = res
-    ins, out = spec.split("->")
-    sa, sb = ins.split(",")
-
-    def grad(other, s_other, s_self):
-        hi, lo = _split_bf16(other)
-        f = f"{out},{s_other}->{s_self}"
-        return (jnp.einsum(f, g, hi, preferred_element_type=jnp.float32)
-                + jnp.einsum(f, g, lo, preferred_element_type=jnp.float32))
-
-    return grad(b, sb, sa), grad(a, sa, sb)
-
-
-_stored_volume.defvjp(_stored_volume_fwd, _stored_volume_bwd)
-
-
-def refine_volume(cfg, f1: jax.Array, f2: jax.Array):
-    """The refinement's one-level volume of the backbone's 1/4 maps, in the
-    layout the lookup ``corr_impl_at`` picks reads (``ops/corr.py
-    build_corr_pyramid_flat`` query-minor and padded for the Mosaic kernel,
-    ``build_corr_pyramid`` query-major for XLA's) and stored in the compute
-    type: ``F1 F2^T / sqrt(C)``, the features float32 and the product at
-    ``HIGHEST`` precision, as RAFT's levels are built.
-
-    At 1/4 the volume is ``B x N^2`` (2.4 GB in bfloat16 at 96x128 and
-    batch 8), and what RAFT's builder does on the way (a float32 product
-    rounded after it, a float32 cotangent in front of the backward's two
-    products) would hold 4.8 GB more: here the product is written in the
-    storage type, and the backward multiplies the bfloat16 cotangent with
-    each float32 operand as two bfloat16 halves, so that nothing of the
-    volume's size is ever float32."""
-    from raft_tpu.models.raft import corr_impl_at
-
-    B, h, w, C = f1.shape
-    dt = jnp.dtype(cfg.resolved_corr_dtype)
-    if dt != jnp.bfloat16:
-        from raft_tpu.models.raft import _build_corr_state
-
-        return _build_corr_state(cfg, f1, f2)
-    a = f1.reshape(B, h * w, C).astype(jnp.float32) * (1.0 / float(C) ** 0.5)
-    b = f2.astype(jnp.float32)
-    if corr_impl_at(cfg, h, w) == "allpairs_pallas":
-        pad = (-h * w) % cfg.lookup_block_q
-        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-        return [_stored_volume("byxc,bqc->byxq", b, a)]
-    return [_stored_volume("bqc,byxc->bqyx", a, b)]
 
 
 class RefineStep(nn.Module):
@@ -574,7 +492,7 @@ def _refine(cfg, iters, f1, fmaps, flow):
     same projection in every iteration), the volume of the backbone's 1/4
     maps ``fmaps`` built once.  Returns the stacked flows and masks,
     ``(iters, B, h, w, 2)`` and ``(iters, B, h, w, 144)``."""
-    from raft_tpu.models.raft import _remat_wrap
+    from raft_tpu.models.raft import _build_corr_state, _remat_wrap
 
     if cfg.corr_dtype_is_quantized:
         raise ValueError(f"arch {cfg.arch!r} keeps its volume in the "
@@ -587,7 +505,7 @@ def _refine(cfg, iters, f1, fmaps, flow):
                     torch_default_init=True, in_features=C)(f1.astype(dt))
         net = jnp.tanh(proj[..., :cfg.hidden_dim])
         inp = nn.relu(proj[..., cfg.hidden_dim:])
-        corr_state = refine_volume(cfg, *fmaps)
+        corr_state = _build_corr_state(cfg, *fmaps)
     scan = nn.scan(_remat_wrap(RefineStep, cfg),
                    variable_broadcast="params",
                    split_rngs={"params": False, "dropout": True},

@@ -79,7 +79,7 @@ def corr_impl_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
     Everything that builds or samples the pyramid, or has to know which
     of the two a program will hold, asks here while it traces, where
     shapes are static: :func:`_build_corr_state` and
-    :class:`RefinementStep` (so the two layouts cannot disagree),
+    :func:`lookup_corr` (so the two layouts cannot disagree),
     ``make_train_step``'s row-split check, the serve engine's AOT key,
     ``cli/train.py``'s banner."""
     impl = cfg.resolved_corr_impl
@@ -284,46 +284,8 @@ class RefinementStep(nn.Module):
                 pyramid=corr_state, coords=coords1,
                 channels=cfg.corr_planes, radius=cfg.corr_radius,
                 block_q=cfg.lookup_block_q)
-        elif corr_impl == "allpairs":
-            corr = corr_lookup(corr_state, coords1, cfg.corr_radius,
-                               cfg.resolved_corr_precision)
-        elif corr_impl == "chunked":
-            fmap1, f2_pyramid = corr_state
-            corr = chunked_corr_lookup(fmap1, f2_pyramid, coords1,
-                                       cfg.corr_radius,
-                                       block_size=cfg.corr_block_size,
-                                       precision=cfg.resolved_corr_precision)
-        elif corr_impl == "allpairs_pallas":
-            if isinstance(corr_state[0], QuantizedLevel):
-                from raft_tpu.ops.pallas_corr import \
-                    pallas_pyramid_lookup_quantized
-
-                # Same kernel, int8/fp8 codes on the load path, dequant
-                # fused onto the tap output (linear sampling); no
-                # custom_vjp — the quantize boundary upstream is
-                # stop_gradient'd, so the lookup is primal-only.
-                corr = pallas_pyramid_lookup_quantized(
-                    corr_state, coords1, cfg.corr_radius,
-                    cfg.lookup_block_q, None, dt)
-            else:
-                from raft_tpu.ops.pallas_corr import pallas_pyramid_lookup
-
-                # Taps are consumed in cfg.dtype (the astype below) — emit
-                # them in that dtype from the kernel and skip the fp32
-                # round-trip through HBM (np.dtype is hashable, so it works
-                # as a custom_vjp static arg).
-                corr = pallas_pyramid_lookup(corr_state, coords1,
-                                             cfg.corr_radius,
-                                             cfg.lookup_block_q, None, dt)
-        elif corr_impl == "pallas":
-            from raft_tpu.ops.pallas_corr import pallas_corr_lookup
-
-            fmap1, f2_pyramid = corr_state
-            corr = pallas_corr_lookup(fmap1, tuple(f2_pyramid), coords1,
-                                      cfg.corr_radius,
-                                      min(cfg.corr_block_size, 128))
         else:
-            raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
+            corr = lookup_corr(cfg, corr_state, coords1, dt)
 
         # Tag the sampled window features so remat_policy='save_corr' can
         # keep them (and only them) for the backward pass: the window
@@ -543,7 +505,9 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
 
     A materialized pyramid comes query-minor for the Mosaic lookup or
     query-major for XLA's, as :func:`corr_impl_at` picks for this map's
-    shape; :class:`RefinementStep` asks it the same."""
+    shape; :func:`lookup_corr` asks it the same.  A one-level bfloat16
+    volume (arch 'unimatch' at 1/4) is written in bfloat16 by the product
+    itself (``ops/corr.py _bf16_level``)."""
     corr_impl = corr_impl_at(cfg, fmap1.shape[1], fmap1.shape[2])
     if corr_impl == "allpairs":
         # corr_dtype (storage) applies here too: the XLA lookup
@@ -565,6 +529,52 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
                 f"{corr_impl!r} path never stores the volume, so "
                 "there is nothing to quantize")
         return (fmap1, pool_fmap_pyramid(fmap2, cfg.corr_levels))
+    raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
+
+
+def lookup_corr(cfg: RAFTConfig, corr_state, coords, dt):
+    """The ``levels * (2r+1)^2`` window taps of ``corr_state`` (as
+    :func:`_build_corr_state` built it) around ``coords``, x-major,
+    dispatched on the lookup :func:`corr_impl_at` picks for the map: the
+    same question the builder asked of the same shape, so the lookup reads
+    the layout the pyramid was built in.  Shared by
+    :class:`RefinementStep` and ``models/gmflow.py RefineStep``."""
+    corr_impl = corr_impl_at(cfg, coords.shape[1], coords.shape[2])
+    if corr_impl == "allpairs":
+        return corr_lookup(corr_state, coords, cfg.corr_radius,
+                           cfg.resolved_corr_precision)
+    if corr_impl == "chunked":
+        fmap1, f2_pyramid = corr_state
+        return chunked_corr_lookup(fmap1, f2_pyramid, coords,
+                                   cfg.corr_radius,
+                                   block_size=cfg.corr_block_size,
+                                   precision=cfg.resolved_corr_precision)
+    if corr_impl == "allpairs_pallas":
+        if isinstance(corr_state[0], QuantizedLevel):
+            from raft_tpu.ops.pallas_corr import \
+                pallas_pyramid_lookup_quantized
+
+            # Same kernel, int8/fp8 codes on the load path, dequant
+            # fused onto the tap output (linear sampling); no
+            # custom_vjp — the quantize boundary upstream is
+            # stop_gradient'd, so the lookup is primal-only.
+            return pallas_pyramid_lookup_quantized(
+                corr_state, coords, cfg.corr_radius,
+                cfg.lookup_block_q, None, dt)
+        from raft_tpu.ops.pallas_corr import pallas_pyramid_lookup
+
+        # Taps are consumed in cfg.dtype — emit them in that dtype from
+        # the kernel and skip the fp32 round-trip through HBM (np.dtype
+        # is hashable, so it works as a custom_vjp static arg).
+        return pallas_pyramid_lookup(corr_state, coords, cfg.corr_radius,
+                                     cfg.lookup_block_q, None, dt)
+    if corr_impl == "pallas":
+        from raft_tpu.ops.pallas_corr import pallas_corr_lookup
+
+        fmap1, f2_pyramid = corr_state
+        return pallas_corr_lookup(fmap1, tuple(f2_pyramid), coords,
+                                  cfg.corr_radius,
+                                  min(cfg.corr_block_size, 128))
     raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
 
 

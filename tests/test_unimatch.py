@@ -257,10 +257,11 @@ def test_local_propagation_is_an_unfold_with_zero_padding(variables):
     assert not np.allclose(got, glob)
 
 
-@pytest.mark.parametrize("impl", ["allpairs", "allpairs_pallas"])
+@pytest.mark.parametrize("impl", ["allpairs", "allpairs_pallas", "chunked"])
 def test_refinement_correlation_is_the_gather_formula(impl):
     """The one-level volume of the backbone's 1/4 maps read by the lookup
-    (XLA's, and the Mosaic kernel in the interpreter) is
+    (XLA's, the Mosaic kernel in the interpreter, and RAFT's on-demand
+    blocks, which never hold the volume) is
     ``local_correlation_with_flow``: ``F1(x) . bil(F2, x + flow + d) /
     sqrt(C)`` with the window's offsets y-major; forward and the gradient
     into both maps."""
@@ -304,6 +305,7 @@ def test_bfloat16_volume_is_rafts_build_rounded_once(impl):
     rounded once to bfloat16, and the gradients into both maps agree with
     the float32 build's to ~1e-5 (the halves keep 16 bits of the
     operand)."""
+    from raft_tpu.models.raft import _build_corr_state
     from raft_tpu.ops.corr import build_corr_pyramid, build_corr_pyramid_flat
 
     cfg = RAFTConfig.preset("unimatch", compute_dtype="bfloat16",
@@ -311,7 +313,7 @@ def test_bfloat16_volume_is_rafts_build_rounded_once(impl):
     rng = np.random.default_rng(6)
     f1 = jnp.asarray(rng.normal(size=(2, 8, 16, 128)), jnp.float32)
     f2 = jnp.asarray(rng.normal(size=(2, 8, 16, 128)), jnp.float32)
-    got, vjp = jax.vjp(lambda a, b: gmflow.refine_volume(cfg, a, b)[0],
+    got, vjp = jax.vjp(lambda a, b: _build_corr_state(cfg, a, b)[0],
                        f1, f2)
     if impl == "allpairs":
         def build(a, b):
@@ -405,6 +407,23 @@ def test_what_needs_a_loop_state_is_refused_by_name(variables):
         args = cli.parse_args(base + ["--arch", "unimatch", "--iters", "6"])
         assert args.arch == "unimatch" and args.iters == 6
     assert variables_arch(variables) == "unimatch"
+
+
+def test_clis_default_to_the_published_six_refinements():
+    """Without ``--iters`` every entry point runs the configuration's 6
+    refinements (``RAFTConfig.published_iters``), not RAFT's defaults;
+    a given ``--iters`` and the other architectures' defaults stand."""
+    from raft_tpu.cli import demo, evaluate, serve, train
+
+    for cli, base, rafts in ((train, [], 12), (serve, ["--random-init"], 32),
+                             (evaluate, ["--model", "x", "--dataset",
+                                         "chairs"], None),
+                             (demo, ["--model", "x"], 20)):
+        assert cli.parse_args(base + ["--arch", "unimatch"]).iters == 6
+        assert cli.parse_args(
+            base + ["--arch", "unimatch", "--iters", str(rafts or 24)]
+        ).iters == (rafts or 24)
+        assert cli.parse_args(base + ["--arch", "full"]).iters == rafts
 
 
 def test_served_flow_is_the_eval_flow_at_a_pad_multiple_of_32(variables):
