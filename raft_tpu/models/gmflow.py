@@ -486,11 +486,13 @@ class RefineStep(nn.Module):
         return flow, (flow, mask)
 
 
-def _refine(cfg, iters, f1, fmaps, flow):
+def _refine(cfg, iters, f1, fmaps, flow, train):
     """``iters`` refinements from ``flow`` at 1/4: ``[net, inp]`` projected
     once from scale 2's Transformer output ``f1`` (UniMatch computes the
     same projection in every iteration), the volume of the backbone's 1/4
-    maps ``fmaps`` built once.  Returns the stacked flows and masks,
+    maps ``fmaps`` built once (in a training forward, ``train``, as
+    ``models/raft.py _build_corr_state`` builds it for a differentiated
+    lookup).  Returns the stacked flows and masks,
     ``(iters, B, h, w, 2)`` and ``(iters, B, h, w, 144)``."""
     from raft_tpu.models.raft import _build_corr_state, _remat_wrap
 
@@ -505,7 +507,7 @@ def _refine(cfg, iters, f1, fmaps, flow):
                     torch_default_init=True, in_features=C)(f1.astype(dt))
         net = jnp.tanh(proj[..., :cfg.hidden_dim])
         inp = nn.relu(proj[..., cfg.hidden_dim:])
-        corr_state = _build_corr_state(cfg, *fmaps)
+        corr_state = _build_corr_state(cfg, *fmaps, train)
     scan = nn.scan(_remat_wrap(RefineStep, cfg),
                    variable_broadcast="params",
                    split_rngs={"params": False, "dropout": True},
@@ -593,11 +595,11 @@ def _convex_terms(cfg, flows, masks, flow_gt, vmask, factor):
 
 
 def _refined_outputs(cfg, iters, preds, flow, f1, fmaps, test_mode,
-                     loss_targets):
+                     loss_targets, train):
     """What arch 'unimatch' returns after its scales: the refinements, then
     ``(flow_low, flow_up)`` in test mode, the ``4 + iters`` predictions
     stacked, or their loss terms and metrics."""
-    flows, masks = _refine(cfg, iters, f1, fmaps, flow)
+    flows, masks = _refine(cfg, iters, f1, fmaps, flow, train)
     if test_mode:
         return flows[-1], convex_upsample(
             flows[-1], masks[-1].astype(jnp.float32), factor=4)
@@ -660,7 +662,7 @@ def forward(cfg, image1, image2, test_mode: bool, train: bool,
     if cfg.refines:
         fmaps = (x[-1][:B], x[-1][B:])
         return _refined_outputs(cfg, iters, preds, flow_low, f1, fmaps,
-                                test_mode, loss_targets)
+                                test_mode, loss_targets, train)
     (flow, _), = preds
     mask = Upsampler(dt, name="upsampler")(flow_low, f1)
 

@@ -36,9 +36,10 @@ channel order matching the reference.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import flax.linen as nn
+import flax.struct
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -98,6 +99,71 @@ def corr_impl_at(cfg: RAFTConfig, h8: int, w8: int) -> str:
             cfg.resolved_corr_dtype, 1),
         rows_split=image_rows_split())
     return "allpairs_pallas" if path == "mosaic" else "allpairs"
+
+
+@flax.struct.dataclass
+class WindowCorrState:
+    """A materialized pyramid built from stopped features, with the
+    features it was built from: what :func:`lookup_corr` hands
+    ``pallas_pyramid_lookup_windows``, whose backward returns the
+    features' gradients over the windows read instead of the pyramid's
+    dense cotangent.  ``fmap2_levels``: ``fmap2`` pooled as the pyramid's
+    target dims are.  The maps are carried in float32, so that the
+    gradients of the refinement's lookups sum in float32 and are rounded
+    once to the maps' own dtype; ``bf16_values``: they hold bfloat16
+    values, which the kernels take whole."""
+
+    pyramid: Sequence[jax.Array]
+    fmap1: jax.Array
+    fmap2_levels: Sequence[jax.Array]
+    bf16_values: bool = flax.struct.field(pytree_node=False, default=False)
+
+
+def _lookup_backward(cfg: RAFTConfig, batch: int, h: int, w: int,
+                     channels: int, feature_bytes: int) -> Optional[str]:
+    """How the differentiated lookup into the volume of two ``(batch, h,
+    w, channels)`` maps returns its gradient: ``'windows'`` (the features'
+    gradients over the windows the taps read: the on-demand impls always,
+    the Mosaic lookup where ``ops.pallas_corr.lookup_backward_path``
+    finds it the cheaper), ``'volume'`` (the pyramid's dense cotangent),
+    or None where no gradient reaches the features through it (a
+    quantized pyramid).  The fused lookup-encoder samples the pyramid
+    itself and keeps the dense backward."""
+    impl = corr_impl_at(cfg, h, w)
+    if impl in ("chunked", "pallas"):
+        return "windows"
+    if cfg.corr_dtype_is_quantized:
+        return None
+    if impl != "allpairs_pallas" or cfg.fused_lookup_encoder:
+        return "volume"
+    from raft_tpu.ops.pallas_corr import lookup_backward_path
+
+    npad = -(-h * w // cfg.lookup_block_q) * cfg.lookup_block_q
+    return lookup_backward_path(
+        jax.default_backend(), [(batch, h >> lvl, w >> lvl, npad)
+                                for lvl in range(cfg.corr_levels)],
+        cfg.corr_radius, channels,
+        {"float32": 4, "bfloat16": 2}.get(cfg.resolved_corr_dtype, 1),
+        feature_bytes)
+
+
+def lookup_backward_at(cfg: RAFTConfig, batch: int, h8: int,
+                       w8: int) -> Optional[str]:
+    """What the train loop's records quote as ``lookup_backward`` for a
+    step over ``batch`` pairs at an ``(H/8, W/8)`` map: what
+    :func:`_build_corr_state` decides for the volume the refinement
+    reads (RAFT's at 1/8 from the float32 feature maps, arch 'unimatch''s
+    at 1/4 from the backbone's maps in the compute type), or None where
+    the model has no lookup (arch 'gmflow')."""
+    if cfg.global_matching:
+        if not cfg.refines:
+            return None
+        from raft_tpu.models import gmflow
+
+        return _lookup_backward(cfg, batch, 2 * h8, 2 * w8, gmflow.CHANNELS,
+                                cfg.dtype.itemsize)
+    return _lookup_backward(cfg, batch, h8, w8, 128 if cfg.small else 256,
+                            4)
 
 
 def attention_bytes(cfg: RAFTConfig, pairs: int, h8: int, w8: int) -> int:
@@ -496,7 +562,7 @@ def _make_encoders(cfg: RAFTConfig):
     return fnet, cnet, None, None
 
 
-def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
+def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2, train: bool = False):
     """Correlation state from a pair of fp32 feature maps, dispatched on
     ``corr_impl``.  Shared by the cold encode (:func:`_encode_state`)
     and the streaming warm encode (:class:`RAFTEncodeWarm`, which feeds
@@ -507,7 +573,13 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
     query-major for XLA's, as :func:`corr_impl_at` picks for this map's
     shape; :func:`lookup_corr` asks it the same.  A one-level bfloat16
     volume (arch 'unimatch' at 1/4) is written in bfloat16 by the product
-    itself (``ops/corr.py _bf16_level``)."""
+    itself (``ops/corr.py _bf16_level``).
+
+    In a training forward (``train``: the program the step differentiates)
+    the Mosaic lookup's pyramid comes as a :class:`WindowCorrState` where
+    :func:`_lookup_backward` says "windows": built from stopped maps, the
+    maps beside it.  An inference program has no backward to choose and
+    keeps the pyramid alone."""
     corr_impl = corr_impl_at(cfg, fmap1.shape[1], fmap1.shape[2])
     if corr_impl == "allpairs":
         # corr_dtype (storage) applies here too: the XLA lookup
@@ -516,6 +588,22 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2):
             fmap1, fmap2, cfg.corr_levels, cfg.resolved_corr_precision,
             out_dtype=jnp.dtype(cfg.resolved_corr_dtype))
     if corr_impl == "allpairs_pallas":
+        B, h, w, C = fmap1.shape
+        if train and _lookup_backward(cfg, B, h, w, C,
+                                      fmap1.dtype.itemsize) == "windows":
+            # the volume is forward only: its lookup sends the gradient
+            # to the features, over the windows it read
+            stop = jax.lax.stop_gradient
+            f32 = jnp.float32
+            return WindowCorrState(
+                build_corr_pyramid_flat(
+                    stop(fmap1), stop(fmap2), cfg.corr_levels,
+                    cfg.resolved_corr_precision, pad_q=cfg.lookup_block_q,
+                    out_dtype=jnp.dtype(cfg.resolved_corr_dtype)),
+                fmap1.astype(f32),
+                tuple(lvl.astype(f32) for lvl in
+                      pool_fmap_pyramid(fmap2, cfg.corr_levels)),
+                bf16_values=fmap1.dtype == jnp.bfloat16)
         return build_corr_pyramid_flat(
             fmap1, fmap2, cfg.corr_levels, cfg.resolved_corr_precision,
             pad_q=cfg.lookup_block_q,
@@ -550,6 +638,14 @@ def lookup_corr(cfg: RAFTConfig, corr_state, coords, dt):
                                    block_size=cfg.corr_block_size,
                                    precision=cfg.resolved_corr_precision)
     if corr_impl == "allpairs_pallas":
+        if isinstance(corr_state, WindowCorrState):
+            from raft_tpu.ops.pallas_corr import \
+                pallas_pyramid_lookup_windows
+
+            return pallas_pyramid_lookup_windows(
+                corr_state.pyramid, corr_state.fmap1,
+                corr_state.fmap2_levels, coords, cfg.corr_radius,
+                cfg.lookup_block_q, None, dt, corr_state.bf16_values)
         if isinstance(corr_state[0], QuantizedLevel):
             from raft_tpu.ops.pallas_corr import \
                 pallas_pyramid_lookup_quantized
@@ -615,7 +711,7 @@ def _encode_state(cfg: RAFTConfig, fnet, cnet, att, start, image1, image2,
         fmap1 = fmaps[:B].astype(jnp.float32)
         fmap2 = fmaps[B:].astype(jnp.float32)
 
-    corr_state = _build_corr_state(cfg, fmap1, fmap2)
+    corr_state = _build_corr_state(cfg, fmap1, fmap2, train)
 
     _, H8, W8, _ = fmap1.shape
     if start is not None:

@@ -1174,6 +1174,401 @@ def _pyr_bwd(radius, block_q, interpret, out_dtype, residuals, g):
 _pyramid_lookup.defvjp(_pyr_fwd, _pyr_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The differentiated lookup that takes the FEATURES' gradients
+#
+# A level of the volume is linear in each feature map,
+# ``C[q, y, x] = <f1[q], f2[y, x]> / sqrt(C)``, so the gradients a lookup
+# sends back are sums over the windows it read:
+#
+#     df1[q]    = sum_{y,x} D[q, y, x] f2[y, x] / sqrt(C)
+#     df2[y, x] = sum_q     D[q, y, x] f1[q]    / sqrt(C)
+#
+# where ``D[q]`` is the tap cotangent of query ``q`` spread bilinearly over
+# the (k+1)^2 positions of its window.  ``_pyr_bwd`` writes ``D`` as a
+# dense volume-sized array, which XLA then sums over the iterations and
+# multiplies back into the features.  Here ``D`` lives in VMEM one query
+# block at a time, over the rows the block's windows reach, and goes
+# straight into two MXU products with the features.
+# ---------------------------------------------------------------------------
+
+# Volume rows a product of the window backward takes at once (f2 rows are
+# a leading dim, so any count tiles), and its query block: swept on a v5e
+# over rows 4, 8, 16 and blocks 128, 256 (scripts/bench_lookup_backward.py).
+# At 96x128 x 1 level (batch 8, C 128, bfloat16), where the window path
+# runs, 4 and 256 were the fastest, 3.09 ms a call (3.15 at 8 and 256,
+# 3.40 at 8 and 128); at 46x62 x 4 levels (batch 16, C 256, float32),
+# where the dense backward is the cheaper, 4.34 (4.24 at 8 and 256).
+_WIN_ROWS = 4
+_WIN_BLOCK_Q = 256
+
+
+def _win_width(wl: int, k: int):
+    """``(wp, nx)`` of a level ``wl`` wide: ``wp`` the width padded to the
+    bfloat16 sublane tile (the features' and ``D``'s rows), ``nx`` the
+    columns of the local frame a window's ``k + 1`` columns fall in (whole
+    8-column tiles), or 0 where the level is no wider than that."""
+    wp = -(-wl // 16) * 16
+    nx = 8 * (-(-(k + 8) // 8))
+    return wp, (nx if wp > nx else 0)
+
+
+def _win_bwd_kernel(*refs, lvl, hl, k, rows, nparts, d_halves, inv_scale):
+    """``df1`` of one query block and its share of ``df2`` for one level.
+
+    refs = [c, g, f1 parts.., f2 parts.., df1, df2, h, d(, loc)]: ``c``
+    ``(1, 2, BQ)`` level-0 centres, ``g`` ``(1, k*k, BQ)`` the level's tap
+    cotangent, ``f1`` parts ``(1, BQ, C)`` and ``f2`` parts ``(1, Hp, wp,
+    C)`` bfloat16 (the features, or their high and low halves), ``df1``
+    ``(1, BQ, C)``, ``df2`` ``(1, Hp, wp, C)`` float32, resident across the
+    query blocks of an image; scratch ``h`` ``(k + 1, wp, BQ)``: window row
+    ``m`` of every lane at full width, ``d`` ``(Hp + 2k, wp, BQ)``: ``D`` by
+    volume row, offset by ``k`` so rows a window has outside the map land
+    in a margin nothing reads; ``loc`` ``(k + 1, nx, BQ)``: the window rows
+    in the lane's local frame.
+
+    **x stage.**  A lane's window columns ``floor(cx) - r .. + k`` lie in
+    ``nx / 8`` consecutive 8-column tiles from ``t0``; the tap cotangent
+    is spread over those ``nx`` columns only (``L_j``, the y tap ``j``'s
+    bilinear x mix), then the two y weights give window row ``m``,
+    ``(1 - fy) L_m + fy L_{m-1}``, which a select over the level's tiles
+    puts at full width.  **y stage**, as the rolled forward's: one pass a
+    distinct ``y0 = floor(cy)`` of the block writes the ``k + 1`` window
+    rows of the lanes that start there into ``d``; rows outside the
+    windows stay zero.  **Products**: over the row tiles the windows
+    reach, ``D`` against the feature parts, float32 accumulation, ``D``
+    as a high and a low bfloat16 half where ``d_halves`` (a float32
+    volume, whose dense cotangent is float32), else rounded to bfloat16
+    as a bfloat16 volume's dense cotangent is: ``df1 += D^T f2`` for the block,
+    ``df2[rows] += D f1``; ``D`` carries the volume's ``1/sqrt(C)``."""
+    c_ref, g_ref = refs[:2]
+    f1_refs = refs[2:2 + nparts]
+    f2_refs = refs[2 + nparts:2 + 2 * nparts]
+    df1_ref, df2_ref, h_ref, d_ref = refs[2 + 2 * nparts:6 + 2 * nparts]
+    loc_ref = refs[6 + 2 * nparts] if len(refs) > 6 + 2 * nparts else None
+    bq = c_ref.shape[2]
+    wp = h_ref.shape[1]
+    C = df1_ref.shape[2]
+    r = (k - 1) // 2
+    lvl_div = 1.0 / (2.0 ** lvl)
+    cx = c_ref[0, 0:1, :] * lvl_div                      # (1, BQ)
+    cy = c_ref[0, 1:2, :] * lvl_div
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        df2_ref[0] = jnp.zeros(df2_ref.shape[1:], jnp.float32)
+
+    y0, live, b, top = _window_span(cy, hl, r)
+    npass = (top - b).astype(jnp.int32) + 1              # <= 0: no live lane
+    df1_ref[0] = jnp.zeros((bq, C), jnp.float32)
+
+    @pl.when(npass > 0)
+    def _():
+        # x stage: each y tap's cotangent over the lane's local columns
+        nx = loc_ref.shape[1] if loc_ref is not None else 0
+        if nx:
+            t0 = jnp.clip(jnp.floor((jnp.floor(cx) - r) * 0.125), 0.0,
+                          float((wp - nx) // 8))          # (1, BQ)
+            posx = jax.lax.broadcasted_iota(jnp.int32, (nx, bq), 0) \
+                .astype(jnp.float32) + 8.0 * t0
+        else:
+            posx = jax.lax.broadcasted_iota(jnp.int32, (wp, bq), 0) \
+                .astype(jnp.float32)
+        wx = [_tap_weight(cx, float(i - r), posx) for i in range(k)]
+        lj = [sum(wx[i] * g_ref[0, i * k + j:i * k + j + 1, :]
+                  for i in range(k)) for j in range(k)]
+        fy = cy - y0
+        w_lo = jnp.where(live, (1.0 - fy) * inv_scale, 0.0)
+        w_hi = jnp.where(live, fy * inv_scale, 0.0)
+        rows_m = [(w_lo * lj[m] if m < k else 0.0)
+                  + (w_hi * lj[m - 1] if m else 0.0) for m in range(k + 1)]
+        if nx:
+            for m, row in enumerate(rows_m):
+                loc_ref[m] = row
+
+            def spread(m, _):
+                loc = loc_ref[m]                          # (nx, BQ)
+                for t in range(wp // 8):
+                    got = jnp.zeros((8, bq), jnp.float32)
+                    for j in range(nx // 8):
+                        if 0 <= t - j <= (wp - nx) // 8:
+                            got = jnp.where(t0 == float(t - j),
+                                            loc[8 * j:8 * j + 8], got)
+                    h_ref[m, 8 * t:8 * t + 8, :] = got
+                return 0
+
+            jax.lax.fori_loop(0, k + 1, spread, 0)
+        else:
+            for m, row in enumerate(rows_m):
+                h_ref[m] = row
+
+        # the row tiles the windows reach inside the map, and what to zero
+        lo = jnp.maximum(b - r, 0.0).astype(jnp.int32)
+        hi = jnp.minimum(top + (r + 1.0), hl - 1.0).astype(jnp.int32)
+        t_lo = jax.lax.div(lo, jnp.int32(rows))
+        t_hi = jax.lax.div(hi, jnp.int32(rows))
+        base = b.astype(jnp.int32) - r + k               # d row of b - r
+        z_lo = jnp.minimum(base, k + t_lo * rows)
+        z_hi = jnp.maximum(base + npass + k, k + (t_hi + 1) * rows)
+
+        def zero(y, _):
+            d_ref[pl.ds(y, 1)] = jnp.zeros((1, wp, bq), jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(z_lo, z_hi, zero, 0)
+
+        # y stage: the lanes whose window starts at b + p
+        o = jnp.where(live, y0 - b, -1.0)
+
+        def pass_body(p, _):
+            pick = (o == p.astype(jnp.float32))[None]     # (1, 1, BQ)
+            for m in range(k + 1):
+                at = pl.ds(base + p + m, 1)
+                d_ref[at] = jnp.where(pick, h_ref[pl.ds(m, 1)], d_ref[at])
+            return 0
+
+        jax.lax.fori_loop(0, npass, pass_body, 0)
+
+        f1s = [ref[0] for ref in f1_refs]                 # (BQ, C)
+
+        def product(t, acc):
+            y = t * rows
+            dm = d_ref[pl.ds(k + y, rows)].reshape(rows * wp, bq)
+            d_hi = dm.astype(jnp.bfloat16)
+            f2s = [ref[0, pl.ds(y, rows)].reshape(rows * wp, C)
+                   for ref in f2_refs]
+            pairs = [(d_hi, i) for i in range(nparts)]
+            if d_halves:
+                pairs.append(((dm - d_hi.astype(jnp.float32))
+                              .astype(jnp.bfloat16), 0))
+            for dp, i in pairs:
+                acc = acc + jax.lax.dot_general(
+                    dp, f2s[i], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # (BQ, C)
+            part = sum(jax.lax.dot_general(
+                dp, f1s[i], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) for dp, i in pairs)
+            df2_ref[0, pl.ds(y, rows)] += part.reshape(rows, wp, C)
+            return acc
+
+        df1_ref[0] = jax.lax.fori_loop(t_lo, t_hi + 1, product,
+                                       jnp.zeros((bq, C), jnp.float32))
+
+
+def _win_vmem_bytes(hl: int, wl: int, radius: int, channels: int,
+                    block_q: int, feature_parts: int) -> int:
+    """Per-instance VMEM of the window backward at one level: the image's
+    target map (``feature_parts`` bfloat16 arrays) and its float32
+    ``df2``, both resident and double-buffered, ``D`` by volume row, the
+    full-width window rows and the query blocks."""
+    k = 2 * radius + 1
+    rows = min(_WIN_ROWS, hl)
+    hp = -(-hl // rows) * rows
+    wp, nx = _win_width(wl, k)
+    maps = 2 * hp * wp * channels * (2 * feature_parts + 4)
+    scratch = ((hp + 2 * k + k + 1) * wp + (k + 1) * nx) * block_q * 4
+    blocks = 2 * block_q * (channels * (2 * feature_parts + 4)
+                            + 4 * (k * k + 2))
+    return maps + scratch + blocks
+
+
+# What the two backwards cost, for lookup_backward_path.  The dense one
+# moves the volume-sized cotangent three times: written by the kernel,
+# read by XLA's sum over the iterations, read by the volume's product
+# (v5e HBM, 819 GB/s; a floor: on a v5e it read 9.4 ms a call against
+# 8.9 at 96x128 x 1 level, 2.8 against 1.3 at 46x62 x 4 levels, where
+# the pooled levels' backward adds its own passes).  The window kernels'
+# time grows with the queries of a level (their x stage and y passes) and
+# with the padded columns of the rows a query's window reaches,
+# (k + 1 + _WIN_ROWS) of them: a part per column and a part per column
+# and MXU channel pass (4 flops at the v5e MXU's 197 TFLOP/s).  The two
+# other constants fit the window kernels' v5e readings at those two
+# geometries, 3.09 and 4.34 ms a call.
+_DENSE_S_PER_BYTE = 3 / 819e9
+_WIN_S_PER_QUERY = 1.56e-8
+_WIN_S_PER_COL = 6.2e-12
+_WIN_S_PER_COL_CH = 2.0e-14
+
+
+def lookup_backward_path(platform: str, shapes, radius: int, channels: int,
+                         storage_bytes: int, feature_bytes: int) -> str:
+    """How a differentiated Mosaic lookup into a materialized pyramid
+    returns its gradient: ``'windows'`` (the features' gradients over the
+    windows read, :func:`pallas_pyramid_lookup_windows`) or ``'volume'``
+    (the dense cotangent of every level, :func:`_pyr_bwd`).
+
+    From what a program observes while it traces: ``shapes`` the
+    query-minor levels ``(B, hl, wl, Npad)``, the window radius, the
+    features' channels and bytes (bfloat16 features enter whole, wider
+    ones as two bfloat16 halves), the volume's storage bytes.
+    ``D`` takes one pass against each feature part, and a second (its
+    low half) for a float32 volume.
+    The dense cotangent's traffic grows as the square of the map, the
+    window kernels' work as the map; the cheaper by the estimate above,
+    where the kernels' blocks fit the VMEM budget, on a TPU, for a
+    volume stored in floats (a quantized one sends no gradient back)."""
+    if platform != "tpu" or storage_bytes not in (2, 4):
+        return "volume"
+    k = 2 * radius + 1
+    parts = 1 if feature_bytes == 2 else 2
+    passes = parts + (storage_bytes > 2)
+    dense = windows = 0.0
+    for B, hl, wl, npad in shapes:
+        if not (hl and wl):
+            continue
+        if _win_vmem_bytes(hl, wl, radius, channels, _WIN_BLOCK_Q,
+                           parts) > _PYR_LOOKUP_BUDGET:
+            return "volume"
+        dense += B * hl * wl * npad * storage_bytes * _DENSE_S_PER_BYTE
+        cols = B * npad * (k + 1 + _WIN_ROWS) * _win_width(wl, k)[0]
+        windows += B * npad * _WIN_S_PER_QUERY + cols * (
+            _WIN_S_PER_COL + _WIN_S_PER_COL_CH * channels * passes)
+    return "windows" if windows < dense else "volume"
+
+
+def _bf16_parts(x, split: bool):
+    """``x`` as bfloat16 arrays that sum to it: its high and low halves
+    where ``split``, else itself rounded (exact for a bfloat16 ``x``)."""
+    x32 = x.astype(jnp.float32)
+    hi = x32.astype(jnp.bfloat16)
+    if not split:
+        return [hi]
+    return [hi, (x32 - hi.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _win_bwd_level(lvl, f2, f1_parts, cpt, gl, k, block_q, d_halves,
+                   interpret):
+    """``(df1, df2)`` of one level, float32: ``f1_parts``
+    ``(B, Npad, C)`` bfloat16, ``cpt`` ``(B, 2, Npad)``, ``gl`` ``(B,
+    k*k, Npad)``, padded to ``block_q``; ``f2`` ``(B, hl, wl, C)``, split
+    as ``f1`` is; ``d_halves`` as :func:`_win_bwd_kernel` takes it."""
+    B, hl, wl, C = f2.shape
+    Npad = cpt.shape[2]
+    nparts = len(f1_parts)
+    rows = min(_WIN_ROWS, hl)
+    hp = -(-hl // rows) * rows
+    wp, nx = _win_width(wl, k)
+    f2_parts = [jnp.pad(p, ((0, 0), (0, hp - hl), (0, wp - wl), (0, 0)))
+                for p in _bf16_parts(f2, nparts > 1)]
+    kern = functools.partial(_win_bwd_kernel, lvl=lvl, hl=hl, k=k,
+                             rows=rows, nparts=nparts, d_halves=d_halves,
+                             inv_scale=1.0 / float(C) ** 0.5)
+    qspec = pl.BlockSpec((1, block_q, C), lambda b, i: (b, i, 0),
+                         memory_space=pltpu.VMEM)
+    mspec = pl.BlockSpec((1, hp, wp, C), lambda b, i: (b, 0, 0, 0),
+                         memory_space=pltpu.VMEM)
+    df1, df2 = tpu_pallas_call(
+        kern,
+        grid=(B, Npad // block_q),
+        in_specs=[pl.BlockSpec((1, 2, block_q), lambda b, i: (b, 0, i),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, k * k, block_q), lambda b, i: (b, 0, i),
+                               memory_space=pltpu.VMEM)]
+        + [qspec] * nparts + [mspec] * nparts,
+        out_specs=[qspec, mspec],
+        out_shape=[jax.ShapeDtypeStruct((B, Npad, C), jnp.float32),
+                   jax.ShapeDtypeStruct((B, hp, wp, C), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k + 1, wp, block_q), jnp.float32),
+                        pltpu.VMEM((hp + 2 * k, wp, block_q), jnp.float32)]
+        + ([pltpu.VMEM((k + 1, nx, block_q), jnp.float32)] if nx else []),
+        interpret=interpret,
+    )(cpt, gl, *f1_parts, *f2_parts)
+    return df1, df2[:, :hl, :wl]
+
+
+def window_grads(fmap1, fmap2_levels, coords, g, radius: int,
+                 interpret=None, d_halves: bool = True,
+                 bf16_values: bool = False):
+    """The features' gradients of a lookup whose taps got cotangent ``g``
+    ``(B, H1, W1, L*k*k)``: ``(df1, [df2 a level])`` in the features'
+    dtypes, by the window kernels above (``fmap1`` ``(B, H1, W1, C)``,
+    ``fmap2_levels`` the pooled target maps ``(B, hl, wl, C)``, ``coords``
+    ``(B, H1, W1, 2)`` level-0 centres).  ``d_halves``: ``D`` enters the
+    products as two bfloat16 halves (the precision of a float32 volume's
+    dense cotangent) or rounded once (a bfloat16 volume's).
+    ``bf16_values``: the maps, whatever their dtype, hold bfloat16
+    values and enter the products whole."""
+    if interpret is None:
+        interpret = _auto_interpret()
+    block_q = _WIN_BLOCK_Q
+    B, H1, W1, C = fmap1.shape
+    N = H1 * W1
+    k = 2 * radius + 1
+    Npad = -(-N // block_q) * block_q
+    cpt = _pad_coords_oor(coords.reshape(B, N, 2).astype(jnp.float32),
+                          Npad).transpose(0, 2, 1)
+    gt = g.reshape(B, N, -1).transpose(0, 2, 1).astype(jnp.float32)
+    gt = jnp.pad(gt, ((0, 0), (0, 0), (0, Npad - N)))
+    # bfloat16 features enter whole, wider ones as two bfloat16 halves
+    split = not bf16_values and any(
+        x.dtype != jnp.bfloat16 for x in [fmap1, *fmap2_levels])
+    f1_parts = [jnp.pad(p.reshape(B, N, C), ((0, 0), (0, Npad - N), (0, 0)))
+                for p in _bf16_parts(fmap1, split)]
+    df1 = jnp.zeros((B, N, C), jnp.float32)
+    df2s = []
+    for lvl, f2 in enumerate(fmap2_levels):
+        if not (f2.shape[1] and f2.shape[2]):
+            df2s.append(jnp.zeros_like(f2))
+            continue
+        d1, d2 = _win_bwd_level(lvl, f2, f1_parts, cpt,
+                                gt[:, lvl * k * k:(lvl + 1) * k * k],
+                                k, block_q, d_halves, interpret)
+        df1 = df1 + d1[:, :N]
+        df2s.append(d2.astype(f2.dtype))
+    return df1.reshape(fmap1.shape).astype(fmap1.dtype), df2s
+
+
+def pallas_pyramid_lookup_windows(pyramid, fmap1, fmap2_levels, coords,
+                                  radius: int = 4, block_q: int = 128,
+                                  interpret=None, out_dtype=jnp.float32,
+                                  bf16_values: bool = False):
+    """:func:`pallas_pyramid_lookup` of a pyramid built from ``fmap1`` and
+    ``fmap2_levels`` (``fmap2`` pooled as :func:`raft_tpu.ops.corr.
+    pool_fmap_pyramid` pools it: pooling the volume's target dims is
+    correlating with the pooled map), whose backward returns the
+    features' gradients over the windows the taps read
+    (:func:`window_grads`) and none to the pyramid, which the caller
+    builds from stopped features.  The forward is the same kernel's; the
+    coordinates' cotangent is zero, as there.  ``bf16_values``: as
+    :func:`window_grads` takes it."""
+    d_halves = pyramid[0].dtype.itemsize > 2
+
+    def lookup(p, f1, f2s, c):
+        return _pyramid_lookup_windows(p, f1, tuple(f2s), c, radius,
+                                       block_q, interpret, out_dtype,
+                                       d_halves, bf16_values)
+
+    return per_data_shard(lookup, (BATCH, BATCH, BATCH, BATCH))(
+        pyramid, fmap1, list(fmap2_levels), coords)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _pyramid_lookup_windows(pyramid, fmap1, fmap2_levels, coords, radius,
+                            block_q, interpret, out_dtype, d_halves,
+                            bf16_values):
+    return _pyramid_lookup(pyramid, coords, radius, block_q, interpret,
+                           out_dtype)
+
+
+def _pyr_win_fwd(pyramid, fmap1, fmap2_levels, coords, radius, block_q,
+                 interpret, out_dtype, d_halves, bf16_values):
+    out, _ = _pyr_fwd(pyramid, coords, radius, block_q, interpret,
+                      out_dtype)
+    return out, (fmap1, fmap2_levels, coords)
+
+
+def _pyr_win_bwd(radius, block_q, interpret, out_dtype, d_halves,
+                 bf16_values, residuals, g):
+    fmap1, fmap2_levels, coords = residuals
+    df1, df2s = window_grads(fmap1, fmap2_levels, coords, g, radius,
+                             interpret=interpret, d_halves=d_halves,
+                             bf16_values=bf16_values)
+    return None, df1, tuple(df2s), jnp.zeros_like(coords)
+
+
+_pyramid_lookup_windows.defvjp(_pyr_win_fwd, _pyr_win_bwd)
+
+
 def pallas_pyramid_lookup_quantized(pyramid, coords, radius: int = 4,
                                     block_q: int = 128, interpret=None,
                                     out_dtype=jnp.float32):

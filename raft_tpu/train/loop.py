@@ -35,7 +35,8 @@ from raft_tpu import chaos
 from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.data.prefetch import DevicePipeline, PipelineInterrupted
 from raft_tpu.models.raft import (RAFT, attention_bytes, batch_norm_calls,
-                                  predictions, refine_counters,
+                                  lookup_backward_at, predictions,
+                                  refine_counters,
                                   window_attention_at)
 from raft_tpu.obs import stages, trace
 from raft_tpu.obs.health import HealthMonitor
@@ -325,13 +326,18 @@ def train(model_cfg: RAFTConfig, cfg: TrainConfig,
     # 'gmflow' and 'unimatch', one a scale: 'mosaic' on a TPU, 'xla' off
     # it; 'none' elsewhere), and for a refinement after a matching (arch
     # 'unimatch') which lookup reads its volume and how many refinements
-    # run, asked as make_train_step's trace asks them.
+    # run, and how the differentiated lookup's gradient reaches the
+    # features (``lookup_backward``), asked as make_train_step's trace
+    # asks them.
     with data_parallel_kernels(mesh, rows_split=shard_spatial):
         attn_path = window_attention_at(model_cfg, cfg.image_size[0] // 8,
                                         cfg.image_size[1] // 8)
         refine_facts = refine_counters(model_cfg, cfg.iters,
                                        cfg.image_size[0] // 8,
                                        cfg.image_size[1] // 8)
+        refine_facts["lookup_backward"] = lookup_backward_at(
+            model_cfg, cfg.batch_size, cfg.image_size[0] // 8,
+            cfg.image_size[1] // 8)
     attn_counter = telem.registry.counter(
         "raft_attention_bytes_total",
         "bytes of global-motion attention matrix built and held "

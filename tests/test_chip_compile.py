@@ -297,6 +297,55 @@ def test_one_level_lookup_compiles_at_the_quarter_map(one_chip):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_differentiated_quarter_map_lookup_writes_no_volume_cotangent(
+        one_chip, monkeypatch):
+    """Arch 'unimatch''s refinement, differentiated as a train step does
+    it: the model's own corr state and lookup at the 1/4 map of a
+    384x512 crop (batch 2), two lookups, gradients of both maps.  The
+    window kernels return the maps' gradients, so no array of the dense
+    cotangent's shape ``bf16[2,96,128,12288]`` is written by a kernel or
+    summed: the volume's build is the only thing of that shape."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.models.raft import (WindowCorrState, _build_corr_state,
+                                      lookup_corr)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = RAFTConfig.preset("unimatch", compute_dtype="bfloat16")
+    fmap = jax.ShapeDtypeStruct((2, 96, 128, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    coords = jax.ShapeDtypeStruct((2, 96, 128, 2), jnp.float32,
+                                  sharding=one_chip)
+
+    def loss(f1, f2, c):
+        state = _build_corr_state(cfg, f1, f2, True)
+        assert isinstance(state, WindowCorrState)
+        return sum(jnp.sum(jnp.square(lookup_corr(
+            cfg, state, c + i, jnp.bfloat16).astype(jnp.float32)))
+            for i in range(2))
+
+    # an inference program has no backward to choose: the pyramid alone
+    assert not isinstance(jax.eval_shape(
+        lambda a, b: _build_corr_state(cfg, a, b), fmap, fmap),
+        WindowCorrState)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        fmap, fmap, coords).compile().as_text()
+    made = re.findall(r"= bf16\[2,96,128,12288\]\{[^}]*\} ([\w-]+)\(",
+                      text)
+    assert made and len(made) <= 2, made
+    assert "custom-call" not in made and "add" not in made, made
+    # two forward lookups, two window backwards (df1, df2 in float32)
+    assert len(re.findall(r"= bf16\[2,81,12288\]\{[^}]*\} custom-call",
+                          text)) == 2
+    assert len(re.findall(r"= \(f32\[2,12288,128\]\{[^}]*\}, "
+                          r"f32\[2,96,128,128\]\{[^}]*\}\) custom-call",
+                          text)) == 2
+
+
 def test_window_attention_partitions_over_data_mesh(dp_mesh, monkeypatch):
     """With the maps sharded over ``data`` the window attention lowers per
     shard (``per_data_shard``), forward and backward, shifted: what a

@@ -15,7 +15,11 @@ samples the pyramid asks there while it traces.  Pinned here:
 - the serve program pair (``encode_admit`` + ``iter_step``) built with
   the Mosaic lookup (interpreted) against the XLA pair, both radii;
 - ``engine.stats()["lookup"]``, the ``compile`` ring's ``program`` record,
-  and the AOT artifact's refusal of programs built with another lookup.
+  and the AOT artifact's refusal of programs built with another lookup;
+- how a differentiated lookup's gradient reaches the features
+  (``ops/pallas_corr.lookup_backward_path``: the window kernels or the
+  dense volume cotangent), what the model hands it, and the
+  ``lookup_backward`` field of the train loop's records.
 """
 
 import jax
@@ -26,6 +30,7 @@ import pytest
 from raft_tpu.config import RAFTConfig
 from raft_tpu.models.raft import corr_impl_at
 from raft_tpu.ops.pallas_corr import (_PYR_LOOKUP_BUDGET,
+                                      lookup_backward_path,
                                       pyramid_lookup_path,
                                       pyramid_lookup_vmem_bytes)
 
@@ -59,6 +64,77 @@ def test_lookup_path_by_platform_and_shape(platform, h8, w8, radius,
     assert pyramid_lookup_path(platform, h8, w8, levels=4, radius=radius,
                                block_q=block_q, storage_bytes=store,
                                rows_split=True) == "xla"
+
+
+def _levels(batch, h, w, levels):
+    npad = -(-h * w // 128) * 128
+    return [(batch, h >> lvl, w >> lvl, npad) for lvl in range(levels)]
+
+
+# (platform, level shapes, channels, storage bytes, feature bytes) -> how
+# the gradient goes back.  96x128 x 1 level is arch 'unimatch''s volume at
+# 1/4 of a 384x512 crop (batch 8, bfloat16 maps); 46x62 x 4 levels RAFT's
+# at 1/8 of the chairs crop (batch 16, float32 maps).
+@pytest.mark.parametrize("platform,shapes,channels,store,feat,want", [
+    ("tpu", _levels(8, 96, 128, 1), 128, 2, 2, "windows"),
+    ("tpu", _levels(2, 96, 128, 1), 128, 2, 2, "windows"),
+    ("tpu", _levels(16, 46, 62, 4), 256, 2, 4, "volume"),
+    ("cpu", _levels(8, 96, 128, 1), 128, 2, 2, "volume"),
+    ("tpu", _levels(8, 96, 128, 1), 128, 1, 2, "volume"),      # int8 codes
+    ("tpu", _levels(1, 400, 800, 1), 256, 2, 4, "volume"),     # over VMEM
+])
+def test_lookup_backward_path_by_shape(platform, shapes, channels, store,
+                                       feat, want):
+    assert lookup_backward_path(platform, shapes, 4, channels, store,
+                                feat) == want
+
+
+@pytest.mark.parametrize("arch,backend,hw,kw,want", [
+    ("unimatch", "tpu", (48, 64), {}, "windows"),
+    ("unimatch", "cpu", (48, 64), {}, "volume"),
+    ("full", "tpu", (46, 62), {}, "volume"),
+    ("full", "tpu", (46, 62), {"corr_impl": "chunked"}, "windows"),
+    ("full", "tpu", (46, 62), {"corr_dtype": "int8"}, None),
+    ("gmflow", "tpu", (48, 64), {}, None),
+])
+def test_model_asks_how_the_lookup_gradient_goes_back(arch, backend, hw, kw,
+                                                      want, monkeypatch):
+    from raft_tpu.models.raft import lookup_backward_at
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = RAFTConfig.preset(arch, compute_dtype="bfloat16", **kw)
+    batch = 8 if arch == "unimatch" else 16
+    assert lookup_backward_at(cfg, batch, *hw) == want
+
+
+@pytest.mark.parametrize("arch,backend,size,want", [
+    ("unimatch", "tpu", (384, 512), "windows"),
+    ("unimatch", "cpu", (384, 512), "volume"),
+    ("full", "tpu", (368, 496), "volume"),
+    ("gmflow", "tpu", (384, 512), None),
+])
+def test_train_records_name_the_lookup_backward(arch, backend, size, want,
+                                                tmp_path, monkeypatch):
+    """Every ``train`` record of the stage clock says how the step's
+    differentiated lookup sends its gradient back (the step is a stub:
+    nothing compiles)."""
+    from test_obs import _slow_batches, _stub_loop
+
+    from raft_tpu.config import TrainConfig
+    from raft_tpu.obs import stages
+    from raft_tpu.train import loop as loop_mod
+
+    _stub_loop(monkeypatch, loop_mod)
+    monkeypatch.delenv("RAFT_TELEMETRY_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = TrainConfig(name="t", num_steps=2, batch_size=8, image_size=size,
+                      iters=2, val_freq=100, log_freq=2,
+                      ckpt_dir=str(tmp_path / "ck"))
+    loop_mod.train(RAFTConfig.preset(arch, compute_dtype="bfloat16"), cfg,
+                   batches=_slow_batches(2, 8, (16, 16)),
+                   telemetry_dir=None)
+    records = stages.recent("train")[-2:]
+    assert [r["lookup_backward"] for r in records] == [want, want]
 
 
 def test_lookup_residency_counts_the_level0_block_twice():
